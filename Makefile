@@ -7,9 +7,14 @@ proto:
 	       --python_out=seldon_core_tpu/proto \
 	       seldon_core_tpu/proto/prediction.proto
 
+# no -march=native: the tree is copied to other hosts (the chip tool), and a
+# binary built for this CPU could fault there.  NATIVE_OUT lets the tests
+# build into a temp dir instead of leaving a binary in the package.
+NATIVE_OUT ?= seldon_core_tpu/_native
+
 native:
-	mkdir -p seldon_core_tpu/_native
-	g++ -O3 -march=native -shared -fPIC -o seldon_core_tpu/_native/libsctcodec.so csrc/codec.cpp
+	mkdir -p $(NATIVE_OUT)
+	g++ -O3 -shared -fPIC -o $(NATIVE_OUT)/libsctcodec.so csrc/codec.cpp
 
 test:
 	$(PYTHON) -m pytest tests/ -x -q

@@ -3,6 +3,9 @@
 Loads ``seldon_core_tpu/_native/libsctcodec.so`` when present (``make
 native``); every entry point has a pure-Python answer, so the package works
 without the native build — the binding only changes speed, never behavior.
+The binary is git-ignored and built without ``-march=native``, so a tree
+copied to another host runs it; which codec a server runs is reported on
+``/stats/warmup`` (``device.native_codec``).
 """
 
 from __future__ import annotations
@@ -21,13 +24,13 @@ _LIB_PATH = os.path.join(
 _lib = None
 
 
-def _load() -> None:
+def _load(path: str = _LIB_PATH) -> None:
     global _lib
-    if not os.path.exists(_LIB_PATH):
+    if not os.path.exists(path):
         _lib = None
         return
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(path)
         lib.sct_parse_dense.restype = ctypes.c_longlong
         lib.sct_parse_dense.argtypes = [
             ctypes.c_char_p, ctypes.c_size_t,
@@ -53,9 +56,10 @@ def available() -> bool:
     return _lib is not None
 
 
-def reload() -> bool:
-    """Re-probe for the .so (e.g. after an on-demand ``make native``)."""
-    _load()
+def reload(path: str = _LIB_PATH) -> bool:
+    """Re-probe for the .so (e.g. after an on-demand ``make native``);
+    ``path`` loads a build kept outside the package directory."""
+    _load(path)
     return _lib is not None
 
 

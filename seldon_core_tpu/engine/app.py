@@ -32,7 +32,11 @@ from seldon_core_tpu.contract import (
 from seldon_core_tpu import chaos
 from seldon_core_tpu import disagg as disagg_mod
 from seldon_core_tpu import qos
-from seldon_core_tpu.engine.service import PredictionService, load_predictor_spec
+from seldon_core_tpu.engine.service import (
+    PredictionService,
+    load_co_predictor_specs,
+    load_predictor_spec,
+)
 from seldon_core_tpu.graph.units import GraphUnitError
 from seldon_core_tpu.obs import (
     LOOP_LAG,
@@ -132,6 +136,13 @@ class EngineApp:
         self._warmup_error: BaseException | None = None
         self._warmup_task: asyncio.Task | None = None
         self._warmup_total_s: float | None = None
+        # what the graph's JAX units serve on (utils/device.py), set at
+        # startup; None for a graph with no device unit, which must never
+        # initialise JAX (it would take the chip from a co-located engine)
+        self.device: dict | None = None
+        # XLA compile requests seen when readiness flipped: /stats/warmup
+        # reports how many came AFTER it (a warmed server must show zero)
+        self._compiles_at_ready: int | None = None
         self._profile_dir: str | None = None
         # ingress-tier response cache: bound at startup, and ONLY when the
         # whole graph is deterministic (a randomized router poisons
@@ -267,6 +278,26 @@ class EngineApp:
         for svc in self.co_services:
             await svc.start()
         self._register_packed_units()
+        if any(
+            jax_units(svc.predictor.graph)
+            for svc in (self.service, *self.co_services)
+        ):
+            from seldon_core_tpu.executor.batcher import _chip_peak
+            from seldon_core_tpu.utils.device import (
+                serving_device,
+                xla_compile_count,
+            )
+
+            xla_compile_count()  # start counting before warmup compiles
+            self.device = serving_device()
+            # the MFU gauges' denominator: a TPU with no published peaks
+            # fails the boot here instead of serving without the gauge
+            _chip_peak()
+            log.info(
+                "serving on platform=%(platform)s device_kind=%(device_kind)r "
+                "device_count=%(device_count)d native_codec=%(native_codec)s",
+                self.device,
+            )
         if self.service.response_cache is not None and self.service.graph_deterministic():
             self._resp_cache = self.service.response_cache
         if self.service.semantic_cache is not None and self.service.graph_deterministic():
@@ -294,7 +325,7 @@ class EngineApp:
         if driver is not None:
             driver.start_heartbeat()
         if os.environ.get("ENGINE_WARMUP", "1") == "0" or not self.service.warmable_units():
-            self.warmed = True
+            self._set_warmed()
         else:
             # warm in the background so liveness (/ping) answers while the
             # compiles run; /ready stays 503 until every bucket is compiled
@@ -333,12 +364,19 @@ class EngineApp:
             log.info(
                 "warmup complete in %.1fs: %s", self._warmup_total_s, report
             )
-            self.warmed = True
+            self._set_warmed()
         except asyncio.CancelledError:
             raise
         except BaseException as e:
             self._warmup_error = e
             log.exception("warmup failed; readiness stays false")
+
+    def _set_warmed(self) -> None:
+        if self.device is not None:
+            from seldon_core_tpu.utils.device import xla_compile_count
+
+            self._compiles_at_ready = xla_compile_count()
+        self.warmed = True
 
     async def _cleanup(self, app: web.Application) -> None:
         if self._warmup_task is not None and not self._warmup_task.done():
@@ -928,11 +966,41 @@ class EngineApp:
 
     async def stats_warmup(self, request: web.Request) -> web.Response:
         """Compile-warmup plane state: readiness, per-unit programs
-        compiled + wall seconds, total warmup time.  Readiness stays 503
-        until every (bucket, program) pair is compiled, so a user request
-        can never pay a first-touch XLA compile."""
+        compiled + wall seconds, total warmup time, and the device the
+        programs were compiled for (``platform`` / ``device_kind`` /
+        ``device_count`` as JAX reports them, whether the native codec is
+        loaded, per-device bytes in use where the backend reports them).
+        Readiness stays 503 until every (bucket, program) pair is
+        compiled, so a user request can never pay a first-touch XLA
+        compile."""
         snap = self.service.warmup_snapshot()
+        device = self.device
+        if device is not None:
+            import jax
+
+            from seldon_core_tpu.utils.device import xla_compile_count
+
+            n = xla_compile_count()
+            device = {
+                **device,
+                "xla_compiles": n,
+                "xla_compiles_since_ready": (
+                    n - self._compiles_at_ready
+                    if self._compiles_at_ready is not None
+                    else None
+                ),
+                "memory": [
+                    {
+                        "id": d.id,
+                        "bytes_in_use": st.get("bytes_in_use"),
+                        "peak_bytes_in_use": st.get("peak_bytes_in_use"),
+                    }
+                    for d in jax.local_devices()
+                    if (st := d.memory_stats())
+                ],
+            }
         snap.update(
+            device=device,
             warmed=self.warmed,
             error=(
                 str(self._warmup_error)
@@ -1789,6 +1857,20 @@ class EngineApp:
         return web.json_response({"chaos": chaos.snapshot()})
 
 
+def jax_units(graph) -> list[str]:
+    """Names of the graph's units that compile for and hold the device."""
+    from seldon_core_tpu.graph.spec import Implementation
+
+    device_impls = (Implementation.JAX_MODEL, Implementation.JAX_GENERATIVE)
+    names, stack = [], [graph]
+    while stack:
+        unit = stack.pop()
+        if unit.implementation in device_impls:
+            names.append(unit.name)
+        stack.extend(unit.children)
+    return names
+
+
 def main(argv: list[str] | None = None) -> None:
     parser = argparse.ArgumentParser(description="seldon-core-tpu engine")
     parser.add_argument("--port", type=int, default=int(os.environ.get("ENGINE_SERVER_PORT", "8000")))
@@ -1797,15 +1879,26 @@ def main(argv: list[str] | None = None) -> None:
         "--workers",
         type=int,
         default=int(os.environ.get("ENGINE_WORKERS", "1")),
-        help="worker processes sharing the ports via SO_REUSEPORT. Use >1 "
-        "only for CPU-bound graphs (stubs, routing): a JAX_MODEL graph "
-        "owns the TPU chip and must stay at 1 (batching provides its "
-        "concurrency)",
+        help="worker processes sharing the ports via SO_REUSEPORT. >1 is "
+        "for CPU-bound graphs (stubs, routing) only: a graph with a "
+        "JAX_MODEL or JAX_GENERATIVE unit owns the chip, one process per "
+        "chip, and is refused (batching provides its concurrency)",
     )
     args = parser.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO)
     if args.workers > 1:
+        device_units = [
+            name
+            for spec in (load_predictor_spec(), *load_co_predictor_specs())
+            for name in jax_units(spec.graph)
+        ]
+        if device_units:
+            parser.error(
+                f"--workers {args.workers} with device units {device_units}: "
+                "each worker would build the graph and claim the chip, and "
+                "a chip belongs to one process.  Run one worker."
+            )
         # The reference engine is a multithreaded JVM on 16 cores
         # (docs/benchmarking.md:19-36); the Python equivalent of that CPU
         # budget is processes, kernel-balanced across a shared port.
@@ -1841,17 +1934,23 @@ def _serve(port: int, grpc_port: int, reuse_port: bool) -> None:
 
         init_driver(mesh_cfg.is_coordinator)
     predictor = load_predictor_spec()
-    service = PredictionService(
-        predictor, deployment_name=os.environ.get("SELDON_DEPLOYMENT_ID", "")
-    )
     # chip packing (docs/PACKING.md): ENGINE_CO_PREDICTORS co-boots extra
     # deployments in this process; they time-share the device via the
     # arbiter instead of each claiming a chip
-    from seldon_core_tpu.engine.service import load_co_predictor_specs
+    co_specs = load_co_predictor_specs()
+    if any(jax_units(spec.graph) for spec in (predictor, *co_specs)):
+        # before the first JAX call: the graph build below compiles
+        from seldon_core_tpu.utils.device import configure_compile_cache
 
+        log.info(
+            "compile cache: %s",
+            configure_compile_cache() or "off (process pinned to the CPU)",
+        )
+    service = PredictionService(
+        predictor, deployment_name=os.environ.get("SELDON_DEPLOYMENT_ID", "")
+    )
     co_services = [
-        PredictionService(spec, deployment_name=spec.name)
-        for spec in load_co_predictor_specs()
+        PredictionService(spec, deployment_name=spec.name) for spec in co_specs
     ]
     engine = EngineApp(
         service,

@@ -11,7 +11,7 @@ Two latencies matter:
 * collection latency — how long a request waits for batch-mates
   (``max_delay_ms``, one timer per step, drain via ``get_nowait``);
 * device round-trip — dispatch is sub-ms, but *materializing* a result
-  blocks for the full device (or tunnel) round trip.  The queue therefore
+  blocks for the full device round trip.  The queue therefore
   dispatches each step immediately on the event loop and fetches results on
   a thread pool with up to ``pipeline_depth`` steps in flight, so round-trip
   latency amortizes across the stream instead of serializing it.
@@ -26,6 +26,7 @@ from __future__ import annotations
 import asyncio
 import collections
 import concurrent.futures
+import functools
 import os
 import time
 from typing import Callable
@@ -46,19 +47,14 @@ from seldon_core_tpu.qos import DeadlineExceeded, QueueFull, note_deadline_miss
 from seldon_core_tpu.qos.context import get_deadline
 from seldon_core_tpu.utils.metrics import DEFAULT as DEFAULT_METRICS
 
-_peak_flops_cache: list = []  # [float | None], filled on first use
-
-
+@functools.cache
 def _chip_peak() -> float | None:
-    """Chip bf16 peak FLOP/s (None off-TPU), resolved once per process."""
-    if not _peak_flops_cache:
-        try:
-            from seldon_core_tpu.utils.roofline import chip_peak_flops
+    """Chip bf16 peak FLOP/s (None off-TPU), resolved once per process.
+    An unknown TPU raises (utils/roofline.py) — the engine resolves this
+    at startup so that is a boot failure, not a vanished MFU gauge."""
+    from seldon_core_tpu.utils.roofline import chip_peak_flops
 
-            _peak_flops_cache.append(chip_peak_flops())
-        except Exception:
-            _peak_flops_cache.append(None)
-    return _peak_flops_cache[0]
+    return chip_peak_flops()
 
 
 class BatchQueue:
@@ -369,8 +365,7 @@ class BatchQueue:
                 peak = _chip_peak()
                 if peak:
                     # MFU against DEVICE time (step minus host dispatch):
-                    # the wall view double-charges host tracing overhead to
-                    # the chip and understates it on a tunnel
+                    # the wall view charges host tracing overhead to the chip
                     self._m_mfu.set(
                         batch.shape[0] * self.flops_per_row / device_s / peak
                     )

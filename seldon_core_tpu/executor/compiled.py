@@ -154,11 +154,10 @@ class CompiledModel:
     def dispatch(self, batch: np.ndarray) -> tuple[jax.Array, int]:
         """Enqueue one padded device step WITHOUT materializing the result.
 
-        Dispatch is cheap (~sub-ms); the expensive part is the round trip
-        that :meth:`fetch` pays.  Splitting them lets the batching queue keep
-        several steps in flight, which matters enormously when the chip is
-        reached over a network tunnel (per-round-trip latency amortizes
-        across the pipeline).
+        Dispatch is cheap (~sub-ms); the expensive part is the wait for
+        the device that :meth:`fetch` pays.  Splitting them lets the
+        batching queue keep several steps in flight, so the device starts
+        the next step while the host still fetches the last one.
         """
         batch = np.asarray(batch)
         if batch.ndim == 1:

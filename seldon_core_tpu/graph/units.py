@@ -314,8 +314,10 @@ def _jax_model(parameters: dict[str, Any]) -> Any:
     want few compiled programs), ``mesh`` ("auto" or "tp=4,fsdp=2" — shards
     params over the slice per the family's logical axes), ``input_dtype``
     (warm the buckets for a non-default wire dtype, e.g. "uint8" images
-    normalized on device), plus any model-config field override (e.g.
-    ``n_classes``).
+    normalized on device), ``seq`` (token models: warm the buckets at the
+    sequence length requests arrive at — a program is compiled per length,
+    and the default example's is a placeholder), plus any model-config
+    field override (e.g. ``n_classes``).
     """
     from seldon_core_tpu.models import registry as model_registry
 

@@ -94,16 +94,40 @@ def resolve_config(family: str, preset: str | None = None, **overrides) -> Any:
     return cfg
 
 
-def _resolve_params(fam: Family, cfg: Any, params: Any, checkpoint: str | None, rng: int):
-    """Explicit params > checkpoint load > fresh init (host arrays either
-    way; the compiled wrapper casts/shards them at construction)."""
+def _resolve_params(
+    fam: Family, cfg: Any, params: Any, checkpoint: str | None, rng: int,
+    mesh: Mesh | None = None, rules: Any = None,
+):
+    """Explicit params > checkpoint load > fresh init; the compiled wrapper
+    casts/shards them at construction.  A fresh init runs under jit — one
+    program, kept by the compile cache, instead of a compile per tensor —
+    and for a mesh with the family's own shardings as the output, so each
+    device generates its shard: the whole float32 tree never sits on the
+    default device first, where a model sized for the mesh does not fit.
+    (The values are the eager, unsharded init's: threefry is
+    partitionable.)"""
     if params is not None:
         return params
     if checkpoint is not None:
         from seldon_core_tpu.executor.checkpoint import load_params
 
         return load_params(checkpoint)
-    return fam.init_params(jax.random.PRNGKey(rng), cfg)
+
+    def init():
+        return fam.init_params(jax.random.PRNGKey(rng), cfg)
+
+    shardings = None
+    if mesh is not None:
+        from seldon_core_tpu.parallel.sharding import (
+            DEFAULT_RULES,
+            param_shardings,
+        )
+
+        shapes = jax.eval_shape(init)
+        shardings = param_shardings(
+            shapes, mesh, fam.param_logical_axes(shapes), rules or DEFAULT_RULES
+        )
+    return jax.jit(init, out_shardings=shardings)()
 
 
 def build_compiled(
@@ -131,7 +155,7 @@ def build_compiled(
             f"{family!r} (config fields: "
             f"{sorted(f.name for f in dataclasses.fields(fam.config_cls))})"
         )
-    params = _resolve_params(fam, cfg, params, checkpoint, rng)
+    params = _resolve_params(fam, cfg, params, checkpoint, rng, mesh, rules)
     apply_fn = lambda p, x: fam.apply(p, x, cfg)  # noqa: E731
     extra = {} if rules is None else {"rules": rules}
     return CompiledModel(
@@ -157,6 +181,7 @@ def build_component(
     max_delay_ms: float = 2.0,
     max_queue: int | None = None,
     input_dtype: str | None = None,
+    seq: int | None = None,
     **kwargs,
 ) -> JaxModelComponent:
     if cfg is None:
@@ -172,6 +197,15 @@ def build_component(
     # (e.g. a typo'd config field) fails loudly in build_compiled
     model = build_compiled(family, preset=preset, cfg=cfg, **kwargs)
     warmup = example_input(family, cfg, 1)
+    if seq is not None:
+        # token models: the example's sequence length is a placeholder, and
+        # a program is compiled per length — warm the one requests arrive at
+        if warmup.ndim != 2 or warmup.dtype != np.int32:
+            raise TypeError(
+                f"seq applies to token models; family {family!r} takes "
+                f"{warmup.dtype} inputs of shape (batch, {warmup.shape[1:]})"
+            )
+        warmup = np.ones((1, int(seq)), np.int32)
     if input_dtype is not None:
         # serve a non-default wire dtype (e.g. uint8 images, normalized on
         # device): warmup must compile the buckets for THAT dtype, or the
@@ -295,7 +329,7 @@ def build_generative_component(
         cfg = resolve_config(family, preset, **overrides)
     elif overrides:
         raise TypeError(f"unknown generative parameters {sorted(overrides)}")
-    params = _resolve_params(fam, cfg, params, checkpoint, rng)
+    params = _resolve_params(fam, cfg, params, checkpoint, rng, mesh)
     model = GenerativeModel(
         cfg,
         params,

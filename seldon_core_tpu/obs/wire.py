@@ -4,8 +4,8 @@ Round 5's headline collapsed 4.5x with `bench.py` byte-identical and
 nothing in the repo could attribute the swing — the spans plane says WHERE
 latency went, but not whether a stage was bandwidth-bound.  This module is
 the missing layer: every transport edge records request/response bytes and
-(where the transfer is timed) an achieved-MB/s EWMA, so "the tunnel
-degraded" and "the framework regressed" become distinguishable live.
+(where the transfer is timed) an achieved-MB/s EWMA, so "the network
+path degraded" and "the framework regressed" become distinguishable live.
 
 Edges (the ``stage`` vocabulary, one :class:`WireCounter` per
 ``(stage, deployment)``):

@@ -15,9 +15,9 @@ key tiles in VMEM scratch and writes its output once on the final key
 step.  Causal masking is per-tile (fully-masked tiles skip the matmul
 entirely).
 
-On non-TPU backends (the CPU test harness) the kernel runs in Pallas
-interpret mode, so equivalence tests pin it to the dense reference
-everywhere.
+The kernel is compiled by Mosaic on every backend but the CPU, where it
+runs in Pallas interpret mode so the equivalence tests pin it to the dense
+reference.
 """
 
 from __future__ import annotations
@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from seldon_core_tpu.ops.paged_attention import mxu_operands
 
 NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
 
@@ -49,13 +51,16 @@ def _flash_kernel(
     # fully masked: skip their FLOPs entirely
     live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
 
+    cdt, prec = mxu_operands(q_ref.dtype)
+
     @pl.when(live)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        q = (q_ref[0, 0] * scale).astype(cdt)  # (bq, D)
+        k = k_ref[0, 0].astype(cdt)  # (bk, D)
+        v = v_ref[0, 0].astype(cdt)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
         if causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
@@ -68,7 +73,8 @@ def _flash_kernel(
         alpha = jnp.exp(m_prev - m_cur)
         l_cur = alpha * l_prev + p.sum(axis=-1)
         acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(cdt), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )
         m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
@@ -104,7 +110,7 @@ def flash_attention(
             f"({block_q}, {block_k})"
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = jax.default_backend() == "cpu"
     n_q = S // block_q
     n_k = Sk // block_k
     scale = 1.0 / math.sqrt(D)
@@ -137,10 +143,11 @@ def flash_attention(
 
 
 def _fit_block(s: int, preferred: int = 128) -> int:
-    """Largest divisor of ``s`` that is <= ``preferred`` — lengths that are
-    not a multiple of the preferred tile still run (a 192-token bucket
-    tiles at 96, a prime length degrades to 1 in interpret mode) instead
-    of rejecting the shape the model zoo handed us."""
+    """Largest divisor of ``s`` that is <= ``preferred`` — a length that is
+    not a multiple of the preferred tile still runs (a 192-token bucket
+    tiles at 96).  On the chip the divisor must also be a tile Mosaic
+    takes (a multiple of 8, or the whole length); one that is not — a
+    prime length, say — is a compile error there, not a fallback."""
     b = min(preferred, s)
     while s % b:
         b -= 1

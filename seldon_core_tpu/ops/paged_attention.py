@@ -13,15 +13,26 @@ time — pool bytes are read once, nothing intermediate touches HBM
 (guide: /opt/skills/guides/pallas_guide.md; the gather idiom is the
 standard TPU paged-attention pattern, the recurrence is flash decoding).
 
+Layout.  The pool is ``(NB, BS, KV, D)`` and stays that way; Mosaic takes
+a block whose last two dimensions are whole tiles or the whole dimension,
+so one grid step reads a pool block as ``(BS, KV*D)`` — every KV head of
+``BS`` positions, a free reshape of contiguous memory — and the scales as
+``(BS, KV)``.  All heads then share ONE matmul per step: the queries are
+laid out block-diagonally, row ``h*R + r`` holding head ``h``'s query in
+lanes ``[h*D, (h+1)*D)`` and zeros elsewhere, so ``Q_bd @ K_blk^T`` is
+every head's scores at once and ``P @ V_blk`` carries head ``h``'s output
+in the same lanes of row ``h*R + r`` (the wrapper keeps that diagonal).
+The zeros cost MXU passes the memory-bound decode step has to spare, and
+buy a kernel with no per-head slicing of packed tiles.
+
 Query shapes are the decode step's: ``L = 1`` for the plain step,
 ``L = 1 + spec_draft`` for the fused speculative verify pass.  Grouped
 queries attend the *un-repeated* KV heads (GQA), exactly like the XLA path.
 
-On non-TPU backends (the CPU test harness) the kernel runs in Pallas
-interpret mode, so equivalence tests pin it to the dense reference
-everywhere; :func:`paged_decode_attention_reference` is the XLA-path math
-factored out for those tests and for callers that want the fallback
-explicitly.
+The kernel is compiled by Mosaic on every backend but the CPU, where it
+runs in Pallas interpret mode so the equivalence tests pin it to the dense
+reference; :func:`paged_decode_attention_reference` is the XLA-path math
+factored out for those tests.
 """
 
 from __future__ import annotations
@@ -37,17 +48,28 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
 
 
+def mxu_operands(dtype) -> tuple:
+    """``(operand dtype, precision)`` for a kernel matmul fed ``dtype``
+    data: bfloat16 goes to the MXU as it is (exact products, float32
+    accumulation); anything else is computed in float32 at HIGHEST
+    precision, because Mosaic's default rounds float32 operands on the way
+    in and the float32 references would not be met."""
+    if dtype == jnp.bfloat16:
+        return jnp.bfloat16, None
+    return jnp.float32, jax.lax.Precision.HIGHEST
+
+
 def _paged_kernel(
     table_ref,  # (S, WB) int32 scalar-prefetch: physical block per grid step
     pos_ref,  # (S,) int32 scalar-prefetch: per-slot base position
-    q_ref,  # (1, 1, R, D) queries for this (slot, kv head)
-    k_ref,  # (1, BS, 1, D) one gathered KV block
+    q_ref,  # (1, KV*R, KV*D) block-diagonal, pre-scaled queries of one slot
+    k_ref,  # (1, BS, KV*D) one gathered KV block, every head
     v_ref,
     *refs,  # [k_scale_ref, v_scale_ref,] o_ref, m_scr, l_scr, acc_scr
     bs,
     groups,
+    rows,
     n_w,
-    scale,
     quant,
 ):
     if quant:
@@ -55,7 +77,7 @@ def _paged_kernel(
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
     s_i = pl.program_id(0)
-    w = pl.program_id(2)
+    w = pl.program_id(1)
 
     @pl.when(w == 0)
     def _init():
@@ -63,29 +85,51 @@ def _paged_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    R = q_ref.shape[2]
+    HR = q_ref.shape[1]  # KV * rows
     base = pos_ref[s_i]
     # key blocks entirely past every query position are dead weight: the
-    # furthest query sits at base + L - 1 (row R-1 is query L-1's last group)
-    live = w * bs <= base + (R - 1) // groups
+    # furthest query sits at base + L - 1 (each head's last row is query
+    # L-1's last group)
+    live = w * bs <= base + (rows - 1) // groups
+
+    # int8 pool values are exact in bfloat16, so a quantized pool rides
+    # the queries' operand dtype too
+    cdt, prec = mxu_operands(q_ref.dtype)
 
     @pl.when(live)
     def _tile():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (R, D)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (BS, D)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        q = q_ref[0].astype(cdt)  # (HR, KV*D)
+        k = k_ref[0].astype(cdt)  # (BS, KV*D)
+        v = v_ref[0].astype(cdt)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
+        )  # (HR, BS): row h*rows + r is head h's scores
         if quant:
             # per-(position, head) symmetric scales: the dequant the XLA
-            # path pays as a separate HBM-resident op happens in VMEM here
-            k = k * ks_ref[0, :, 0].astype(jnp.float32)[:, None]
-            v = v * vs_ref[0, :, 0].astype(jnp.float32)[:, None]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # (R, BS)
-        # query row r belongs to query position j = r // groups and may see
-        # pool rows [0, base + j] — the causal-speculation window
-        rows_j = jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0) // groups
-        cols = w * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
+            # path pays as a separate HBM-resident op folds into the scores
+            # and probabilities here.  The one-hot matmul spreads the
+            # (BS, KV) scale block to the (HR, BS) score layout.
+            kv = ks_ref.shape[2]
+            sdt, sprec = mxu_operands(ks_ref.dtype)
+            onehot = (
+                jax.lax.broadcasted_iota(jnp.int32, (HR, kv), 0) // rows
+                == jax.lax.broadcasted_iota(jnp.int32, (HR, kv), 1)
+            ).astype(sdt)
+            spread = functools.partial(
+                jax.lax.dot_general,
+                onehot,
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=sprec,
+            )
+            s = s * spread(ks_ref[0].astype(sdt))
+        # row h*rows + r belongs to query position j = r // groups and may
+        # see pool rows [0, base + j] — the causal-speculation window
+        rows_j = (
+            jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 0) % rows
+        ) // groups
+        cols = w * bs + jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 1)
         s = jnp.where(cols <= base + rows_j, s, NEG_INF)
         m_prev = m_scr[:, 0]
         l_prev = l_scr[:, 0]
@@ -93,8 +137,11 @@ def _paged_kernel(
         p = jnp.exp(s - m_cur[:, None])
         alpha = jnp.exp(m_prev - m_cur)
         l_cur = alpha * l_prev + p.sum(axis=-1)
+        if quant:
+            p = p * spread(vs_ref[0].astype(sdt))
         acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(cdt), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )
         m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
@@ -103,7 +150,7 @@ def _paged_kernel(
     def _emit():
         l = l_scr[:, 0]
         safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-        o_ref[0, 0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -126,6 +173,10 @@ def paged_decode_attention(
     slot's base position — query ``j`` sees pool rows ``[0, pos + j]``.
     Returns ``(S, L, H, D)`` in the query dtype.  Semantics are exactly
     :func:`paged_decode_attention_reference` (the XLA gather path).
+
+    ``interpret`` defaults to True on the CPU backend only; every other
+    backend compiles the kernel, and a shape Mosaic refuses is an error —
+    nothing falls back to the interpreter or the XLA path in its name.
     """
     S, L, H, D = q.shape
     NB, BS, KV, _ = k_pages.shape
@@ -136,50 +187,69 @@ def paged_decode_attention(
     R = L * groups
     quant = k_scale is not None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    scale = 1.0 / math.sqrt(D)
+        interpret = jax.default_backend() == "cpu"
+    scale = jnp.asarray(1.0 / math.sqrt(D), q.dtype)
     # row r = j * groups + g: query-major so r // groups recovers j
     qr = (
         q.reshape(S, L, KV, groups, D)
         .transpose(0, 2, 1, 3, 4)
         .reshape(S, KV, R, D)
     )
+    # block-diagonal queries: (S, KV, R, KV', D) is zero off KV == KV'
+    eye = jnp.eye(KV, dtype=q.dtype)
+    q_bd = (
+        (qr * scale)[:, :, :, None, :] * eye[None, :, None, :, None]
+    ).reshape(S, KV * R, KV * D)
     kernel = functools.partial(
-        _paged_kernel, bs=BS, groups=groups, n_w=WB, scale=scale, quant=quant
+        _paged_kernel, bs=BS, groups=groups, rows=R, n_w=WB, quant=quant
     )
-    in_specs = [
-        pl.BlockSpec((1, 1, R, D), lambda s, h, w, t, p: (s, h, 0, 0)),
+
+    def slot_block(s, w, t, p):
+        return (s, 0, 0)
+
+    def pool_block(s, w, t, p):
         # the gather: scalar-prefetched table entries drive the DMA source
-        pl.BlockSpec((1, BS, 1, D), lambda s, h, w, t, p: (t[s, w], 0, h, 0)),
-        pl.BlockSpec((1, BS, 1, D), lambda s, h, w, t, p: (t[s, w], 0, h, 0)),
+        return (t[s, w], 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, KV * R, KV * D), slot_block),
+        pl.BlockSpec((1, BS, KV * D), pool_block),
+        pl.BlockSpec((1, BS, KV * D), pool_block),
     ]
-    args = [qr, k_pages, v_pages]
+    args = [
+        q_bd,
+        k_pages.reshape(NB, BS, KV * D),
+        v_pages.reshape(NB, BS, KV * D),
+    ]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, BS, 1), lambda s, h, w, t, p: (t[s, w], 0, h)),
-            pl.BlockSpec((1, BS, 1), lambda s, h, w, t, p: (t[s, w], 0, h)),
+            pl.BlockSpec((1, BS, KV), pool_block),
+            pl.BlockSpec((1, BS, KV), pool_block),
         ]
         args += [k_scale, v_scale]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(S, KV, WB),
+            grid=(S, WB),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, R, D), lambda s, h, w, t, p: (s, h, 0, 0)
-            ),
+            out_specs=pl.BlockSpec((1, KV * R, KV * D), slot_block),
             scratch_shapes=[
-                pltpu.VMEM((R, 128), jnp.float32),  # running max (col 0)
-                pltpu.VMEM((R, 128), jnp.float32),  # running denom (col 0)
-                pltpu.VMEM((R, D), jnp.float32),  # output accumulator
+                pltpu.VMEM((KV * R, 128), jnp.float32),  # running max (col 0)
+                pltpu.VMEM((KV * R, 128), jnp.float32),  # running denom (col 0)
+                pltpu.VMEM((KV * R, KV * D), jnp.float32),  # accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, KV, R, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, KV * R, KV * D), q.dtype),
         interpret=interpret,
     )(jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32), *args)
+    # head h's output sits in lanes [h*D, (h+1)*D) of its own rows
+    out = jnp.diagonal(
+        out.reshape(S, KV, R, KV, D), axis1=1, axis2=3
+    )  # (S, R, D, KV)
     return (
-        out.reshape(S, KV, L, groups, D)
+        out.transpose(0, 3, 1, 2)
+        .reshape(S, KV, L, groups, D)
         .transpose(0, 2, 1, 3, 4)
         .reshape(S, L, H, D)
     )

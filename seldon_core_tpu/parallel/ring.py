@@ -25,21 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.5: top-level export, replication check via ``check_vma``
-    from jax import shard_map as _shard_map
-
-    def _shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-except ImportError:  # jax 0.4.x: experimental module, kwarg named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
 
 def _block_attend(q, k, v, q_offset, k_offset, causal, scale):
     """Dense attention of a local Q block against one KV block with global
@@ -140,10 +125,11 @@ def ring_self_attention(
     # batch stays dp-sharded through the ring; heads are gathered (ring+tp
     # jointly would need head-sharded specs — future kernel work)
     spec = P(("dp", "fsdp"), seq_axis, None, None)
-    wrapped = _shard_map_unchecked(
+    wrapped = jax.shard_map(
         functools.partial(fn, axis_name=seq_axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     return wrapped(q, k, v)

@@ -67,19 +67,24 @@ def logical_sharding(
     return NamedSharding(mesh, rules.spec(logical_axes))
 
 
-def shard_params(params, mesh: Mesh, annotations, rules: ShardingRules = DEFAULT_RULES):
-    """Place a param pytree on the mesh.
+def param_shardings(params, mesh: Mesh, annotations, rules: ShardingRules = DEFAULT_RULES):
+    """The ``NamedSharding`` of every leaf of ``params`` (arrays or shape
+    structs): ``annotations`` is a matching pytree of logical-axis tuples
+    (or ``None`` for replicated)."""
 
-    ``annotations`` is a matching pytree of logical-axis tuples (or ``None``
-    for replicated).  Returns the sharded params (device_put, zero host copy
-    beyond the first transfer).
-    """
-
-    def _put(p, ann):
+    def _sharding(_, ann):
         if ann is None:
-            sh = NamedSharding(mesh, P())
-        else:
-            sh = logical_sharding(mesh, ann, rules)
-        return jax.device_put(p, sh)
+            return NamedSharding(mesh, P())
+        return logical_sharding(mesh, ann, rules)
 
-    return jax.tree.map(_put, params, annotations, is_leaf=lambda x: x is None)
+    return jax.tree.map(
+        _sharding, params, annotations, is_leaf=lambda x: x is None
+    )
+
+
+def shard_params(params, mesh: Mesh, annotations, rules: ShardingRules = DEFAULT_RULES):
+    """Place a param pytree on the mesh (device_put, zero host copy beyond
+    the first transfer); see :func:`param_shardings` for ``annotations``."""
+    return jax.tree.map(
+        jax.device_put, params, param_shardings(params, mesh, annotations, rules)
+    )

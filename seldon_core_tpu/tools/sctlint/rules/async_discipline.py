@@ -86,9 +86,7 @@ def _fire_and_forget(src, fn) -> Iterable[Finding]:
                 and isinstance(stmt.value, ast.Call):
             f = stmt.value.func
             is_ct = (isinstance(f, ast.Attribute) and f.attr == "create_task"
-                     ) or (isinstance(f, ast.Name)
-                           and f.id in ("create_task",
-                                        "create_task_in_context"))
+                     ) or (isinstance(f, ast.Name) and f.id == "create_task")
             if not is_ct:
                 continue
             name = stmt.targets[0].id

@@ -639,9 +639,7 @@ class _ServerConn(_Conn):
                     stream_id, GRPC_STATUS_UNKNOWN, f"bad request metadata: {e}"
                 )
                 return
-            from seldon_core_tpu.utils.compat import create_task_in_context
-
-            task = create_task_in_context(asyncio.get_running_loop(), coro, ctx)
+            task = asyncio.get_running_loop().create_task(coro, context=ctx)
         else:
             task = asyncio.ensure_future(coro)
         self._tasks.add(task)

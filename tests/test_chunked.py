@@ -373,17 +373,36 @@ class TestChunkConfig:
         model = GenerativeModel(cfg, params, n_slots=2)
         assert model.decode_kernel is True
 
-    def test_kernel_disabled_on_mesh(self, tiny):
-        """The Pallas kernel does not partition over a mesh yet: a sharded
-        deployment falls back to the XLA gather path with a warning."""
+    def test_kernel_on_mesh_is_a_build_error(self, tiny):
+        """The Pallas kernel does not partition over a mesh: asking for it
+        on a sharded deployment fails at build — never a logged switch to
+        the XLA gather path under the kernel's name."""
+        from seldon_core_tpu.graph.units import GraphUnitError
         from seldon_core_tpu.parallel import best_mesh
 
         cfg, params = tiny
-        model = GenerativeModel(
-            cfg, params, n_slots=2, mesh=best_mesh(2, tp=2),
-            param_axes=llama.param_logical_axes(params), decode_kernel=True,
+        with pytest.raises(GraphUnitError, match="single-device"):
+            GenerativeModel(
+                cfg, params, n_slots=2, mesh=best_mesh(2, tp=2),
+                param_axes=llama.param_logical_axes(params),
+                decode_kernel=True,
+            )
+
+    def test_kernel_without_family_path_is_a_build_error(self, tiny):
+        import types
+
+        from seldon_core_tpu.graph.units import GraphUnitError
+
+        cfg, params = tiny
+        no_kernel = types.SimpleNamespace(
+            __name__="no_kernel_family",
+            decode_slots_paged=lambda params, tokens, cache, active, cfg: None,
         )
-        assert model.decode_kernel is False
+        with pytest.raises(GraphUnitError, match="no kernel decode path"):
+            GenerativeModel(
+                cfg, params, family_mod=no_kernel, n_slots=2,
+                decode_kernel=True,
+            )
 
 
 class TestKernelGeneration:

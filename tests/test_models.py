@@ -165,6 +165,14 @@ class TestUint8Ingest:
         assert comp.warmup_example.dtype == np.uint8
 
 
+    def test_seq_warms_token_models_at_the_served_length(self):
+        comp = registry.build_component("bert", preset="tiny", seq=48)
+        assert comp.warmup_example.shape == (1, 48)
+        assert comp.warmup_example.dtype == np.int32
+        with pytest.raises(TypeError, match="token models"):
+            registry.build_component("mlp", preset="tiny", seq=48)
+
+
 class TestRoofline:
     def test_model_roofline_reports_flops_and_time(self):
         from seldon_core_tpu.utils import roofline
@@ -183,10 +191,24 @@ class TestRoofline:
         assert out["tokens_per_s_device"] > 0
         assert out["n_params"] > 0
 
-    def test_peak_lookup_known_kinds(self):
-        from seldon_core_tpu.utils.roofline import _PEAKS
+    def test_peaks_are_keyed_by_exact_device_kind(self):
+        """``"TPU v5 lite"`` is the v5e (Cloud TPU v5e documentation); an
+        unknown TPU is an error, never a neighbour's figures or a silent
+        None; off-TPU there is no peak to compare against."""
+        import types
 
-        # marker table stays ordered most-specific-first ("v5 lite" must
-        # match before bare "v5" which is the v5p peak)
-        kinds = [m for m, _ in _PEAKS]
-        assert kinds.index("v5 lite") < kinds.index("v5")
+        from seldon_core_tpu.utils import roofline
+
+        def dev(platform, kind):
+            return types.SimpleNamespace(platform=platform, device_kind=kind)
+
+        v5e = dev("tpu", "TPU v5 lite")
+        assert roofline.chip_peak_flops(v5e) == 197e12
+        assert roofline.chip_hbm_bandwidth(v5e) == 819e9
+        for kind in ("TPU v5", "TPU v5p", "TPU v9 lite"):
+            with pytest.raises(ValueError, match="no published peaks"):
+                roofline.chip_peak_flops(dev("tpu", kind))
+            with pytest.raises(ValueError, match="no published peaks"):
+                roofline.chip_hbm_bandwidth(dev("tpu", kind))
+        assert roofline.chip_peak_flops(dev("cpu", "cpu")) is None
+        assert roofline.chip_hbm_bandwidth() is None  # the CPU test backend

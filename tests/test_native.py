@@ -1,13 +1,13 @@
 """Native codec tests: parse/format round trips, equivalence of the fast
 JSON paths with the pure-Python decoder, and graceful fallback when the
-content is not dense numeric.  Builds the .so on demand (``make native``)
-so a plain local ``pytest`` run exercises the C++ plane instead of
-silently reporting green without it; only a missing toolchain skips."""
+content is not dense numeric.  Builds the .so (``make native``) into a temp
+dir and loads it from there, so a plain local ``pytest`` run exercises the
+C++ plane without leaving a binary in the package directory for whoever
+copies the tree next; only a missing toolchain skips."""
 
 import json
 import shutil
 import subprocess
-import sys
 from pathlib import Path
 
 import numpy as np
@@ -23,33 +23,27 @@ from seldon_core_tpu.contract.codec import payload_from_dict, payload_to_dict
 from seldon_core_tpu.contract.payload import DataKind
 
 
-def _ensure_native() -> str | None:
-    """Build the codec if missing; returns a skip reason or None."""
-    if native.available():
-        return None
+@pytest.fixture(scope="module", autouse=True)
+def _native_built_in_tmp(tmp_path_factory):
+    """Build the codec into a temp dir, serve it to this module, and put
+    back whatever the package directory holds (usually nothing) after."""
     repo = Path(__file__).resolve().parent.parent
-    if not (repo / "Makefile").exists():
-        return "native codec not built and no Makefile to build it"
-    if shutil.which("g++") is None and shutil.which("make") is None:
-        return "native codec not built and no C++ toolchain present"
+    if shutil.which("g++") is None or shutil.which("make") is None:
+        pytest.skip("no C++ toolchain to build the native codec")
+    out = tmp_path_factory.mktemp("native")
     proc = subprocess.run(
-        ["make", "native"], cwd=repo, capture_output=True, text=True
+        ["make", "native", f"NATIVE_OUT={out}"],
+        cwd=repo, capture_output=True, text=True,
     )
     if proc.returncode != 0:
         # a BROKEN build must fail the suite, not skip it
         pytest.fail(
             f"`make native` failed (rc={proc.returncode}):\n{proc.stderr[-2000:]}"
         )
-    native.reload()
-    if not native.available():
+    if not native.reload(str(out / "libsctcodec.so")):
         pytest.fail("`make native` succeeded but the codec did not load")
-    return None
-
-
-_skip_reason = _ensure_native()
-pytestmark = pytest.mark.skipif(
-    _skip_reason is not None, reason=_skip_reason or ""
-)
+    yield
+    native.reload()
 
 
 class TestParseDense:

@@ -1,8 +1,8 @@
-"""Pallas kernels: flash attention pinned to the dense reference.
+"""Pallas kernels pinned to their dense references.
 
-Runs in interpret mode on the CPU harness (the same kernel compiles for
-real TPU; tested there manually — the wire benches exercise it via
-seq_impl=flash)."""
+Runs in interpret mode on the CPU harness.  The same kernels are compiled
+by Mosaic and held to the same references at the 1B serving geometry by
+``chip_smoke.py``'s ``ops`` phase, on the chip."""
 
 import jax
 import jax.numpy as jnp

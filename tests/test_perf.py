@@ -16,6 +16,7 @@ from __future__ import annotations
 import asyncio
 import json
 
+import jax
 import numpy as np
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -101,7 +102,9 @@ class TestWarmupPlane:
 
     def test_stats_warmup_attributes_the_readiness_tail(self):
         """GET /stats/warmup reports per-unit programs + seconds once
-        readiness flips — the attribution for a slow warm start."""
+        readiness flips — the attribution for a slow warm start — and the
+        device the programs were compiled for, with the count of XLA
+        compiles since readiness (a warmed server shows zero)."""
 
         async def go():
             service = PredictionService(
@@ -125,6 +128,19 @@ class TestWarmupPlane:
                 assert snap["programs"]["m"] == len(model.buckets.sizes)
                 assert snap["seconds"]["m"] > 0
                 assert snap["total_seconds"] >= snap["seconds"]["m"] * 0.5
+                resp = await client.post(
+                    "/api/v0.1/predictions",
+                    json={"data": {"ndarray": [[0.0] * 16]}},
+                )
+                assert resp.status == 200
+                dev = (await (await client.get("/stats/warmup")).json())[
+                    "warmup"]["device"]
+                assert dev["platform"] == "cpu"
+                assert dev["device_kind"] == jax.devices()[0].device_kind
+                assert dev["device_count"] == len(jax.devices())
+                assert isinstance(dev["native_codec"], bool)
+                assert dev["xla_compiles"] >= snap["programs"]["m"]
+                assert dev["xla_compiles_since_ready"] == 0
             finally:
                 await client.close()
 
