@@ -16,8 +16,9 @@ native:
 	mkdir -p $(NATIVE_OUT)
 	g++ -O3 -shared -fPIC -o $(NATIVE_OUT)/libsctcodec.so csrc/codec.cpp
 
+# tier-1, as the PR check selects and spreads it (ROADMAP.md "Tier-1 verify")
 test:
-	$(PYTHON) -m pytest tests/ -x -q
+	$(PYTHON) -m pytest tests/ -q -m 'not slow' -p xdist -n 6 --dist loadfile
 
 bench:
 	$(PYTHON) bench.py

@@ -1484,14 +1484,18 @@ class H1SpliceFrontend:
         if self._reap_handle is not None:
             self._reap_handle.cancel()
             self._reap_handle = None
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        server, self._server = self._server, None
+        if server is not None:
+            server.close()
         for conn in list(self._conns):
             if conn.transport is not None:
                 conn.transport.close()
         self._conns.clear()
+        if server is not None:
+            # 3.12+: wait_closed also waits for every accepted connection,
+            # so it must come AFTER the transports are closed or an idle
+            # keep-alive client holds stop() for ever
+            await server.wait_closed()
         pools, self._pools = list(self._pools.values()), {}
         for p in pools:
             p.evict()

@@ -621,8 +621,14 @@ class TestFleetCollector:
         coroutines for longer than its own scrape timeout."""
 
         async def go():
+            release = asyncio.Event()
+
             async def hang(reader, writer):
-                await asyncio.sleep(30.0)
+                try:
+                    await release.wait()  # accepts, never answers
+                finally:
+                    # 3.12+: wait_closed() waits for accepted connections
+                    writer.close()
 
             server = await asyncio.start_server(hang, "127.0.0.1", 0)
             port = server.sockets[0].getsockname()[1]
@@ -649,6 +655,7 @@ class TestFleetCollector:
                 assert col.errors == 0
             finally:
                 await col.stop()
+                release.set()
                 server.close()
                 await server.wait_closed()
 

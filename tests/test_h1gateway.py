@@ -5,6 +5,7 @@ feedback), framing strictness (content-length smuggling guards, chunked
 uploads), chunked/SSE response forwarding, and engine-failure handling."""
 
 import asyncio
+import contextlib
 import json
 
 import aiohttp
@@ -31,6 +32,31 @@ async def _engine_client(spec=SIMPLE) -> TestClient:
     client = TestClient(TestServer(app))
     await client.start_server()
     return client
+
+
+@contextlib.asynccontextmanager
+async def _fake_engine(handle):
+    """A raw fake engine on a free port; yields the port.  A connection
+    stays open after ``handle`` returns (an engine that accepts and never
+    answers is a ``handle`` that does nothing) until the test leaves the
+    block, and is closed then: from Python 3.12 ``Server.wait_closed()``
+    waits for every connection the server accepted."""
+    done = asyncio.Event()
+
+    async def serve(reader, writer):
+        try:
+            await handle(reader, writer)
+            await done.wait()
+        finally:
+            writer.close()
+
+    server = await asyncio.start_server(serve, "127.0.0.1", 0)
+    try:
+        yield server.sockets[0].getsockname()[1]
+    finally:
+        done.set()
+        server.close()
+        await server.wait_closed()
 
 
 async def _frontend(engine_port: int, **gw_kwargs):
@@ -197,6 +223,28 @@ class TestSplicePredict:
         assert run(go()) == 404
 
 
+class TestStop:
+    def test_stop_returns_with_idle_keepalive_client(self):
+        """From Python 3.12 ``Server.wait_closed()`` waits for every
+        accepted connection: stop() must close them first, or one idle
+        keep-alive client holds a stopping gateway until it is killed."""
+
+        async def go():
+            frontend, gw, port = await _frontend(1)  # no engine needed
+            reader, writer = await asyncio.open_connection("127.0.0.1", port)
+            for _ in range(100):
+                if frontend._conns:
+                    break
+                await asyncio.sleep(0.01)
+            assert frontend._conns, "the gateway never saw the client"
+            await asyncio.wait_for(frontend.stop(), timeout=1)
+            eof = await asyncio.wait_for(reader.read(), timeout=1)
+            writer.close()
+            return eof
+
+        assert run(go()) == b""
+
+
 class TestFramingStrictness:
     """The splice forwards raw bytes onto a SHARED pipelined engine
     connection — framing the gateway and engine could read differently is
@@ -336,28 +384,25 @@ class TestEvictedPoolFailsFast:
 
         async def go():
             async def handle(reader, writer):
-                await asyncio.sleep(5)
+                pass  # accepts, never answers
 
-            server = await asyncio.start_server(handle, "127.0.0.1", 0)
-            eport = server.sockets[0].getsockname()[1]
             fails = []
 
             class Down:
                 def upstream_failed(self, reason, forwarded, status=503):
                     fails.append((reason, forwarded, status))
 
-            pool = _UpstreamPool("127.0.0.1", eport, asyncio.get_running_loop())
-            pool.closed = True  # evicted while the job was being dispatched
-            job = _Job(Down(), b"POST /x HTTP/1.1\r\ncontent-length: 0\r\n\r\n", False)
-            pending = _Job(Down(), b"POST /y HTTP/1.1\r\ncontent-length: 0\r\n\r\n", False)
-            pool.pending.append(pending)
-            pool.spawn_send(job)
-            for _ in range(100):
-                if len(fails) >= 2:
-                    break
-                await asyncio.sleep(0.02)
-            server.close()
-            await server.wait_closed()
+            async with _fake_engine(handle) as eport:
+                pool = _UpstreamPool("127.0.0.1", eport, asyncio.get_running_loop())
+                pool.closed = True  # evicted while the job was being dispatched
+                job = _Job(Down(), b"POST /x HTTP/1.1\r\ncontent-length: 0\r\n\r\n", False)
+                pending = _Job(Down(), b"POST /y HTTP/1.1\r\ncontent-length: 0\r\n\r\n", False)
+                pool.pending.append(pending)
+                pool.spawn_send(job)
+                for _ in range(100):
+                    if len(fails) >= 2:
+                        break
+                    await asyncio.sleep(0.02)
             return fails
 
         fails = run(go())
@@ -450,37 +495,34 @@ class TestSpliceBackpressure:
                 )
                 await writer.drain()
 
-            server = await asyncio.start_server(handle, "127.0.0.1", 0)
-            eport = server.sockets[0].getsockname()[1]
-            frontend, gw, port = await _frontend(eport)
-            async with aiohttp.ClientSession() as s:
-                tok = await _token(s, port)
-            reader, writer = await asyncio.open_connection("127.0.0.1", port)
-            writer.write(
-                b"POST /api/v0.1/predictions HTTP/1.1\r\n"
-                + f"authorization: Bearer {tok}\r\n".encode()
-                + b"content-length: 2\r\n\r\n{}"
-            )
-            await writer.drain()
-            # flood 1MB of pipelined bytes while the response is pending
-            junk = b"X" * (1 << 20)
-            writer.write(junk)
-            paused_conn = None
-            for _ in range(200):
-                await asyncio.sleep(0.01)
-                for conn in frontend._conns:
-                    if conn._read_paused:
-                        paused_conn = conn
+            async with _fake_engine(handle) as eport:
+                frontend, gw, port = await _frontend(eport)
+                async with aiohttp.ClientSession() as s:
+                    tok = await _token(s, port)
+                reader, writer = await asyncio.open_connection("127.0.0.1", port)
+                writer.write(
+                    b"POST /api/v0.1/predictions HTTP/1.1\r\n"
+                    + f"authorization: Bearer {tok}\r\n".encode()
+                    + b"content-length: 2\r\n\r\n{}"
+                )
+                await writer.drain()
+                # flood 1MB of pipelined bytes while the response is pending
+                junk = b"X" * (1 << 20)
+                writer.write(junk)
+                paused_conn = None
+                for _ in range(200):
+                    await asyncio.sleep(0.01)
+                    for conn in frontend._conns:
+                        if conn._read_paused:
+                            paused_conn = conn
+                            break
+                    if paused_conn is not None:
                         break
-                if paused_conn is not None:
-                    break
-            buffered = len(paused_conn.buf) if paused_conn is not None else -1
-            release.set()
-            data = await asyncio.wait_for(reader.read(200), timeout=5)
-            writer.close()
-            await frontend.stop()
-            server.close()
-            await server.wait_closed()
+                buffered = len(paused_conn.buf) if paused_conn is not None else -1
+                release.set()
+                data = await asyncio.wait_for(reader.read(200), timeout=5)
+                writer.close()
+                await frontend.stop()
             return paused_conn is not None, buffered, data
 
         paused, buffered, data = run(go())
@@ -505,55 +547,52 @@ class TestSpliceBackpressure:
                     await writer.drain()
                 await writer.drain()
 
-            server = await asyncio.start_server(handle, "127.0.0.1", 0)
-            eport = server.sockets[0].getsockname()[1]
-            frontend, gw, port = await _frontend(eport)
-            async with aiohttp.ClientSession() as s:
-                tok = await _token(s, port)
-            import socket as _socket
+            async with _fake_engine(handle) as eport:
+                frontend, gw, port = await _frontend(eport)
+                async with aiohttp.ClientSession() as s:
+                    tok = await _token(s, port)
+                import socket as _socket
 
-            sock = _socket.socket()
-            # tiny client receive buffer: the kernel must not absorb the
-            # whole stream, or the gateway-side pause never has to fire
-            sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_RCVBUF, 8192)
-            sock.connect(("127.0.0.1", port))
-            reader, writer = await asyncio.open_connection(sock=sock, limit=1 << 16)
-            writer.write(
-                b"POST /api/v0.1/predictions HTTP/1.1\r\n"
-                + f"authorization: Bearer {tok}\r\n".encode()
-                + b"content-length: 2\r\n\r\n{}"
-            )
-            await writer.drain()
-            # force the downstream transport to signal fullness early
-            for _ in range(100):
-                await asyncio.sleep(0.01)
-                if frontend._conns:
-                    for c in frontend._conns:
-                        if c.transport is not None:
-                            c.transport.set_write_buffer_limits(high=4096)
-                    break
-            # do NOT read: the gateway's downstream buffer must fill and
-            # propagate the pause to the ENGINE connection
-            saw_pause = False
-            for _ in range(500):
-                await asyncio.sleep(0.01)
-                if any(c._write_paused for c in frontend._conns):
-                    saw_pause = True
-                    break
-            # now drain everything; the stream must complete intact
-            got = 0
-            head = await asyncio.wait_for(
-                reader.readuntil(b"\r\n\r\n"), timeout=10
-            )
-            while got < total:
-                blob = await asyncio.wait_for(reader.read(1 << 20), timeout=10)
-                if not blob:
-                    break
-                got += len(blob)
-            writer.close()
-            await frontend.stop()
-            server.close()
-            await server.wait_closed()
+                sock = _socket.socket()
+                # tiny client receive buffer: the kernel must not absorb the
+                # whole stream, or the gateway-side pause never has to fire
+                sock.setsockopt(_socket.SOL_SOCKET, _socket.SO_RCVBUF, 8192)
+                sock.connect(("127.0.0.1", port))
+                reader, writer = await asyncio.open_connection(sock=sock, limit=1 << 16)
+                writer.write(
+                    b"POST /api/v0.1/predictions HTTP/1.1\r\n"
+                    + f"authorization: Bearer {tok}\r\n".encode()
+                    + b"content-length: 2\r\n\r\n{}"
+                )
+                await writer.drain()
+                # force the downstream transport to signal fullness early
+                for _ in range(100):
+                    await asyncio.sleep(0.01)
+                    if frontend._conns:
+                        for c in frontend._conns:
+                            if c.transport is not None:
+                                c.transport.set_write_buffer_limits(high=4096)
+                        break
+                # do NOT read: the gateway's downstream buffer must fill and
+                # propagate the pause to the ENGINE connection
+                saw_pause = False
+                for _ in range(500):
+                    await asyncio.sleep(0.01)
+                    if any(c._write_paused for c in frontend._conns):
+                        saw_pause = True
+                        break
+                # now drain everything; the stream must complete intact
+                got = 0
+                head = await asyncio.wait_for(
+                    reader.readuntil(b"\r\n\r\n"), timeout=10
+                )
+                while got < total:
+                    blob = await asyncio.wait_for(reader.read(1 << 20), timeout=10)
+                    if not blob:
+                        break
+                    got += len(blob)
+                writer.close()
+                await frontend.stop()
             return saw_pause, head, got
 
         saw_pause, head, got = run(go())
