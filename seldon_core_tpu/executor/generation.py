@@ -767,6 +767,12 @@ class GenerativeModel:
         # path when enabled, the XLA gather path otherwise (both ride the
         # program cache keys via _program_config)
         dec_kw = {"kernel": True} if self.decode_kernel else {}
+        # the pool's kv-head axis rides tp (placement above); the model
+        # picks its pool read by it (models/llama.py::_pool_read).  A
+        # draft pool whose heads do not divide tp was placed replicated
+        tp = int(mesh.shape.get("tp", 1)) if mesh is not None else 1
+        pool_kw = {"kv_sharded": True} if tp > 1 else {}
+        dec_kw.update(pool_kw)
         # cascade confidence: static branch — programs with the signal emit
         # one extra (rows, S) float32 output riding the existing fetch
         conf_on = self.conf_signal
@@ -946,6 +952,7 @@ class GenerativeModel:
                             dlogits, dc = fam.decode_slots_paged(
                                 spec_ps, cur, dc, active, dcfg,
                                 window=window,
+                                **(pool_kw if dcfg.n_kv_heads % tp == 0 else {}),
                             )
                             nxt = jnp.argmax(dlogits, axis=-1).astype(
                                 jnp.int32
@@ -1065,6 +1072,7 @@ class GenerativeModel:
                         params, tokens, prefix_len, length, slot, blocks_row,
                         suffix_blocks, cache, cfg, prefix_window=pw,
                         lora=lora, adapter_id=aid, return_hidden=True,
+                        **pool_kw,
                     )
                     cache["hlast"] = cache["hlast"].at[slot].set(
                         hid.astype(cache["hlast"].dtype)
@@ -1073,7 +1081,7 @@ class GenerativeModel:
                     logits, cache = fam.prefill_suffix_paged(
                         params, tokens, prefix_len, length, slot, blocks_row,
                         suffix_blocks, cache, cfg, prefix_window=pw,
-                        lora=lora, adapter_id=aid,
+                        lora=lora, adapter_id=aid, **pool_kw,
                     )
                 key = jax.random.PRNGKey(seed)
                 tok = _sample(logits[None], temperature[None], key)[0]

@@ -652,6 +652,30 @@ def _dequant_kv(q, scale, dtype):
     )
 
 
+def _pool_layer(pool, li):
+    """Layer ``li`` (a traced scalar) of the ``(layers, n_blocks, ...)`` pool."""
+    return jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
+
+
+def _pool_read(pool, li, read_idx, kv_sharded=False):
+    """The blocks ``read_idx`` of layer ``li`` (a traced scalar) of the
+    carried ``(layers, n_blocks, ...)`` pool, chosen by where the pool lives
+    (PERF.md §6, PR 25; same elements in the same order either way).
+
+    A pool on one device: ONE gather addressed by (layer, block).  Cutting
+    the layer out first makes XLA materialise the layer's whole pool every
+    layer of every step — it does not fuse a dynamic-slice into a gather's
+    operand — and that copy cost more than the step's own reads.
+
+    ``kv_sharded`` (static): the kv-head axis is split over a mesh.  A
+    device then holds a few heads of every block in 2-row tiles, which the
+    gather reads at under half the rate; the layer, a fraction the size, is
+    cut out first, and XLA re-tiles it into fast memory on the way."""
+    if kv_sharded:
+        return _pool_layer(pool, li)[read_idx]
+    return pool[li, read_idx]
+
+
 def _fake_quant_hook(scale_dtype):
     """kv_hook for :func:`_layer` under an int8 pool: attention sees the
     dequantized values, the scan collects ``(qk, sk, qv, sv)`` to store."""
@@ -756,6 +780,7 @@ def prefill_suffix_paged(
     lora: dict | None = None,
     adapter_id: jax.Array | None = None,
     return_hidden: bool = False,
+    kv_sharded: bool = False,
 ) -> tuple[jax.Array, dict] | tuple[jax.Array, dict, jax.Array]:
     """Prefill only the SUFFIX of a prompt whose first ``prefix_len``
     tokens already have K/V in the slot's table blocks (KV prefix reuse,
@@ -769,7 +794,8 @@ def prefill_suffix_paged(
     ``suffix_blocks`` ``(Ls // bs,)`` the physical blocks the suffix K/V
     scatters into.  ``prefix_window`` (STATIC; one compiled program per
     (suffix bucket, window)) bounds how many prefix rows attention reads —
-    the smallest block-multiple covering ``prefix_len``.
+    the smallest block-multiple covering ``prefix_len``.  ``kv_sharded``
+    (STATIC) as in :func:`decode_slots_paged`.
 
     Numerics: suffix queries attend over [gathered prefix K/V ++ suffix
     K/V] with the same einsum/mask/softmax shapes as the full-prefill
@@ -821,18 +847,17 @@ def prefill_suffix_paged(
             # attend the dequantized suffix K/V (fake-quant: exactly what
             # the pool will hold) and collect the quantized form to store
             k, v, (qk, sk, qv, sv) = hook(k, v)
-        ckl = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-        cvl = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
+
+        def read(pool):
+            return _pool_read(pool, li, read_idx, kv_sharded)
+
+        kp = read(ck)  # (pb, bs, kv, hd)
+        vp = read(cv)
         if quant:
-            sk_l = jax.lax.dynamic_index_in_dim(cks, li, 0, keepdims=False)
-            sv_l = jax.lax.dynamic_index_in_dim(cvs, li, 0, keepdims=False)
-            kp = _dequant_kv(ckl[read_idx], sk_l[read_idx], k.dtype)
-            vp = _dequant_kv(cvl[read_idx], sv_l[read_idx], v.dtype)
-            kp = kp.reshape(1, pb * bs, kvh, hd)
-            vp = vp.reshape(1, pb * bs, kvh, hd)
-        else:
-            kp = ckl[read_idx].reshape(1, pb * bs, kvh, hd).astype(k.dtype)
-            vp = cvl[read_idx].reshape(1, pb * bs, kvh, hd).astype(v.dtype)
+            kp = _dequant_kv(kp, read(cks), k.dtype)
+            vp = _dequant_kv(vp, read(cvs), v.dtype)
+        kp = kp.reshape(1, pb * bs, kvh, hd).astype(k.dtype)
+        vp = vp.reshape(1, pb * bs, kvh, hd).astype(v.dtype)
         k_all = jnp.concatenate([kp, k], axis=1)  # (1, P+Ls, kv, hd)
         v_all = jnp.concatenate([vp, v], axis=1)
         kf = _gqa_repeat(k_all, cfg.n_heads)
@@ -904,21 +929,26 @@ def decode_slots_paged(
     kernel: bool = False,
     lora: dict | None = None,
     adapter_ids: jax.Array | None = None,
+    kv_sharded: bool = False,
 ) -> tuple[jax.Array, dict]:
     """One decode step for every slot against the paged cache.
 
     Identical contract to :func:`decode_slots`; attention reads gather the
-    first ``window // block_size`` table entries per slot (same byte volume
-    as the static window read — the pool layout changes where rows LIVE,
-    not how many are read).  ``kernel`` (static) routes the attention read
-    through the fused Pallas paged decode-attention kernel
-    (``ops/paged_attention.py``) instead of the XLA gather path.
+    first ``window // block_size`` table entries per slot straight from the
+    carried pool by (layer, block) (:func:`_pool_read`), so the bytes moved
+    are the window's, as in the static window read — the pool layout
+    changes where rows LIVE, not how many are read.  ``kernel`` (static)
+    routes the attention read through the fused Pallas paged
+    decode-attention kernel (``ops/paged_attention.py``) instead of the
+    XLA gather path.  ``kv_sharded`` (static) says the pool's kv-head axis
+    is split over a mesh, which picks the other read of :func:`_pool_read`.
     ``lora``/``adapter_ids (S,)`` gather each slot's adapter delta inside
     the same fused step — mixed-adapter batches ride ONE program
     (docs/MULTITENANT.md)."""
     logits, cache = _decode_paged_multi(
         params, tokens[:, None], cache, active, active[:, None], cfg,
         window=window, kernel=kernel, lora=lora, adapter_ids=adapter_ids,
+        kv_sharded=kv_sharded,
     )
     cache["pos"] = jnp.where(active, cache["pos"] + 1, cache["pos"])
     return logits[:, 0], cache
@@ -937,6 +967,7 @@ def decode_slots_spec_paged(
     lora: dict | None = None,
     adapter_ids: jax.Array | None = None,
     return_hidden: bool = False,
+    kv_sharded: bool = False,
 ) -> tuple[jax.Array, dict] | tuple[jax.Array, dict, jax.Array]:
     """Speculative verify pass: score ``L = 1 + draft`` query positions per
     slot in ONE model call (docs/PERFORMANCE.md).
@@ -959,7 +990,7 @@ def decode_slots_spec_paged(
     return _decode_paged_multi(
         params, qtokens, cache, active, qvalid, cfg, window=window,
         kernel=kernel, lora=lora, adapter_ids=adapter_ids,
-        return_hidden=return_hidden,
+        return_hidden=return_hidden, kv_sharded=kv_sharded,
     )
 
 
@@ -967,6 +998,7 @@ def _decode_paged_multi(
     params, qtokens, cache, active, qvalid, cfg: Config, *, window,
     kernel: bool = False, lora: dict | None = None,
     adapter_ids: jax.Array | None = None, return_hidden: bool = False,
+    kv_sharded: bool = False,
 ):
     """Shared L-query decode body: ``L=1`` is the classic decode step,
     ``L>1`` the fused speculative verify.  The per-row contraction shapes
@@ -1045,32 +1077,30 @@ def _decode_paged_multi(
         else:
             ck = ck.at[li, write_blk, write_off].set(k.astype(ck.dtype))
             cv = cv.at[li, write_blk, write_off].set(v.astype(cv.dtype))
-        ckl = jax.lax.dynamic_index_in_dim(ck, li, 0, keepdims=False)
-        cvl = jax.lax.dynamic_index_in_dim(cv, li, 0, keepdims=False)
-        if quant:
-            sk_l = jax.lax.dynamic_index_in_dim(cks, li, 0, keepdims=False)
-            sv_l = jax.lax.dynamic_index_in_dim(cvs, li, 0, keepdims=False)
         if kernel:
             # fused Pallas read side: table gather + (dequant +) attention
-            # in one VMEM pass over the window's pool blocks
+            # in one VMEM pass over the window's pool blocks.  The kernel
+            # takes a layer's view of the pool (the XLA path below does not)
             from seldon_core_tpu.ops import paged_decode_attention
 
             o = paged_decode_attention(
-                q, ckl, cvl, read_idx, pos,
-                k_scale=sk_l if quant else None,
-                v_scale=sv_l if quant else None,
+                q, _pool_layer(ck, li), _pool_layer(cv, li), read_idx, pos,
+                k_scale=_pool_layer(cks, li) if quant else None,
+                v_scale=_pool_layer(cvs, li) if quant else None,
             )
         else:
             # gather each slot's visible blocks:
             # (S, wb, bs, kv, hd) -> (S, W, ..)
+            def read(pool):
+                return _pool_read(pool, li, read_idx, kv_sharded)
+
+            kw = read(ck)
+            vw = read(cv)
             if quant:
-                kw = _dequant_kv(ckl[read_idx], sk_l[read_idx], q.dtype)
-                vw = _dequant_kv(cvl[read_idx], sv_l[read_idx], q.dtype)
-                kw = kw.reshape(S, W, kv, hd)
-                vw = vw.reshape(S, W, kv, hd)
-            else:
-                kw = ckl[read_idx].reshape(S, W, kv, hd)
-                vw = cvl[read_idx].reshape(S, W, kv, hd)
+                kw = _dequant_kv(kw, read(cks), q.dtype)
+                vw = _dequant_kv(vw, read(cvs), q.dtype)
+            kw = kw.reshape(S, W, kv, hd)
+            vw = vw.reshape(S, W, kv, hd)
             # grouped-query attention against the *un-repeated* cache:
             # repeating kv to n_heads here would multiply cache reads by the
             # group size every decode step, defeating GQA's bandwidth savings

@@ -276,3 +276,128 @@ class TestPagedDecodeAttention:
                 np.asarray(dense_logits[0]), np.asarray(kern_logits[0]),
                 rtol=2e-5, atol=2e-5,
             )
+
+
+def _old_pool_read(pool, li, read_idx, kv_sharded=False):
+    """The read this repo had before ``llama._pool_read``: cut the layer out
+    of the pool, then gather from the layer's view.  Kept here only as the
+    yardstick: same elements, same order, one whole-layer copy more."""
+    layer = jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
+    return layer[read_idx]
+
+
+class TestPoolRead:
+    """The XLA paged read addresses the carried pool by (layer, block) in
+    one gather (``llama._pool_read``); a pool whose kv heads are split over
+    a mesh (``kv_sharded``) keeps the layer-first read.  Either is a choice
+    of addressing only: every program that reads the pool is bit-equal to
+    the same program on the old read, and the one-device lowering cuts no
+    layer out of the pool."""
+
+    S, NB, BS = 3, 17, 8
+
+    def _setup(self, pool):
+        """A tiny model and a pool full of random rows (the sink block 0
+        too); the table's blocks are out of order, slots 0 and 1 share two
+        prefix blocks, and entries past a slot's length point at the sink."""
+        from seldon_core_tpu.models import llama
+
+        dtype = jnp.bfloat16 if pool == "bfloat16" else jnp.float32
+        cfg = llama.Config.tiny(max_seq=64)
+        params = llama.init_params(jax.random.PRNGKey(0), cfg, dtype)
+        cache = llama.init_paged_cache(
+            cfg, self.S, self.NB, self.BS, dtype,
+            kv_dtype="int8" if pool == "int8" else None,
+        )
+        rng = np.random.default_rng(7)
+        for name in ("k", "v", "k_scale", "v_scale"):
+            if name not in cache:
+                continue
+            shape, dt = cache[name].shape, cache[name].dtype
+            if dt == jnp.int8:
+                rows = rng.integers(-127, 128, shape)
+            elif name.endswith("_scale"):
+                rows = rng.uniform(0.001, 0.02, shape)
+            else:
+                rows = rng.standard_normal(shape)
+            cache[name] = jnp.asarray(rows).astype(dt)
+        cache["table"] = jnp.asarray(
+            [[11, 3, 14, 2, 9, 16, 0, 0],
+             [11, 3, 7, 0, 0, 0, 0, 0],
+             [5, 13, 1, 8, 15, 4, 10, 12]], jnp.int32)
+        cache["pos"] = jnp.asarray([43, 17, 60], jnp.int32)
+        return llama, cfg, params, cache
+
+    def _run(self, which, window, llama, cfg, params, cache, **kw):
+        """One of the three pool readers, as a fresh trace of ``llama`` as
+        it stands (so a patched ``_pool_read`` is what gets traced)."""
+        active = jnp.asarray([True, True, False])
+        if which == "decode":
+            return llama.decode_slots_paged(
+                params, jnp.asarray([5, 9, 2], jnp.int32), dict(cache),
+                active, cfg, window=window, **kw,
+            )
+        if which == "spec":
+            qtokens = jnp.asarray(
+                np.arange(12).reshape(self.S, 4) + 3, jnp.int32)
+            qvalid = jnp.asarray(
+                [[True] * 4, [True, True, False, False], [False] * 4])
+            return llama.decode_slots_spec_paged(
+                params, qtokens, dict(cache), active, qvalid, cfg,
+                window=window, **kw,
+            )
+        # suffix prefill of slot 1: ``window // 2`` rows of prefix are read
+        # from slot 0's blocks, 16 new rows go to block 6 and the sink
+        row = cache["table"][0]
+        return llama.prefill_suffix_paged(
+            params, jnp.asarray(np.arange(16)[None, :] + 1, jnp.int32),
+            jnp.int32(window // 2), jnp.int32(window // 2 + 11),
+            jnp.int32(1), row, jnp.asarray([6, 0], jnp.int32), dict(cache),
+            cfg, prefix_window=window // 2, **kw,
+        )
+
+    @pytest.mark.parametrize("window", [16, 64])
+    @pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+    @pytest.mark.parametrize("which", ["decode", "spec", "suffix"])
+    @pytest.mark.parametrize("against", ["old-read", "kv-sharded-read"])
+    def test_bit_equal(self, against, which, pool, window, monkeypatch):
+        setup = self._setup(pool)
+        new_logits, new_cache = self._run(which, window, *setup)
+        if against == "old-read":
+            monkeypatch.setattr(setup[0], "_pool_read", _old_pool_read)
+            old_logits, old_cache = self._run(which, window, *setup)
+        else:
+            old_logits, old_cache = self._run(
+                which, window, *setup, kv_sharded=True)
+        assert new_logits.dtype == old_logits.dtype
+        assert np.array_equal(
+            np.asarray(new_logits, np.float32), np.asarray(old_logits, np.float32)
+        )
+        assert new_cache.keys() == old_cache.keys()
+        for name in new_cache:
+            assert np.array_equal(
+                np.asarray(new_cache[name], np.float32),
+                np.asarray(old_cache[name], np.float32),
+            ), name
+
+    def _layer_slices(self, which, llama, cfg, params, cache, **kw):
+        """``dynamic_slice`` lines of the lowered program whose result is
+        one layer of a pool tensor, ``(1, n_blocks, block, kv[, hd])``."""
+        text = jax.jit(
+            lambda p, c: self._run(which, 32, llama, cfg, p, c, **kw)
+        ).lower(params, cache).as_text()
+        layer = f"tensor<1x{self.NB}x{self.BS}x{cfg.n_kv_heads}x"
+        return [
+            line for line in text.splitlines()
+            if "dynamic_slice" in line and layer in line.split("->")[-1]
+        ]
+
+    @pytest.mark.parametrize("pool", ["float32", "int8"])
+    @pytest.mark.parametrize("which", ["decode", "spec", "suffix"])
+    def test_lowering_cuts_no_layer_out_of_the_pool(self, which, pool):
+        setup = self._setup(pool)
+        assert self._layer_slices(which, *setup) == []
+        # the guard sees the layer-first read where it is chosen: K and V,
+        # and an int8 pool's two scales
+        found = self._layer_slices(which, *setup, kv_sharded=True)
+        assert len(found) == (4 if pool == "int8" else 2), found
