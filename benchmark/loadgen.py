@@ -19,6 +19,8 @@ import time
 import aiohttp
 import numpy as np
 
+import traffic
+
 STREAM_PATH = "/api/v0.1/predictions/stream"
 PREDICT_PATH = "/api/v0.1/predictions"
 TOKEN_PREFIX = b'data: {"token": '
@@ -214,12 +216,12 @@ class Load:
         async with aiohttp.ClientSession(connector=conn, timeout=timeout) as session:
             t0 = time.perf_counter()
             w0, w1 = t0 + lead, t0 + lead + window_s
-            if self.mix["loop"] == "closed":
+            if not traffic.open_loop(self.mix):
                 tasks = [
                     asyncio.create_task(self._closed_client(session, w1))
                     for _ in range(int(self.mix["clients"]))
                 ]
-            elif self.mix["loop"] == "open-poisson":
+            else:  # whatever the arrival process, the schedule is ``dues``
                 tasks = []
                 for due in dues:
                     if t0 + due >= w1:
@@ -234,8 +236,6 @@ class Load:
                 wait = w1 - time.perf_counter()
                 if wait > 0:
                     await asyncio.sleep(wait)
-            else:
-                raise ValueError(f"unknown loop {self.mix['loop']!r}")
             # the drain: what is in flight may finish; what does not, failed
             left = w1 + drain - time.perf_counter()
             done, pending = await asyncio.wait(tasks, timeout=max(left, 0.0))

@@ -80,8 +80,11 @@ def main() -> int:
                   f"failed={r['failed']}/{r['attempted']} wall={r['wall_s']:.0f}s "
                   + " ".join(f"{m}={v['value']:.4f}" for m, v in r["metrics"].items()),
                   flush=True)
-            print("   ", json.dumps(r["info"][-1])[:900], flush=True)
-            print("    reference:", json.dumps(r["info"][1].get("reference"))[:300], flush=True)
+            for line in r["info"]:
+                if "requests_in_window" in line or "stall_s" in line:
+                    print("   ", json.dumps(line)[:1200], flush=True)
+                if "reference" in line:
+                    print("    reference:", json.dumps(line["reference"])[:300], flush=True)
     for i in range(args.trace_runs):
         r = one_run(args.workload, args.seed0 + i, seconds, 1, args.extra)
         report["traced"].append(r)

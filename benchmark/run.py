@@ -13,7 +13,9 @@ of ``benchmark/rehearsal/`` on the CPU and prints no device metric.
 
 Everything a cell is made of is found by name: the configuration's file and
 the traffic file from BENCHMARK.json, each metric's reader at
-``benchmark/metrics/<metric>.py``.  This process never imports jax.
+``benchmark/metrics/<metric>.py``, the configuration's reference kind and
+its judge at ``benchmark/reference/kinds|judges/<name>.py``; the arrival
+process is a parameter of the traffic file.  This process never imports jax.
 """
 
 from __future__ import annotations
@@ -22,8 +24,8 @@ T_COMMAND = __import__("time").perf_counter()
 
 import argparse
 import asyncio
+import copy
 import hashlib
-import importlib.util
 import json
 import os
 import shutil
@@ -34,9 +36,10 @@ import types
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-sys.path.insert(0, HERE)
+sys.path[:0] = [HERE, os.path.join(HERE, "reference")]
 
 import costs  # noqa: E402
+import frame  # noqa: E402
 import loadgen  # noqa: E402
 import peaks  # noqa: E402
 import stats  # noqa: E402
@@ -101,14 +104,18 @@ def reference_verdict(config_name: str, config_path: str, seed: int,
     return {**found, "cached": False}
 
 
-def judge(found: dict, limits: dict) -> bool:
-    """Hold what the reference found to the configuration's stated margins."""
-    if found["kind"] == "llama_decoder":
-        return (
-            found["logit_deficit_max"] <= limits["logit_margin"]
-            and found["argmax_agree_share"] >= limits["argmax_agree_min"]
-        )
-    return found["prob_abs_err_max"] <= limits["prob_abs_tol"]
+def judge(found: dict, reference: dict) -> tuple[bool, list]:
+    """Hold what the reference found to the configuration's stated limits,
+    by the judge the configuration names (``benchmark/reference/judges/
+    <judge>.py``: ``judge(found, limits) -> bool``), or its kind's own.
+    Beside the verdict, each number compared with its limit, where the
+    judge says which they are (``compared(found, limits)``)."""
+    try:
+        mod = frame.named_module("judges", reference.get("judge") or found["judge"])
+    except LookupError as e:
+        raise BenchFailure(str(e)) from None
+    rows = mod.compared(found, reference) if hasattr(mod, "compared") else []
+    return bool(mod.judge(found, reference)), [list(r) for r in rows]
 
 
 # ---------------------------------------------------------------- probes
@@ -194,15 +201,10 @@ def reduce_trace(trace_dir: str) -> dict | None:
 
 
 def read_metric(name: str, run) -> float | None:
-    path = os.path.join(HERE, "metrics", f"{name}.py")
-    if not os.path.exists(path):
-        raise BenchFailure(f"metric {name!r} has no reader at {path}")
-    spec = importlib.util.spec_from_file_location(
-        "metric_" + name.replace(".", "_").replace("-", "_"), path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(run)
+    try:
+        return frame.named_module(os.path.join(HERE, "metrics"), name).read(run)
+    except LookupError as e:
+        raise BenchFailure(f"metric {name!r} has no reader: {e}") from None
 
 
 def metrics_of(manifest: dict, group: str, cell: str, run) -> dict:
@@ -258,10 +260,16 @@ def admission_groups(run) -> dict:
     import metriclib as ml
 
     gaps = sorted(
-        b[0] - a[0] for s in run.samples if s.ok
+        (b[0] - a[0], a[0]) for s in run.samples if s.ok
         for blocks in [ml.blocks_of(s.token_times)]
         for a, b in zip(blocks, blocks[1:])
     )
+    longest_s, longest_from = gaps[-1] if gaps else (0.0, 0.0)
+    # a stall of the engine (or of this process) stops every live stream at
+    # once; one stream that waits alone was held back by the scheduler
+    stalled = sum(1 for g, t in gaps if g > 1.0 and t < longest_from + longest_s
+                  and t + g > longest_from)
+    gaps = [g for g, _ in gaps]
     firsts = sorted(s.first for s in run.samples if s.ok and s.first is not None)
     if not gaps or not firsts:
         return {}
@@ -275,7 +283,9 @@ def admission_groups(run) -> dict:
     sizes.append(n)
     count = collections.Counter(sizes)
     return {"block_interval_ms": block_s * 1e3,
-            "longest_block_gap_ms": gaps[-1] * 1e3,  # a stall shows here
+            "longest_block_gap_ms": longest_s * 1e3,  # a stall shows here,
+            "longest_block_gap_from_s": longest_from - run.w0,  # from the window's start
+            "streams_stalled_with_it": stalled,  # gaps over 1 s that overlap it
             "admission_group_sizes": {str(k): count[k] for k in sorted(count)},
             "admission_groups_first": sizes[:12]}
 
@@ -303,6 +313,10 @@ def main() -> int:
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse-cpu", action="store_true")
     ap.add_argument("--rate", type=float, help="sweep only: another open-loop rate")
+    ap.add_argument("--graph-param", action="append", default=[], metavar="NAME=JSON",
+                    help="control only: the engine's graph with this parameter set "
+                         "(a lower-precision path switched on); the reference keeps "
+                         "the configuration as it is committed")
     args = ap.parse_args()
 
     manifest = load_json("BENCHMARK.json")
@@ -335,8 +349,12 @@ def main() -> int:
     window_s = float(args.seconds)
     lead = float(mix.get("lead_in_s", 0.0))
 
+    served = copy.deepcopy(config)  # the reference child reads the file as it is
+    for item in args.graph_param:
+        name, _, value = item.partition("=")
+        served["graph"]["parameters"][name] = json.loads(value)
     engine = Engine(
-        graph_of(config, args.seed), platform,
+        graph_of(served, args.seed), platform,
         os.path.join(run_dir, "engine.log"), extra_env,
     )
     trace_dir = os.path.join(run_dir, "trace")
@@ -345,7 +363,7 @@ def main() -> int:
         # before the window, never inside it
         horizon = lead + window_s
         dues = None
-        if mix["loop"] == "open-poisson":
+        if traffic.open_loop(mix):
             dues = traffic.due_times(mix, horizon)
             n = len(dues)
         else:
@@ -428,15 +446,20 @@ def main() -> int:
         peaks=chip_peaks, chips=cell["chips"],
         stats=stats, costs=costs, traffic=traffic,
     )
-    checks = {
-        "platform": dev["platform"] == platform,
-        "no_compile_after_ready": warm_after["xla_compiles_since_ready"] == 0,
-        "probes_repeat": probes_before == probes_after,
-        "reference": judge(found, config["reference"]),
-        "no_failure_outside_window": not others_failed,
+    reference_ok, compared = judge(found, config["reference"])
+    held = {  # every other check, as the number compared and its limit
+        "platform": ["platform", dev["platform"], "==", platform],
+        "no_compile_after_ready": ["xla_compiles_since_ready",
+                                   warm_after["xla_compiles_since_ready"], "==", 0],
+        "probes_repeat": ["probes_changed_over_window",
+                          int(probes_before != probes_after), "==", 0],
+        "no_failure_outside_window": ["failed_outside_window", len(others_failed), "==", 0],
     }
+    checks = {name: frame.all_hold([row]) for name, row in held.items()}
+    checks["reference"] = reference_ok
+    compared += held.values()
     limits = {k: v for k, v in config["reference"].items() if k != "why"}
-    info(checks=checks, reference=found, reference_limits=limits,
+    info(checks=checks, reference=found, reference_limits=limits, compared=compared,
          xla_compiles_since_ready=warm_after["xla_compiles_since_ready"],
          errors=sorted({s.error for s in failed + others_failed})[:5])
     groups = {
@@ -449,7 +472,11 @@ def main() -> int:
              admission_group_sizes=admission_groups(run).get("admission_group_sizes"))
     else:
         info(**{g: {k: v["value"] for k, v in m.items()} for g, m in groups.items()})
-        info(**summary(run))
+        seen = summary(run)
+        info(**seen)
+        if seen.get("longest_block_gap_ms", 0.0) > 1000.0:
+            info(stall_s=seen["longest_block_gap_ms"] / 1e3,
+                 engine_log_tail=engine.log_tail(1500))
 
     memory = [m["peak_bytes_in_use"] for m in warm_after.get("memory", [])
               if m.get("peak_bytes_in_use") is not None]
@@ -474,6 +501,11 @@ def main() -> int:
             device["window_s"] = reduced["window_s"]
             result["breakdown"] = reduced["breakdown"]
     shutil.rmtree(run_dir, ignore_errors=True)
+    # each number compared beside its limit: the last lines of standard
+    # error, which the driver's record keeps where a run is not correct
+    for name, value, op, limit in compared:
+        print(f"compared {name}: {value} {op} {limit}", file=sys.stderr)
+    print(f"correct: {result['correct']} {json.dumps(checks)}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
