@@ -1224,6 +1224,14 @@ class GenerativeModel:
                 f"gen:{name}:lora", self._exec_lora_load
             )
 
+        # the family's own device counters (``cache["counters"]``, named by
+        # ``family.COUNTERS``: uint32, cumulative, wrapping): a copy is
+        # fetched WITH every decode block's tokens and the wrap-aware
+        # difference summed here — no sync point of its own
+        self._ctr_names = tuple(getattr(family_mod, "COUNTERS", ()))
+        self._ctr_dev = None
+        self._ctr_last = np.zeros(len(self._ctr_names), np.uint64)
+        self._ctr_total = np.zeros(len(self._ctr_names), np.uint64)
         # observability
         self.steps = 0
         self.prefills = 0
@@ -2975,6 +2983,9 @@ class GenerativeModel:
             "prefill_chunk": self.prefill_chunk or None,
             "prefill_chunks": self.prefill_chunks,
             "decode_kernel": self.decode_kernel,
+            # the family's own device counters (routing of an expert
+            # layer), as of the last fetched decode block
+            "counters": self.counters_snapshot(),
             # batched multi-LoRA (docs/MULTITENANT.md): the adapter-pool
             # ledger — resident/evicted counts, bytes, per-adapter slot
             # occupancy and tokens served
@@ -3283,7 +3294,7 @@ class GenerativeModel:
         conf_seq = res[2] if len(res) > 2 else None
         act = np.asarray(active, bool)
         self._pos_ceiling[act] += k * self._tps
-        return (toks_seq, act_seq, conf_seq, t0, act, int(k))
+        return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
 
     def step_k_continue(
         self, active: np.ndarray, seed: int, k: int, window: int | None = None
@@ -3310,14 +3321,14 @@ class GenerativeModel:
         act = np.asarray(active, bool)
         self._pos_ceiling[act] += k * self._tps
         self.overlapped += 1
-        return (toks_seq, act_seq, conf_seq, t0, act, int(k))
+        return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
 
     def step_k_fetch(self, handle: tuple) -> tuple[np.ndarray, np.ndarray]:
         """Materialize a dispatched block's ``(rows, S)`` tokens + emitted
         mask (``rows = k`` plain, ``k * (1 + spec_draft)`` speculative).
         ONE device_get for both arrays: two separate fetches would pay two
         host round trips per block."""
-        toks_seq, act_seq, conf_seq, t0, disp_active, k = handle
+        toks_seq, act_seq, conf_seq, t0, disp_active, k, ctr_dev = handle
         # the runtime audit (tests/test_perf.py) budgets exactly one
         # host sync per fused k-block: this is it — confidence margins
         # (conf_signal) ride the SAME fetch, never a second one
@@ -3327,11 +3338,15 @@ class GenerativeModel:
             else (toks_seq, act_seq)
         )
         # sct: host-sync-ok THE one fused-block fetch
-        fetched = jax.device_get(pull)
+        fetched, ctr_np = jax.device_get((pull, ctr_dev))
         toks_np, act_np = fetched[0], fetched[1]
         self.last_conf_seq = (
             np.asarray(fetched[2], np.float32) if len(fetched) > 2 else None
         )
+        if ctr_np is not None:
+            now = np.asarray(ctr_np).astype(np.uint64)
+            self._ctr_total += (now - self._ctr_last) % np.uint64(1 << 32)
+            self._ctr_last = now
         act_np = np.asarray(act_np)
         if self.spec_draft and disp_active is not None and disp_active.any():
             # speculation accounting + ceiling tightening: dispatch assumed
@@ -3445,6 +3460,7 @@ class GenerativeModel:
             # adapter bindings only change at sync points (admission /
             # release), so the continue path reuses the dispatched ids
             self._carry_aux = (temps, eos, aid)
+            self._ctr_dev = self._counters_copy()
             self.steps += k
         if self.conf_signal:
             return toks_seq, act_seq, conf_seq
@@ -3489,10 +3505,29 @@ class GenerativeModel:
             if fresh:
                 self._note_compile(label, time.perf_counter() - t0)
             self._carry = (tok_c, act_c, rem_c)
+            self._ctr_dev = self._counters_copy()
             self.steps += k
         if self.conf_signal:
             return toks_seq, act_seq, conf_seq
         return toks_seq, act_seq
+
+    def _counters_copy(self):
+        """The family's counters as the block just dispatched leaves them,
+        as an array of its own: the cache's buffer is donated to the next
+        dispatch, which may come before this block's fetch."""
+        ctr = self._cache.get("counters") if self._ctr_names else None
+        if ctr is None:
+            return None
+        import jax.numpy as jnp
+
+        return jnp.copy(ctr)
+
+    def counters_snapshot(self) -> dict | None:
+        """``{name: count}`` of the family's device counters as of the last
+        fetched decode block (None: the family has none)."""
+        if not self._ctr_names:
+            return None
+        return {n: int(v) for n, v in zip(self._ctr_names, self._ctr_total)}
 
     def warmup(self) -> int:
         """Compile the decode program and every prefill bucket.

@@ -17,7 +17,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from seldon_core_tpu.executor import BucketSpec, CompiledModel, JaxModelComponent
-from seldon_core_tpu.models import bert, cnn, llama, mlp, resnet
+from seldon_core_tpu.models import bert, cnn, cohere2_moe, llama, mlp, resnet
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +29,10 @@ class Family:
     param_logical_axes: Callable
     presets: dict[str, Callable[[], Any]]
     example_input: Callable[[Any, int], np.ndarray]  # (cfg, batch) -> array
+    # init_params(rng, cfg, dtype) makes the weights IN the served dtype: a
+    # family whose share of a deployment fills a chip in bfloat16 cannot be
+    # made in float32 first and cast
+    init_in_dtype: bool = False
 
 
 def _f32(shape):
@@ -73,6 +77,16 @@ _FAMILIES: dict[str, Family] = {
         },
         example_input=lambda c, b: np.ones((b, 16), np.int32),
     ),
+    "cohere2_moe": Family(
+        "cohere2_moe", cohere2_moe.Config, cohere2_moe.init_params,
+        cohere2_moe.apply, cohere2_moe.param_logical_axes,
+        presets={
+            "command-a-plus": cohere2_moe.Config,
+            "tiny": cohere2_moe.Config.tiny,
+        },
+        example_input=lambda c, b: np.ones((b, 16), np.int32),
+        init_in_dtype=True,
+    ),
 }
 
 
@@ -96,7 +110,7 @@ def resolve_config(family: str, preset: str | None = None, **overrides) -> Any:
 
 def _resolve_params(
     fam: Family, cfg: Any, params: Any, checkpoint: str | None, rng: int,
-    mesh: Mesh | None = None, rules: Any = None,
+    mesh: Mesh | None = None, rules: Any = None, dtype: Any = None,
 ):
     """Explicit params > checkpoint load > fresh init; the compiled wrapper
     casts/shards them at construction.  A fresh init runs under jit — one
@@ -105,7 +119,9 @@ def _resolve_params(
     device generates its shard: the whole float32 tree never sits on the
     default device first, where a model sized for the mesh does not fit.
     (The values are the eager, unsharded init's: threefry is
-    partitionable.)"""
+    partitionable.)  A family that makes its weights in the served dtype
+    (``init_in_dtype``) is given ``dtype``, and its key as an argument: one
+    compiled init program for every seed."""
     if params is not None:
         return params
     if checkpoint is not None:
@@ -113,8 +129,17 @@ def _resolve_params(
 
         return load_params(checkpoint)
 
-    def init():
-        return fam.init_params(jax.random.PRNGKey(rng), cfg)
+    if fam.init_in_dtype:
+        args = (jax.random.PRNGKey(rng),)
+        as_dtype = jnp.float32 if dtype is None else dtype
+
+        def init(key):
+            return fam.init_params(key, cfg, as_dtype)
+    else:
+        args = ()
+
+        def init():
+            return fam.init_params(jax.random.PRNGKey(rng), cfg)
 
     shardings = None
     if mesh is not None:
@@ -123,11 +148,11 @@ def _resolve_params(
             param_shardings,
         )
 
-        shapes = jax.eval_shape(init)
+        shapes = jax.eval_shape(init, *args)
         shardings = param_shardings(
             shapes, mesh, fam.param_logical_axes(shapes), rules or DEFAULT_RULES
         )
-    return jax.jit(init, out_shardings=shardings)()
+    return jax.jit(init, out_shardings=shardings)(*args)
 
 
 def build_compiled(
@@ -228,7 +253,9 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 
 # families exposing the slot-cache generative contract
 # (init_slot_cache / prefill_slot / decode_slots / sample_tokens)
-GENERATIVE_FAMILIES: dict[str, Any] = {"llama": llama}
+GENERATIVE_FAMILIES: dict[str, Any] = {
+    "llama": llama, "cohere2_moe": cohere2_moe,
+}
 
 
 def build_generative_component(
@@ -329,7 +356,7 @@ def build_generative_component(
         cfg = resolve_config(family, preset, **overrides)
     elif overrides:
         raise TypeError(f"unknown generative parameters {sorted(overrides)}")
-    params = _resolve_params(fam, cfg, params, checkpoint, rng, mesh)
+    params = _resolve_params(fam, cfg, params, checkpoint, rng, mesh, dtype=dtype)
     model = GenerativeModel(
         cfg,
         params,

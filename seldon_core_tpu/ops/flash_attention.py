@@ -13,7 +13,11 @@ Layout: ``(B, H, S, D)``.  The grid is ``(B, H, Sq/bq, Sk/bk)`` — TPU
 iterates the last axis fastest, so each query tile accumulates over its
 key tiles in VMEM scratch and writes its output once on the final key
 step.  Causal masking is per-tile (fully-masked tiles skip the matmul
-entirely).
+entirely): tiles wholly after a query tile, and with a sliding ``window``
+(key ``j`` visible to query ``i`` iff ``i - window < j <= i``) tiles wholly
+before it too.  ``k``/``v`` may carry fewer heads than ``q`` (grouped-query
+attention): query head ``h`` reads key head ``h // (H // Hk)`` through the
+block index, so the keys are never repeated in HBM.
 
 The kernel is compiled by Mosaic on every backend but the CPU, where it
 runs in Pallas interpret mode so the equivalence tests pin it to the dense
@@ -36,7 +40,8 @@ NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, block_q, block_k, n_k, causal, scale
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, block_q, block_k,
+    n_k, causal, scale, window=None
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -48,8 +53,11 @@ def _flash_kernel(
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # tiles where every key position is after every query position are
-    # fully masked: skip their FLOPs entirely
+    # fully masked: skip their FLOPs entirely; under a window so are tiles
+    # whose last key lies at or before the first query's ``i - window``
     live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
+    if window is not None:
+        live = live & (ki * block_k + block_k - 1 > qi * block_q - window)
 
     cdt, prec = mxu_operands(q_ref.dtype)
 
@@ -65,7 +73,10 @@ def _flash_kernel(
         if causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, NEG_INF)
+            seen = rows >= cols
+            if window is not None:
+                seen = seen & (cols > rows - window)
+            s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, 0]
         l_prev = l_scr[:, 0]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1))
@@ -87,7 +98,8 @@ def _flash_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
 )
 def flash_attention(
     q: jax.Array,
@@ -98,10 +110,19 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool | None = None,
+    window: int | None = None,
 ) -> jax.Array:
-    """``(B, H, S, D)`` attention; blocks clamp to S and must divide it."""
+    """``(B, H, S, D)`` attention; blocks clamp to S and must divide it.
+    ``window`` (static, causal only) keeps keys ``j > i - window``;
+    ``k``/``v`` of shape ``(B, Hk, Sk, D)`` with ``Hk`` dividing ``H`` are
+    read grouped, never repeated."""
     B, H, S, D = q.shape
-    Sk = k.shape[2]
+    Hk, Sk = k.shape[1], k.shape[2]
+    if H % Hk:
+        raise ValueError(f"{H} query heads do not group over {Hk} key heads")
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
+    g = H // Hk
     block_q = min(block_q, S)
     block_k = min(block_k, Sk)
     if S % block_q or Sk % block_k:
@@ -122,14 +143,15 @@ def flash_attention(
         n_k=n_k,
         causal=causal,
         scale=scale,
+        window=None if window is None else int(window),
     )
     return pl.pallas_call(
         kernel,
         grid=(B, H, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),

@@ -62,6 +62,7 @@ def mxu_operands(dtype) -> tuple:
 def _paged_kernel(
     table_ref,  # (S, WB) int32 scalar-prefetch: physical block per grid step
     pos_ref,  # (S,) int32 scalar-prefetch: per-slot base position
+    first_ref,  # (S,) int32 scalar-prefetch: position of the first row read
     q_ref,  # (1, KV*R, KV*D) block-diagonal, pre-scaled queries of one slot
     k_ref,  # (1, BS, KV*D) one gathered KV block, every head
     v_ref,
@@ -71,6 +72,7 @@ def _paged_kernel(
     rows,
     n_w,
     quant,
+    window=None,
 ):
     if quant:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
@@ -87,10 +89,17 @@ def _paged_kernel(
 
     HR = q_ref.shape[1]  # KV * rows
     base = pos_ref[s_i]
+    # grid step w reads the rows at positions col0 .. col0 + bs - 1: the
+    # table's blocks are the slot's first ones (first = 0) or, under a
+    # sliding window, the ones from the block that holds its lower edge on
+    col0 = first_ref[s_i] + w * bs
     # key blocks entirely past every query position are dead weight: the
     # furthest query sits at base + L - 1 (each head's last row is query
-    # L-1's last group)
-    live = w * bs <= base + (rows - 1) // groups
+    # L-1's last group); under a window so are blocks that end at or
+    # before the first query's ``base - window``
+    live = col0 <= base + (rows - 1) // groups
+    if window is not None:
+        live = live & (col0 + bs - 1 > base - window)
 
     # int8 pool values are exact in bfloat16, so a quantized pool rides
     # the queries' operand dtype too
@@ -129,8 +138,11 @@ def _paged_kernel(
         rows_j = (
             jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 0) % rows
         ) // groups
-        cols = w * bs + jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 1)
-        s = jnp.where(cols <= base + rows_j, s, NEG_INF)
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 1)
+        seen = cols <= base + rows_j
+        if window is not None:
+            seen = seen & (cols > base + rows_j - window)
+        s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, 0]
         l_prev = l_scr[:, 0]
         m_cur = jnp.maximum(m_prev, s.max(axis=-1))
@@ -163,6 +175,8 @@ def paged_decode_attention(
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
     interpret: bool | None = None,
+    first: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Attention for ``L`` decode queries per slot over the paged KV pool.
 
@@ -173,6 +187,12 @@ def paged_decode_attention(
     slot's base position — query ``j`` sees pool rows ``[0, pos + j]``.
     Returns ``(S, L, H, D)`` in the query dtype.  Semantics are exactly
     :func:`paged_decode_attention_reference` (the XLA gather path).
+
+    A sliding-window layer hands ``table`` the blocks that hold its window
+    and ``first (S,)`` the position of the first row of the first of them
+    (a multiple of ``BS``; default 0: the slot's first blocks), and
+    ``window`` (static): query ``j`` then sees rows at positions
+    ``(pos + j - window, pos + j]``, and blocks wholly outside are skipped.
 
     ``interpret`` defaults to True on the CPU backend only; every other
     backend compiles the kernel, and a shape Mosaic refuses is an error —
@@ -201,13 +221,16 @@ def paged_decode_attention(
         (qr * scale)[:, :, :, None, :] * eye[None, :, None, :, None]
     ).reshape(S, KV * R, KV * D)
     kernel = functools.partial(
-        _paged_kernel, bs=BS, groups=groups, rows=R, n_w=WB, quant=quant
+        _paged_kernel, bs=BS, groups=groups, rows=R, n_w=WB, quant=quant,
+        window=None if window is None else int(window),
     )
+    if first is None:
+        first = jnp.zeros((S,), jnp.int32)
 
-    def slot_block(s, w, t, p):
+    def slot_block(s, w, t, p, f):
         return (s, 0, 0)
 
-    def pool_block(s, w, t, p):
+    def pool_block(s, w, t, p, f):
         # the gather: scalar-prefetched table entries drive the DMA source
         return (t[s, w], 0, 0)
 
@@ -230,7 +253,7 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(S, WB),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KV * R, KV * D), slot_block),
@@ -242,7 +265,10 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((S, KV * R, KV * D), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32), *args)
+    )(
+        jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
+        jnp.asarray(first, jnp.int32), *args,
+    )
     # head h's output sits in lanes [h*D, (h+1)*D) of its own rows
     out = jnp.diagonal(
         out.reshape(S, KV, R, KV, D), axis1=1, axis2=3

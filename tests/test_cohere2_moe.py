@@ -1,0 +1,484 @@
+"""The ``cohere2_moe`` family (models/cohere2_moe.py) against the benchmark's
+plain reference (benchmark/reference/cohere2_moe_decoder.py), at a small
+size on the CPU: hidden 64, 8 heads / 2 kv, 16 experts top-4 of width 32,
+2 shared, window 8, 4 layers (sliding, sliding, sliding, full), vocabulary
+256.  Logits, not tokens."""
+
+import dataclasses
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from seldon_core_tpu.models import cohere2_moe as m
+
+sys.path.insert(
+    0, os.path.join(os.path.dirname(__file__), "..", "benchmark", "reference")
+)
+import cohere2_moe_decoder as ref  # noqa: E402
+
+BS = 4  # KV block
+TOL = 2e-5  # float32 against float32: summation order only
+
+
+def _cfg(**kw):
+    return m.Config.tiny(max_seq=64, experts_held="4:8", **kw)
+
+
+def _params(cfg, seed=3, dtype=jnp.float32):
+    return m.init_params(jax.random.PRNGKey(seed), cfg, dtype)
+
+
+def _ref_kw(cfg):
+    return dict(
+        pattern=cfg.layer_pattern, theta=cfg.rope_theta, eps=cfg.norm_eps,
+        window=cfg.sliding_window, top_k=cfg.experts_per_tok, held=cfg.held,
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(cfg, which, **static):
+    """One compiled program for each (configuration, entry point): the tests
+    share them, as serving does."""
+    fn = {
+        "prefill": m.prefill_slot_paged, "suffix": m.prefill_suffix_paged,
+        "decode": m.decode_slots_paged, "multi": m._decode_paged_multi,
+        "spec": m.decode_slots_spec_paged,
+    }[which]
+    # where ``cfg`` stands among each entry point's positional arguments
+    cfg_at = {"prefill": 6, "suffix": 8, "decode": 4, "multi": 5, "spec": 5}[which]
+
+    def call(*args):
+        return fn(*args[:cfg_at], cfg, *args[cfg_at:], **static)
+
+    return jax.jit(call)
+
+
+def _slot_row(n_blocks=14, width=16):
+    """A table row whose blocks are out of order (block 0 is the sink)."""
+    row = np.zeros(width, np.int32)
+    row[:n_blocks] = np.arange(1, n_blocks + 1)[::-1]
+    return row
+
+
+def _prefill(cfg, params, prompt, *, seq_impl="dense", chunks=None, slot=1):
+    """Prompt -> (last logits, cache), whole or in ``chunks`` (the first
+    through ``prefill_slot_paged``, the others through the suffix program
+    over the slot's own blocks, as the scheduler's chunked prefill does)."""
+    cache = m.init_paged_cache(cfg, 2, 40, BS, params["ln_f"].dtype)
+    row = jnp.asarray(_slot_row())
+    L = len(prompt)
+    spans = [(0, L)] if not chunks else list(zip(chunks[:-1], chunks[1:]))
+    logits = None
+    for a, b in spans:
+        bucket = -(-(b - a) // BS) * BS
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, : b - a] = prompt[a:b]
+        if a == 0:
+            logits, cache = _jitted(cfg, "prefill", seq_impl=seq_impl)(
+                params, jnp.asarray(padded), jnp.int32(b), jnp.int32(slot),
+                row, cache,
+            )
+        else:
+            sb = np.zeros(bucket // BS, np.int32)
+            have = np.asarray(row)[a // BS: a // BS + bucket // BS]
+            sb[: have.size] = have
+            pw = BS
+            while pw < a:
+                pw *= 2
+            logits, cache = _jitted(
+                cfg, "suffix", prefix_window=min(pw, cfg.max_seq)
+            )(
+                params, jnp.asarray(padded), jnp.int32(a), jnp.int32(b),
+                jnp.int32(slot), row, jnp.asarray(sb), cache,
+            )
+    return logits, cache
+
+
+def _decode(cfg, params, cache, first, steps, **kw):
+    """Greedy decode of slot 1 -> (tokens fed, logits of every step, cache)."""
+    active = jnp.asarray([False, True])
+    fed, out, nxt = [], [], int(first)
+    for _ in range(steps):
+        fed.append(nxt)
+        lg, cache = _jitted(cfg, "decode", window=cfg.max_seq, **kw)(
+            params, jnp.asarray([0, nxt], jnp.int32), cache, active,
+        )
+        out.append(np.asarray(lg[1]))
+        nxt = int(np.argmax(out[-1]))
+    return fed, out, cache
+
+
+@pytest.fixture(scope="module")
+def prompt():
+    return np.random.default_rng(0).integers(1, 256, 37)
+
+
+class TestAgainstReference:
+    """Prefill, then decode through the paged cache, against the reference's
+    full forward pass: contexts of 3 to 6 windows (37 to 49 tokens, window
+    8), so both layer kinds, the window's edge and the position-free layers
+    are all crossed."""
+
+    @pytest.mark.parametrize("seq_impl", ["dense", "flash"])
+    @pytest.mark.parametrize("experts", ["dense", "grouped"])
+    def test_prefill_then_decode(self, monkeypatch, prompt, seq_impl, experts):
+        if experts == "grouped":
+            # the prefill's formulation at a prompt of 37: sorted pairs,
+            # grouped products, three passes of 32 rows
+            monkeypatch.setattr(m, "GROUPED_FROM", 8)
+            monkeypatch.setattr(m, "GROUP_CHUNK", 32)
+        cfg = _cfg()
+        params = _params(cfg)
+        logits, cache = _prefill(cfg, params, prompt, seq_impl=seq_impl)
+        want = ref.logits(params, prompt, **_ref_kw(cfg))
+        np.testing.assert_allclose(logits, want[-1], atol=TOL, rtol=0)
+        fed, got, _ = _decode(cfg, params, cache, np.argmax(logits), 12)
+        want = ref.logits(params, np.concatenate([prompt, fed]), **_ref_kw(cfg))
+        np.testing.assert_allclose(
+            np.stack(got), want[len(prompt):], atol=TOL, rtol=0
+        )
+
+    @pytest.mark.parametrize("chunks", [[0, 16, 37], [0, 8, 24, 32, 37]])
+    def test_prefill_in_chunks(self, prompt, chunks):
+        """Through ``prefill_suffix_paged``: chunk boundaries inside and
+        past the window, the prefix read from the slot's own blocks."""
+        cfg = _cfg()
+        params = _params(cfg)
+        whole, _ = _prefill(cfg, params, prompt)
+        logits, cache = _prefill(cfg, params, prompt, chunks=chunks)
+        np.testing.assert_allclose(logits, whole, atol=TOL, rtol=0)
+        fed, got, _ = _decode(cfg, params, cache, np.argmax(logits), 4)
+        want = ref.logits(params, np.concatenate([prompt, fed]), **_ref_kw(cfg))
+        np.testing.assert_allclose(
+            np.stack(got), want[len(prompt):], atol=TOL, rtol=0
+        )
+
+    def test_bfloat16_as_served(self, prompt):
+        """The served dtype: bfloat16 weights and activations, the router in
+        float32, against the float32 reference on the same weights."""
+        cfg = _cfg()
+        params = _params(cfg, dtype=jnp.bfloat16)
+        logits, cache = _prefill(cfg, params, prompt, seq_impl="flash")
+        fed, got, _ = _decode(cfg, params, cache, np.argmax(logits), 8)
+        want = np.asarray(
+            ref.logits(params, np.concatenate([prompt, fed]), **_ref_kw(cfg))
+        )[len(prompt):]
+        got = np.stack(got).astype(np.float32)
+        # bfloat16 at hidden 64 is coarse (8 bits of mantissa, little to
+        # average over): the bulk of the logits agrees closely, the worst one
+        # loosely; a wrong layer (TestNegativeControls) is off by far more
+        err = np.abs(got - want)
+        assert err.mean() < 0.03 and err.max() < 0.6
+        # each token served lies within a margin of the reference's top
+        served = np.asarray(fed[1:])
+        rows = want[: len(served)]
+        assert (rows.max(-1) - rows[np.arange(len(served)), served]).max() < 0.5
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_decode_through_the_paged_kernel(self, prompt, dtype):
+        """``kernel=True``: the Pallas paged decode-attention kernel (in
+        interpret mode here) with the window inside it, against the XLA
+        gather path, on both layer kinds past the window."""
+        cfg = _cfg()
+        params = _params(cfg, dtype=dtype)
+        logits, cache = _prefill(cfg, params, prompt)
+        first = np.argmax(logits)
+        fed, xla, _ = _decode(cfg, params, dict(cache), first, 6)
+        fed_k, ker, _ = _decode(cfg, params, dict(cache), first, 6, kernel=True)
+        tol = TOL if dtype == jnp.float32 else 0.05
+        np.testing.assert_allclose(
+            np.stack(ker[:1]).astype(np.float32),
+            np.stack(xla[:1]).astype(np.float32), atol=tol, rtol=0,
+        )
+        if dtype == jnp.float32:
+            assert fed_k == fed
+            np.testing.assert_allclose(np.stack(ker), np.stack(xla), atol=TOL, rtol=0)
+
+    def test_spec_verify_positions_equal_single_steps(self, prompt):
+        """``decode_slots_spec_paged`` (L queries a slot) scores the same
+        positions as L single steps."""
+        cfg = _cfg()
+        params = _params(cfg)
+        logits, cache = _prefill(cfg, params, prompt)
+        fed, got, _ = _decode(cfg, params, dict(cache), np.argmax(logits), 3)
+        q = jnp.asarray([[0, 0, 0], fed], jnp.int32)
+        active = jnp.asarray([False, True])
+        lg, _ = _jitted(cfg, "spec", window=cfg.max_seq)(
+            params, q, dict(cache), active,
+            jnp.broadcast_to(active[:, None], (2, 3)),
+        )
+        np.testing.assert_allclose(lg[1], np.stack(got), atol=TOL, rtol=0)
+
+
+class TestShareTiesToTheModel:
+    def test_eight_shares_add_up_to_the_uncut_layer(self):
+        """The parts that all 8 shares of a layer give (2 of the 16 experts
+        each), the shared mean counted once, add up to the uncut reference
+        layer: the routed experts' weights are the same in every share that
+        holds them, the router and its normalisation are the whole model's."""
+        whole = m.Config.tiny(max_seq=64)
+        wp = _params(whole)
+        h = jax.random.normal(jax.random.PRNGKey(9), (21, whole.hidden))
+        lp0 = {k: v[0] for k, v in wp["layers"].items()}
+        with jax.default_matmul_precision("highest"):
+            want = ref.moe(h, lp0, top_k=whole.experts_per_tok, held=(0, 16))
+        mask = jnp.ones((21,), bool)
+
+        @functools.partial(jax.jit, static_argnums=0)
+        def part(cfg, layers):
+            lp = {k: v[0] for k, v in layers.items()}
+            out, _ = m._moe(h, lp, cfg, mask, None, decode=True,
+                            stacks=layers, li=0)
+            g = jnp.einsum("te,jef->jtf", h, lp["ws_gate"])
+            u = jnp.einsum("te,jef->jtf", h, lp["ws_up"])
+            shared = jnp.einsum(
+                "jtf,jfe->te", jax.nn.silu(g) * u, lp["ws_down"]
+            ) / cfg.n_shared_experts
+            return out - shared, shared
+
+        total = 0.0
+        for k in range(8):
+            cfg = dataclasses.replace(whole, experts_held=f"{2 * k}:2")
+            # a share's weights ARE the whole model's experts 2k, 2k + 1
+            layers = {
+                name: a[:, 2 * k: 2 * k + 2] if name.startswith("we_") else a
+                for name, a in wp["layers"].items()
+            }
+            if k in (0, 5):  # ... which is what its own init makes
+                own = _params(cfg)["layers"]
+                for name in layers:
+                    np.testing.assert_array_equal(own[name], layers[name])
+            routed, shared = part(cfg, layers)
+            total = total + routed
+        np.testing.assert_allclose(total + shared, want, atol=TOL, rtol=0)
+        # and one share alone is NOT the layer
+        assert np.abs(np.asarray(routed + shared - want)).max() > 1e-2
+
+
+class TestWindowRead:
+    """A sliding layer reads the blocks that hold its window
+    (``window_read``), not the slot's first blocks; the rows it reads are the
+    full read's rows at the same positions, bit for bit, and the step's
+    logits are those of a full read under the window mask."""
+
+    def test_rows_read_are_the_full_reads_rows(self, prompt):
+        cfg = _cfg()
+        params = _params(cfg, dtype=jnp.bfloat16)
+        _, cache = _prefill(cfg, params, prompt)
+        pos, table = cache["pos"], cache["table"]
+        assert m.window_blocks(cfg, BS) == 3 < cfg.max_seq // BS
+        phys, kpos = m.window_read(table, pos, cfg, BS)
+        rows = np.asarray(cache["k"][0, phys]).reshape(2, -1, 2 * 8)
+        full = np.asarray(cache["k"][0, table]).reshape(2, -1, 2 * 8)
+        p, kp = int(pos[1]), np.asarray(kpos[1])
+        inside = (kp <= p) & (kp > p - cfg.sliding_window)
+        assert inside.sum() == cfg.sliding_window
+        np.testing.assert_array_equal(rows[1][inside], full[1][kp[inside]])
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_step_equals_full_read_under_the_mask(self, prompt, dtype):
+        cfg = _cfg()
+        params = _params(cfg, dtype=dtype)
+        logits, cache = _prefill(cfg, params, prompt)
+        nxt = jnp.asarray([0, int(np.argmax(logits))], jnp.int32)
+        active = jnp.asarray([False, True])
+        args = (params, nxt[:, None], dict(cache), active, active[:, None])
+        windowed, c1 = _jitted(cfg, "multi", window=cfg.max_seq)(*args)
+        full, c2 = _jitted(
+            cfg, "multi", window=cfg.max_seq, window_read_off=True
+        )(*args)
+        # the same rows under the same mask: equal but for the order the
+        # softmax sums them in (the full read sums the masked rows' zeros too)
+        tol = 1e-5 if dtype == jnp.float32 else 0.02
+        np.testing.assert_allclose(
+            np.asarray(windowed[1], np.float32), np.asarray(full[1], np.float32),
+            atol=tol, rtol=0,
+        )
+        np.testing.assert_array_equal(c1["k"][0], c2["k"][0])
+
+
+class TestNegativeControls:
+    """Each of the four ways to get the layer wrong fails the comparison the
+    tests above pass: the reference, made wrong in that one way, no longer
+    agrees with the program."""
+
+    @pytest.mark.parametrize("wrong", [
+        {"window": None},              # the window mask dropped
+        {"rope_on_full": True},        # RoPE on the position-free layers
+        {"shared_mean": False},        # a plain sum of the shared experts
+        {"norm_over": "held"},         # normalised over the held picks only
+    ], ids=["no-window", "rope-on-full", "shared-sum", "norm-over-held"])
+    def test_wrong_layer_disagrees(self, prompt, wrong):
+        cfg = _cfg()
+        params = _params(cfg)
+        logits, _ = _prefill(cfg, params, prompt)
+        kw = {**_ref_kw(cfg), **wrong}
+        bad = ref.logits(params, prompt, **kw)[-1]
+        assert np.abs(np.asarray(logits - bad)).max() > 100 * TOL
+
+
+class TestCounters:
+    def test_routing_is_counted_on_the_device(self, prompt):
+        cfg = _cfg()
+        params = _params(cfg)
+        logits, cache = _prefill(cfg, params, prompt)
+        c = dict(zip(m.COUNTERS, np.asarray(cache["counters"]).tolist()))
+        n, k, layers = len(prompt), cfg.experts_per_tok, cfg.n_layers
+        assert c["moe.prefill_tokens"] == n
+        assert c["moe.prefill_pairs_routed"] == n * k * layers
+        assert 0 < c["moe.prefill_pairs_held"] < n * k * layers
+        assert c["moe.steps"] == 0
+        _, _, cache = _decode(cfg, params, cache, np.argmax(logits), 5)
+        c = dict(zip(m.COUNTERS, np.asarray(cache["counters"]).tolist()))
+        assert c["moe.steps"] == 5
+        assert c["moe.pairs_routed"] == 5 * k * layers  # one active slot
+        assert c["moe.pairs_held"] <= c["moe.pairs_routed"]
+        # one token a step: every expert it touches holds exactly that token
+        assert c["moe.experts_touched"] == c["moe.pairs_held"]
+        assert c["moe.max_tokens_on_expert"] <= 5 * layers
+
+
+class TestServedPath:
+    """Through ``JAX_GENERATIVE``'s own objects: the registry builds the
+    family in the served dtype, ``GenerativeModel`` warms it and serves it
+    with the scheduler's programs, and the counters reach the snapshot."""
+
+    def _component(self, **kw):
+        from seldon_core_tpu.models.registry import build_generative_component
+
+        return build_generative_component(
+            "cohere2_moe", preset="tiny", experts_held="4:8", max_seq=64,
+            n_slots=2, decode_block=4, kv_block_size=4, dtype=jnp.bfloat16,
+            rng=5, **kw,
+        )
+
+    def test_weights_are_made_in_the_served_dtype(self):
+        from seldon_core_tpu.models import registry
+
+        fam = registry.get_family("cohere2_moe")
+        cfg = registry.resolve_config("cohere2_moe", "tiny")
+        params = registry._resolve_params(
+            fam, cfg, None, None, 7, dtype=jnp.bfloat16
+        )
+        assert {str(a.dtype) for a in jax.tree.leaves(params)} == {"bfloat16"}
+        again = m.init_params(jax.random.PRNGKey(7), cfg, jnp.bfloat16)
+        for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(again)):
+            np.testing.assert_array_equal(a, b)
+
+    def test_llama_init_is_left_as_it_was(self):
+        from seldon_core_tpu.models import llama, registry
+
+        cfg = llama.Config.tiny()
+        params = registry._resolve_params(
+            registry.get_family("llama"), cfg, None, None, 11,
+            dtype=jnp.bfloat16,
+        )
+        want = llama.init_params(jax.random.PRNGKey(11), cfg)
+        assert params["tok_emb"].dtype == jnp.float32
+        np.testing.assert_array_equal(params["layers"]["wq"], want["layers"]["wq"])
+
+    @pytest.mark.parametrize("seq_impl,kernel", [
+        ("dense", False), ("flash", True),  # the XLA paths; the Pallas paths
+    ])
+    def test_generates_what_the_family_computes(self, prompt, seq_impl, kernel):
+        from seldon_core_tpu.utils.device import xla_compile_count
+
+        comp = self._component(seq_impl=seq_impl, decode_kernel=kernel)
+        model = comp.model
+        assert model.family is m and model.params["ln_f"].dtype == jnp.bfloat16
+        model.warmup()
+        warmed = xla_compile_count()
+        tok = model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=12)
+        cur, active = np.zeros(2, np.int32), np.zeros(2, bool)
+        cur[0], active[0] = int(tok), True
+        toks, emitted = model.step_k(
+            cur, active, np.zeros(2, np.float32), 0,
+            np.full(2, -1, np.int32), np.full(2, 12, np.int32), 4,
+        )
+        assert emitted[:, 0].all()
+        assert xla_compile_count() == warmed  # nothing compiled after warm-up
+        served = [int(tok)] + [int(t) for t in toks[:, 0]]
+        # teacher-forced on the served tokens, the float32 reference puts
+        # each of them within a small margin of its top logit
+        want = np.asarray(ref.logits(
+            model.params, np.concatenate([prompt, served[:-1]]),
+            **_ref_kw(model.cfg),
+        ))[len(prompt) - 1:]
+        deficit = want.max(-1) - want[np.arange(len(served)), served]
+        assert deficit.max() < 0.25
+        snap = model.spec_snapshot()["counters"]
+        assert snap["moe.steps"] >= 4
+        assert snap["moe.pairs_routed"] >= 4 * 4 * 4  # steps x top-4 x layers
+        assert snap["moe.prefill_tokens"] >= len(prompt)
+
+    def test_what_the_family_does_not_have_is_refused(self):
+        from seldon_core_tpu.graph.units import GraphUnitError
+
+        with pytest.raises(GraphUnitError, match="kv_cache_dtype"):
+            self._component(kv_cache_dtype="int8")
+        # no LoRA path either: the pool is switched off with a warning
+        assert self._component(lora_rank=4).model.lora_rank == 0
+
+
+class TestEngineRoutes:
+    """``examples/cohere2-moe-generative/graph.json`` through the engine's
+    own app: ``/predictions`` and ``/predictions/stream`` give the same
+    tokens, and the routing counters are in ``/stats/summary``."""
+
+    def test_the_example_graph_serves_both_routes(self):
+        import asyncio
+        import json
+
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.app import EngineApp
+        from seldon_core_tpu.engine.service import PredictionService
+        from seldon_core_tpu.graph.spec import PredictorSpec
+
+        path = os.path.join(
+            os.path.dirname(__file__), "..", "examples",
+            "cohere2-moe-generative", "graph.json",
+        )
+        with open(path) as f:
+            predictor = json.load(f)
+        prompt = list(range(3, 40))
+
+        async def go():
+            service = PredictionService(PredictorSpec.model_validate(predictor))
+            client = TestClient(TestServer(EngineApp(service).build()))
+            await client.start_server()
+            try:
+                resp = await client.post(
+                    "/api/v0.1/predictions",
+                    json={"strData": json.dumps(
+                        {"tokens": prompt, "max_new_tokens": 20})},
+                )
+                assert resp.status == 200, await resp.text()
+                expected = json.loads((await resp.json())["strData"])["tokens"]
+                assert len(expected) == 20
+                resp = await client.post(
+                    "/api/v0.1/predictions/stream",
+                    json={"tokens": prompt, "max_new_tokens": 20},
+                )
+                assert resp.status == 200, await resp.text()
+                events = [
+                    json.loads(line[len("data: "):])
+                    for line in (await resp.text()).splitlines()
+                    if line.startswith("data: ")
+                ]
+                assert [e["token"] for e in events if "token" in e] == expected
+                stats = await (await client.get("/stats/summary")).json()
+                unit = stats["breakdown"]["generation"]["cohere2_moe:tiny"]
+                c = unit["counters"]
+                assert c["moe.steps"] > 0 and c["moe.pairs_routed"] > 0
+                assert 0 < c["moe.pairs_held"] <= c["moe.pairs_routed"]
+                assert c["moe.prefill_tokens"] >= 2 * len(prompt)
+            finally:
+                await client.close()
+
+        asyncio.run(go())

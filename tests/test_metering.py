@@ -269,10 +269,31 @@ class TestAttributionConservation:
             for name, blk in blocks.items()
         }
         prompt = np.asarray([5, 9, 2, 17, 3], np.int32)
+        # blocks dispatched against blocks delivered: the overlapped
+        # pipeline has one more block in flight when the last request's
+        # future resolves, and close() would cancel it between its fetch
+        # (which the wall total below counts) and its delivery (where the
+        # meter charges it) — so a round trip ends once the two are level
+        blocks_seen = {"dispatched": 0, "delivered": 0}
+
+        def counted(fn, key):
+            def wrapped(*args, **kwargs):
+                out = fn(*args, **kwargs)
+                blocks_seen[key] += 1
+                return out
+
+            return wrapped
+
+        for model in models.values():
+            model.step_k_dispatch = counted(model.step_k_dispatch, "dispatched")
+            model.step_k_continue = counted(model.step_k_continue, "dispatched")
 
         def round_trip():
             arb = DeviceArbiter()
             scheds = {n: GenerationScheduler(m) for n, m in models.items()}
+            blocks_seen.update(dispatched=0, delivered=0)
+            for s in scheds.values():
+                s._deliver = counted(s._deliver, "delivered")
 
             async def go():
                 scheds["met-inter"].attach_arbiter(
@@ -281,11 +302,16 @@ class TestAttributionConservation:
                 scheds["met-bulk-0"].attach_arbiter(arb, priority="batch")
                 scheds["met-bulk-1"].attach_arbiter(arb, priority="batch")
                 try:
-                    return await asyncio.gather(*(
+                    outs = await asyncio.gather(*(
                         s.submit(prompt, max_new_tokens=max_new)
                         for s in scheds.values()
                         for _ in range(2)
                     ))
+                    for _ in range(1000):
+                        if blocks_seen["dispatched"] == blocks_seen["delivered"]:
+                            break
+                        await asyncio.sleep(0.01)
+                    return outs
                 finally:
                     for s in scheds.values():
                         await s.close()

@@ -54,6 +54,43 @@ class TestFlashAttention:
         with pytest.raises(ValueError, match="divisible"):
             flash_attention(q, q, q, block_q=64, block_k=64)
 
+    @pytest.mark.parametrize("window,blocks", [
+        (40, (64, 64)),    # the window inside one tile: its lower edge cuts tiles
+        (64, (32, 64)),    # the edge on a tile boundary
+        (100, (64, 32)),   # key tiles wholly before i - window are skipped
+        (1, (32, 32)),     # a query sees itself alone
+        (4096, (64, 64)),  # wider than the sequence: plain causal
+    ])
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1])
+    def test_window_matches_dense_mask(self, window, blocks, kv_heads):
+        """A sliding window (key j visible to query i iff i - window < j <=
+        i) inside the kernel, the tile-skipping test knowing its lower edge
+        as it knows the causal upper one, pinned against the dense mask in
+        interpret mode; grouped key heads are read by index, not repeated."""
+        B, H, S, D = 1, 4, 256, 32
+        rng = np.random.default_rng(2)
+        q = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(B, kv_heads, S, D)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(B, kv_heads, S, D)), jnp.float32)
+        out = flash_attention(
+            q, k, v, causal=True, block_q=blocks[0], block_k=blocks[1],
+            window=window,
+        )
+        kr = jnp.repeat(k, H // kv_heads, axis=1)
+        vr = jnp.repeat(v, H // kv_heads, axis=1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, kr) / np.sqrt(D)
+        i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        s = jnp.where((j <= i) & (j > i - window), s, -1e30)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), vr)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
+
+    def test_window_needs_causal(self):
+        q = jnp.zeros((1, 1, 64, 32), jnp.float32)
+        with pytest.raises(ValueError, match="causal"):
+            flash_attention(q, q, q, causal=False, window=8)
+
 
 class TestFlashBlhdAdapter:
     """Direct unit coverage for ``flash_causal_attention_blhd`` — the
@@ -209,6 +246,49 @@ class TestPagedDecodeAttention:
     @pytest.mark.parametrize("L", [1, 3, 5])
     def test_matches_reference_across_query_counts(self, L):
         self._compare(3, L, 2, 2, 16, 9, 16, 3, seed=L)
+
+    @pytest.mark.parametrize("L,window", [(1, 24), (1, 40), (3, 24), (2, 1000)])
+    def test_a_sliding_window_reads_its_own_blocks(self, L, window):
+        """``first`` + ``window``: the table holds the blocks from the one
+        with the window's lower edge on, ``first`` is that block's first
+        position, and query ``j`` sees positions ``(pos + j - window, pos +
+        j]`` — against dense attention over the slot's rows in order."""
+        from seldon_core_tpu.ops import paged_decode_attention
+
+        rng = np.random.default_rng(7 * L + window)
+        S, KV, G, D, NB, BS, MB = 3, 2, 4, 16, 40, 8, 12
+        H = KV * G
+        q = self._rand(rng, S, L, H, D)
+        k = self._rand(rng, NB, BS, KV, D)
+        v = self._rand(rng, NB, BS, KV, D)
+        slot_blocks = jnp.asarray(
+            rng.permutation(NB - 1)[: S * MB].reshape(S, MB) + 1, jnp.int32
+        )
+        pos = jnp.asarray([70, 37, 11], jnp.int32)
+        nb = -(-(window + L - 2) // BS) + 1  # blocks that cover the window
+        nb = min(nb, MB)
+        start = jnp.maximum(pos - window + 1, 0) // BS
+        logical = jnp.minimum(start[:, None] + jnp.arange(nb)[None, :], MB - 1)
+        table = jnp.take_along_axis(slot_blocks, logical, axis=1)
+        out = paged_decode_attention(
+            q, k, v, table, pos, first=start * BS, window=window
+        )
+        # dense: every row of the slot in order, masked by position
+        kw = k[slot_blocks].reshape(S, MB * BS, KV, D)
+        vw = v[slot_blocks].reshape(S, MB * BS, KV, D)
+        qpos = pos[:, None] + jnp.arange(L)[None, :]
+        rows = jnp.arange(MB * BS)[None, None, :]
+        seen = (rows <= qpos[..., None]) & (rows > qpos[..., None] - window)
+        s = jnp.einsum(
+            "bqkgd,bskd->bkgqs", q.reshape(S, L, KV, G, D), kw
+        ) / np.sqrt(D)
+        s = jnp.where(seen[:, None, None], s, -1e30)
+        want = jnp.einsum(
+            "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1), vw
+        ).reshape(S, L, H, D)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5
+        )
 
     @pytest.mark.parametrize("KV,G", [(1, 4), (2, 2), (3, 2), (4, 1)])
     def test_matches_reference_across_gqa_head_counts(self, KV, G):
