@@ -316,23 +316,33 @@ class GenerativeModel:
         self.prefill_chunk = prefill_chunk
         # Pallas paged decode-attention kernel (ops/paged_attention.py):
         # fuses block-table gather + int8 dequant + attention over the
-        # paged pool inside the compiled decode step.  Single-device only
-        # — the kernel does not partition over a mesh axis — compiled by
-        # Mosaic on the chip, interpret-mode on CPU so tier-1 covers it.
-        # Opt-in via the ``decode_kernel`` graph parameter or
-        # SCT_DECODE_KERNEL=1.  Asking for it where it cannot run is a
-        # build error: a deployment whose operator believes the kernel is
-        # on must never be quietly served by the XLA gather path.
+        # paged pool inside the compiled decode step, and reads only the
+        # blocks a live slot holds.  Single-device only — the kernel does
+        # not partition over a mesh axis — compiled by Mosaic on the chip,
+        # interpret-mode on CPU so tier-1 covers it.  Unset (the
+        # ``decode_kernel`` graph parameter and SCT_DECODE_KERNEL both),
+        # the program chooses: the kernel where the pool is on one device,
+        # the family's decode takes ``kernel=`` and the backend compiles
+        # Pallas; else the XLA gather.  Set, it is honoured, and asking
+        # for the kernel where it cannot run is a build error: a
+        # deployment whose operator believes the kernel is on must never
+        # be quietly served by the XLA gather path.
+        import inspect
+
+        _dsp = getattr(family_mod, "decode_slots_paged", None)
+        _takes_kernel = _dsp is not None and (
+            "kernel" in inspect.signature(_dsp).parameters
+        )
+        if decode_kernel is None and os.environ.get("SCT_DECODE_KERNEL"):
+            decode_kernel = os.environ["SCT_DECODE_KERNEL"] == "1"
         if decode_kernel is None:
-            decode_kernel = os.environ.get("SCT_DECODE_KERNEL", "0") == "1"
+            decode_kernel = (
+                mesh is None and _takes_kernel
+                and jax.default_backend() != "cpu"
+            )
         decode_kernel = bool(decode_kernel)
         if decode_kernel:
-            import inspect
-
-            _dsp = getattr(family_mod, "decode_slots_paged", None)
-            if _dsp is None or "kernel" not in inspect.signature(
-                _dsp
-            ).parameters:
+            if not _takes_kernel:
                 raise GraphUnitError(
                     f"generative model {name!r}: decode_kernel is set but "
                     f"family {family_mod.__name__} has no kernel decode "
@@ -348,6 +358,12 @@ class GenerativeModel:
                     "SCT_DECODE_KERNEL) or drop the mesh."
                 )
         self.decode_kernel = decode_kernel
+        # what the decode read has to touch against what its window spans,
+        # in pool blocks, summed over decode dispatches from what the host
+        # holds (no device sync): Σ over active slots of the blocks up to
+        # the position ceiling, and slots x window / block
+        self.kv_blocks_live = 0
+        self.kv_blocks_window = 0
         # batched multi-LoRA serving (docs/MULTITENANT.md): a stacked
         # (n_layers, lora_slots, ...) adapter pool in HBM, gathered per
         # generation slot INSIDE the fused prefill/decode programs —
@@ -521,11 +537,15 @@ class GenerativeModel:
         self._slot_row: dict[int, np.ndarray] = {}
 
         cache_dtype = dtype if dtype is not None else np.float32
+        # a pool that is placed over a mesh keeps its kv-head axis to be
+        # split by; on one device a row holds its heads side by side
+        # (models/llama.py::init_paged_cache)
+        pool_axes = {"kv_sharded": True} if mesh is not None else {}
         if self.kv_dtype:
             try:
                 cache = family_mod.init_paged_cache(
                     cfg, self.n_slots, self.kv_blocks, kv_block_size,
-                    dtype=cache_dtype, kv_dtype=self.kv_dtype,
+                    dtype=cache_dtype, kv_dtype=self.kv_dtype, **pool_axes,
                 )
             except TypeError:
                 raise GraphUnitError(
@@ -535,7 +555,7 @@ class GenerativeModel:
         else:
             cache = family_mod.init_paged_cache(
                 cfg, self.n_slots, self.kv_blocks, kv_block_size,
-                dtype=cache_dtype,
+                dtype=cache_dtype, **pool_axes,
             )
         if self.spec_draft:
             # per-slot history ring for the on-device n-gram proposer:
@@ -658,7 +678,7 @@ class GenerativeModel:
             d_blocks = 1 + self.n_slots * mbd
             dkv = family_mod.init_paged_cache(
                 dcfg, self.n_slots, d_blocks, kv_block_size,
-                dtype=cache_dtype,
+                dtype=cache_dtype, **pool_axes,
             )
             cache["d_k"] = dkv["k"]
             cache["d_v"] = dkv["v"]
@@ -952,7 +972,7 @@ class GenerativeModel:
                             dlogits, dc = fam.decode_slots_paged(
                                 spec_ps, cur, dc, active, dcfg,
                                 window=window,
-                                **(pool_kw if dcfg.n_kv_heads % tp == 0 else {}),
+                                **(dec_kw if dcfg.n_kv_heads % tp == 0 else {}),
                             )
                             nxt = jnp.argmax(dlogits, axis=-1).astype(
                                 jnp.int32
@@ -1901,23 +1921,24 @@ class GenerativeModel:
             raise GraphUnitError(f"slot {slot} holds no reservation to export")
         nb = -(-int(prompt_len) // self.kv_block_size)
         phys = np.asarray(row[:nb], np.int32)
+        # once per migrated slot, off the per-token path (DISAGG.md)
+        k, v, ks, vs = self._fetch_blocks(phys)
+        return (k, v, ks, vs) if self.kv_dtype else (k, v)
+
+    def _fetch_blocks(self, phys: np.ndarray) -> tuple:
+        """The pool's blocks ``phys`` on the host, ``(k, v, k_scale,
+        v_scale)`` (the scales None on a float pool), in the shape every
+        frame and store outside the programs holds: ``(layers, n,
+        block_size, kv_heads, head_dim)``, whatever row shape the pool is
+        carried in (same row-major bytes).  ONE batched fetch."""
+        names = ("k", "v") + (("k_scale", "v_scale") if self.kv_dtype else ())
         with self._lock:
-            # once per migrated slot, off the per-token path (DISAGG.md)
-            k = np.asarray(  # sct: host-sync-ok handoff export
-                jax.device_get(self._cache["k"][:, phys])
-            )
-            v = np.asarray(  # sct: host-sync-ok handoff export
-                jax.device_get(self._cache["v"][:, phys])
-            )
-            if self.kv_dtype:
-                ks = np.asarray(  # sct: host-sync-ok handoff export
-                    jax.device_get(self._cache["k_scale"][:, phys])
-                )
-                vs = np.asarray(  # sct: host-sync-ok handoff export
-                    jax.device_get(self._cache["v_scale"][:, phys])
-                )
-                return k, v, ks, vs
-        return k, v
+            # sct: host-sync-ok handoff export / tier demotion / peer pull
+            got = jax.device_get([self._cache[n][:, phys] for n in names])
+        frame = (self.cfg.n_kv_heads, self.cfg.head_dim)
+        k, v = (np.asarray(a).reshape(a.shape[:3] + frame) for a in got[:2])
+        ks, vs = (np.asarray(a) for a in got[2:]) if self.kv_dtype else (None, None)
+        return k, v, ks, vs
 
     def export_spec_state(self, slot: int) -> dict | None:
         """Proposer state for a handoff/suspend frame (codec v5): the
@@ -2134,6 +2155,13 @@ class GenerativeModel:
             return arr.view(ml_dtypes.bfloat16)
         return arr
 
+    @classmethod
+    def _unpack_blocks(cls, arr, pool) -> np.ndarray:
+        """A frame's K or V blocks ``(layers, n, block_size, kv_heads,
+        head_dim)`` as rows of ``pool`` (:meth:`_fetch_blocks` backwards)."""
+        arr = cls._unpack_bf16(np.asarray(arr), pool.dtype)
+        return arr.reshape(arr.shape[:3] + pool.shape[3:])
+
     def _exec_import(self, payload: dict) -> None:
         """Symmetric import body (runs on every slice process): scatter the
         imported blocks (+ scales on an int8 pool) and set the slot's
@@ -2150,8 +2178,8 @@ class GenerativeModel:
             quant = self.kv_dtype is not None
             k = v = ks = vs = None
             if phys.size:
-                k = self._unpack_bf16(np.asarray(payload["k"]), newk.dtype)
-                v = self._unpack_bf16(np.asarray(payload["v"]), newv.dtype)
+                k = self._unpack_blocks(payload["k"], newk)
+                v = self._unpack_blocks(payload["v"], newv)
                 if quant:
                     ks = self._unpack_bf16(
                         np.asarray(payload["k_scale"]), newks.dtype
@@ -2249,21 +2277,7 @@ class GenerativeModel:
         if self.host_store is not None:
             try:
                 phys = np.asarray([b for _k, _d, b in victims], np.int32)
-                with self._lock:
-                    k = np.asarray(  # sct: host-sync-ok tier demotion
-                        jax.device_get(self._cache["k"][:, phys])
-                    )
-                    v = np.asarray(  # sct: host-sync-ok tier demotion
-                        jax.device_get(self._cache["v"][:, phys])
-                    )
-                    ks = vs = None
-                    if self.kv_dtype:
-                        ks = np.asarray(  # sct: host-sync-ok tier demotion
-                            jax.device_get(self._cache["k_scale"][:, phys])
-                        )
-                        vs = np.asarray(  # sct: host-sync-ok tier demotion
-                            jax.device_get(self._cache["v_scale"][:, phys])
-                        )
+                k, v, ks, vs = self._fetch_blocks(phys)
                 # shallowest level first so each chain stays contiguous
                 # in the store (a rejected level truncates the chain's
                 # tail instead of stranding it)
@@ -2374,8 +2388,8 @@ class GenerativeModel:
             newk, newv = c["k"], c["v"]
             newks, newvs = c.get("k_scale"), c.get("v_scale")
             quant = self.kv_dtype is not None
-            k = self._unpack_bf16(np.asarray(payload["k"]), newk.dtype)
-            v = self._unpack_bf16(np.asarray(payload["v"]), newv.dtype)
+            k = self._unpack_blocks(payload["k"], newk)
+            v = self._unpack_blocks(payload["v"], newv)
             ks = vs = None
             if quant:
                 ks = self._unpack_bf16(
@@ -2453,16 +2467,7 @@ class GenerativeModel:
         if pinned:
             try:
                 phys = np.asarray([b for _k, _d, b in pinned], np.int32)
-                with self._lock:
-                    k = np.asarray(jax.device_get(self._cache["k"][:, phys]))
-                    v = np.asarray(jax.device_get(self._cache["v"][:, phys]))
-                    if self.kv_dtype:
-                        ks = np.asarray(
-                            jax.device_get(self._cache["k_scale"][:, phys])
-                        )
-                        vs = np.asarray(
-                            jax.device_get(self._cache["v_scale"][:, phys])
-                        )
+                k, v, ks, vs = self._fetch_blocks(phys)
             finally:
                 self.prefix_index.release(tokens, depth, salt=salt)
         if self.host_store is not None and depth < cap:
@@ -2983,6 +2988,11 @@ class GenerativeModel:
             "prefill_chunk": self.prefill_chunk or None,
             "prefill_chunks": self.prefill_chunks,
             "decode_kernel": self.decode_kernel,
+            # what the decode programs were built with, and the share of
+            # its window the read has to touch (live / window)
+            "decode_read": "kernel" if self.decode_kernel else "gather",
+            "kv_blocks_live": self.kv_blocks_live,
+            "kv_blocks_window": self.kv_blocks_window,
             # the family's own device counters (routing of an expert
             # layer), as of the last fetched decode block
             "counters": self.counters_snapshot(),
@@ -3136,11 +3146,22 @@ class GenerativeModel:
         # sct: host-sync-ok unbatched embed fetch
         return np.asarray(jax.device_get(vec), np.float32)
 
+    def _note_read(self, active: np.ndarray, window: int) -> None:
+        """Bump the decode read's two block counters for one dispatch."""
+        bs = self.kv_block_size
+        act = np.asarray(active, bool)
+        self.kv_blocks_live += int((-(-self._pos_ceiling[act] // bs)).sum())
+        self.kv_blocks_window += self.n_slots * (int(window) // bs)
+
     def _window_for(self, active: np.ndarray, extra: int) -> int:
         """Smallest power-of-two cache window covering every ACTIVE slot's
         position ceiling after ``extra`` more tokens (min 64, capped at
         max_seq).  Computed on the coordinator and shipped in the payload so
-        every host compiles the same static shape."""
+        every host compiles the same static shape.  The paged kernel reads
+        by each slot's own position, whatever the window: one program, at
+        max_seq."""
+        if self.decode_kernel:
+            return self.cfg.max_seq
         act = np.asarray(active, bool)
         hi = int(self._pos_ceiling[act].max()) if act.any() else 0
         need = hi + extra + 1
@@ -3202,6 +3223,7 @@ class GenerativeModel:
         }
         if self._lora is not None:
             payload["aid"] = self._slot_aidx.copy()
+        self._note_read(active, payload["window"])
         t0 = time.perf_counter()
         if self.driver is not None:
             res = self.driver.lead(self._mh_decode_key, payload)
@@ -3294,6 +3316,7 @@ class GenerativeModel:
         conf_seq = res[2] if len(res) > 2 else None
         act = np.asarray(active, bool)
         self._pos_ceiling[act] += k * self._tps
+        self._note_read(act, payload["window"])
         return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
 
     def step_k_continue(
@@ -3320,6 +3343,7 @@ class GenerativeModel:
         conf_seq = res[2] if len(res) > 2 else None
         act = np.asarray(active, bool)
         self._pos_ceiling[act] += k * self._tps
+        self._note_read(act, payload["window"])
         self.overlapped += 1
         return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
 
@@ -3680,6 +3704,9 @@ class GenerativeModel:
         return out
 
     def _window_buckets(self) -> list[int]:
+        """Every window :meth:`_window_for` can return."""
+        if self.decode_kernel:
+            return [self.cfg.max_seq]
         out = []
         w = 64
         while w < self.cfg.max_seq:

@@ -538,14 +538,16 @@ def apply(params: dict, batch: jax.Array, cfg: Config) -> jax.Array:
 
 def init_paged_cache(
     cfg: Config, n_slots: int, n_blocks: int, block_size: int,
-    dtype=jnp.float32,
+    dtype=jnp.float32, kv_sharded: bool = False,
 ) -> dict:
     """The uniform pool of ``models/llama.py``: every layer, sliding ones
     too, keeps every token (a pool sized by layer type is PERF.md §7's).
     A row holds its kv heads side by side, ``(layers, blocks, block_size,
     kv_heads * head_dim)``: the layout the paged kernel reads a block in, so
-    the pool is never re-tiled on the way to it.
+    the pool is never re-tiled on the way to it (``kv_sharded``: this
+    family has no pool split by head).
     ``counters`` are the routing counters (``COUNTERS``), uint32, wrapping."""
+    del kv_sharded
     if cfg.max_seq % block_size:
         raise ValueError(
             f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
@@ -842,10 +844,11 @@ def _decode_paged_multi(
                 # cut out of the pool would be a copy of it (XLA fuses no
                 # slice into a kernel's operand)
                 nb = ck.shape[1]
-                flat = (cfg.n_layers * nb, bs, kvh, d)
+                flat = (cfg.n_layers * nb, bs, kvh * d)
                 o = paged_decode_attention(
                     q, ck.reshape(flat), cv.reshape(flat), idx + li * nb, pos,
                     first=None if full else win_first, window=window,
+                    active=active,
                 )
             else:
                 big = 2 * idx.size * bs * kvh * d * ck.dtype.itemsize

@@ -570,7 +570,9 @@ def embed_pooled(
 # 16-slot 1.1B cache is 8.6 GB even when every request is 200 tokens.  The
 # paged layout allocates from a pool of fixed-size blocks:
 #
-#   k/v: (layers, n_blocks, block_size, kv_heads, head_dim)
+#   k/v: (layers, n_blocks, block_size, kv_heads * head_dim) on one device,
+#        (layers, n_blocks, block_size, kv_heads, head_dim) with the kv heads
+#        split over a mesh — the same row-major bytes (init_paged_cache)
 #   table: (n_slots, max_seq // block_size) int32  — physical block ids
 #
 # A slot's logical position p lives in physical row
@@ -588,13 +590,23 @@ def init_paged_cache(
     block_size: int,
     dtype=jnp.float32,
     kv_dtype: str | None = None,
+    kv_sharded: bool = False,
 ) -> dict:
     """``kv_dtype="int8"`` stores K/V blocks as int8 with one ``dtype``
     scale per (position, kv-head) — ``k_scale``/``v_scale`` of shape
     ``(layers, n_blocks, block_size, kv_heads)`` — roughly doubling the
     sequences a fixed HBM pool holds (docs/PERFORMANCE.md).  Attention
     reads dequantize in place; writes quantize per row, so incremental
-    decode appends never rescale neighbouring rows."""
+    decode appends never rescale neighbouring rows.
+
+    A row of the pool holds its heads side by side, ``(..., block_size,
+    kv_heads * head_dim)``: the shape the paged decode kernel copies blocks
+    of, so the carried pool is its operand as it is (a pool with a head
+    axis is re-tiled whole on the way to a kernel, PERF.md §6).
+    ``kv_sharded``: the pool is to be split over a mesh by kv head, and
+    keeps the head axis to split, ``(..., block_size, kv_heads,
+    head_dim)``.  The programs read either (same bytes, same order); what
+    leaves the device is always the five-dimensional frame."""
     if cfg.max_seq % block_size:
         raise ValueError(
             f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
@@ -603,9 +615,10 @@ def init_paged_cache(
         raise ValueError(f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
     mb = cfg.max_seq // block_size
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    rows = shape if kv_sharded else shape[:3] + (shape[3] * shape[4],)
     cache = {
-        "k": jnp.zeros(shape, jnp.int8 if kv_dtype == "int8" else dtype),
-        "v": jnp.zeros(shape, jnp.int8 if kv_dtype == "int8" else dtype),
+        "k": jnp.zeros(rows, jnp.int8 if kv_dtype == "int8" else dtype),
+        "v": jnp.zeros(rows, jnp.int8 if kv_dtype == "int8" else dtype),
         "pos": jnp.zeros((n_slots,), jnp.int32),
         "table": jnp.zeros((n_slots, mb), jnp.int32),
     }
@@ -652,11 +665,6 @@ def _dequant_kv(q, scale, dtype):
     )
 
 
-def _pool_layer(pool, li):
-    """Layer ``li`` (a traced scalar) of the ``(layers, n_blocks, ...)`` pool."""
-    return jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)
-
-
 def _pool_read(pool, li, read_idx, kv_sharded=False):
     """The blocks ``read_idx`` of layer ``li`` (a traced scalar) of the
     carried ``(layers, n_blocks, ...)`` pool, chosen by where the pool lives
@@ -672,8 +680,14 @@ def _pool_read(pool, li, read_idx, kv_sharded=False):
     gather reads at under half the rate; the layer, a fraction the size, is
     cut out first, and XLA re-tiles it into fast memory on the way."""
     if kv_sharded:
-        return _pool_layer(pool, li)[read_idx]
+        return jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False)[read_idx]
     return pool[li, read_idx]
+
+
+def _pool_rows(pool, rows):
+    """``rows (..., kv_heads, head_dim)`` in the K/V pool's own row shape
+    and dtype (heads side by side, or a head axis: init_paged_cache)."""
+    return rows.reshape(rows.shape[:-2] + pool.shape[3:]).astype(pool.dtype)
 
 
 def _fake_quant_hook(scale_dtype):
@@ -737,10 +751,10 @@ def prefill_slot_paged(
     if quant:
         qk, sk, qv, sv = stored
         cache["k"] = cache["k"].at[:, phys].set(
-            qk[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd)
+            _pool_rows(cache["k"], qk[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd))
         )
         cache["v"] = cache["v"].at[:, phys].set(
-            qv[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd)
+            _pool_rows(cache["v"], qv[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd))
         )
         cache["k_scale"] = cache["k_scale"].at[:, phys].set(
             sk[:, 0].reshape(cfg.n_layers, lb, bs, kvh)
@@ -752,8 +766,8 @@ def prefill_slot_paged(
         ks, vs = stored
         ksb = ks[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd)
         vsb = vs[:, 0].reshape(cfg.n_layers, lb, bs, kvh, hd)
-        cache["k"] = cache["k"].at[:, phys].set(ksb.astype(cache["k"].dtype))
-        cache["v"] = cache["v"].at[:, phys].set(vsb.astype(cache["v"].dtype))
+        cache["k"] = cache["k"].at[:, phys].set(_pool_rows(cache["k"], ksb))
+        cache["v"] = cache["v"].at[:, phys].set(_pool_rows(cache["v"], vsb))
     cache["pos"] = cache["pos"].at[slot].set(length)
     cache["table"] = cache["table"].at[slot].set(blocks_row)
     h = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
@@ -851,8 +865,8 @@ def prefill_suffix_paged(
         def read(pool):
             return _pool_read(pool, li, read_idx, kv_sharded)
 
-        kp = read(ck)  # (pb, bs, kv, hd)
-        vp = read(cv)
+        kp = read(ck).reshape(pb, bs, kvh, hd)
+        vp = read(cv).reshape(pb, bs, kvh, hd)
         if quant:
             kp = _dequant_kv(kp, read(cks), k.dtype)
             vp = _dequant_kv(vp, read(cvs), v.dtype)
@@ -873,15 +887,17 @@ def prefill_suffix_paged(
         h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
         mlp = _mlp_block(h, lp, ll, aid)
         if quant:
-            ck = ck.at[li, suffix_blocks].set(qk[0].reshape(lb, bs, kvh, hd))
-            cv = cv.at[li, suffix_blocks].set(qv[0].reshape(lb, bs, kvh, hd))
+            ck = ck.at[li, suffix_blocks].set(
+                _pool_rows(ck, qk[0].reshape(lb, bs, kvh, hd)))
+            cv = cv.at[li, suffix_blocks].set(
+                _pool_rows(cv, qv[0].reshape(lb, bs, kvh, hd)))
             cks = cks.at[li, suffix_blocks].set(sk[0].reshape(lb, bs, kvh))
             cvs = cvs.at[li, suffix_blocks].set(sv[0].reshape(lb, bs, kvh))
         else:
             ksb = k[0].reshape(lb, bs, kvh, hd)
             vsb = v[0].reshape(lb, bs, kvh, hd)
-            ck = ck.at[li, suffix_blocks].set(ksb.astype(ck.dtype))
-            cv = cv.at[li, suffix_blocks].set(vsb.astype(cv.dtype))
+            ck = ck.at[li, suffix_blocks].set(_pool_rows(ck, ksb))
+            cv = cv.at[li, suffix_blocks].set(_pool_rows(cv, vsb))
         return (x + mlp, ck, cv, cks, cvs), None
 
     zero = jnp.zeros((), jnp.int8)  # scan carries need SOME leaf when not quant
@@ -1070,23 +1086,31 @@ def _decode_paged_multi(
         if quant:
             qk, sk = _quant_kv(k, sdt)
             qv, sv = _quant_kv(v, sdt)
-            ck = ck.at[li, write_blk, write_off].set(qk)
-            cv = cv.at[li, write_blk, write_off].set(qv)
+            ck = ck.at[li, write_blk, write_off].set(_pool_rows(ck, qk))
+            cv = cv.at[li, write_blk, write_off].set(_pool_rows(cv, qv))
             cks = cks.at[li, write_blk, write_off].set(sk)
             cvs = cvs.at[li, write_blk, write_off].set(sv)
         else:
-            ck = ck.at[li, write_blk, write_off].set(k.astype(ck.dtype))
-            cv = cv.at[li, write_blk, write_off].set(v.astype(cv.dtype))
+            ck = ck.at[li, write_blk, write_off].set(_pool_rows(ck, k))
+            cv = cv.at[li, write_blk, write_off].set(_pool_rows(cv, v))
         if kernel:
             # fused Pallas read side: table gather + (dequant +) attention
-            # in one VMEM pass over the window's pool blocks.  The kernel
-            # takes a layer's view of the pool (the XLA path below does not)
+            # in one VMEM pass over the blocks a live slot holds.  The
+            # kernel is handed the WHOLE carried pool, layers flattened
+            # into blocks (a reshape of leading dimensions: no copy), and
+            # this layer's blocks by offset: a layer cut out of the pool
+            # would be a copy of it (XLA fuses no slice into a kernel's
+            # operand, PERF.md §6)
             from seldon_core_tpu.ops import paged_decode_attention
 
+            def whole(pool):
+                return pool.reshape((-1,) + pool.shape[2:])
+
             o = paged_decode_attention(
-                q, _pool_layer(ck, li), _pool_layer(cv, li), read_idx, pos,
-                k_scale=_pool_layer(cks, li) if quant else None,
-                v_scale=_pool_layer(cvs, li) if quant else None,
+                q, whole(ck), whole(cv), read_idx + li * ck.shape[1], pos,
+                k_scale=whole(cks) if quant else None,
+                v_scale=whole(cvs) if quant else None,
+                active=active,
             )
         else:
             # gather each slot's visible blocks:
@@ -1094,8 +1118,8 @@ def _decode_paged_multi(
             def read(pool):
                 return _pool_read(pool, li, read_idx, kv_sharded)
 
-            kw = read(ck)
-            vw = read(cv)
+            kw = read(ck).reshape(S, wb, bs, kv, hd)
+            vw = read(cv).reshape(S, wb, bs, kv, hd)
             if quant:
                 kw = _dequant_kv(kw, read(cks), q.dtype)
                 vw = _dequant_kv(vw, read(cvs), q.dtype)

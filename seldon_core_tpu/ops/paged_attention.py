@@ -1,29 +1,36 @@
 """Paged decode-attention as a Pallas TPU kernel.
 
 The fused decode step (``models/llama.py::_decode_paged_multi``) spends its
-HBM budget reading each slot's attention window out of the paged KV pool.
+HBM budget reading each slot's keys and values out of the paged KV pool.
 The XLA path does that as gather -> (dequant) -> einsum -> softmax -> einsum,
-which materializes the gathered ``(S, W, kv, hd)`` window (and, under int8,
-its dequantized copy) in HBM between ops.  This kernel fuses the whole read
-side: the block-table gather is the BlockSpec index map (scalar-prefetched
-table entries steer each grid step's DMA straight at the right pool block),
-int8 blocks dequantize in VMEM against their per-(position, head) scales,
-and attention runs the online-softmax recurrence over one KV block at a
-time — pool bytes are read once, nothing intermediate touches HBM
-(guide: /opt/skills/guides/pallas_guide.md; the gather idiom is the
-standard TPU paged-attention pattern, the recurrence is flash decoding).
+which materializes the gathered ``(S, W, kv, hd)`` window of EVERY slot at
+the batch-wide window (and, under int8, its dequantized copy) in HBM between
+ops.  This kernel fuses the whole read side and reads what a live slot
+holds: one grid step is one slot; the slot's live blocks are copied by their
+(scalar-prefetched) table entries from the pool in HBM into a VMEM tile of
+:data:`STEP_ROWS` key rows (16 blocks of 16 tokens, one of 256: derived from
+the block size the pool shows), the next step's blocks — this slot's next
+tile or the next live slot's first — in flight while this one is attended;
+int8 blocks dequantize in VMEM against their per-(position, head) scales;
+attention runs the online-softmax recurrence over one tile at a time.  Pool
+bytes are read once, nothing intermediate touches HBM, a block past the
+slot's last query or before a sliding window's lower edge is not fetched,
+and an inactive slot reads nothing (guide: /opt/skills/guides/pallas_guide.md;
+manual double-buffered copies are the standard TPU paged-attention pattern,
+the recurrence is flash decoding).
 
-Layout.  The pool is ``(NB, BS, KV, D)`` and stays that way; Mosaic takes
-a block whose last two dimensions are whole tiles or the whole dimension,
-so one grid step reads a pool block as ``(BS, KV*D)`` — every KV head of
-``BS`` positions, a free reshape of contiguous memory — and the scales as
-``(BS, KV)``.  All heads then share ONE matmul per step: the queries are
-laid out block-diagonally, row ``h*R + r`` holding head ``h``'s query in
-lanes ``[h*D, (h+1)*D)`` and zeros elsewhere, so ``Q_bd @ K_blk^T`` is
-every head's scores at once and ``P @ V_blk`` carries head ``h``'s output
-in the same lanes of row ``h*R + r`` (the wrapper keeps that diagonal).
-The zeros cost MXU passes the memory-bound decode step has to spare, and
-buy a kernel with no per-head slicing of packed tiles.
+Layout.  The pool is ``(NB, BS, KV*D)``: a row holds every KV head of one
+position side by side, which is how the programs carry it on one device
+(``models/llama.py::init_paged_cache``), so the carried pool — every layer's
+blocks, the layer an offset into the table — is the kernel's operand as it
+is; a pool with a head axis would be re-tiled whole on the way in.  All heads
+share ONE matmul per step: the queries are laid out block-diagonally, row
+``h*R + r`` holding head ``h``'s query in lanes ``[h*D, (h+1)*D)`` and zeros
+elsewhere, so ``Q_bd @ K_tile^T`` is every head's scores at once and ``P @
+V_tile`` carries head ``h``'s output in the same lanes of row ``h*R + r``
+(the wrapper keeps that diagonal).  The zeros cost MXU passes the
+memory-bound decode step has to spare, and buy a kernel with no per-head
+slicing of packed tiles.
 
 Query shapes are the decode step's: ``L = 1`` for the plain step,
 ``L = 1 + spec_draft`` for the fused speculative verify pass.  Grouped
@@ -46,6 +53,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
+STEP_ROWS = 256  # key rows attended in one step (PERF.md §6, PR 29)
+# rows of the kernel's second scalar operand, one column a slot (+ one)
+_POS, _FIRST, _BLO, _BHI, _WLO, _NW, _NXT = range(7)
 
 
 def mxu_operands(dtype) -> tuple:
@@ -59,89 +69,141 @@ def mxu_operands(dtype) -> tuple:
     return jnp.float32, jax.lax.Precision.HIGHEST
 
 
+def blocks_per_step(block_size: int) -> int:
+    """Pool blocks one step of the kernel attends together: as many as make
+    :data:`STEP_ROWS` key rows (16 at 16-token blocks, 1 at 256)."""
+    return max(1, STEP_ROWS // int(block_size))
+
+
 def _paged_kernel(
-    table_ref,  # (S, WB) int32 scalar-prefetch: physical block per grid step
-    pos_ref,  # (S,) int32 scalar-prefetch: per-slot base position
-    first_ref,  # (S,) int32 scalar-prefetch: position of the first row read
+    table_ref,  # (S, WB) int32 scalar-prefetch: physical block per column
+    meta_ref,  # (7, S + 1) int32 scalar-prefetch: rows _POS .. _NXT
     q_ref,  # (1, KV*R, KV*D) block-diagonal, pre-scaled queries of one slot
-    k_ref,  # (1, BS, KV*D) one gathered KV block, every head
-    v_ref,
-    *refs,  # [k_scale_ref, v_scale_ref,] o_ref, m_scr, l_scr, acc_scr
+    k_hbm,  # (NB, BS, KV*D) the whole pool, left in HBM
+    v_hbm,
+    *refs,  # [ks_ref, vs_ref,] o_ref, kbuf, vbuf, sem, cnt, m_scr, l_scr,
+    #         acc_scr; the scales are (1, KV, steps * T): the table's rows
     bs,
+    g_blocks,
     groups,
     rows,
-    n_w,
+    n_cols,
     quant,
     window=None,
 ):
     if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        o_ref, m_scr, l_scr, acc_scr = refs
+        ks_ref, vs_ref, *refs = refs
+    o_ref, kbuf, vbuf, sem, cnt, m_scr, l_scr, acc_scr = refs
+    pools = ((k_hbm, kbuf), (v_hbm, vbuf))
     s_i = pl.program_id(0)
-    w = pl.program_id(1)
-
-    @pl.when(w == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
+    n_slots = pl.num_programs(0)
+    G = g_blocks
+    T = G * bs  # key rows a step
     HR = q_ref.shape[1]  # KV * rows
-    base = pos_ref[s_i]
-    # grid step w reads the rows at positions col0 .. col0 + bs - 1: the
-    # table's blocks are the slot's first ones (first = 0) or, under a
-    # sliding window, the ones from the block that holds its lower edge on
-    col0 = first_ref[s_i] + w * bs
-    # key blocks entirely past every query position are dead weight: the
-    # furthest query sits at base + L - 1 (each head's last row is query
-    # L-1's last group); under a window so are blocks that end at or
-    # before the first query's ``base - window``
-    live = col0 <= base + (rows - 1) // groups
-    if window is not None:
-        live = live & (col0 + bs - 1 > base - window)
 
+    def copies(slot, w, buf, go):
+        """Start (``go``) or await the copies of step ``w`` of ``slot``:
+        its live blocks, each by its table entry, into tile ``buf``."""
+        lo = jnp.maximum(meta_ref[_BLO, slot], w * G)
+        hi = jnp.minimum(meta_ref[_BHI, slot], w * G + G - 1)
+
+        def one(b, carry):
+            blk = table_ref[slot, b]
+            for o, (src, dst) in enumerate(pools):
+                cp = pltpu.make_async_copy(
+                    src.at[blk], dst.at[buf, b - w * G], sem.at[buf, o]
+                )
+                cp.start() if go else cp.wait()
+            return carry
+
+        jax.lax.fori_loop(lo, hi + 1, one, 0)
+
+    @pl.when(s_i == 0)
+    def _prime():
+        # rows of a tile that no copy fills are masked out of the scores,
+        # and 0 * (what fast memory held before) must still be 0
+        for _, dst in pools:
+            dst[...] = jnp.zeros_like(dst)
+        cnt[0] = 0
+        head = meta_ref[_NXT, 0]
+
+        @pl.when(head < n_slots)
+        def _head():
+            copies(head, meta_ref[_WLO, head], 0, True)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    base = meta_ref[_POS, s_i]
+    first = meta_ref[_FIRST, s_i]
+    w_lo = meta_ref[_WLO, s_i]
+    n_w = meta_ref[_NW, s_i]
     # int8 pool values are exact in bfloat16, so a quantized pool rides
     # the queries' operand dtype too
     cdt, prec = mxu_operands(q_ref.dtype)
 
-    @pl.when(live)
-    def _tile():
+    def step(i, carry):
+        w = w_lo + i
+        cur = cnt[0] % 2
+        # the next step's blocks travel while this one is attended: this
+        # slot's next group, else the first group of the next slot that
+        # has any (none: the last work of the call).  Their copies start
+        # BEFORE this step's are awaited (the other tile is free since the
+        # step before this one was attended), so the copy engine always
+        # has the next tile queued: 14 % a call on the chip (PERF.md §6)
+        last = i == n_w - 1
+        nslot = jnp.where(last, meta_ref[_NXT, s_i + 1], s_i)
+        nw = jnp.where(last, meta_ref[_WLO, nslot], w + 1)
+
+        @pl.when(nslot < n_slots)
+        def _ahead():
+            copies(nslot, nw, 1 - cur, True)
+
+        copies(s_i, w, cur, False)
+
         q = q_ref[0].astype(cdt)  # (HR, KV*D)
-        k = k_ref[0].astype(cdt)  # (BS, KV*D)
-        v = v_ref[0].astype(cdt)
+        k = kbuf[cur].astype(cdt).reshape(T, -1)  # (T, KV*D)
+        v = vbuf[cur].astype(cdt).reshape(T, -1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
-        )  # (HR, BS): row h*rows + r is head h's scores
+        )  # (HR, T): row h*rows + r is head h's scores
         if quant:
             # per-(position, head) symmetric scales: the dequant the XLA
             # path pays as a separate HBM-resident op folds into the scores
             # and probabilities here.  The one-hot matmul spreads the
-            # (BS, KV) scale block to the (HR, BS) score layout.
-            kv = ks_ref.shape[2]
+            # step's (KV, T) scales to the (HR, T) score layout.
+            kv = ks_ref.shape[1]
             sdt, sprec = mxu_operands(ks_ref.dtype)
             onehot = (
                 jax.lax.broadcasted_iota(jnp.int32, (HR, kv), 0) // rows
                 == jax.lax.broadcasted_iota(jnp.int32, (HR, kv), 1)
             ).astype(sdt)
-            spread = functools.partial(
-                jax.lax.dot_general,
-                onehot,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=sprec,
-            )
-            s = s * spread(ks_ref[0].astype(sdt))
+            at = pl.ds(pl.multiple_of(w * T, T), T)
+
+            def spread(ref):
+                return jax.lax.dot_general(
+                    onehot, ref[0, :, at].astype(sdt),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=sprec,
+                )
+
+            s = s * spread(ks_ref)
         # row h*rows + r belongs to query position j = r // groups and may
-        # see pool rows [0, base + j] — the causal-speculation window
+        # see pool rows [0, base + j] — the causal-speculation window.  A
+        # block the step did not fetch lies past the last query or before
+        # the window's lower edge, so the same test hides its stale rows
         rows_j = (
-            jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 0) % rows
+            jax.lax.broadcasted_iota(jnp.int32, (HR, T), 0) % rows
         ) // groups
-        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, (HR, bs), 1)
+        col = w * T + jax.lax.broadcasted_iota(jnp.int32, (HR, T), 1)
+        cols = first + col
         seen = cols <= base + rows_j
         if window is not None:
             seen = seen & (cols > base + rows_j - window)
+        if n_cols % T:
+            seen = seen & (col < n_cols)  # columns the table does not have
         s = jnp.where(seen, s, NEG_INF)
         m_prev = m_scr[:, 0]
         l_prev = l_scr[:, 0]
@@ -150,19 +212,21 @@ def _paged_kernel(
         alpha = jnp.exp(m_prev - m_cur)
         l_cur = alpha * l_prev + p.sum(axis=-1)
         if quant:
-            p = p * spread(vs_ref[0].astype(sdt))
+            # the scales come by table entry, a dead block's too
+            p = p * jnp.where(seen, spread(vs_ref), 0.0)
         acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
             p.astype(cdt), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )
         m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
+        cnt[0] = cnt[0] + 1
+        return carry
 
-    @pl.when(w == n_w - 1)
-    def _emit():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_w, step, 0)
+    l = l_scr[:, 0]
+    safe_l = jnp.where(l == 0.0, 1.0, l)  # nothing read or seen -> zeros
+    o_ref[0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -177,34 +241,45 @@ def paged_decode_attention(
     interpret: bool | None = None,
     first: jax.Array | None = None,
     window: int | None = None,
+    active: jax.Array | None = None,
 ) -> jax.Array:
     """Attention for ``L`` decode queries per slot over the paged KV pool.
 
     ``q (S, L, H, D)`` post-RoPE queries (``H = kv_heads * groups``);
-    ``k_pages``/``v_pages (NB, BS, KV, D)`` ONE layer's pool (float, or
-    int8 with ``k_scale``/``v_scale (NB, BS, KV)``); ``table (S, WB)`` the
-    physical blocks each slot's attention window reads; ``pos (S,)`` the
-    slot's base position — query ``j`` sees pool rows ``[0, pos + j]``.
-    Returns ``(S, L, H, D)`` in the query dtype.  Semantics are exactly
-    :func:`paged_decode_attention_reference` (the XLA gather path).
+    ``k_pages``/``v_pages (NB, BS, KV * D)`` the pool as it is carried,
+    every layer's blocks in one row of blocks (``(NB, BS, KV, D)`` is taken
+    too, and is re-tiled on the way by a backend that tiles its memory);
+    float, or int8 with ``k_scale``/``v_scale (NB, BS, KV)``.  ``table (S,
+    WB)`` the physical blocks each slot's attention window reads; ``pos
+    (S,)`` the slot's base position — query ``j`` sees pool rows ``[0, pos +
+    j]``.  Returns ``(S, L, H, D)`` in the query dtype.  Semantics are
+    exactly :func:`paged_decode_attention_reference` (the XLA gather path)
+    for every slot that is ``active (S,)`` (default: all); an inactive slot
+    reads nothing and gets zeros.
 
     A sliding-window layer hands ``table`` the blocks that hold its window
     and ``first (S,)`` the position of the first row of the first of them
     (a multiple of ``BS``; default 0: the slot's first blocks), and
     ``window`` (static): query ``j`` then sees rows at positions
-    ``(pos + j - window, pos + j]``, and blocks wholly outside are skipped.
+    ``(pos + j - window, pos + j]``.  Blocks wholly outside what any query
+    of the slot sees are not fetched.
 
     ``interpret`` defaults to True on the CPU backend only; every other
     backend compiles the kernel, and a shape Mosaic refuses is an error —
     nothing falls back to the interpreter or the XLA path in its name.
     """
     S, L, H, D = q.shape
-    NB, BS, KV, _ = k_pages.shape
+    if k_pages.ndim == 4:
+        k_pages = k_pages.reshape(k_pages.shape[:2] + (-1,))
+        v_pages = v_pages.reshape(v_pages.shape[:2] + (-1,))
+    NB, BS, KVD = k_pages.shape
+    KV = KVD // D
     WB = table.shape[1]
     if H % KV:
         raise ValueError(f"H {H} must be a multiple of kv heads {KV}")
     groups = H // KV
     R = L * groups
+    G = blocks_per_step(BS)
     quant = k_scale is not None
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
@@ -220,55 +295,78 @@ def paged_decode_attention(
     q_bd = (
         (qr * scale)[:, :, :, None, :] * eye[None, :, None, :, None]
     ).reshape(S, KV * R, KV * D)
+    # which table columns a slot's queries can see, and the steps of G
+    # columns that hold them: all of it scalar work, done once out here
+    pos = jnp.asarray(pos, jnp.int32)
+    first = (
+        jnp.zeros((S,), jnp.int32) if first is None
+        else jnp.asarray(first, jnp.int32)
+    )
+    b_hi = jnp.minimum((pos + L - 1 - first) // BS, WB - 1)
+    b_lo = jnp.zeros((S,), jnp.int32)
+    if window is not None:
+        b_lo = jnp.maximum((pos - int(window) + 1 - first) // BS, 0)
+    if active is not None:
+        b_hi = jnp.where(active, b_hi, -1)
+    has = b_hi >= b_lo
+    w_lo = b_lo // G
+    n_w = jnp.where(has, b_hi // G - w_lo + 1, 0)
+    nxt = jax.lax.cummin(
+        jnp.where(has, jnp.arange(S, dtype=jnp.int32), S), reverse=True
+    )
+    meta = jnp.stack([pos, first, b_lo, b_hi, w_lo, n_w, nxt])  # _POS .. _NXT
+    meta = jnp.pad(meta, ((0, 0), (0, 1)), constant_values=S).astype(jnp.int32)
     kernel = functools.partial(
-        _paged_kernel, bs=BS, groups=groups, rows=R, n_w=WB, quant=quant,
+        _paged_kernel, bs=BS, g_blocks=G, groups=groups, rows=R,
+        n_cols=WB * BS, quant=quant,
         window=None if window is None else int(window),
     )
-    if first is None:
-        first = jnp.zeros((S,), jnp.int32)
 
-    def slot_block(s, w, t, p, f):
+    def slot_block(s, t, m):
         return (s, 0, 0)
 
-    def pool_block(s, w, t, p, f):
-        # the gather: scalar-prefetched table entries drive the DMA source
-        return (t[s, w], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, KV * R, KV * D), slot_block),
-        pl.BlockSpec((1, BS, KV * D), pool_block),
-        pl.BlockSpec((1, BS, KV * D), pool_block),
-    ]
-    args = [
-        q_bd,
-        k_pages.reshape(NB, BS, KV * D),
-        v_pages.reshape(NB, BS, KV * D),
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, KV * R, KV * D), slot_block), hbm, hbm]
+    args = [q_bd, k_pages, v_pages]
+    tiles = [
+        pltpu.VMEM((2, G, BS, KVD), k_pages.dtype),
+        pltpu.VMEM((2, G, BS, KVD), v_pages.dtype),
     ]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, BS, KV), pool_block),
-            pl.BlockSpec((1, BS, KV), pool_block),
-        ]
-        args += [k_scale, v_scale]
+        # one scale a (row, head) is a 64th of the rows' bytes and too
+        # narrow a slice to copy by block: the table's rows of scales are
+        # gathered out here, heads by rows, a slot's at a time in VMEM
+        cols = -(-WB // G) * G * BS
+
+        def by_rows(scales):
+            rows_ = scales[table].reshape(S, WB * BS, KV)
+            rows_ = jnp.pad(rows_, ((0, 0), (0, cols - WB * BS), (0, 0)))
+            return rows_.transpose(0, 2, 1)
+
+        in_specs += [pl.BlockSpec((1, KV, cols), slot_block)] * 2
+        args += [by_rows(k_scale), by_rows(v_scale)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(S, WB),
+            num_scalar_prefetch=2,
+            grid=(S,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, KV * R, KV * D), slot_block),
-            scratch_shapes=[
+            scratch_shapes=tiles + [
+                pltpu.SemaphoreType.DMA((2, len(tiles))),
+                pltpu.SMEM((1,), jnp.int32),  # steps done: the tile in turn
                 pltpu.VMEM((KV * R, 128), jnp.float32),  # running max (col 0)
                 pltpu.VMEM((KV * R, 128), jnp.float32),  # running denom (col 0)
                 pltpu.VMEM((KV * R, KV * D), jnp.float32),  # accumulator
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, KV * R, KV * D), q.dtype),
+        # one slot's tiles are filled while the slot before it is attended
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        ),
         interpret=interpret,
-    )(
-        jnp.asarray(table, jnp.int32), jnp.asarray(pos, jnp.int32),
-        jnp.asarray(first, jnp.int32), *args,
-    )
+    )(jnp.asarray(table, jnp.int32), meta, *args)
     # head h's output sits in lanes [h*D, (h+1)*D) of its own rows
     out = jnp.diagonal(
         out.reshape(S, KV, R, KV, D), axis1=1, axis2=3

@@ -167,9 +167,13 @@ declare("SCT_PREFILL_CHUNK", "0", "int",
         "Chunked-prefill chunk size in tokens (0 = monolithic prefill; "
         "docs/PERFORMANCE.md §7).",
         section="executor")
-declare("SCT_DECODE_KERNEL", "0", "bool",
-        "Use the Pallas paged decode-attention kernel "
-        "(ops/paged_attention.py) instead of the dense gather path.",
+declare("SCT_DECODE_KERNEL", None, "bool",
+        "Read the paged KV pool in decode through the Pallas paged "
+        "decode-attention kernel (ops/paged_attention.py): ``1`` the "
+        "kernel, ``0`` the XLA gather; chosen by the program when unset "
+        "(the kernel where the pool is on one device and the backend "
+        "compiles Pallas; the ``decode_kernel`` graph parameter comes "
+        "first).",
         section="executor")
 declare("SCT_KV_DTYPE", None, "str",
         "Paged-KV quantization dtype (``int8``; unset = model dtype).",

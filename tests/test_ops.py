@@ -327,6 +327,143 @@ class TestPagedDecodeAttention:
             rtol=2e-5, atol=2e-5,
         )
 
+    def _blocks_case(self, BS, WB, pos, L, *, quant=False, active=None,
+                     poison=False, seed=0):
+        """One call at ``BS``-token blocks, every slot with blocks of its
+        own, against the reference; an inactive slot reads nothing and gets
+        zeros.  ``poison``: every block past a slot's last query, and every
+        block of an inactive slot, holds NaN (an int8 pool: NaN scales)."""
+        from seldon_core_tpu.ops import (
+            paged_decode_attention,
+            paged_decode_attention_reference,
+        )
+
+        rng = np.random.default_rng(seed)
+        S, KV, G, D = len(pos), 2, 2, 16
+        NB = 1 + S * WB
+        q = self._rand(rng, S, L, KV * G, D)
+        table = jnp.asarray(
+            rng.permutation(NB - 1).reshape(S, WB) + 1, jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
+        act = np.ones(S, bool) if active is None else np.asarray(active)
+        kw = {}
+        if quant:
+            k = jnp.asarray(rng.integers(-127, 128, (NB, BS, KV * D)), jnp.int8)
+            v = jnp.asarray(rng.integers(-127, 128, (NB, BS, KV * D)), jnp.int8)
+            kw["k_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * 0.1, jnp.float32)
+            kw["v_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * 0.1, jnp.float32)
+        else:
+            k = self._rand(rng, NB, BS, KV * D)
+            v = self._rand(rng, NB, BS, KV * D)
+        ref = paged_decode_attention_reference(
+            q, k.reshape(NB, BS, KV, D), v.reshape(NB, BS, KV, D), table,
+            pos, **kw)
+        if poison:
+            last = (np.asarray(pos) + L - 1) // BS
+            dead = np.zeros(NB, bool)
+            for s_ in range(S):
+                cols = np.arange(WB) > (last[s_] if act[s_] else -1)
+                dead[np.asarray(table)[s_, cols]] = True
+            bad = jnp.asarray(dead)[:, None, None]
+            if quant:
+                kw = {n: jnp.where(bad, jnp.nan, a) for n, a in kw.items()}
+            else:
+                k = jnp.where(bad, jnp.nan, k)
+                v = jnp.where(bad, jnp.nan, v)
+        out = np.asarray(paged_decode_attention(
+            q, k, v, table, pos, active=jnp.asarray(act), **kw))
+        assert np.isfinite(out).all()
+        np.testing.assert_allclose(
+            out[act], np.asarray(ref)[act], rtol=2e-5, atol=2e-5)
+        assert not out[~act].any()
+
+    # (block, blocks a step): a step attends 256 rows whatever the block
+    BLOCKS = [(16, 16), (64, 4), (128, 2), (256, 1)]
+
+    @pytest.mark.parametrize("BS,G", BLOCKS)
+    def test_blocks_a_step_follow_the_block_size(self, BS, G):
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
+
+        assert blocks_per_step(BS) == G and G * BS == 256
+
+    @pytest.mark.parametrize("L", [1, 3])
+    @pytest.mark.parametrize("BS,G", BLOCKS)
+    def test_several_blocks_a_step(self, BS, G, L):
+        """Three steps of ``G`` blocks (the last one short): positions on
+        and off block and step boundaries, a slot at position 0, a slot far
+        below the window (two dead steps), the last row of the window."""
+        WB = 2 * G + max(1, G // 2)
+        top = WB * BS - L
+        self._blocks_case(
+            BS, WB, [0, BS - 1, BS, 255, 256, 300, 511, top], L,
+            seed=BS + L)
+
+    @pytest.mark.parametrize("L", [1, 3])
+    def test_inactive_slots_read_nothing(self, L):
+        self._blocks_case(
+            16, 40, [0, 300, 17, 639 - L, 255], L,
+            active=[True, False, True, True, False], seed=3 + L)
+
+    @pytest.mark.parametrize("BS,WB", [(16, 40), (16, 3), (256, 3)])
+    def test_int8_with_several_blocks_a_step(self, BS, WB):
+        top = WB * BS - 2
+        self._blocks_case(
+            BS, WB, [0, top // 3, top], 2, quant=True,
+            active=[True, True, True], seed=BS + WB)
+
+    @pytest.mark.parametrize("quant", [False, True])
+    @pytest.mark.parametrize("BS", [16, 256])
+    def test_dead_blocks_are_not_read(self, BS, quant):
+        """Blocks past a slot's position and the blocks of inactive slots
+        hold NaN: the output is finite and what the clean pool gives."""
+        WB = 40 if BS == 16 else 3
+        top = WB * BS - 2
+        self._blocks_case(
+            BS, WB, [0, 5, top // 2, 300, top], 2, quant=quant, poison=True,
+            active=[True, True, True, False, True], seed=BS + quant)
+
+    def test_a_sliding_window_over_several_steps(self):
+        """``first`` + ``window`` at 16-token blocks: the window's blocks
+        span three steps of 16, and blocks before its lower edge (NaN
+        here) are not fetched even inside a step that is."""
+        from seldon_core_tpu.ops import paged_decode_attention
+
+        rng = np.random.default_rng(11)
+        S, L, KV, G, D, BS, MB, window = 3, 2, 2, 2, 16, 16, 60, 500
+        NB = 1 + S * MB
+        q = self._rand(rng, S, L, KV * G, D)
+        k = self._rand(rng, NB, BS, KV * D)
+        v = self._rand(rng, NB, BS, KV * D)
+        slot_blocks = jnp.asarray(
+            rng.permutation(NB - 1).reshape(S, MB) + 1, jnp.int32)
+        pos = jnp.asarray([930, 411, 37], jnp.int32)
+        nb = -(-(window + L - 2) // BS) + 1  # blocks that cover the window
+        # the table starts two blocks below the window's lower edge
+        start = jnp.maximum((pos - window + 1) // BS - 2, 0)
+        logical = jnp.minimum(start[:, None] + jnp.arange(nb + 2)[None, :], MB - 1)
+        table = jnp.take_along_axis(slot_blocks, logical, axis=1)
+        kw = k[slot_blocks].reshape(S, MB * BS, KV, D)
+        vw = v[slot_blocks].reshape(S, MB * BS, KV, D)
+        qpos = pos[:, None] + jnp.arange(L)[None, :]
+        rows = jnp.arange(MB * BS)[None, None, :]
+        seen = (rows <= qpos[..., None]) & (rows > qpos[..., None] - window)
+        s = jnp.einsum(
+            "bqkgd,bskd->bkgqs", q.reshape(S, L, KV, G, D), kw) / np.sqrt(D)
+        s = jnp.where(seen[:, None, None], s, -1e30)
+        want = jnp.einsum(
+            "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1), vw
+        ).reshape(S, L, KV * G, D)
+        # poison what no query sees, by whole blocks
+        blk_seen = np.asarray(seen.any(1)).reshape(S, MB, BS).any(-1)
+        dead = np.ones(NB, bool)
+        dead[np.asarray(slot_blocks)[blk_seen]] = False
+        bad = jnp.asarray(dead)[:, None, None]
+        out = paged_decode_attention(
+            q, jnp.where(bad, jnp.nan, k), jnp.where(bad, jnp.nan, v),
+            table, pos, first=start * BS, window=window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
+
     def test_in_model_decode_matches_dense_path(self):
         """The kernel call site inside ``decode_slots_paged``: one decode
         step with kernel on equals the XLA gather path bit-for-bit-ish
@@ -376,10 +513,11 @@ class TestPoolRead:
 
     S, NB, BS = 3, 17, 8
 
-    def _setup(self, pool):
+    def _setup(self, pool, kv_sharded=False):
         """A tiny model and a pool full of random rows (the sink block 0
         too); the table's blocks are out of order, slots 0 and 1 share two
-        prefix blocks, and entries past a slot's length point at the sink."""
+        prefix blocks, and entries past a slot's length point at the sink.
+        ``kv_sharded``: the same rows in the pool a mesh splits by head."""
         from seldon_core_tpu.models import llama
 
         dtype = jnp.bfloat16 if pool == "bfloat16" else jnp.float32
@@ -388,6 +526,7 @@ class TestPoolRead:
         cache = llama.init_paged_cache(
             cfg, self.S, self.NB, self.BS, dtype,
             kv_dtype="int8" if pool == "int8" else None,
+            kv_sharded=kv_sharded,
         )
         rng = np.random.default_rng(7)
         for name in ("k", "v", "k_scale", "v_scale"):
@@ -448,7 +587,8 @@ class TestPoolRead:
             old_logits, old_cache = self._run(which, window, *setup)
         else:
             old_logits, old_cache = self._run(
-                which, window, *setup, kv_sharded=True)
+                which, window, *self._setup(pool, kv_sharded=True),
+                kv_sharded=True)
         assert new_logits.dtype == old_logits.dtype
         assert np.array_equal(
             np.asarray(new_logits, np.float32), np.asarray(old_logits, np.float32)
@@ -456,7 +596,8 @@ class TestPoolRead:
         assert new_cache.keys() == old_cache.keys()
         for name in new_cache:
             assert np.array_equal(
-                np.asarray(new_cache[name], np.float32),
+                np.asarray(new_cache[name], np.float32).reshape(
+                    old_cache[name].shape),
                 np.asarray(old_cache[name], np.float32),
             ), name
 
@@ -466,7 +607,8 @@ class TestPoolRead:
         text = jax.jit(
             lambda p, c: self._run(which, 32, llama, cfg, p, c, **kw)
         ).lower(params, cache).as_text()
-        layer = f"tensor<1x{self.NB}x{self.BS}x{cfg.n_kv_heads}x"
+        layer = "tensor<" + "x".join(
+            map(str, (1,) + cache["k"].shape[1:4])) + "x"
         return [
             line for line in text.splitlines()
             if "dynamic_slice" in line and layer in line.split("->")[-1]
@@ -479,5 +621,6 @@ class TestPoolRead:
         assert self._layer_slices(which, *setup) == []
         # the guard sees the layer-first read where it is chosen: K and V,
         # and an int8 pool's two scales
-        found = self._layer_slices(which, *setup, kv_sharded=True)
+        found = self._layer_slices(
+            which, *self._setup(pool, kv_sharded=True), kv_sharded=True)
         assert len(found) == (4 if pool == "int8" else 2), found

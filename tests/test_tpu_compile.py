@@ -1,0 +1,81 @@
+"""The paged decode kernel through the TPU's own compiler, at the widths
+the benchmark's cells serve, for a v5e that is described and not attached
+(no chip time; nothing runs).  The interpreter the other tests use accepts
+what Mosaic refuses: a copy or slice off the tiling, too much fast memory.
+
+All in this one file, the topology described inside a fixture: only the
+worker that is given the file loads the TPU's library (see the
+``on-chip-measurement`` guide, section 2).
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# (name, slots, queries, heads, head_dim, pool blocks, block, kv heads,
+#  table columns, int8, sliding window)
+CASES = [
+    ("mistral-7b-l8 w2048", 32, 1, 32, 128, 8 * 4097, 16, 8, 128, False, None),
+    ("mistral-7b-l8 w64", 32, 1, 32, 128, 8 * 4097, 16, 8, 4, False, None),
+    ("mistral-7b-l8 verify of 5", 32, 5, 32, 128, 8 * 4097, 16, 8, 128, False, None),
+    ("mistral-7b-l8 int8 w2048", 32, 1, 32, 128, 8 * 4097, 16, 8, 128, True, None),
+    ("mistral-7b-l8 int8 w64", 32, 1, 32, 128, 8 * 4097, 16, 8, 4, True, None),
+    ("command-a-plus full layer", 32, 1, 128, 128, 4 * 769, 256, 8, 32, False, None),
+    ("command-a-plus window layer", 32, 1, 128, 128, 4 * 769, 256, 8, 17, False, 4096),
+]
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_the_paged_kernel_compiles_for_v5e(one_chip, case):
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.paged_attention import paged_decode_attention
+
+    _, S, L, H, D, NB, BS, KV, WB, quant, window = case
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    dt = jnp.bfloat16
+    pool = sds((NB, BS, KV * D), jnp.int8 if quant else dt)
+    args = [
+        sds((S, L, H, D), dt), pool, pool, sds((S, WB), jnp.int32),
+        sds((S,), jnp.int32), sds((S,), jnp.bool_),
+    ]
+    if quant:
+        args += [sds((NB, BS, KV), dt)] * 2
+
+    def f(q, k, v, table, pos, active, ks=None, vs=None):
+        kw = {}
+        if window:
+            first = jnp.maximum(pos - window + 1, 0) // BS * BS
+            kw = dict(first=first, window=window)
+        return paged_decode_attention(
+            q, k, v, table, pos, k_scale=ks, v_scale=vs, active=active,
+            interpret=False, **kw)
+
+    compiled = jax.jit(f).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool goes in as it is: no copy of it among the temporaries
+    pool_bytes = NB * BS * KV * D * (1 if quant else 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
