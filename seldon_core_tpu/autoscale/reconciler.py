@@ -18,7 +18,7 @@ actuates the decision through the SAME kube client the controller uses:
   Zero dropped streams by construction; a failed drain aborts the
   shrink and the hold-down stops it from being hammered.
 
-Embedded pools (kubesim e2e, the elastic bench stage, bare-metal dev)
+Embedded pools (kubesim e2e, bare-metal dev)
 declare the provisionable replica set up front via
 ``seldon.io/autoscale-pool``; the actuator then also maintains
 ``seldon.io/engine-endpoints`` as the live pool-ordered subset, which is

@@ -7,8 +7,10 @@ this is the TPU-native capability the BASELINE Llama configs require.
 
 Design (vLLM-style slots, XLA-flavored):
 
-* a persistent KV cache holds ``n_slots`` independent sequences
-  (``models/llama.py::init_slot_cache``), each with its own position;
+* a persistent paged KV cache holds ``n_slots`` independent sequences
+  (a family's ``init_paged_cache``; the contract a family meets is listed
+  at ``models/registry.py::GENERATIVE_FAMILIES``), each with its own
+  position;
 * **admission** prefills one request's prompt into a free slot — prompts are
   right-padded to a power-of-two bucket so there is one compiled prefill
   program per bucket, never per length;
@@ -96,8 +98,8 @@ class GenerativeModel:
     an internal lock serializes them (the scheduler already serializes its
     own calls, but warmup may overlap traffic that arrives before /ready).
 
-    ``family_mod`` must expose ``init_slot_cache / prefill_slot /
-    decode_slots / sample_tokens`` (``models/llama.py`` does).
+    What ``family_mod`` must expose, and what is probed for, is listed at
+    ``models/registry.py::GENERATIVE_FAMILIES``.
     """
 
     def __init__(

@@ -624,7 +624,7 @@ class _DownConn(WriteCoalescer, asyncio.Protocol):
     def pause_writing(self) -> None:
         """Downstream socket buffer is full (slow client).  Stop reading
         from the engine conn whose response is streaming to us, or a fast
-        SSE stream buffers unboundedly in our transport (ADVICE r5.2)."""
+        SSE stream buffers unboundedly in our transport."""
         self._write_paused = True
         job = self.job
         up = job.up if job is not None else None
@@ -710,8 +710,8 @@ class _DownConn(WriteCoalescer, asyncio.Protocol):
             and self.transport is not None
         ):
             # a client pipelining (or flooding a body) ahead of its
-            # in-flight response parks in the kernel buffer, not ours
-            # (ADVICE r5.2: bounded read-ahead while awaiting)
+            # in-flight response parks in the kernel buffer, not ours:
+            # bounded read-ahead while awaiting
             try:
                 self.transport.pause_reading()
                 self._read_paused = True

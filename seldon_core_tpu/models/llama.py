@@ -432,7 +432,7 @@ def prefill(
 
 def _prefill_core(params, tokens, cfg: Config, attn_fn, kv_hook=None,
                   lora=None, aid=None):
-    """Embed + layer scan shared by :func:`prefill` and :func:`prefill_slot`.
+    """Embed + layer scan shared by :func:`prefill`, :func:`embed_pooled` and the paged prefills.
     Returns ``(hidden (B, L, E), stored)`` where ``stored`` is
     ``(ks, vs) (layers, B, L, kv, hd)`` for float pools, or the kv_hook's
     per-layer pytree (quantized blocks + scales) when one is given.
@@ -487,53 +487,6 @@ def decode_step(params: dict, token: jax.Array, cache: dict, cfg: Config) -> tup
 # slot — continuous batching with zero dynamic shapes: one compiled decode
 # program serves every step of every mix of requests.
 
-def init_slot_cache(cfg: Config, n_slots: int, dtype=jnp.float32) -> dict:
-    """Per-slot KV cache: ``pos`` is a vector — each slot has its own write
-    position, unlike :func:`init_cache`'s single-sequence scalar."""
-    shape = (cfg.n_layers, n_slots, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-    }
-
-
-def prefill_slot(
-    params: dict,
-    tokens: jax.Array,
-    length: jax.Array,
-    slot: jax.Array,
-    cache: dict,
-    cfg: Config,
-    *,
-    mesh: Mesh | None = None,
-    seq_impl: str = "dense",
-) -> tuple[jax.Array, dict]:
-    """Prefill ONE request's prompt into cache slot ``slot``.
-
-    ``tokens`` is ``(1, Lpad)`` right-padded to a bucket length; ``length``
-    is the true prompt length (traced, so one compiled program per bucket).
-    Returns ``(last_logits (V,), cache)``.  Correctness under padding: pad
-    positions only feed pad *queries* (causal mask), the returned logits are
-    taken at ``length - 1``, and decode's validity mask never reaches pad
-    cache rows before they are overwritten.
-    """
-    x, (ks, vs) = _prefill_core(params, tokens, cfg, _select_attn(mesh, seq_impl))
-    # ks: (layers, 1, Lp, kv, hd) -> write rows [0, Lp) of this slot
-    cache = {
-        "k": jax.lax.dynamic_update_slice(
-            cache["k"], ks.astype(cache["k"].dtype), (0, slot, 0, 0, 0)
-        ),
-        "v": jax.lax.dynamic_update_slice(
-            cache["v"], vs.astype(cache["v"].dtype), (0, slot, 0, 0, 0)
-        ),
-        "pos": cache["pos"].at[slot].set(length),
-    }
-    h = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
-    h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
-    return h @ params["head"], cache
-
-
 def embed_pooled(
     params: dict,
     tokens: jax.Array,
@@ -547,7 +500,7 @@ def embed_pooled(
 
     ``tokens`` is ``(1, Lpad)`` right-padded to a bucket length; ``length``
     is the true prompt length (traced — one compiled program per bucket,
-    exactly like :func:`prefill_slot`).  Pure forward: no KV cache is
+    exactly like :func:`prefill_slot_paged`).  Pure forward: no KV cache is
     written and no slot is consumed, so the scheduler can batch these
     alongside decode without spending pool blocks.  Returns the final-norm
     hidden states averaged over the real (unpadded) rows, ``(E,) float32``

@@ -251,8 +251,23 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
     return get_family(family).example_input(cfg, batch)
 
 
-# families exposing the slot-cache generative contract
-# (init_slot_cache / prefill_slot / decode_slots / sample_tokens)
+# The generative contract: what ``executor/generation.py::GenerativeModel``
+# reads of a family module.
+#   required: ``init_params``, ``param_logical_axes`` (under a mesh),
+#     ``init_paged_cache``, ``prefill_slot_paged``, ``decode_slots_paged``,
+#     ``sample_tokens`` (a ``top_k`` argument where top-k sampling is asked
+#     for; a ``kernel`` argument of ``decode_slots_paged`` where the paged
+#     decode kernel is);
+#   probed for (``hasattr``/``getattr``), each the feature named:
+#     ``prefill_suffix_paged`` (prefix reuse, chunked prefill),
+#     ``decode_slots_spec_paged`` (speculative decode; ``apply_medusa_heads``
+#     + ``init_medusa_heads`` for learned heads), ``embed_pooled``
+#     (embeddings), ``init_lora_params`` with ``LORA_*_TARGETS`` and
+#     ``lora_adapter_factors`` (adapters), ``truncate_params`` (a layer-
+#     truncated draft), ``paged_kv_slot_bytes`` (the KV ledger's own size of
+#     a slot), ``COUNTERS`` (names of the on-device counters a step returns).
+# A feature asked of a family without its function is refused at build;
+# prefix reuse, chunked prefill and adapters are turned off with a warning.
 GENERATIVE_FAMILIES: dict[str, Any] = {
     "llama": llama, "cohere2_moe": cohere2_moe,
 }

@@ -1,11 +1,10 @@
 """Wire-throughput accounting: per-deployment, per-stage byte counters.
 
-Round 5's headline collapsed 4.5x with `bench.py` byte-identical and
-nothing in the repo could attribute the swing — the spans plane says WHERE
-latency went, but not whether a stage was bandwidth-bound.  This module is
-the missing layer: every transport edge records request/response bytes and
-(where the transfer is timed) an achieved-MB/s EWMA, so "the network
-path degraded" and "the framework regressed" become distinguishable live.
+The spans plane says WHERE latency went, but not whether a stage was
+bandwidth-bound.  This module is that layer: every transport edge records
+request/response bytes and (where the transfer is timed) an achieved-MB/s
+EWMA, so "the network path degraded" and "the framework regressed" become
+distinguishable live.
 
 Edges (the ``stage`` vocabulary, one :class:`WireCounter` per
 ``(stage, deployment)``):

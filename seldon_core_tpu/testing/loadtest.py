@@ -9,8 +9,9 @@ asyncio loop with ``--concurrency`` in-flight requests over pooled
 connections, merged into one latency histogram (log-spaced bins, so
 percentiles merge exactly across processes).
 
-Every request crosses a real socket and pays JSON/proto codec cost — this is
-the harness behind ``bench.py``'s headline numbers, and a product CLI:
+Every request crosses a real socket and pays JSON/proto codec cost.  An
+operator's tool for their own cluster (the repo's speeds are measured by
+``benchmark/``, PERF.md):
 
     sct-loadtest http://host:8000/api/v0.1/predictions -c 64 -P 4 -d 10
     sct-loadtest host:5001 --grpc -c 64 -P 4 -d 10
@@ -331,104 +332,6 @@ def run_load(
         requests=ok + fail, failures=fail, elapsed_s=elapsed, hist=hist,
         offered=offered,
     )
-
-
-# ---------------------------------------------------------------------------
-# diurnal trace generator (docs/AUTOSCALING.md)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class TraceConfig:
-    """Shape of a synthetic production day, scaled to any duration.
-
-    Models the three properties of a large consumer-serving trace that an
-    autoscaler actually has to survive: a diurnal arrival rate (trough →
-    peak → trough, raised-cosine), heavy-tailed lognormal prompt/output
-    lengths (medians are small, the p99 is many multiples of it), and a
-    Zipf-skewed shared-prefix population (a handful of system prompts
-    dominate, which is what makes prefix-affinity routing and digest-aware
-    drain victim selection matter)."""
-
-    duration_s: float = 86400.0
-    base_rps: float = 1.0  # trough arrival rate
-    peak_rps: float = 10.0  # midday peak
-    peak_at_frac: float = 0.55  # where in the window the peak sits
-    prompt_len_median: int = 200  # tokens
-    prompt_len_sigma: float = 0.8  # lognormal shape (ln-space stddev)
-    output_len_median: int = 64
-    output_len_sigma: float = 0.9
-    max_len: int = 4096
-    prefix_population: int = 512  # distinct shared system prompts
-    prefix_zipf_a: float = 1.2  # Zipf exponent over that population
-    seed: int = 0
-
-
-@dataclasses.dataclass(frozen=True)
-class TraceRequest:
-    at_s: float  # arrival offset from trace start
-    prefix_id: int  # which shared prefix this request reuses
-    prompt_len: int
-    output_len: int
-
-
-def diurnal_rate(cfg: TraceConfig, t: float) -> float:
-    """Instantaneous arrival rate at offset ``t``: raised cosine between
-    ``base_rps`` and ``peak_rps`` peaking at ``peak_at_frac``."""
-    phase = 2.0 * np.pi * (t / cfg.duration_s - cfg.peak_at_frac)
-    return cfg.base_rps + (cfg.peak_rps - cfg.base_rps) * (
-        1.0 + np.cos(phase)
-    ) / 2.0
-
-
-def generate_trace(cfg: TraceConfig) -> list[TraceRequest]:
-    """Non-homogeneous Poisson arrivals via thinning (Lewis-Shedler):
-    candidate gaps at the PEAK rate, each kept with probability
-    rate(t)/peak — exact for any bounded rate shape, no time-step bias."""
-    rng = np.random.default_rng(cfg.seed)
-    lam_max = max(cfg.peak_rps, cfg.base_rps, 1e-9)
-    # Zipf pmf over a FINITE rank population (np.random's zipf is
-    # unbounded); rank 0 is the most-shared prefix
-    ranks = np.arange(1, cfg.prefix_population + 1, dtype=np.float64)
-    pmf = ranks ** -cfg.prefix_zipf_a
-    pmf /= pmf.sum()
-    out: list[TraceRequest] = []
-    t = float(rng.exponential(1.0 / lam_max))
-    while t < cfg.duration_s:
-        if rng.random() < diurnal_rate(cfg, t) / lam_max:
-            out.append(
-                TraceRequest(
-                    at_s=t,
-                    prefix_id=int(rng.choice(cfg.prefix_population, p=pmf)),
-                    prompt_len=_lognormal_len(
-                        rng, cfg.prompt_len_median, cfg.prompt_len_sigma,
-                        cfg.max_len,
-                    ),
-                    output_len=_lognormal_len(
-                        rng, cfg.output_len_median, cfg.output_len_sigma,
-                        cfg.max_len,
-                    ),
-                )
-            )
-        t += float(rng.exponential(1.0 / lam_max))
-    return out
-
-
-def _lognormal_len(rng, median: int, sigma: float, max_len: int) -> int:
-    n = int(round(rng.lognormal(np.log(max(1, median)), sigma)))
-    return max(1, min(n, max_len))
-
-
-def trace_rate_series(
-    cfg: TraceConfig, trace: list[TraceRequest], bucket_s: float
-) -> list[float]:
-    """Achieved arrivals per second, bucketed — for asserting the shape
-    the generator produced (ramp up, peak, ebb) without re-deriving the
-    analytic curve."""
-    n = max(1, int(np.ceil(cfg.duration_s / bucket_s)))
-    counts = [0] * n
-    for req in trace:
-        counts[min(n - 1, int(req.at_s / bucket_s))] += 1
-    return [c / bucket_s for c in counts]
 
 
 # ---------------------------------------------------------------------------

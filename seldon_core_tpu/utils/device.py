@@ -1,8 +1,7 @@
 """What the process serves on, and where its compiled programs are kept.
 
 Two facts every entry point that compiles needs before its first JAX call
-(``engine/app.py::_serve``, ``utils/roofline.py::main``, the bench and
-smoke children):
+(``engine/app.py::_serve``, the benchmark's and the smoke's children):
 
 - :func:`configure_compile_cache` places JAX's persistent compilation
   cache.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it

@@ -618,7 +618,7 @@ PREDICTOR = {
 
 class TestDrainIdempotency:
     # boots real generative engines (one JAX compile each) — excluded from
-    # the tier-1 `-m 'not slow'` sweep; `make scale-check` runs the full file
+    # the tier-1 `-m 'not slow'` sweep
     pytestmark = pytest.mark.slow
 
     def test_repeat_drain_conflicts_with_state_undrain_races_refused(self):

@@ -20,8 +20,6 @@
 * **program cache-key audit** — ``prefill_chunk`` and ``decode_kernel``
   are folded into every compiled-program cache key, and ``/stats/warmup``
   variant labels name the chunk programs.
-
-``make chunk-check`` runs exactly this file.
 """
 
 from __future__ import annotations

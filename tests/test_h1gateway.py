@@ -339,7 +339,7 @@ class TestChunkedResponseSplice:
 
 class TestUpstreamReplayCap:
     def test_engine_that_always_closes_yields_502(self):
-        """ADVICE finding 3: an engine that answers by closing the
+        """An engine that answers by closing the
         connection must exhaust the replay budget (2) and fail the client
         with 502 — not connect/close-loop until the deadline reaper."""
 
@@ -377,7 +377,7 @@ class TestUpstreamReplayCap:
 
 class TestEvictedPoolFailsFast:
     def test_spawn_send_on_closed_pool_fails_job_promptly(self):
-        """ADVICE finding 4: a connect that lands after the pool was
+        """A connect that lands after the pool was
         evicted (deployment removed) must fail the downstream with a
         prompt 503, not silently drop the job until the 504 reaper."""
         from seldon_core_tpu.gateway.h1gateway import _Job, _UpstreamPool
@@ -412,7 +412,7 @@ class TestEvictedPoolFailsFast:
 
 
 class TestHeaderFieldNameStrictness:
-    """ADVICE finding 1: the raw head splices onto a SHARED pipelined
+    """The raw head splices onto a SHARED pipelined
     engine connection — header names that are not RFC 7230 tokens (and
     obs-fold continuations) are smuggling vectors and must be 400'd."""
 
@@ -476,7 +476,7 @@ class TestHeaderFieldNameStrictness:
 
 
 class TestSpliceBackpressure:
-    """ADVICE r5 item 2: bounded buffering in BOTH directions of the
+    """Bounded buffering in BOTH directions of the
     splice — a client pipelining ahead of its response parks in the
     kernel buffer (pause_reading), and a fast engine stream toward a slow
     client pauses the ENGINE conn's reads instead of buffering unboundedly

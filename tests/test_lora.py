@@ -22,8 +22,6 @@
 * **traffic split** — the existing RandomABTest machinery routing between
   two adapter ids of one base deployment, asserted over the per-adapter
   token ledger and the timeline ledger.
-
-``make lora-check`` runs exactly this file.
 """
 
 from __future__ import annotations

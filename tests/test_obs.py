@@ -3,7 +3,7 @@
 inheritance), the span recorder + flight recorder, bounded exporters,
 the perf-attribution plane (wire byte counters on every transport edge,
 `/stats/wire`, the jax profiler start/stop lifecycle, event-loop lag +
-export drop gauges), and the obs-check acceptance gate (`make obs-check`):
+export drop gauges), and the obs-check acceptance gate:
 gateway -> engine -> 2-node graph -> batcher yields one trace with >= 4
 spans and a breakdown whose stages account for the measured wall time."""
 
@@ -500,7 +500,7 @@ class TestExporters:
 class TestWireAccounting:
     """The perf-attribution plane's byte counters: every transport edge
     must account request/response bytes that match the payloads actually
-    sent (the attribution BENCH_r05's 4.5x collapse lacked)."""
+    sent."""
 
     def test_h1_splice_counts_request_and_response_bytes(self):
         from seldon_core_tpu.obs import WIRE, WIRE_GATEWAY_H1
@@ -779,7 +779,7 @@ class TestErrorCodeAudit:
 
 class TestObsCheck:
     def test_obs_check_end_to_end(self):
-        """`make obs-check` / the acceptance gate: 50 requests through
+        """The acceptance gate: 50 requests through
         gateway -> engine -> 2-node graph -> batcher.  Asserts (1) one
         trace holds >= 4 spans, (2) /stats/breakdown reports non-zero
         queue-wait and device-step, (3) /prometheus exposes the new

@@ -7,8 +7,6 @@
   and a warmed stub engine's p99 stays bounded relative to its p95
   (first-touch compiles must never land on a user request);
 * overlap smoke — the overlap actually engages under concurrent load.
-
-``make perf-check`` runs exactly this file.
 """
 
 from __future__ import annotations

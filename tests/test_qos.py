@@ -1,6 +1,6 @@
 """QoS plane tests: admission control, deadline propagation, priority
 classes, brownout, bounded queues, cancel-on-disconnect, and the
-overload acceptance gate (`make qos-check`): under a saturating load with
+overload acceptance gate: under a saturating load with
 50 ms deadlines, the QoS-on engine 429s shed requests in milliseconds
 WITHOUT spending device steps on them, and completes strictly more
 requests within deadline than the QoS-off engine."""
@@ -768,7 +768,7 @@ class TestGatewayQos:
 
 
 # ---------------------------------------------------------------------------
-# acceptance gate: goodput under saturating load (`make qos-check`)
+# acceptance gate: goodput under saturating load
 # ---------------------------------------------------------------------------
 
 class SlowRunner:

@@ -13,8 +13,6 @@
   the quantized representation, prefix reuse pinned-equal under int8;
 * **program cache-key audit** — static sampling/speculation/quantization
   config is folded into every compiled-program cache key.
-
-``make spec-check`` runs exactly this file.
 """
 
 from __future__ import annotations

@@ -19,8 +19,6 @@ optimizations:
   Prometheus ledger, and the usage meter;
 * **rider** — ``spec_draft`` with ``decode_block=1`` is a loud
   build-time error, not a silent degradation.
-
-``make spec-check`` runs this file alongside tests/test_spec.py.
 """
 
 from __future__ import annotations

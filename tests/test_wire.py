@@ -98,7 +98,7 @@ class TestStreamStateCleanup:
 
 
 class TestRetryClassification:
-    """Pin the UNAVAILABLE connect-vs-sent wordings (ADVICE r3): grpc-core
+    """Pin the UNAVAILABLE connect-vs-sent wordings: grpc-core
     messages are unstable, so classification matches several markers."""
 
     def test_connect_failure_markers(self):
@@ -761,7 +761,7 @@ class TestServerStreaming:
 
 class TestServerConnLossCancelsRelays:
     def test_on_closed_pops_and_invokes_relay_cancels(self):
-        """ADVICE finding 5: a dead downstream gRPC connection must cancel
+        """A dead downstream gRPC connection must cancel
         in-flight inline relays upstream — full connection loss gets the
         same treatment a per-stream RST already had."""
         from seldon_core_tpu.wire.h2grpc import _ServerConn
