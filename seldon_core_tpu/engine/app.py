@@ -916,6 +916,15 @@ class EngineApp:
         stalls land here; docs/PERFORMANCE.md §7)."""
         return web.json_response(self._breakdown_payload())
 
+    @staticmethod
+    def _unit_snapshot(unit) -> dict:
+        """One generative unit's ledgers: the model's, then the
+        scheduler's (packing, decode-block boundaries by outcome)."""
+        snap = unit.model.spec_snapshot()
+        snap["packing"] = unit.scheduler.packing_snapshot()
+        snap["block_boundaries"] = unit.scheduler.boundary_snapshot()
+        return snap
+
     def _breakdown_payload(self) -> dict:
         payload: dict = {"stages": RECORDER.breakdown()}
         try:
@@ -924,9 +933,7 @@ class EngineApp:
             units = []
         gen = {}
         for unit in units:
-            snap = unit.model.spec_snapshot()
-            snap["packing"] = unit.scheduler.packing_snapshot()
-            gen[unit.model.name] = snap
+            gen[unit.model.name] = self._unit_snapshot(unit)
         # co-resident deployments (docs/PACKING.md): keyed by
         # "<deployment>/<model>" so two co-tenants of the same preset
         # keep separate isolation ledgers
@@ -936,9 +943,9 @@ class EngineApp:
             except Exception:
                 co_units = []
             for unit in co_units:
-                snap = unit.model.spec_snapshot()
-                snap["packing"] = unit.scheduler.packing_snapshot()
-                gen[f"{svc.deployment_name}/{unit.model.name}"] = snap
+                gen[f"{svc.deployment_name}/{unit.model.name}"] = (
+                    self._unit_snapshot(unit)
+                )
         if gen:
             payload["generation"] = gen
         if self.co_services:

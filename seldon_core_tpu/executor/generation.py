@@ -22,12 +22,17 @@ Design (vLLM-style slots, XLA-flavored):
   logits;
 * **overlapped pipeline** (docs/PERFORMANCE.md): the fused k-step decode
   program returns its final ``(tokens, active, remaining)`` carry as device
-  arrays, so in steady state block N+1 dispatches straight from block N's
-  on-device carry *before* the host fetches block N's tokens — the host
-  consumes results while the chip is already computing the next block, and
-  the per-block host round trip vanishes from the critical path.  Any
-  host-side state change (admission, deadline reap, disconnect) marks the
-  carry dirty and forces one synchronous dispatch rebuilt from host state.
+  arrays, so block N+1 dispatches straight from block N's on-device carry:
+  *before* the host fetches block N's tokens where nothing could be
+  admitted at N's end (a full house of fixed budgets, whoever waits) — the
+  host consumes results while the chip is already computing the next block,
+  and the per-block host round trip vanishes from the critical path.  Where
+  something could, the decision is held while N runs (N+1 goes out as N is
+  about to end if nobody came, else at the sync point or with N's tokens in
+  hand), so a request that has a slot never waits for a block chained
+  ahead of it.  Any host-side state change (admission, deadline reap,
+  disconnect) marks the carry dirty and forces one synchronous dispatch
+  rebuilt from host state.
 
 ``GenerationScheduler`` is the asyncio front: ``submit(prompt) ->
 generated ids``; per-request ``max_new_tokens`` / ``temperature`` /
@@ -3317,7 +3322,8 @@ class GenerativeModel:
         toks_seq, act_seq = res[0], res[1]
         conf_seq = res[2] if len(res) > 2 else None
         act = np.asarray(active, bool)
-        self._pos_ceiling[act] += k * self._tps
+        with self._lock:
+            self._pos_ceiling[act] += k * self._tps
         self._note_read(act, payload["window"])
         return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
 
@@ -3344,7 +3350,10 @@ class GenerativeModel:
         toks_seq, act_seq = res[0], res[1]
         conf_seq = res[2] if len(res) > 2 else None
         act = np.asarray(active, bool)
-        self._pos_ceiling[act] += k * self._tps
+        # under the lock: a block dispatched behind one that is about to
+        # end shares the ceiling with that block's fetch, on another thread
+        with self._lock:
+            self._pos_ceiling[act] += k * self._tps
         self._note_read(act, payload["window"])
         self.overlapped += 1
         return (toks_seq, act_seq, conf_seq, t0, act, int(k), self._ctr_dev)
@@ -3380,9 +3389,10 @@ class GenerativeModel:
             # what actually landed.  The ceiling stays an overestimate of
             # the true device position throughout (never an underestimate).
             emitted = act_np.sum(axis=0).astype(np.int64)
-            self._pos_ceiling[disp_active] -= (
-                k * self._tps - emitted[disp_active]
-            )
+            with self._lock:
+                self._pos_ceiling[disp_active] -= (
+                    k * self._tps - emitted[disp_active]
+                )
             # acceptance counts PRODUCTIVE (pass, slot) pairs only — a slot
             # that finished its budget mid-block rides the rest of the
             # fused block inactive in the plain path too, so charging those
@@ -3540,13 +3550,18 @@ class GenerativeModel:
     def _counters_copy(self):
         """The family's counters as the block just dispatched leaves them,
         as an array of its own: the cache's buffer is donated to the next
-        dispatch, which may come before this block's fetch."""
+        dispatch, which may come before this block's fetch.  The copy is a
+        program of its own, queued behind the block: it is dispatched under
+        an annotation of its own, or a trace that pairs programs with
+        dispatch annotations in order hands it the NEXT block's (a chained
+        block's annotation is there before this block ends)."""
         ctr = self._cache.get("counters") if self._ctr_names else None
         if ctr is None:
             return None
         import jax.numpy as jnp
 
-        return jnp.copy(ctr)
+        with jax.profiler.TraceAnnotation("decode:counters"):
+            return jnp.copy(ctr)
 
     def counters_snapshot(self) -> dict | None:
         """``{name: count}`` of the family's device counters as of the last
@@ -3902,7 +3917,7 @@ class GenerationScheduler:
     ):
         self.model = model
         # overlapped pipeline (docs/PERFORMANCE.md): dispatch block N+1
-        # from the device carry before consuming block N's tokens.  On by
+        # from block N's device carry, not from rebuilt host arrays.  On by
         # default for fused blocks; SCT_GEN_OVERLAP=0 (or the ``overlap``
         # graph parameter) restores the strictly sequential loop.
         if overlap is None:
@@ -3970,6 +3985,8 @@ class GenerationScheduler:
         self._quiesced = asyncio.Event()
         self.drains = 0
         self.drained_out = 0
+        # decode-block boundaries by outcome (boundary_snapshot)
+        self.boundaries: dict[str, int] = {}
         # Random base so temperature>0 sampling differs across restarts and
         # replicas; within one process the sequence stays deterministic.
         self._seed = int.from_bytes(os.urandom(4), "little")
@@ -5144,6 +5161,135 @@ class GenerationScheduler:
             self.model.release_slot(i)
         active[:] = False
 
+    # block-boundary outcomes that are no break of the chain (the rest are
+    # the causes an ``overlap-break`` timeline event names)
+    _CHAINED = ("chained-early", "chained-due", "chained-late")
+    _NO_BREAK = frozenset(_CHAINED + ("idle", "overlap-off"))
+    # how long before the in-flight block's expected end a held decision
+    # falls: the hand-off to a thread and the dispatch (1.1 ms on a v5e
+    # host, PERF.md §6, PR 31) and the estimate's own error, which grows
+    # with the block
+    _LEAD_S, _LEAD_SHARE = 0.002, 0.03
+
+    def _chain_break(self, carry_dirty: bool) -> str | None:
+        """Why the next block cannot come off the device carry, whoever
+        waits: the host decided something the chip cannot see."""
+        if carry_dirty:
+            return "carry-dirty"
+        if self._preempt or self._arb_contended():
+            # packed chip: a co-tenant wants (or was granted) the device —
+            # yield at the block boundary instead of chaining another block
+            return "arbiter-yield"
+        if self._external_release:
+            return "handoff-release"
+        if self._prefilling:
+            return "chunked-prefill"
+        return None
+
+    def _outlasts(self, slots, active, k: int) -> np.ndarray:
+        """The live slots that cannot end inside the block in flight: no
+        ``eos_id``, and a budget past the block's worst case (``k`` tokens
+        a slot, ``k * (1 + draft)`` with speculation)."""
+        worst = k * getattr(self.model, "_tps", 1)
+        return np.array([
+            bool(live) and req is not None and req.eos_id is None
+            and req.max_new_tokens - len(req.out) > worst
+            for req, live in zip(slots, active)
+        ])
+
+    async def _nobody_came(
+        self, due: float, carry_dirty: bool, tokens: asyncio.Future
+    ) -> bool:
+        """Hold the decision on the next block until the one in flight is
+        about to end (``due``, on ``time.perf_counter``), so a request that
+        arrives meanwhile is seen.  True: nobody waits and nothing broke
+        the chain — dispatch now, behind the block in flight.  False: leave
+        it to the sync point, or to the block's ``tokens`` (its fetch came
+        back first: the estimate was late, and the next one starts from
+        this block)."""
+        while True:
+            # no await between the clear and the checks: a submit landing
+            # after them sets the event the wait below returns on
+            self._wake.clear()
+            if (
+                tokens.done() or self._waiting or self._overflow
+                or self._chain_break(carry_dirty)
+            ):
+                return False
+            left = due - time.perf_counter()
+            if left <= 0:
+                return True
+            woke = asyncio.ensure_future(self._wake.wait())
+            try:
+                await asyncio.wait(
+                    {tokens, woke}, timeout=left,
+                    return_when=asyncio.FIRST_COMPLETED,
+                )
+            finally:
+                woke.cancel()
+
+    @staticmethod
+    def _ended_in(toks_seq, act_seq, slots, active) -> np.ndarray:
+        """The live slots a fetched block finished (budget or eos), read
+        off its tokens and emitted mask as ``_deliver`` is about to find
+        them — the chip flipped them inactive at the same step."""
+        ended = np.zeros(len(slots), bool)
+        for i in np.flatnonzero(active):
+            req = slots[i]
+            if req is None:
+                continue
+            took = np.asarray(act_seq[:, i], bool)
+            ended[i] = len(req.out) + int(took.sum()) >= req.max_new_tokens or (
+                req.eos_id is not None
+                and bool((toks_seq[took, i] == req.eos_id).any())
+            )
+        return ended
+
+    def _admission_break(self, live: np.ndarray) -> str | None:
+        """The cause to name when the sync point at this boundary could
+        admit a request that waits: a slot is free (``live`` is the slots
+        still running after the block in hand) or the request needs none."""
+        if not (self._waiting or self._overflow):
+            return None
+        free = len(live) - int(live.sum()) - len(self._external)
+        if free > 0 or any(r.embed_only for r in self._waiting):
+            return "admission" if self._waiting else "kv-starved"
+        return None
+
+    async def _chain(self, active, k: int, how: str) -> tuple[tuple | None, str]:
+        """Dispatch the next block from the device carry -> ``(handle,
+        how)``, or ``(None, "dispatch-error")``."""
+        try:
+            nxt = await asyncio.to_thread(
+                self.model.step_k_continue, active, self._next_seed(), k
+            )
+        except asyncio.CancelledError:
+            raise
+        except Exception:
+            log.exception(
+                "overlapped dispatch failed; falling back to sequential"
+            )
+            return None, "dispatch-error"
+        return nxt, how
+
+    def boundary_snapshot(self) -> dict:
+        """Decode-block boundaries by outcome (``GET /stats/breakdown``):
+        the next block chained off the device carry when this one was
+        dispatched (``chained_early``: a full house of known budgets), when
+        it was about to end and nobody had come (``chained_due``) or once
+        its tokens were in hand (``chained_late``); a sync point, by the
+        cause that broke the chain; or nothing dispatched because no slot
+        was live (``idle``)."""
+        b = self.boundaries
+        return {
+            **{c.replace("-", "_"): b.get(c, 0) for c in self._CHAINED},
+            "idle": b.get("idle", 0),
+            "sync": {
+                c: n for c, n in sorted(b.items())
+                if c not in self._CHAINED and c != "idle"
+            },
+        }
+
     async def _run(self) -> None:
         S = self.model.n_slots
         slots: list[_Request | None] = [None] * S
@@ -5156,6 +5302,10 @@ class GenerationScheduler:
         # admission makes the next dispatch rebuild from host arrays)
         pending: tuple | None = None
         carry_dirty = True
+        # host-clock estimate of when the block in flight ends: when it
+        # started (its dispatch, or its predecessor's tokens coming back,
+        # whichever is later) plus what the last fetched block took
+        pending_t = fetched_t = block_s = 0.0
         try:
             while True:
                 if self._closed:
@@ -5288,6 +5438,7 @@ class GenerationScheduler:
                         await self._arb_acquire()
                     if embeds:
                         await self._admit_embeds(embeds)
+                    live_before = int(active.sum())
                     if batch:
                         await self._admit_batch(batch, slots, cur, temps, active)
                     if self._prefilling:
@@ -5296,6 +5447,20 @@ class GenerationScheduler:
                         # by a chunk, not a prompt (docs/PERFORMANCE.md §7)
                         await self._advance_prefill(slots, cur, temps, active)
                     self._reap_slots(slots, active)
+                    if (
+                        int(active.sum()) > live_before
+                        and self._waiting
+                        and not self._prefilling
+                        and not self._preempt
+                        and not self._arb_contended()
+                    ):
+                        # somebody came while the prefills ran: the sync
+                        # point is taken again (it admits them if a slot is
+                        # left, and falls through if none is) before a
+                        # block is dispatched ahead of them.  Each pass
+                        # fills a slot, so there are at most as many
+                        # passes as slots.
+                        continue
                     if not active.any():
                         # nothing to dispatch: the grant goes back before
                         # any park or spin below
@@ -5406,59 +5571,54 @@ class GenerationScheduler:
                         self._fail_inflight(slots, active, exc)
                         continue
                     carry_dirty = False
+                    pending_t = time.perf_counter()
                     continue
-                # fetch phase — THE overlap: while block N's results are in
-                # flight, dispatch block N+1 straight from the on-device
-                # carry, so the chip starts the next block before the host
-                # has even seen this one.  Only in steady state: waiting
-                # work needs a sync point (admission), and a dirty carry
-                # (host-side reap) must be rebuilt from host arrays.
+                # fetch phase — THE overlap: block N+1 is dispatched straight
+                # from block N's on-device carry.  WHEN is decided from what
+                # could be admitted at N's end (docs/PERFORMANCE.md §1):
+                # nothing, whatever arrives -> chain at once, so the chip
+                # never waits for the host; something -> hold the decision
+                # while N runs, so a request that has a slot never waits for
+                # a block chained ahead of it: chain when N is about to end
+                # if nobody came and a slot is sure to stay live, else
+                # decide with N's tokens in hand.
                 nxt: tuple | None = None
-                break_cause: str | None = None
-                if self.overlap and active.any():
-                    # the overlap pipeline only continues from the device
-                    # carry in steady state; name WHY it breaks (the cause
-                    # lands on every live stream's timeline — the forensics
-                    # for "this request's ITL spiked right here")
-                    if carry_dirty:
-                        break_cause = "carry-dirty"
-                    elif self._preempt or self._arb_contended():
-                        # packed chip: a co-tenant wants (or was granted)
-                        # the device — yield at the block boundary instead
-                        # of chaining another block off the carry
-                        break_cause = "arbiter-yield"
-                    elif self._waiting:
-                        break_cause = "admission"
-                    elif self._overflow:
-                        break_cause = "kv-starved"
-                    elif self._external_release:
-                        break_cause = "handoff-release"
-                    elif self._prefilling:
-                        break_cause = "chunked-prefill"
-                if self.overlap and active.any() and break_cause is None:
-                    try:
-                        nxt = await asyncio.to_thread(
-                            self.model.step_k_continue, active, self._next_seed(), k
+                outcome: str | None = None
+                tokens = None  # N's fetch, where it runs beside the decision
+                if not self.overlap:
+                    outcome = "overlap-off"
+                elif not active.any():
+                    outcome = "idle"
+                elif self._chain_break(carry_dirty) is None:
+                    stays = self._outlasts(slots, active, k)
+                    how = None
+                    if stays.all() and not any(
+                        r.embed_only for r in self._waiting
+                    ):
+                        # every slot is taken and none can end inside N: a
+                        # sync point at its end could admit nobody, whoever
+                        # waits (an embed-only request needs no slot)
+                        how = "chained-early"
+                    elif block_s > 0 and stays.any():
+                        # a slot is sure to stay live, and somebody may yet
+                        # come for a free one: N's fetch waits on its own
+                        # thread while the decision is held
+                        tokens = asyncio.ensure_future(
+                            asyncio.to_thread(self.model.step_k_fetch, pending)
                         )
-                    except asyncio.CancelledError:
-                        raise
-                    except Exception:
-                        log.exception(
-                            "overlapped dispatch failed; falling back to sequential"
-                        )
-                        nxt = None
-                        carry_dirty = True
-                        break_cause = "dispatch-error"
-                if break_cause is not None:
-                    for i in range(S):
-                        if slots[i] is not None and active[i]:
-                            self._tl(
-                                slots[i], "overlap-break", cause=break_cause
-                            )
+                        if await self._nobody_came(
+                            max(pending_t, fetched_t) + block_s
+                            - (self._LEAD_S + self._LEAD_SHARE * block_s),
+                            carry_dirty, tokens,
+                        ):
+                            how = "chained-due"
+                    if how is not None:
+                        nxt, outcome = await self._chain(active, k, how)
+                        nxt_t = time.perf_counter()
+                if tokens is None:
+                    tokens = asyncio.to_thread(self.model.step_k_fetch, pending)
                 try:
-                    toks_seq, act_seq = await asyncio.to_thread(
-                        self.model.step_k_fetch, pending
-                    )
+                    toks_seq, act_seq = await tokens
                 except asyncio.CancelledError:
                     raise
                 except Exception as exc:
@@ -5478,8 +5638,41 @@ class GenerationScheduler:
                     self._arb_release()
                     self._fail_inflight(slots, active, exc)
                     continue
+                now = time.perf_counter()
+                block_s = now - max(pending_t, fetched_t)
+                fetched_t = now
+                if outcome is None:
+                    # N's tokens are in hand and nothing is chained: the
+                    # sync point if it can do something, else the next
+                    # block now, and N's delivery while it runs
+                    live = active & ~self._ended_in(
+                        toks_seq, act_seq, slots, active
+                    )
+                    outcome = (
+                        self._chain_break(carry_dirty)
+                        or self._admission_break(live)
+                    )
+                    if outcome is None and not live.any():
+                        outcome = "idle"
+                    if outcome is None:
+                        nxt, outcome = await self._chain(
+                            active, k, "chained-late"
+                        )
+                        nxt_t = time.perf_counter()
+                self.boundaries[outcome] = self.boundaries.get(outcome, 0) + 1
+                if outcome not in self._NO_BREAK:
+                    # name WHY the chain broke: the cause lands on every
+                    # live stream's timeline — the forensics for "this
+                    # request's ITL spiked right here"
+                    for i in range(S):
+                        if slots[i] is not None and active[i]:
+                            self._tl(slots[i], "overlap-break", cause=outcome)
+                    if outcome == "dispatch-error":
+                        carry_dirty = True
                 pending = nxt
-                if pending is None:
+                if pending is not None:
+                    pending_t = nxt_t
+                else:
                     # pipeline drained to a sync point: rotate the grant
                     # BEFORE host-side delivery so a parked co-tenant's
                     # dispatch overlaps our bookkeeping

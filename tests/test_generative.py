@@ -497,6 +497,30 @@ class TestOverlapPinnedEqual:
             assert np.array_equal(a, b), (a.tolist(), b.tolist())
         assert model.overlapped >= 1
 
+    def test_full_house_with_waiters_bit_identical_on_tp2_mesh(self, tiny):
+        """Twice the slots' requests on the tp=2 mesh: the second wave
+        waits while the first chains block after block off the sharded
+        device carry — the same tokens as the sequential loop's."""
+        from seldon_core_tpu.parallel import best_mesh
+
+        cfg, params = tiny
+        mesh = best_mesh(2, tp=2)
+
+        def build():
+            return GenerativeModel(
+                cfg, params, n_slots=2, decode_block=4, mesh=mesh,
+                param_axes=llama.param_logical_axes(params),
+            )
+
+        base = self._generate(build(), overlap=False, max_new=14)
+        model = build()
+        overlapped = self._generate(model, overlap=True, max_new=14)
+        for a, b in zip(base, overlapped):
+            assert np.array_equal(a, b), (a.tolist(), b.tolist())
+        # three of a wave's four boundaries chained, in both waves
+        assert model.overlapped == 6
+        assert model.steps == 8 * 4
+
     def test_overlap_bit_identical_with_prefix_reuse(self, tiny):
         """Overlap x KV prefix reuse: shared-prefix admissions (suffix-only
         prefills) feeding overlapped decode stay pinned to the sequential
@@ -587,6 +611,253 @@ class TestOverlapPinnedEqual:
         logits = llama.forward(params, prompt[None], cfg)[0, -1]
         top = set(np.asarray(jax.lax.top_k(logits, k)[1]).tolist())
         assert int(out[0]) in top
+
+
+class TestBlockBoundaryRule:
+    """When block N+1 is chained off block N's device carry is decided from
+    what could be admitted at N's end (docs/PERFORMANCE.md §1): a full house
+    of fixed budgets chains before N's tokens are seen, whoever waits; a
+    free slot or an ``eos_id`` decides with N's tokens in hand.  Either way
+    the tokens are the sequential loop's, bit for bit."""
+
+    K = 4
+
+    @staticmethod
+    def _component(family, overlap, **kw):
+        import jax.numpy as jnp
+
+        from seldon_core_tpu.models.registry import build_generative_component
+
+        if family == "cohere2_moe":
+            kw = dict(experts_held="4:8", kv_block_size=4,
+                      dtype=jnp.bfloat16, **kw)
+        return build_generative_component(
+            family, preset="tiny", max_seq=64, n_slots=2,
+            decode_block=TestBlockBoundaryRule.K, rng=5, overlap=overlap, **kw,
+        )
+
+    @staticmethod
+    def _serve(comp, prompts, *, max_new, eos_id=None):
+        sched = comp.scheduler
+
+        async def go():
+            try:
+                return await asyncio.gather(
+                    *(
+                        sched.submit(
+                            np.asarray(p, np.int32), max_new_tokens=max_new,
+                            eos_id=eos_id,
+                        )
+                        for p in prompts
+                    )
+                )
+            finally:
+                await sched.close()
+
+        return run(go())
+
+    PROMPTS = [[5, 9, 2, 17, 3], [30, 7], [1, 2, 3, 4], [11, 13, 17, 19, 23]]
+
+    @pytest.mark.parametrize("family", ["llama", "cohere2_moe"])
+    def test_a_full_house_chains_whoever_waits(self, family):
+        """Twice as many requests as slots, fixed budgets, no ``eos_id``:
+        every boundary but a wave's last is chained before the block's
+        tokens are seen, and no block runs with no live slot."""
+        max_new = 14  # the prefill's token + 13: blocks of 4, 4, 4 and 1
+        base = self._serve(
+            self._component(family, False), self.PROMPTS, max_new=max_new
+        )
+        comp = self._component(family, True)
+        outs = self._serve(comp, self.PROMPTS, max_new=max_new)
+        for a, b in zip(base, outs):
+            assert a.size == max_new
+            assert np.array_equal(a, b), (a.tolist(), b.tolist())
+        # two waves of four blocks: three chained early, and the fourth
+        # ends every budget — the first wave's end admits the second, the
+        # second's dispatches nothing
+        assert comp.scheduler.boundary_snapshot() == {
+            "chained_early": 6, "chained_due": 0, "chained_late": 0,
+            "idle": 1, "sync": {"admission": 1},
+        }
+        assert comp.model.overlapped == 6
+        assert comp.model.steps == 8 * self.K  # no ninth, empty block
+
+    def test_the_sequential_loop_counts_its_boundaries_apart(self):
+        comp = self._component("llama", False)
+        self._serve(comp, self.PROMPTS[:2], max_new=6)
+        assert comp.scheduler.boundary_snapshot() == {
+            "chained_early": 0, "chained_due": 0, "chained_late": 0,
+            "idle": 0, "sync": {"overlap-off": 2},
+        }
+
+    def test_a_free_slot_is_not_made_to_wait_for_a_chained_block(self):
+        """A request submitted while block N is in flight, with a slot
+        free, is admitted at N's end: N+1 is not chained ahead of it."""
+        import time
+
+        comp = self._component("llama", True)
+        comp.model.warmup()
+        sched, model = comp.scheduler, comp.model
+        fetch = model.step_k_fetch
+        handed_over = []
+
+        def slow_fetch(handle):
+            # a block takes 0.15 s, every block: the scheduler's estimate
+            # of when the block in flight ends is as steady as a chip's
+            time.sleep(0.15)
+            out = fetch(handle)
+            handed_over.append(time.perf_counter())
+            return out
+
+        model.step_k_fetch = slow_fetch
+        stamps = {}
+
+        async def go():
+            try:
+                first = asyncio.ensure_future(
+                    sched.submit(np.asarray([5, 9, 2], np.int32), max_new_tokens=30)
+                )
+                while len(handed_over) < 2:
+                    await asyncio.sleep(0.005)
+                # a third of the way into block 3: nothing is chained yet
+                await asyncio.sleep(0.05)
+                stamps["queued"] = len(handed_over)
+                second = asyncio.ensure_future(
+                    sched.submit(
+                        np.asarray([30, 7], np.int32), max_new_tokens=5,
+                        on_token=lambda _t: stamps.setdefault(
+                            "admitted", len(handed_over)
+                        ),
+                    )
+                )
+                return await asyncio.gather(first, second)
+            finally:
+                await sched.close()
+
+        o1, o2 = run(go())
+        assert o1.size == 30 and o2.size == 5
+        # blocks the scheduler was handed between ``queued`` and the
+        # admission's first token: the one in flight, and no successor
+        # chained ahead of the request
+        assert stamps == {"queued": 2, "admitted": 3}
+        snap = sched.boundary_snapshot()
+        assert snap["sync"].get("admission", 0) >= 1
+        # before the request came nobody waited: block 3 was dispatched as
+        # block 2 was about to end, not with its tokens in hand
+        assert snap["chained_due"] >= 1 and snap["chained_early"] == 0
+        # and the tokens are the sequential loop's
+        seq = self._component("llama", False)
+        s1 = self._serve(seq, [[5, 9, 2]], max_new=30)[0]
+        assert np.array_equal(o1, s1)
+
+    def test_an_arrival_during_an_admission_is_admitted_before_the_next_block(self):
+        """A request that comes while an admission's prefills run has a free
+        slot too: the sync point is taken again, and no block is dispatched
+        ahead of it."""
+        comp = self._component("llama", True)
+        sched, model = comp.scheduler, comp.model
+        admit = sched._admit_batch
+        rounds, late = [], []
+
+        async def admit_and_note(batch, *state):
+            await admit(batch, *state)
+            rounds.append((len(batch), model.steps))
+            if len(rounds) == 1:
+                late.append(asyncio.ensure_future(
+                    sched.submit(np.asarray([30, 7], np.int32), max_new_tokens=5)
+                ))
+                await asyncio.sleep(0)  # the submit enqueues before we return
+
+        sched._admit_batch = admit_and_note
+
+        async def go():
+            try:
+                first = await sched.submit(
+                    np.asarray([5, 9, 2], np.int32), max_new_tokens=9
+                )
+                return first, await late[0]
+            finally:
+                await sched.close()
+
+        o1, o2 = run(go())
+        # two rounds of one request each, and no decode step between them
+        assert rounds == [(1, 0), (1, 0)]
+        seq = self._component("llama", False)
+        s1, s2 = self._serve(seq, [[5, 9, 2], [30, 7]], max_new=9)
+        assert np.array_equal(o1, s1) and np.array_equal(o2, s2[:5])
+
+    def test_a_late_estimate_gives_way_to_the_tokens(self):
+        """The held decision falls when the block in flight is expected to
+        end; blocks that turn ten times shorter end long before that, so
+        the fetch comes back first, the next block is chained with the
+        tokens in hand, and the estimate starts again from that block:
+        the hold never makes the chip wait for a stale estimate."""
+        import time
+
+        comp = self._component("llama", True)
+        comp.model.warmup()
+        sched, model = comp.scheduler, comp.model
+        fetch = model.step_k_fetch
+        handed_over = []
+
+        def fetch_at_a_pace(handle):
+            time.sleep(0.2 if len(handed_over) < 3 else 0.02)
+            out = fetch(handle)
+            handed_over.append(time.perf_counter())
+            return out
+
+        model.step_k_fetch = fetch_at_a_pace
+        out = self._serve(comp, [[5, 9, 2]], max_new=41)[0]  # ten blocks
+        assert out.size == 41 and len(handed_over) == 10
+        snap = sched.boundary_snapshot()
+        assert snap["chained_late"] >= 1 and snap["chained_due"] >= 4, snap
+        # the seven short blocks took their own time, not the long ones'
+        assert handed_over[-1] - handed_over[2] < 7 * 0.02 + 0.2
+        seq = self._serve(self._component("llama", False), [[5, 9, 2]], max_new=41)[0]
+        assert np.array_equal(out, seq)
+
+    def test_slots_with_an_eos_id_decide_at_the_fetch(self):
+        """An ``eos_id`` makes a slot's end unknowable: a full house of them
+        never chains early, and stays bit-identical."""
+        probe = self._serve(
+            self._component("llama", False), self.PROMPTS, max_new=14
+        )
+        eos = int(probe[0][6])  # ends the first request inside block 2
+        base = self._serve(
+            self._component("llama", False), self.PROMPTS, max_new=14,
+            eos_id=eos,
+        )
+        comp = self._component("llama", True)
+        outs = self._serve(comp, self.PROMPTS, max_new=14, eos_id=eos)
+        for a, b in zip(base, outs):
+            assert np.array_equal(a, b), (a.tolist(), b.tolist())
+        assert base[0].size < 14 and base[0][-1] == eos
+        snap = comp.scheduler.boundary_snapshot()
+        assert snap["chained_early"] == 0
+        assert snap["chained_late"] >= 1
+        assert snap["sync"].get("admission", 0) >= 1
+
+    def test_speculation_budgets_a_block_at_its_worst_case(self):
+        """With drafting on a block may emit ``k * (1 + draft)`` tokens a
+        slot: a budget that a plain block could not end is not chained
+        early, one past the worst case is, and both match the sequential
+        loop."""
+        draft = 3
+        worst = self.K * (1 + draft)
+
+        def serve(overlap, max_new):
+            comp = self._component("llama", overlap, spec_draft=draft)
+            outs = self._serve(comp, self.PROMPTS, max_new=max_new)
+            return comp, outs
+
+        for max_new, early in ((worst + 1, False), (worst + 2, True)):
+            _, base = serve(False, max_new)
+            comp, outs = serve(True, max_new)
+            for a, b in zip(base, outs):
+                assert a.size == max_new
+                assert np.array_equal(a, b), (a.tolist(), b.tolist())
+            snap = comp.scheduler.boundary_snapshot()
+            assert (snap["chained_early"] > 0) is early, (max_new, snap)
 
 
 class TestStreaming:

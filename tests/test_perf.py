@@ -84,6 +84,121 @@ class TestHostSyncAudit:
         assert model.overlapped >= 1
 
 
+    @pytest.mark.parametrize(
+        "n_req,eos,chained",
+        [(8, False, "chained_early"), (4, True, "chained_late")],
+        ids=["full-house-with-waiters", "eos-decides-at-the-fetch"],
+    )
+    def test_one_sync_a_block_whichever_way_the_boundary_goes(
+        self, tiny, n_req, eos, chained
+    ):
+        """The boundary rule reads only what the one fetch brought: chained
+        before the block's tokens are seen (a full house of fixed budgets,
+        four more waiting) or once they are in hand (an ``eos_id`` nobody
+        samples), a block still costs one host sync."""
+        from seldon_core_tpu.obs import host_sync_snapshot
+
+        cfg, params = tiny
+        block, max_new = 8, 25
+        name = f"sync-audit-{chained}"
+
+        def serve(model, eos_id):
+            sched = GenerationScheduler(model, overlap=True)
+
+            async def go():
+                try:
+                    return await asyncio.gather(
+                        *(
+                            sched.submit(
+                                np.asarray([5 + i, 9, 2], np.int32),
+                                max_new_tokens=max_new, eos_id=eos_id,
+                            )
+                            for i in range(n_req)
+                        )
+                    )
+                finally:
+                    await sched.close()
+
+            return sched, run(go())
+
+        eos_id = None
+        if eos:
+            _, outs = serve(
+                GenerativeModel(cfg, params, n_slots=4, decode_block=block), None
+            )
+            eos_id = min(set(range(cfg.vocab_size)) - set(np.concatenate(outs).tolist()))
+        model = GenerativeModel(
+            cfg, params, n_slots=4, decode_block=block, name=name
+        )
+        before = host_sync_snapshot().get(name, 0)
+        sched, outs = serve(model, eos_id)
+        assert all(o.size == max_new for o in outs)
+        syncs = host_sync_snapshot().get(name, 0) - before
+        blocks = model.steps // block
+        assert blocks == (n_req // 4) * 3  # 24 tokens a wave after the prefill's
+        # one fetch a block, one first-token fetch an admission round
+        assert syncs <= blocks + n_req // 4 + 1, f"{syncs} syncs, {blocks} blocks"
+        snap = sched.boundary_snapshot()
+        assert snap[chained] == (n_req // 4) * 2, snap
+        assert (snap["chained_early"] + snap["chained_due"]
+                + snap["chained_late"]) == model.overlapped
+
+
+    def test_the_boundary_counter_is_in_stats_summary(self):
+        """``breakdown.generation.<unit>.block_boundaries`` of
+        ``/stats/summary``: every fetched decode block is one boundary, by
+        outcome; the chained ones are ``overlapped_blocks``."""
+        predictor = {
+            "name": "p",
+            "graph": {
+                "name": "gen", "type": "MODEL",
+                "implementation": "JAX_GENERATIVE",
+                "parameters": [
+                    {"name": "family", "value": "llama", "type": "STRING"},
+                    {"name": "preset", "value": "tiny", "type": "STRING"},
+                    {"name": "n_slots", "value": "2", "type": "INT"},
+                    {"name": "decode_block", "value": "4", "type": "INT"},
+                ],
+            },
+        }
+
+        async def go():
+            service = PredictionService(PredictorSpec.model_validate(predictor))
+            client = TestClient(TestServer(EngineApp(service).build()))
+            await client.start_server()
+            try:
+                async def post(i):
+                    resp = await client.post(
+                        "/api/v0.1/predictions",
+                        json={"strData": json.dumps(
+                            {"tokens": [5 + i, 9, 2], "max_new_tokens": 14})},
+                    )
+                    assert resp.status == 200, await resp.text()
+
+                await asyncio.gather(*(post(i) for i in range(4)))
+                stats = await (await client.get("/stats/summary")).json()
+                (unit,) = stats["breakdown"]["generation"].values()
+                (gen,) = service.generative_units()
+                return unit["block_boundaries"], gen.model
+            finally:
+                await client.close()
+
+        found, model = run(go())
+        chained = ("chained_early", "chained_due", "chained_late")
+        assert set(found) == {*chained, "idle", "sync"}
+        total = (sum(found[c] for c in chained)
+                 + found["idle"] + sum(found["sync"].values()))
+        # 13 tokens after the prefill's: four blocks of 4 a request, two
+        # requests a block at the most (warm-up's steps are in
+        # ``model.steps`` too)
+        assert 8 <= total <= model.steps // 4
+        assert sum(found[c] for c in chained) == model.overlapped
+        # four requests on two slots: somebody waited, and was admitted at
+        # a boundary that names it
+        assert found["sync"].get("admission", 0) >= 1
+        assert set(found["sync"]) <= {"admission", "carry-dirty"}
+
+
 class TestWarmupPlane:
     JAX_PREDICTOR = {
         "name": "warm",
