@@ -76,11 +76,27 @@ from seldon_core_tpu.utils.metrics import DEFAULT as DEFAULT_METRICS
 log = logging.getLogger(__name__)
 
 
-def _prefill_buckets(max_seq: int, smallest: int = 16) -> tuple[int, ...]:
+# from this rung up the prefill ladder also holds the midpoint between a
+# rung and its double: under it a program is bound by the weights it reads
+# once and padding is nearly free; over it a rung's worst padding is
+# thousands of tokens of compute-bound work, and a further program to warm
+# is the cheaper of the two
+HALF_RUNGS_FROM = 4096
+
+
+def _prefill_buckets(max_seq: int, block: int = 16) -> tuple[int, ...]:
+    """The lengths prompts are padded to, one compiled program each:
+    doubling from the KV block size (16 at the least) and, from
+    ``HALF_RUNGS_FROM`` up, the midpoint before each double (x1.5, rounded
+    up to whole KV blocks); the last rung is ``max_seq``."""
     sizes = []
-    b = smallest
+    b = max(16, block)
     while b < max_seq:
         sizes.append(b)
+        if b >= HALF_RUNGS_FROM:
+            mid = -(-(b + b // 2) // block) * block
+            if mid < max_seq:
+                sizes.append(mid)
         b *= 2
     sizes.append(max_seq)
     return tuple(sizes)
@@ -743,9 +759,7 @@ class GenerativeModel:
                 cache, next(iter(jax.tree.leaves(self.params)[0].devices()))
             )
         self._cache = cache
-        self.prefill_buckets = tuple(
-            b for b in _prefill_buckets(cfg.max_seq) if b >= kv_block_size
-        ) or (cfg.max_seq,)
+        self.prefill_buckets = _prefill_buckets(cfg.max_seq, kv_block_size)
 
         fam = family_mod
 
@@ -1270,6 +1284,8 @@ class GenerativeModel:
         self.last_conf_seq: np.ndarray | None = None
         self.prefills_reused = 0  # prefills that skipped a reused prefix
         self.prefill_chunks = 0  # chunked-prefill chunk dispatches
+        # tokens prefilled, the rungs they were padded to, dispatches a rung
+        self.prefill_rows: dict = {"real": 0, "padded": 0, "by_rung": {}}
         self.imports = 0  # disagg KV handoffs imported into this pool
         # KV/HBM pool ledger (docs/OBSERVABILITY.md "generation forensics"):
         # high-water mark of blocks in use, and the byte classes the HBM
@@ -1692,7 +1708,18 @@ class GenerativeModel:
         admission counts ONE logical prefill (on its final chunk) plus one
         ``prefill_chunks`` tick per chunk dispatched; ``prefills_reused``
         only counts admissions whose reservation matched a shared prefix —
-        never the suffix-program calls chunking itself issues."""
+        never the suffix-program calls chunking itself issues.
+        ``prefill_rows`` counts every dispatch but warm-up's: the tokens it
+        prefilled and the rung it ran in, host integers already in hand."""
+        if not self._in_warmup:
+            rung = int(payload["padded"].shape[1])
+            rows = self.prefill_rows
+            rows["real"] += int(payload["length"]) - int(
+                payload.get("prefix_len", 0)
+            )
+            rows["padded"] += rung
+            key = str(rung)  # a JSON object's keys
+            rows["by_rung"][key] = rows["by_rung"].get(key, 0) + 1
         ch = payload.get("chunk")
         if ch is None:
             self.prefills += 1
@@ -2994,6 +3021,13 @@ class GenerativeModel:
             # chunked prefill + decode kernel state (docs/PERFORMANCE.md §7)
             "prefill_chunk": self.prefill_chunk or None,
             "prefill_chunks": self.prefill_chunks,
+            # what the ladder's padding costs: tokens of prompts and
+            # suffixes prefilled, the sum of the rungs they ran in, and the
+            # dispatches of each rung (warm-up's left out)
+            "prefill_rows": {
+                **self.prefill_rows,
+                "by_rung": dict(self.prefill_rows["by_rung"]),
+            },
             "decode_kernel": self.decode_kernel,
             # what the decode programs were built with, and the share of
             # its window the read has to touch (live / window)

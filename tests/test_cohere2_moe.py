@@ -65,17 +65,20 @@ def _slot_row(n_blocks=14, width=16):
     return row
 
 
-def _prefill(cfg, params, prompt, *, seq_impl="dense", chunks=None, slot=1):
+def _prefill(cfg, params, prompt, *, seq_impl="dense", chunks=None, slot=1,
+             rung=None):
     """Prompt -> (last logits, cache), whole or in ``chunks`` (the first
     through ``prefill_slot_paged``, the others through the suffix program
-    over the slot's own blocks, as the scheduler's chunked prefill does)."""
+    over the slot's own blocks, as the scheduler's chunked prefill does).
+    ``rung``: the length each span is padded to (the whole blocks it fills,
+    if not given)."""
     cache = m.init_paged_cache(cfg, 2, 40, BS, params["ln_f"].dtype)
     row = jnp.asarray(_slot_row())
     L = len(prompt)
     spans = [(0, L)] if not chunks else list(zip(chunks[:-1], chunks[1:]))
     logits = None
     for a, b in spans:
-        bucket = -(-(b - a) // BS) * BS
+        bucket = rung or -(-(b - a) // BS) * BS
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : b - a] = prompt[a:b]
         if a == 0:
@@ -141,6 +144,41 @@ class TestAgainstReference:
         want = ref.logits(params, np.concatenate([prompt, fed]), **_ref_kw(cfg))
         np.testing.assert_allclose(
             np.stack(got), want[len(prompt):], atol=TOL, rtol=0
+        )
+
+    @pytest.mark.parametrize("seq_impl", ["dense", "flash"])
+    def test_a_rung_of_three_eighths_gives_what_the_next_double_gives(
+        self, monkeypatch, prompt, seq_impl
+    ):
+        """The prefill ladder's rungs above 4,096 are 3 x a power of two
+        (6,144 in the served cell): here a prompt of 37 in a rung of 48
+        and in one of 64, grouped experts on.  Padding is masked, so the
+        logits, the first token and the prompt's K/V rows are the same,
+        and the float32 reference's."""
+        monkeypatch.setattr(m, "GROUPED_FROM", 8)
+        monkeypatch.setattr(m, "GROUP_CHUNK", 32)
+        cfg = _cfg()
+        params = _params(cfg)
+        (got, c48), (want, c64) = (
+            _prefill(cfg, params, prompt, seq_impl=seq_impl, rung=r)
+            for r in (48, 64)
+        )
+        np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+        assert int(np.argmax(got)) == int(np.argmax(want))
+        ref_logits = ref.logits(params, prompt, **_ref_kw(cfg))
+        np.testing.assert_allclose(got, ref_logits[-1], atol=TOL, rtol=0)
+        held = _slot_row()[:10]  # the 10 blocks the prompt's 37 rows lie in
+        for name in ("k", "v"):
+            a, b = (
+                np.asarray(c[name])[:, held].reshape(cfg.n_layers, 40, -1)[:, :37]
+                for c in (c48, c64)
+            )
+            np.testing.assert_allclose(a, b, atol=TOL, rtol=0)
+        # and the decode that follows reads what the rung of 48 wrote
+        fed, out, _ = _decode(cfg, params, c48, np.argmax(got), 4)
+        after = ref.logits(params, np.concatenate([prompt, fed]), **_ref_kw(cfg))
+        np.testing.assert_allclose(
+            np.stack(out), after[len(prompt):], atol=TOL, rtol=0
         )
 
     @pytest.mark.parametrize("chunks", [[0, 16, 37], [0, 8, 24, 32, 37]])
@@ -415,6 +453,27 @@ class TestServedPath:
         assert snap["moe.steps"] >= 4
         assert snap["moe.pairs_routed"] >= 4 * 4 * 4  # steps x top-4 x layers
         assert snap["moe.prefill_tokens"] >= len(prompt)
+
+    def test_a_rung_between_doubles_is_warmed_and_served(self, monkeypatch, prompt):
+        """The ladder with its midpoints (from 16 up here, from 4,096 up as
+        served): the prompt of 37 runs in ``prefill:b48``, warmed before
+        it came, and ``prefill_rows`` says so."""
+        from seldon_core_tpu.executor import generation
+        from seldon_core_tpu.utils.device import xla_compile_count
+
+        monkeypatch.setattr(generation, "HALF_RUNGS_FROM", 16)
+        model = self._component(seq_impl="flash", decode_kernel=True).model
+        assert model.prefill_buckets == (16, 24, 32, 48, 64)
+        model.warmup()
+        assert "prefill:b48[kernel]" in model.warmup_programs
+        warmed = xla_compile_count()
+        tok = model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=4)
+        assert xla_compile_count() == warmed
+        want = np.asarray(ref.logits(model.params, prompt, **_ref_kw(model.cfg)))[-1]
+        assert want.max() - want[int(tok)] < 0.25
+        assert model.spec_snapshot()["prefill_rows"] == {
+            "real": 37, "padded": 48, "by_rung": {"48": 1},
+        }
 
     def test_what_the_family_does_not_have_is_refused(self):
         from seldon_core_tpu.graph.units import GraphUnitError

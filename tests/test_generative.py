@@ -377,6 +377,147 @@ class TestRingPrefill:
         )
 
 
+LADDERS = {
+    (512, 16): (16, 32, 64, 128, 256, 512),
+    (2048, 16): (16, 32, 64, 128, 256, 512, 1024, 2048),
+    (8192, 256): (256, 512, 1024, 2048, 4096, 6144, 8192),
+    (8192, 16): (
+        16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 6144, 8192,
+    ),
+    (32768, 16): (
+        16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 6144, 8192, 12288,
+        16384, 24576, 32768,
+    ),
+    (6000, 16): (16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 6000),
+    (4096, 256): (256, 512, 1024, 2048, 4096),
+}
+
+
+class TestPrefillLadder:
+    """The rungs prompts are padded to (``_prefill_buckets``): doubling up
+    to 4,096, the midpoint before each double above it.  At the tiny sizes
+    ``HALF_RUNGS_FROM`` is lowered to 32, so a context of 128 has the rungs
+    48 and 96: three blocks and six, neither a power of two."""
+
+    @pytest.mark.parametrize("max_seq,block", sorted(LADDERS))
+    def test_rungs(self, max_seq, block):
+        from seldon_core_tpu.executor.generation import _prefill_buckets
+
+        rungs = _prefill_buckets(max_seq, block)
+        assert rungs == LADDERS[max_seq, block]
+        assert rungs[-1] == max_seq
+        assert all(r % block == 0 for r in rungs)
+        for lo, hi in zip(rungs, rungs[1:]):
+            assert lo < hi <= 2 * lo
+            assert lo < 4096 or hi <= 1.5 * lo
+
+    @pytest.fixture()
+    def ladder(self, monkeypatch):
+        from seldon_core_tpu.executor import generation
+
+        monkeypatch.setattr(generation, "HALF_RUNGS_FROM", 32)
+        return (16, 32, 48, 64, 96, 128)
+
+    @pytest.fixture(scope="class")
+    def wide(self):
+        import jax
+
+        cfg = llama.Config.tiny(max_seq=128)
+        return cfg, llama.init_params(jax.random.PRNGKey(0), cfg)
+
+    @pytest.mark.parametrize("seq_impl", ["dense", "flash", "ring"])
+    def test_a_rung_of_six_blocks_gives_what_eight_give(self, wide, seq_impl):
+        """A prompt of 70 tokens padded to 96 and to 128: the same logits,
+        first token and written K/V rows, and the float32 forward pass's."""
+        import jax.numpy as jnp
+
+        from seldon_core_tpu.parallel import best_mesh
+
+        cfg, params = wide
+        mesh = best_mesh(8, tp=1, sp=8) if seq_impl == "ring" else None
+        prompt = np.random.default_rng(3).integers(1, cfg.vocab_size, 70)
+        row = jnp.asarray(np.arange(8, 0, -1), jnp.int32)
+
+        def prefill(rung):
+            padded = np.zeros((1, rung), np.int32)
+            padded[0, :70] = prompt
+            logits, cache = llama.prefill_slot_paged(
+                params, jnp.asarray(padded), jnp.int32(70), jnp.int32(1), row,
+                llama.init_paged_cache(cfg, 2, 9, 16), cfg,
+                mesh=mesh, seq_impl=seq_impl,
+            )
+            # the prompt's rows, through the slot's table: blocks 8..4
+            k, v = (
+                np.asarray(cache[name])[:, np.asarray(row[:5])]
+                .reshape(cfg.n_layers, 80, -1)[:, :70]
+                for name in ("k", "v")
+            )
+            return np.asarray(logits), k, v
+
+        got, want = prefill(96), prefill(128)
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+        assert got[0].argmax() == want[0].argmax()
+        ref = np.asarray(llama.forward(params, prompt[None].astype(np.int32), cfg))
+        np.testing.assert_allclose(got[0], ref[0, -1], rtol=2e-4, atol=2e-4)
+
+    def test_warmed_and_served_with_no_compile(self, wide, ladder):
+        from seldon_core_tpu.utils.device import xla_compile_count
+
+        cfg, params = wide
+        model = GenerativeModel(cfg, params, n_slots=2, decode_block=4)
+        assert model.prefill_buckets == ladder
+        model.warmup()
+        assert {f"prefill:b{b}" for b in ladder} <= set(model.warmup_programs)
+        assert model.spec_snapshot()["prefill_rows"] == {
+            "real": 0, "padded": 0, "by_rung": {},
+        }  # warm-up's admissions are not the traffic's
+        warmed = xla_compile_count()
+        prompt = np.random.default_rng(4).integers(1, cfg.vocab_size, 70)
+        tok = model.admit(0, prompt, 0.0, 0, reserve_tokens=4)
+        assert xla_compile_count() == warmed
+        assert tok == reference_generate(cfg, params, prompt, 1)[0]
+        assert model.spec_snapshot()["prefill_rows"] == {
+            "real": 70, "padded": 96, "by_rung": {"96": 1},
+        }
+
+    def test_rows_of_a_suffix_and_of_chunks(self, wide, ladder):
+        cfg, params = wide
+        rng = np.random.default_rng(5)
+        first = rng.integers(1, cfg.vocab_size, 70)
+        # shares one block with the first, then 40 tokens of its own: the
+        # suffix program at a rung of three blocks
+        second = np.concatenate([first[:16], rng.integers(1, cfg.vocab_size, 40)])
+        model = GenerativeModel(cfg, params, n_slots=2, prefix_reuse=True)
+        model.admit(0, first, 0.0, 0)
+        model.release_slot(0)
+        tok = model.admit(1, second, 0.0, 0)
+        assert model.prefills_reused == 1
+        assert tok == reference_generate(cfg, params, second, 1)[0]
+        assert model.spec_snapshot()["prefill_rows"] == {
+            "real": 70 + 40, "padded": 96 + 48, "by_rung": {"96": 1, "48": 1},
+        }
+        # chunks of 64: a prompt of 100 is a chunk of 64 and one of 36
+        chunked = GenerativeModel(cfg, params, n_slots=2, prefill_chunk=64)
+        chunked.warmup()
+        names = set(chunked.warmup_programs)
+        # rungs past the chunk are admitted as prompts of their own length
+        # are: the programs of a prompt of 96 and of 128, no others
+        assert {
+            n.split("[")[0] for n in names if n.startswith("prefill:")
+        } == {
+            "prefill:b16", "prefill:b32", "prefill:b48", "prefill:b64",
+            "prefill:b32:w64", "prefill:b64:w64",
+        }
+        long = rng.integers(1, cfg.vocab_size, 100)
+        tok = chunked.admit(0, long, 0.0, 0)
+        assert tok == reference_generate(cfg, params, long, 1)[0]
+        assert chunked.prefills == len(ladder) + 1
+        assert chunked.spec_snapshot()["prefill_rows"] == {
+            "real": 100, "padded": 64 + 48, "by_rung": {"64": 1, "48": 1},
+        }
+
+
 class TestDecodeBlocks:
     """Multi-token dispatch (decode_block > 1) must be output-identical to
     the single-step loop — eos and budget enforcement move on-device."""

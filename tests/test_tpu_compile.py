@@ -1,5 +1,5 @@
-"""The paged decode kernel through the TPU's own compiler, at the widths
-the benchmark's cells serve, for a v5e that is described and not attached
+"""The paged decode kernel and the prompt's tiled kernel through the TPU's
+own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
 (no chip time; nothing runs).  The interpreter the other tests use accepts
 what Mosaic refuses: a copy or slice off the tiling, too much fast memory.
 
@@ -79,3 +79,25 @@ def test_the_paged_kernel_compiles_for_v5e(one_chip, case):
     # the pool goes in as it is: no copy of it among the temporaries
     pool_bytes = NB * BS * KV * D * (1 if quant else 2)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes // 8
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_the_tiled_kernel_compiles_at_a_rung_of_6144(one_chip, window):
+    """Command A+'s prompt attention at the ladder's rung between 4,096 and
+    8,192: 12 x 12 tiles of 512, a count that is no power of two."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import flash_attention
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct((1, heads, 6144, 128), jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512, window=window,
+            interpret=False,
+        )
+
+    compiled = jax.jit(f).lower(sds(128), sds(8), sds(8)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
