@@ -759,6 +759,13 @@ class GenerativeModel:
                 cache, next(iter(jax.tree.leaves(self.params)[0].devices()))
             )
         self._cache = cache
+        # per-token arrays a family keeps in the pool beside K and V, under
+        # the same table (``family.POOL_EXTRA``): counted with the pool's
+        # bytes; what moves K/V out of the pool carries k and v alone and
+        # refuses such a family by name (:meth:`_kv_alone`)
+        self._pool_extra = tuple(getattr(family_mod, "POOL_EXTRA", ()))
+        if self.host_store is not None:
+            self._kv_alone("the host-DRAM prefix tier (prefix_dram_gb)")
         self.prefill_buckets = _prefill_buckets(cfg.max_seq, kv_block_size)
 
         fam = family_mod
@@ -1378,7 +1385,7 @@ class GenerativeModel:
         # ledger's reserve() REPLACES an owner's class dict, so both
         # classes re-reserve together through _note_host_bytes
         self._host_classes: dict[str, int] = {}
-        kv_bytes = int(self._cache["k"].nbytes) + int(self._cache["v"].nbytes)
+        kv_bytes = self._pool_bytes()
         scale_bytes = (
             int(self._cache["k_scale"].nbytes)
             + int(self._cache["v_scale"].nbytes)
@@ -1931,6 +1938,25 @@ class GenerativeModel:
     def free_block_count(self) -> int:
         return len(self._free_blocks)
 
+    def _pool_bytes(self) -> int:
+        """HBM bytes of the pool's per-token arrays: K, V and the family's
+        further ones (scales are counted apart)."""
+        return sum(
+            int(self._cache[key].nbytes) for key in ("k", "v") + self._pool_extra
+        )
+
+    def _kv_alone(self, what: str) -> None:
+        """Refuse ``what`` for a family whose pool holds more than K and V:
+        the frames and stores outside the programs carry ``k`` and ``v``
+        (and an int8 pool's scales) alone, and a slot moved without its
+        further arrays would decode on garbage."""
+        if self._pool_extra:
+            raise TypeError(
+                f"generative family {self.family.__name__.rsplit('.', 1)[-1]} "
+                f"keeps {', '.join(self._pool_extra)} beside K/V in its paged "
+                f"pool; {what} carries k and v alone and is refused"
+            )
+
     # -------------------------------------------------- disagg KV handoff
 
     def export_slot_kv(self, slot: int, prompt_len: int) -> tuple:
@@ -1965,6 +1991,7 @@ class GenerativeModel:
         frame and store outside the programs holds: ``(layers, n,
         block_size, kv_heads, head_dim)``, whatever row shape the pool is
         carried in (same row-major bytes).  ONE batched fetch."""
+        self._kv_alone("a KV export (handoff, suspend, prefix demotion, peer pull)")
         names = ("k", "v") + (("k_scale", "v_scale") if self.kv_dtype else ())
         with self._lock:
             # sct: host-sync-ok handoff export / tier demotion / peer pull
@@ -2076,6 +2103,7 @@ class GenerativeModel:
         scatter both verbatim — bit-exact, no re-quantization.  Raises
         :class:`OutOfKVBlocks` like a local admission when the pool cannot
         cover it."""
+        self._kv_alone("a KV import (handoff, resume)")
         prompt = np.asarray(prompt, np.int32).ravel()
         L = int(prompt.size)
         if L < 1:
@@ -2540,6 +2568,7 @@ class GenerativeModel:
         absorbed prompt.  Returns the number of levels installed; any
         failure frees every block it took (zero leaks) and the caller
         falls back to plain prefill."""
+        self._kv_alone("a peer prefix install")
         if self.prefix_index is None:
             raise GraphUnitError(
                 f"model {self.name!r} has no prefix index to install into"
@@ -2836,7 +2865,7 @@ class GenerativeModel:
         included on an int8 pool) — sizes the HBM tier's byte telemetry."""
         return sum(
             int(self._cache[key].nbytes) // self.kv_blocks
-            for key in ("k", "v", "k_scale", "v_scale")
+            for key in ("k", "v", "k_scale", "v_scale") + self._pool_extra
             if key in self._cache
         )
 
@@ -2853,12 +2882,7 @@ class GenerativeModel:
                     dtype=dt,
                 )
             )
-        per_block = sum(
-            int(self._cache[key].nbytes) // self.kv_blocks
-            for key in ("k", "v", "k_scale", "v_scale")
-            if key in self._cache
-        )
-        return per_block * self.max_blocks_per_slot
+        return self.kv_bytes_per_block() * self.max_blocks_per_slot
 
     def kv_slots_per_chip(self, hbm_bytes: int | None = None) -> int:
         """Max-seq sequences this pool layout fits per chip after the
@@ -2902,7 +2926,7 @@ class GenerativeModel:
         free = len(self._free_blocks)
         prefix_held = len(self.prefix_index) if self.prefix_index is not None else 0
         slot_held = sum(len(b) for b in self._slot_blocks.values())
-        kv_bytes = int(self._cache["k"].nbytes) + int(self._cache["v"].nbytes)
+        kv_bytes = self._pool_bytes()
         scale_bytes = (
             int(self._cache["k_scale"].nbytes) + int(self._cache["v_scale"].nbytes)
             if "k_scale" in self._cache
@@ -4582,6 +4606,9 @@ class GenerationScheduler:
     def request_preempt(self) -> None:
         """Arbiter verb: suspend this deployment's active slots at the
         next sync point and hold admissions until resumed."""
+        kv_alone = getattr(self.model, "_kv_alone", None)
+        if kv_alone is not None:
+            kv_alone("a preemption (SuspendStore)")
         self._preempt = True
         self._wake.set()
 

@@ -362,9 +362,9 @@ def _experts_dense(h2, lp, local, held, w):
     return jnp.einsum("xte,tx->te", d.astype(jnp.float32), cw)
 
 
-def _experts_grouped(h2, stacks, li, local, held, w):
+def _experts_grouped(h2, stacks, li, local, held, w, chunk: int = GROUP_CHUNK):
     """The held (token, expert) pairs alone, sorted by expert, through
-    grouped products (prefill).  Pairs are taken ``GROUP_CHUNK`` rows at a
+    grouped products (prefill).  Pairs are taken ``chunk`` rows at a
     pass and the passes follow the pairs actually held, so no routing is
     dropped and none is paid for that is not there.  -> (T, E) f32.
 
@@ -377,7 +377,7 @@ def _experts_grouped(h2, stacks, li, local, held, w):
     T, K = local.shape
     n_layers, count = stacks["we_gate"].shape[:2]
     M = T * K
-    R = min(GROUP_CHUNK, M)
+    R = min(chunk, M)
     key = jnp.where(held, local, count).reshape(M)  # pairs not held sort last
     order = jnp.argsort(key, stable=True)
     tok = (order // K).astype(jnp.int32)  # token of each sorted pair
@@ -414,6 +414,31 @@ def _experts_grouped(h2, stacks, li, local, held, w):
     return lax.fori_loop(0, (n_held + R - 1) // R, body, out)
 
 
+def _count_routing(counters, local, held, tok_mask, per_tok: int, count: int,
+                   decode: bool):
+    """``counters`` with one expert layer's routing added (``COUNTERS``'
+    first four in a decode step, the prefill pair in a prompt)."""
+    if counters is None:
+        return None
+    n_tok = jnp.sum(tok_mask).astype(jnp.uint32)
+    n_held = jnp.sum(held).astype(jnp.uint32)
+    n_routed = n_tok * jnp.uint32(per_tok)
+    if decode:
+        per = jnp.sum(
+            (local[..., None] == jnp.arange(count)) & held[..., None],
+            axis=(0, 1),
+        )  # tokens on each held expert
+        add = jnp.zeros_like(counters).at[jnp.arange(4)].add(jnp.stack([
+            n_routed, n_held, jnp.sum(per > 0).astype(jnp.uint32),
+            jnp.max(per).astype(jnp.uint32),
+        ]))
+    else:
+        add = jnp.zeros_like(counters).at[jnp.arange(5, 7)].add(
+            jnp.stack([n_routed, n_held])
+        )
+    return counters + add
+
+
 def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li):
     """``h2 (T, E)`` -> (routed + shared (T, E) float32, counters).  ``lp``
     is this layer's weights, ``stacks`` every layer's and ``li`` the layer
@@ -435,24 +460,9 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li):
             "jtf,jfe->te", jax.nn.silu(g) * u, lp["ws_down"],
             preferred_element_type=jnp.float32,
         ) / cfg.n_shared_experts
-    if counters is not None:
-        n_tok = jnp.sum(tok_mask).astype(jnp.uint32)
-        n_held = jnp.sum(held).astype(jnp.uint32)
-        n_routed = n_tok * jnp.uint32(cfg.experts_per_tok)
-        if decode:
-            per = jnp.sum(
-                (local[..., None] == jnp.arange(count)) & held[..., None],
-                axis=(0, 1),
-            )  # tokens on each held expert
-            add = jnp.zeros_like(counters).at[jnp.arange(4)].add(jnp.stack([
-                n_routed, n_held, jnp.sum(per > 0).astype(jnp.uint32),
-                jnp.max(per).astype(jnp.uint32),
-            ]))
-        else:
-            add = jnp.zeros_like(counters).at[jnp.arange(5, 7)].add(
-                jnp.stack([n_routed, n_held])
-            )
-        counters = counters + add
+    counters = _count_routing(
+        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode
+    )
     return routed + shared, counters
 
 

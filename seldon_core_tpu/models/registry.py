@@ -17,7 +17,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from seldon_core_tpu.executor import BucketSpec, CompiledModel, JaxModelComponent
-from seldon_core_tpu.models import bert, cnn, cohere2_moe, llama, mlp, resnet
+from seldon_core_tpu.models import bert, cnn, cohere2_moe, keye_vl2, llama, mlp, resnet
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +83,16 @@ _FAMILIES: dict[str, Family] = {
         presets={
             "command-a-plus": cohere2_moe.Config,
             "tiny": cohere2_moe.Config.tiny,
+        },
+        example_input=lambda c, b: np.ones((b, 16), np.int32),
+        init_in_dtype=True,
+    ),
+    "keye_vl2": Family(
+        "keye_vl2", keye_vl2.Config, keye_vl2.init_params,
+        keye_vl2.apply, keye_vl2.param_logical_axes,
+        presets={
+            "keye-vl-2-30b-a3b": keye_vl2.Config,
+            "tiny": keye_vl2.Config.tiny,
         },
         example_input=lambda c, b: np.ones((b, 16), np.int32),
         init_in_dtype=True,
@@ -265,11 +275,14 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 #     (embeddings), ``init_lora_params`` with ``LORA_*_TARGETS`` and
 #     ``lora_adapter_factors`` (adapters), ``truncate_params`` (a layer-
 #     truncated draft), ``paged_kv_slot_bytes`` (the KV ledger's own size of
-#     a slot), ``COUNTERS`` (names of the on-device counters a step returns).
+#     a slot), ``COUNTERS`` (names of the on-device counters a step returns),
+#     ``POOL_EXTRA`` (names of the per-token arrays its paged pool holds
+#     beside ``k`` and ``v``: counted with the pool, and what moves K/V out
+#     of the pool — handoff, suspend, the host-DRAM tier — refuses it).
 # A feature asked of a family without its function is refused at build;
 # prefix reuse, chunked prefill and adapters are turned off with a warning.
 GENERATIVE_FAMILIES: dict[str, Any] = {
-    "llama": llama, "cohere2_moe": cohere2_moe,
+    "llama": llama, "cohere2_moe": cohere2_moe, "keye_vl2": keye_vl2,
 }
 
 

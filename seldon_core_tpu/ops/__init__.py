@@ -2,8 +2,10 @@
 
 The compute plane is mostly XLA-fused jit code; kernels live here only
 where explicit tiling beats the compiler — flash attention (O(S^2) HBM
-traffic -> O(S*D)) and paged decode-attention (block-table gather + int8
-dequant + attention fused over the paged KV pool, docs/PERFORMANCE.md §7).
+traffic -> O(S*D)), paged decode-attention (block-table gather + int8
+dequant + attention fused over the paged KV pool, docs/PERFORMANCE.md §7)
+and learned sparse attention (a prompt's exact top-k selection as a mask,
+and the tiled attention under it: ``sparse_attention.py``).
 """
 
 from seldon_core_tpu.ops.flash_attention import (
@@ -14,10 +16,18 @@ from seldon_core_tpu.ops.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_reference,
 )
+from seldon_core_tpu.ops.sparse_attention import (
+    masked_flash_attention,
+    select_topk_mask,
+    sparse_decode_attention,
+)
 
 __all__ = [
     "flash_attention",
     "flash_causal_attention_blhd",
     "paged_decode_attention",
     "paged_decode_attention_reference",
+    "masked_flash_attention",
+    "select_topk_mask",
+    "sparse_decode_attention",
 ]

@@ -624,3 +624,127 @@ class TestPoolRead:
         found = self._layer_slices(
             which, *self._setup(pool, kv_sharded=True), kv_sharded=True)
         assert len(found) == (4 if pool == "int8" else 2), found
+
+
+class TestSparseAttention:
+    """``ops/sparse_attention.py``: the two prompt kernels in interpret mode
+    against their XLA references, and the decode read (XLA: a gather of the
+    selected rows) against dense attention under the same selection."""
+
+    HI, DI, TOPK = 4, 8, 16
+
+    def _index(self, lq, lk, seed=0):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        return (
+            jax.random.normal(ks[0], (lq, self.HI, self.DI)),
+            jax.random.normal(ks[1], (lq, self.HI)),
+            jax.random.normal(ks[2], (lk, self.DI)),
+        )
+
+    @pytest.mark.parametrize("q_offset", [0, 64])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_the_selection_is_the_stable_sorts(self, q_offset, dtype):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        lq = 32
+        qi, wi, ki = self._index(lq, q_offset + lq)
+        qi, ki = qi.astype(dtype), ki.astype(dtype)
+        got = sa.select_topk_mask(
+            qi, wi, ki, topk=self.TOPK, q_offset=q_offset, block_q=16, block_k=32
+        )
+        want = sa.select_topk_mask_reference(
+            qi, wi, ki, topk=self.TOPK, q_offset=q_offset
+        )
+        assert got.dtype == jnp.int8 and int((got != want).sum()) == 0
+        rows = np.asarray(got).sum(1)
+        t = q_offset + np.arange(lq)
+        np.testing.assert_array_equal(rows, np.minimum(self.TOPK, t + 1))
+        # nothing after a query's own position
+        assert not np.triu(np.asarray(got), k=q_offset + 1).any()
+
+    @pytest.mark.parametrize("case", ["all equal", "two levels", "negative zero"])
+    def test_ties_go_to_the_lower_positions(self, case):
+        """Equal scores at the ``topk``-th place: the lower positions are
+        taken, as a stable descending sort takes them."""
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        lq, off = 16, 64
+        qi, wi, ki = self._index(lq, off + lq, seed=1)
+        if case == "all equal":
+            qi = qi * 0
+        elif case == "two levels":
+            # scores take few distinct values: many ties at every level
+            qi = jnp.round(qi)
+            ki = jnp.round(ki)
+            wi = jnp.round(wi)
+        else:  # every product rectified to zero under negative weights
+            qi, ki, wi = -jnp.abs(qi), jnp.abs(ki), -jnp.abs(wi)
+        got = sa.select_topk_mask(
+            qi, wi, ki, topk=self.TOPK, q_offset=off, block_q=16, block_k=16
+        )
+        want = sa.select_topk_mask_reference(qi, wi, ki, topk=self.TOPK, q_offset=off)
+        assert int((got != want).sum()) == 0
+        if case != "two levels":
+            assert np.asarray(got)[:, : self.TOPK].all()
+
+    @pytest.mark.parametrize("q_offset,dtype", [
+        (0, jnp.float32), (64, jnp.float32), (64, jnp.bfloat16),
+    ])
+    def test_tiled_attention_under_the_mask(self, q_offset, dtype):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        lq, h, kv, d = 32, 8, 2, 16
+        lk = q_offset + lq
+        ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        q = jax.random.normal(ks[0], (h, lq, d)).astype(dtype)
+        k = jax.random.normal(ks[1], (kv, lk, d)).astype(dtype)
+        v = jax.random.normal(ks[2], (kv, lk, d)).astype(dtype)
+        mask = sa.select_topk_mask_reference(
+            *self._index(lq, lk), topk=self.TOPK, q_offset=q_offset
+        )
+        got = sa.masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, block_q=16, block_k=32
+        )
+        want = sa.masked_attention_reference(q, k, v, mask)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            atol=tol, rtol=tol,
+        )
+
+    def test_a_row_that_selects_nothing_gives_zeros(self):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        q = jnp.ones((2, 16, 8))
+        kv = jnp.ones((1, 32, 8))
+        mask = jnp.zeros((16, 32), jnp.int8).at[3:, 0].set(1)
+        out = np.asarray(sa.masked_flash_attention(q, kv, kv, mask, block_q=8, block_k=16))
+        assert not out[:, :3].any() and np.allclose(out[:, 3:], 1.0)
+
+    def test_the_decode_read_attends_the_selected_rows_alone(self):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        s, h, d, kv, nr, k = 3, 8, 16, 2, 200, 20
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (s, h, d))
+        k_rows = jax.random.normal(ks[1], (nr, kv * d))
+        v_rows = jax.random.normal(ks[2], (nr, kv * d))
+        rows = jnp.stack([
+            jax.random.permutation(jax.random.fold_in(ks[3], i), nr)[:k]
+            for i in range(s)
+        ])
+        count = jnp.asarray([20, 0, 7])
+        got = sa.sparse_decode_attention(q, k_rows, v_rows, rows, count)
+        # dense attention over the whole pool under the same selection
+        sel = np.zeros((s, nr), bool)
+        for i in range(s):
+            sel[i, np.asarray(rows)[i, : int(count[i])]] = True
+        qg = q.reshape(s, kv, h // kv, d)
+        sc = jnp.einsum("bkgd,skd->bkgs", qg, k_rows.reshape(nr, kv, d)) / np.sqrt(d)
+        sc = jnp.where(sel[:, None, None, :], sc, -jnp.inf)
+        p = jnp.where(sel[:, None, None, :], jax.nn.softmax(sc, -1), 0.0)
+        want = jnp.einsum("bkgs,skd->bkgd", p, v_rows.reshape(nr, kv, d))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want.reshape(s, h, d)), atol=2e-5, rtol=2e-5
+        )
+        assert not np.asarray(got)[1].any()  # a slot that selects nothing

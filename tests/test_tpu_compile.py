@@ -101,3 +101,40 @@ def test_the_tiled_kernel_compiles_at_a_rung_of_6144(one_chip, window):
 
     compiled = jax.jit(f).lower(sds(128), sds(8), sds(8)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("q_offset,chunk", [(0, 4096), (24576, 8192)],
+                         ids=["first chunk of 12,288", "last chunk of 32,768"])
+def test_the_selection_kernels_compile_at_keye_vl2s_widths(one_chip, q_offset, chunk):
+    """``ops/sparse_attention.py`` at the published sizes (16 index heads of
+    64, top-2,048, 32 / 4 heads of 128): a strip's scores against 32,768
+    keys in VMEM, the mask as int8, the tiled attention under it."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops import sparse_attention as sa
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    lk = q_offset + chunk
+
+    def select(qi, wi, ki):
+        return sa.select_topk_mask(
+            qi, wi, ki, topk=2048, q_offset=q_offset, interpret=False
+        )
+
+    def attend(q, k, v, mask):
+        return sa.masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, interpret=False
+        )
+
+    for fn, args in (
+        (select, (sds((chunk, 16, 64)), sds((chunk, 16), jnp.float32), sds((lk, 64)))),
+        (attend, (sds((32, chunk, 128)), sds((4, lk, 128)), sds((4, lk, 128)),
+                  sds((chunk, lk), jnp.int8))),
+    ):
+        compiled = jax.jit(fn).lower(*args).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        # nothing the size of the mask among the temporaries
+        assert compiled.memory_analysis().temp_size_in_bytes < chunk * lk // 8
