@@ -28,13 +28,16 @@ in ``params``; expert ``e`` of layer ``l`` has the same values whichever
 share holds it (its key is folded from ``(l, e)``), so the shares of one
 layer add up to the uncut layer (``tests/test_cohere2_moe.py``).
 
-The held experts' products have two formulations, chosen by the number of
-tokens in the call (static): a decode step (a few tokens) runs every held
-expert over every token, densely — the step is bound by reading the expert
-weights, which it reads once either way — and a prefill (thousands) sorts
-its (token, expert) pairs by expert and runs grouped products
+The held experts' products have three formulations, chosen from static
+shapes in one place (:func:`experts_plan`).  A prefill (thousands of tokens)
+sorts its (token, expert) pairs by expert and runs grouped products
 (``lax.ragged_dot``) over the held pairs alone, in chunks whose count
-follows the pairs actually held.
+follows the pairs actually held.  A decode step (a few tokens) is bound by
+reading expert weights: where its tokens are expected to touch nearly every
+held expert (32 slots over 16 held) it runs every held expert over every
+token, densely, reading each once either way; where they touch a fraction
+(8 slots x top-8 over 128 held: a third) a Pallas kernel streams the touched
+experts alone (``ops/touched_experts.py``).
 
 The paged pool is uniform: every layer keeps every token's K/V, and a
 sliding layer READS only the blocks that hold its window (the window saves
@@ -56,6 +59,11 @@ from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
 
 # tokens in one call above which the held experts' products are grouped
 GROUPED_FROM = 256
+# a call of fewer tokens streams only the experts its tokens chose
+# (ops/touched_experts.py) where it is expected to touch no more than this
+# share of the held experts, and every held expert densely above it
+# (:func:`experts_plan`; PERF.md §6, PR 39 has the measurement behind it)
+TOUCHED_SHARE_MAX = 0.6
 # rows of (token, expert) pairs one grouped pass takes
 GROUP_CHUNK = 4096
 # query rows one pass of the XLA attention scores at once
@@ -74,7 +82,10 @@ COUNTERS = (
     "moe.prefill_pairs_routed",  # prefill: pairs chosen (real tokens only)
     "moe.prefill_pairs_held",
     "moe.prefill_tokens",
+    "moe.experts_read",          # decode: held experts whose weights the step streamed (touched ones under the
+                                 # touched-only kernel, every held one densely), summed over layers and steps
 )
+_EXPERTS_READ = COUNTERS.index("moe.experts_read")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -348,18 +359,78 @@ def _route(h2, w_router, cfg: Config):
     return idx.astype(jnp.int32), w.astype(jnp.float32)
 
 
+def expected_touched_share(n_tokens: int, experts_per_tok: int, n_experts: int) -> float:
+    """The share of the experts that ``n_tokens`` tokens, each choosing
+    ``experts_per_tok`` of ``n_experts`` evenly, are expected to touch."""
+    return 1.0 - (1.0 - experts_per_tok / n_experts) ** n_tokens
+
+
+def experts_plan(n_tokens: int, experts_per_tok: int, n_experts: int, *,
+                 kernel: bool = True) -> str:
+    """Which formulation runs the held experts' products for a call of
+    ``n_tokens`` tokens, from static shapes alone: ``"grouped"`` for a
+    prompt (:data:`GROUPED_FROM` tokens or more); else ``"touched"`` — the
+    kernel that streams only the experts some token chose — where the call
+    is expected to touch at most :data:`TOUCHED_SHARE_MAX` of them (8 slots
+    x top-8 of 128: 0.40), and ``"dense"`` where it touches nearly all it
+    holds anyway (32 x top-8 of 128: 0.87).  ``kernel`` says the kernel can
+    be handed the stacks: every layer's are at hand, and on one device (it
+    is not offered stacks sharded over a mesh); without it, dense."""
+    if n_tokens >= GROUPED_FROM:
+        return "grouped"
+    share = expected_touched_share(n_tokens, experts_per_tok, n_experts)
+    return "touched" if kernel and share <= TOUCHED_SHARE_MAX else "dense"
+
+
+def _tokens_on_experts(local, held, count: int):
+    """(count,): the tokens on each held expert."""
+    return jnp.sum(
+        (local[..., None] == jnp.arange(count)) & held[..., None], axis=(0, 1)
+    )
+
+
+def _combine_weights(local, held, w, count: int):
+    """(T, X) float32: a token's weight on each held expert, 0 where not
+    chosen."""
+    onehot = local[..., None] == jnp.arange(count)  # (T, K, X)
+    return jnp.sum(
+        jnp.where(onehot & held[..., None], w[..., None], 0.0), axis=1
+    )
+
+
 def _experts_dense(h2, lp, local, held, w):
     """Every held expert over every token (decode): the step reads each
-    held expert's weights once whichever tokens chose it.  -> (T, E) f32."""
-    count = lp["we_gate"].shape[0]
-    onehot = local[..., None] == jnp.arange(count)  # (T, K, X)
-    cw = jnp.sum(
-        jnp.where(onehot & held[..., None], w[..., None], 0.0), axis=1
-    )  # (T, X): a token's weight on each held expert, 0 where not chosen
+    held expert's weights once whichever tokens chose it.  -> (T, E) f32.
+    Also what a mesh-sharded expert stack runs."""
+    cw = _combine_weights(local, held, w, lp["we_gate"].shape[0])
     g = jnp.einsum("te,xef->xtf", h2, lp["we_gate"])
     u = jnp.einsum("te,xef->xtf", h2, lp["we_up"])
     d = jnp.einsum("xtf,xfe->xte", jax.nn.silu(g) * u, lp["we_down"])
     return jnp.einsum("xte,tx->te", d.astype(jnp.float32), cw)
+
+
+def _experts_touched(h2, stacks, li, local, held, w):
+    """The held experts that at least one token chose, and no other
+    (decode, few tokens over many held experts): what
+    :func:`_experts_dense` sums, less the terms whose weight is 0, through
+    the kernel that streams an expert by the list of those touched
+    (``ops/touched_experts.py``).  ``stacks`` and ``li`` as
+    :func:`_experts_grouped` takes them, and for its reason.  -> (T, E) f32."""
+    from seldon_core_tpu.ops.touched_experts import touched_expert_products, touched_list
+
+    T, K = local.shape
+    n_layers, count = stacks["we_gate"].shape[:2]
+    ids, n = touched_list(
+        _tokens_on_experts(local, held, count) > 0, min(count, T * K)
+    )
+    flat = [
+        stacks[k].reshape((n_layers * count,) + stacks[k].shape[2:])
+        for k in ("we_gate", "we_up", "we_down")
+    ]
+    return touched_expert_products(
+        h2, _combine_weights(local, held, w, count), ids, n, *flat,
+        base=li * count,
+    )
 
 
 def _experts_grouped(h2, stacks, li, local, held, w, chunk: int = GROUP_CHUNK):
@@ -415,23 +486,22 @@ def _experts_grouped(h2, stacks, li, local, held, w, chunk: int = GROUP_CHUNK):
 
 
 def _count_routing(counters, local, held, tok_mask, per_tok: int, count: int,
-                   decode: bool):
+                   decode: bool, plan: str = "dense"):
     """``counters`` with one expert layer's routing added (``COUNTERS``'
-    first four in a decode step, the prefill pair in a prompt)."""
+    first four and the experts ``plan`` read in a decode step, the prefill
+    pair in a prompt)."""
     if counters is None:
         return None
     n_tok = jnp.sum(tok_mask).astype(jnp.uint32)
     n_held = jnp.sum(held).astype(jnp.uint32)
     n_routed = n_tok * jnp.uint32(per_tok)
     if decode:
-        per = jnp.sum(
-            (local[..., None] == jnp.arange(count)) & held[..., None],
-            axis=(0, 1),
-        )  # tokens on each held expert
+        per = _tokens_on_experts(local, held, count)
+        touched = jnp.sum(per > 0).astype(jnp.uint32)
+        read = touched if plan == "touched" else jnp.uint32(count)
         add = jnp.zeros_like(counters).at[jnp.arange(4)].add(jnp.stack([
-            n_routed, n_held, jnp.sum(per > 0).astype(jnp.uint32),
-            jnp.max(per).astype(jnp.uint32),
-        ]))
+            n_routed, n_held, touched, jnp.max(per).astype(jnp.uint32),
+        ])).at[_EXPERTS_READ].add(read)
     else:
         add = jnp.zeros_like(counters).at[jnp.arange(5, 7)].add(
             jnp.stack([n_routed, n_held])
@@ -439,18 +509,25 @@ def _count_routing(counters, local, held, tok_mask, per_tok: int, count: int,
     return counters + add
 
 
-def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li):
+def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li,
+         sharded: bool = False):
     """``h2 (T, E)`` -> (routed + shared (T, E) float32, counters).  ``lp``
     is this layer's weights, ``stacks`` every layer's and ``li`` the layer
-    (:func:`_experts_grouped` says why it wants those)."""
+    (:func:`_experts_grouped` says why it wants those); ``sharded`` (static)
+    says the stacks lie over a mesh."""
     first, count = cfg.held
+    plan = experts_plan(
+        h2.shape[0], cfg.experts_per_tok, cfg.n_experts, kernel=not sharded
+    )
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
         local = idx - first
         held = (local >= 0) & (local < count) & tok_mask[:, None]
     with jax.named_scope("moe.experts"):
-        if h2.shape[0] >= GROUPED_FROM:
+        if plan == "grouped":
             routed = _experts_grouped(h2, stacks, li, local, held, w)
+        elif plan == "touched":
+            routed = _experts_touched(h2, stacks, li, local, held, w)
         else:
             routed = _experts_dense(h2, lp, local, held, w)
     with jax.named_scope("moe.shared"):
@@ -461,7 +538,7 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li):
             preferred_element_type=jnp.float32,
         ) / cfg.n_shared_experts
     counters = _count_routing(
-        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode
+        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode, plan
     )
     return routed + shared, counters
 
@@ -623,7 +700,7 @@ def prefill_slot_paged(
     (the contract of ``llama.prefill_slot_paged``).  ``seq_impl="flash"``
     runs the prompt's attention through the Pallas tiled kernel with the
     window inside it; ``"dense"`` through chunked XLA attention."""
-    del mesh, adapter_id
+    del adapter_id
     if lora is not None:
         raise TypeError("cohere2_moe has no LoRA path")
     bs = cache["k"].shape[2]
@@ -646,7 +723,10 @@ def prefill_slot_paged(
             else:
                 o = _attend(q, k, v, pos, pos, window)
             attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
-        moe, ctr = _moe(h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li)
+        moe, ctr = _moe(
+            h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li,
+            sharded=mesh is not None,
+        )
         return _residual(x, attn, moe), ck, cv, ctr
 
     ctr = _bump(cache.get("counters"), 7, length)
@@ -687,7 +767,7 @@ def prefill_suffix_paged(
     the contract of ``llama.prefill_suffix_paged``.  Suffix queries attend
     over [the prefix read from the pool ++ the suffix]; a sliding layer
     masks what lies before its window."""
-    del adapter_id, kv_sharded
+    del adapter_id
     if lora is not None:
         raise TypeError("cohere2_moe has no LoRA path")
     bs = cache["k"].shape[2]
@@ -718,7 +798,10 @@ def prefill_suffix_paged(
             attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
             ck = _write_prompt(ck, li, suffix_blocks, k, bs)
             cv = _write_prompt(cv, li, suffix_blocks, v, bs)
-        moe, ctr = _moe(h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li)
+        moe, ctr = _moe(
+            h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li,
+            sharded=kv_sharded,
+        )
         return _residual(x, attn, moe), ck, cv, ctr
 
     ctr = _bump(cache.get("counters"), 7, length - prefix_len)
@@ -781,8 +864,9 @@ def _decode_paged_multi(
     test and ``row > position - sliding_window`` — fewer rows than the full
     read once ``window`` has outgrown them; until then it reads what the
     full layer reads, under its own mask.  ``window_read_off`` (tests) keeps
-    the full read on every layer."""
-    del adapter_ids, kv_sharded
+    the full read on every layer.  ``kv_sharded`` says the deployment lies
+    over a tensor-parallel mesh, its expert stacks too."""
+    del adapter_ids
     if lora is not None:
         raise TypeError("cohere2_moe has no LoRA path")
     pos, table = cache["pos"], cache["table"]
@@ -875,7 +959,7 @@ def _decode_paged_multi(
             )
         moe, ctr = _moe(
             h.reshape(S * L, -1), lp, cfg, tok_mask, ctr, decode=True,
-            stacks=params["layers"], li=li,
+            stacks=params["layers"], li=li, sharded=kv_sharded,
         )
         return _residual(x, attn, moe.reshape(x.shape)), ck, cv, ctr
 

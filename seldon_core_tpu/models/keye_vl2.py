@@ -56,10 +56,13 @@ the tiled attention under it.  The other way — gathering a query's ``topk``
 rows — would move ``topk`` x 2 KB x 2 for every query and layer (200 GB a
 24k prompt) and was not built.
 
-Expert products are ``cohere2_moe``'s (``_experts_dense`` in a decode step,
-``_experts_grouped`` in a prompt) under a softmax route; ``experts_held``
-means what it means there.  ``COUNTERS`` keeps that family's eight names
-and adds the selection's four.
+Expert products are ``cohere2_moe``'s under a softmax route, chosen by its
+one rule of static shapes (``experts_plan``): ``_experts_grouped`` in a
+prompt; in a decode step the kernel that streams only the experts some token
+chose (``_experts_touched``: 8 slots x top-8 touch a third of 128), and
+``_experts_dense`` where a step would touch nearly all of them anyway.
+``experts_held`` means what it means there.  ``COUNTERS`` keeps that
+family's names and adds the selection's four.
 """
 
 from __future__ import annotations
@@ -73,12 +76,13 @@ from jax import lax
 
 from seldon_core_tpu.models.cohere2_moe import (
     COUNTERS as _MOE_COUNTERS,
-    GROUPED_FROM,
     _bump,
     _count_routing,
     _experts_dense,
     _experts_grouped,
+    _experts_touched,
     _layernorm,
+    experts_plan,
 )
 from seldon_core_tpu.models.common import annotate_params
 from seldon_core_tpu.models.llama import _rmsnorm, _rope
@@ -451,27 +455,37 @@ def _route(h2, w_router, cfg: Config):
     return idx.astype(jnp.int32), w.astype(jnp.float32)
 
 
-def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool):
-    """``h2 (T, E)`` -> (the held experts' part (T, E) float32, counters)."""
+def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool,
+         stacks=None, li=None):
+    """``h2 (T, E)`` -> (the held experts' part (T, E) float32, counters).
+    ``stacks`` are the expert weights of every layer and ``li`` this layer
+    (``cohere2_moe._experts_grouped`` says why a kernel wants those and not
+    ``lp``'s); a caller without them gets the dense products where the
+    touched-only kernel would have run."""
     first, count = cfg.held
+    plan = experts_plan(
+        h2.shape[0], cfg.experts_per_tok, cfg.n_experts, kernel=stacks is not None
+    )
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
         local = idx - first
         held = (local >= 0) & (local < count) & tok_mask[:, None]
     with jax.named_scope("moe.experts"):
-        if h2.shape[0] >= GROUPED_FROM:
+        if plan == "grouped":
             # this layer's experts as a stack of one layer: cutting them out
             # of the carried stack is a copy (1.2 GB a layer at the published
             # sizes, 3 ms) against a prompt's hundreds of milliseconds, and
             # the grouped product then runs over 128 groups, not 128 x layers
-            stacks = {k: lp[k][None] for k in _EXPERT_KEYS}
+            one = {k: lp[k][None] for k in _EXPERT_KEYS}
             routed = _experts_grouped(
-                h2, stacks, 0, local, held, w, chunk=GROUP_CHUNK
+                h2, one, 0, local, held, w, chunk=GROUP_CHUNK
             )
+        elif plan == "touched":
+            routed = _experts_touched(h2, stacks, li, local, held, w)
         else:
             routed = _experts_dense(h2, lp, local, held, w)
     counters = _count_routing(
-        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode
+        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode, plan
     )
     return routed, counters
 
@@ -480,12 +494,14 @@ def _add(x, y):
     return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
 
 
-def _after_attention(x, o, lp, cfg: Config, tok_mask, ctr, *, decode: bool):
+def _after_attention(x, o, lp, cfg: Config, tok_mask, ctr, *, decode: bool,
+                     stacks=None, li=None):
     """The rest of a layer behind its attention ``o (T, H, D)``: the output
-    projection and the expert layer, each added to the stream."""
+    projection and the expert layer (``stacks``, ``li``: :func:`_moe`), each
+    added to the stream."""
     x = _add(x, jnp.einsum("thd,hde->te", o, lp["wo"]))
     h2 = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    moe, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode)
+    moe, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode, stacks=stacks, li=li)
     return _add(x, moe), ctr
 
 
@@ -768,6 +784,7 @@ def decode_slots_paged(
     n_seen = jnp.where(active, jnp.minimum(pos + 1, W), 0)
     n_sel = jnp.minimum(n_seen, topk) if sparse else n_seen
     x = params["tok_emb"][tokens]  # (S, E)
+    stacks = {k: params["layers"][k] for k in _EXPERT_KEYS}
 
     def layer(carry, li, lp):
         x, ck, cv, cik, ctr = carry
@@ -781,7 +798,9 @@ def decode_slots_paged(
             q, qi, wi, ck, cv, cik, li, read_blk, pos, active, n_sel, cfg,
             sparse=sparse, kernel=kernel,
         )
-        x, ctr = _after_attention(x, o, lp, cfg, active, ctr, decode=True)
+        x, ctr = _after_attention(
+            x, o, lp, cfg, active, ctr, decode=True, stacks=stacks, li=li
+        )
         return x, ck, cv, cik, ctr
 
     ctr = _bump(cache.get("counters"), _STEPS, 1)

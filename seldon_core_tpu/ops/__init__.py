@@ -3,9 +3,11 @@
 The compute plane is mostly XLA-fused jit code; kernels live here only
 where explicit tiling beats the compiler — flash attention (O(S^2) HBM
 traffic -> O(S*D)), paged decode-attention (block-table gather + int8
-dequant + attention fused over the paged KV pool, docs/PERFORMANCE.md §7)
-and learned sparse attention (a prompt's exact top-k selection as a mask,
-and the tiled attention under it: ``sparse_attention.py``).
+dequant + attention fused over the paged KV pool, docs/PERFORMANCE.md §7),
+learned sparse attention (a prompt's exact top-k selection as a mask,
+and the tiled attention under it: ``sparse_attention.py``) and a decode
+step's routed experts read by the list of those its tokens chose
+(``touched_experts.py``).
 """
 
 from seldon_core_tpu.ops.flash_attention import (
@@ -21,6 +23,7 @@ from seldon_core_tpu.ops.sparse_attention import (
     select_topk_mask,
     sparse_decode_attention,
 )
+from seldon_core_tpu.ops.touched_experts import touched_expert_products
 
 __all__ = [
     "flash_attention",
@@ -30,4 +33,5 @@ __all__ = [
     "masked_flash_attention",
     "select_topk_mask",
     "sparse_decode_attention",
+    "touched_expert_products",
 ]

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from seldon_core_tpu.models import cohere2_moe
 from seldon_core_tpu.models import keye_vl2 as m
 
 sys.path.insert(
@@ -293,7 +294,8 @@ class TestShareTiesToTheModel:
         h = jax.random.normal(jax.random.PRNGKey(4), (40, cfg.hidden))
         mask = jnp.arange(40) < 33
         dense, _ = m._moe(h, lp, cfg, mask, None, decode=False)
-        monkeypatch.setattr(m, "GROUPED_FROM", 8)
+        # the one rule both families' ``_moe`` ask lives in ``cohere2_moe``
+        monkeypatch.setattr(cohere2_moe, "GROUPED_FROM", 8)
         monkeypatch.setattr(m, "GROUP_CHUNK", 64)
         grouped, _ = m._moe(h, lp, cfg, mask, None, decode=False)
         np.testing.assert_allclose(grouped, dense, atol=TOL, rtol=0)

@@ -1,5 +1,5 @@
-"""The paged decode kernel and the prompt's tiled kernel through the TPU's
-own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
+"""The paged decode kernel, the prompt's tiled kernel, the selection's
+kernels and the touched-only expert kernel through the TPU's own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
 (no chip time; nothing runs).  The interpreter the other tests use accepts
 what Mosaic refuses: a copy or slice off the tiling, too much fast memory.
 
@@ -138,3 +138,83 @@ def test_the_selection_kernels_compile_at_keye_vl2s_widths(one_chip, q_offset, c
         assert "tpu_custom_call" in compiled.as_text()
         # nothing the size of the mask among the temporaries
         assert compiled.memory_analysis().temp_size_in_bytes < chunk * lk // 8
+
+
+# (name, tokens, held experts, hidden, expert width, layers)
+EXPERT_CASES = [
+    ("keye-vl-2 a whole expert a step", 8, 128, 2048, 768, 6),
+    ("command-a-plus F in tiles", 32, 16, 4096, 4096, 4),
+]
+
+
+@pytest.mark.parametrize("case", EXPERT_CASES, ids=[c[0] for c in EXPERT_CASES])
+def test_the_touched_only_expert_kernel_compiles_for_v5e(one_chip, case):
+    """``ops/touched_experts.py`` at the widths of both expert cells: three
+    blocks of 3.1 MB double-buffered under a stated VMEM limit, and ``F`` in
+    tiles where one matrix is 33.5 MB; the stacks go in as they are."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.touched_experts import f_tile, touched_expert_products
+
+    _, T, X, E, F, L = case
+
+    def sds(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    G = min(X, T * 8)
+
+    def f(h2, cw, ids, n, gate, up, down):
+        return touched_expert_products(
+            h2, cw, ids, n, gate, up, down, base=X, interpret=False
+        )
+
+    compiled = jax.jit(f).lower(
+        sds((T, E)), sds((T, X), jnp.float32), sds((G,), jnp.int32),
+        sds((), jnp.int32), sds((L * X, E, F)), sds((L * X, E, F)),
+        sds((L * X, F, E)),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert f_tile(E, F, 2) == (F if F == 768 else 512)
+    assert compiled.memory_analysis().temp_size_in_bytes < E * F * 2
+
+
+def test_keye_vl2s_decode_program_holds_no_copy_of_a_layers_experts(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (8 slots, six layers, a
+    pool of 793 blocks of 256, window 32,768): the expert kernel is in it,
+    handed the stack of every layer, and the program's temporaries are not
+    the size of a layer's experts (1.2 GB: XLA fuses no slice into a
+    kernel's operand, so a layer cut out first would be a copy a step)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import keye_vl2 as m
+
+    # the ops ask the backend whether to interpret: this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = m.Config(n_layers=6)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 8, 793, 256, jnp.bfloat16)
+    ))
+    step = jax.jit(
+        functools.partial(m.decode_slots_paged, cfg=cfg, window=32768, kernel=True),
+        donate_argnums=(2,),
+    )
+    compiled = step.lower(
+        params, jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip), cache,
+        jax.ShapeDtypeStruct((8,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    layer_experts = 128 * 3 * 2048 * 768 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_experts // 8
