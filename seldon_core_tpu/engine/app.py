@@ -144,6 +144,7 @@ class EngineApp:
         # reports how many came AFTER it (a warmed server must show zero)
         self._compiles_at_ready: int | None = None
         self._profile_dir: str | None = None
+        self._profile_stopping = False
         # ingress-tier response cache: bound at startup, and ONLY when the
         # whole graph is deterministic (a randomized router poisons
         # whole-response cacheability; node-tier caching still applies to
@@ -683,6 +684,11 @@ class EngineApp:
         the first token after prefill + one decode block instead of waiting
         out the full generation (p50 397ms for 32 tokens in round 3).
         """
+        import time
+
+        # the ``ingress`` stage starts here and ends at the scheduler's own
+        # submit stamp: admission, the body, the response headers
+        t_in = time.perf_counter()
         dep, pred = self.service.deployment_name, self.service.predictor.name
         # the timer covers validation too: a rejected stream request must
         # be a recorded 400, not an unrecorded return
@@ -696,12 +702,12 @@ class EngineApp:
                 h["code"] = str(e.status)
                 return self._qos_reject(e)
             try:
-                return await self._predictions_stream_admitted(request, h)
+                return await self._predictions_stream_admitted(request, h, t_in)
             finally:
                 ticket.release()
 
     async def _predictions_stream_admitted(
-        self, request: web.Request, h: dict
+        self, request: web.Request, h: dict, t_in: float
     ) -> web.StreamResponse:
         import json
         import time
@@ -717,6 +723,8 @@ class EngineApp:
             h["code"] = "400"
             return web.json_response(_status_body(400, reason), status=400)
         unit = units[0]
+        import jax  # a graph with a generative unit has it loaded
+
         try:
             body = await self._json(request)
             if "strData" in body:  # full contract wrapper also accepted
@@ -753,19 +761,28 @@ class EngineApp:
         await resp.prepare(request)
         out: list[int] = []
         flush_s = 0.0  # cumulative socket-write time -> stream-flush stage
+        marks: dict = {}  # the scheduler's ``first_written`` lands here
         try:
             gen = unit.stream(
                 prompt,
                 max_new_tokens=max_new,
                 temperature=temperature,
                 eos_id=eos,
+                t_ingress=t_in,
+                info=marks,
             )
             async for tok in gen:
                 out.append(tok)
+                event = f"data: {json.dumps({'token': tok})}\n\n".encode()
                 t_w = time.perf_counter()
-                await resp.write(
-                    f"data: {json.dumps({'token': tok})}\n\n".encode()
-                )
+                if len(out) > 1:
+                    await resp.write(event)
+                else:
+                    # the ``first-write`` stage: the scheduler's first-token
+                    # stamp -> this write returned
+                    with jax.profiler.TraceAnnotation("engine:first-write"):
+                        await resp.write(event)
+                    marks["first_written"]()
                 flush_s += time.perf_counter() - t_w
             t_w = time.perf_counter()
             await resp.write(
@@ -923,6 +940,7 @@ class EngineApp:
         snap = unit.model.spec_snapshot()
         snap["packing"] = unit.scheduler.packing_snapshot()
         snap["block_boundaries"] = unit.scheduler.boundary_snapshot()
+        snap["stalls"] = unit.scheduler.stall_snapshot()
         return snap
 
     def _breakdown_payload(self) -> dict:
@@ -1067,7 +1085,9 @@ class EngineApp:
             # the capture dir must exist up front: operators tail it while
             # the trace runs, and a bad path should 500 HERE, not at stop
             os.makedirs(out_dir, exist_ok=True)
-            jax.profiler.start_trace(out_dir)
+            # off the event loop: starting the profiler's session takes
+            # long enough for every live stream to feel it
+            await asyncio.to_thread(jax.profiler.start_trace, out_dir)
         except Exception as e:
             self._profile_dir = None
             return web.json_response({"error": str(e)}, status=500)
@@ -1076,12 +1096,20 @@ class EngineApp:
     async def profile_stop(self, request: web.Request) -> web.Response:
         import jax
 
-        if self._profile_dir is None:
+        # no await between the check and the claim: of two concurrent
+        # stops one takes the trace and the other is a 409; the directory
+        # stays set until the trace is written, so a start meanwhile is a
+        # 409 too
+        if self._profile_dir is None or self._profile_stopping:
             return web.json_response({"error": "profiler not running"}, status=409)
+        self._profile_stopping = True
         try:
-            jax.profiler.stop_trace()
+            # off the event loop: ``stop_trace`` collects and writes the
+            # whole trace, seconds during which the loop would serve nobody
+            await asyncio.to_thread(jax.profiler.stop_trace)
         finally:
             out_dir, self._profile_dir = self._profile_dir, None
+            self._profile_stopping = False
         return web.json_response({"status": "stopped", "dir": out_dir})
 
     # -- disaggregated prefill/decode (docs/DISAGGREGATION.md) -------------

@@ -58,8 +58,19 @@ import numpy as np
 
 from seldon_core_tpu import qos
 from seldon_core_tpu.graph.units import GraphUnitError, SeldonComponent
-from seldon_core_tpu.obs import RECORDER, STAGE_DEVICE_STEP, STAGE_TTFT, TIMELINE
+from seldon_core_tpu.obs import (
+    RECORDER,
+    STAGE_ADMIT_ROUND,
+    STAGE_DEVICE_STEP,
+    STAGE_FIRST_WRITE,
+    STAGE_INGRESS,
+    STAGE_SLOT_WAIT,
+    STAGE_SYNC_POINT,
+    STAGE_TTFT,
+    TIMELINE,
+)
 from seldon_core_tpu.obs.metering import METER
+from seldon_core_tpu.obs.stall import StallWatchdog
 from seldon_core_tpu.obs.timeline import (
     EVENT_PREEMPT,
     EVENT_RESUME,
@@ -3954,6 +3965,42 @@ class _Request:
     conf_n: int = 0
 
 
+class _Part:
+    """One named part of the scheduler's run loop, for the three readers of
+    "what was the host doing": the profiler's trace (a ``TraceAnnotation``,
+    so the part lies on the clock of the device's programs; ``note`` goes
+    on it), the stall watchdog (the scheduler's current part and since
+    when) and, where ``stage`` names one, the flight recorder.  ``t0`` and
+    ``t1`` are the part's two instants on ``time.perf_counter``: the run
+    loop reads them where it needs the stamp, and takes none beside them.
+    Outside a trace an annotation is a flag test.  No name may match
+    ``benchmark/trace.py``'s ``LABEL``: that reduction pairs such labels
+    with device programs in dispatch order (see ``_counters_copy``)."""
+
+    __slots__ = ("sched", "name", "stage", "ann", "t0", "t1")
+
+    # what the run loop is in between two parts
+    LOOP = "sched:loop"
+
+    def __init__(self, sched, name: str, stage: str | None = None, **note):
+        self.sched, self.name, self.stage = sched, name, stage
+        self.ann = jax.profiler.TraceAnnotation(name, **note)
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_Part":
+        self.t0 = time.perf_counter()
+        self.sched._part_now = (self.name, self.t0)
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ann.__exit__(*exc)
+        self.t1 = time.perf_counter()
+        self.sched._part_now = (self.LOOP, self.t1)
+        if self.stage is not None:
+            RECORDER.record_stage(self.stage, self.t1 - self.t0)
+
+
 class GenerationScheduler:
     """Continuous-batching front: admits requests into free slots while
     decode steps keep running for in-flight ones.
@@ -4045,6 +4092,12 @@ class GenerationScheduler:
         self.drained_out = 0
         # decode-block boundaries by outcome (boundary_snapshot)
         self.boundaries: dict[str, int] = {}
+        # the part of the run loop the scheduler is in and since when
+        # (``_Part``), the slots' live mask, and the watchdog that reads
+        # both from a thread of its own while the run task lives
+        self._part_now: tuple[str, float] = (_Part.LOOP, 0.0)
+        self._active = np.zeros(0, bool)
+        self._watchdog = StallWatchdog(model.name, self._watched)
         # Random base so temperature>0 sampling differs across restarts and
         # replicas; within one process the sequence stays deterministic.
         self._seed = int.from_bytes(os.urandom(4), "little")
@@ -4052,6 +4105,45 @@ class GenerationScheduler:
     def _next_seed(self) -> int:
         self._seed = (self._seed + 1) % (2**31 - 1)
         return self._seed
+
+    def _part(self, name: str, stage: str | None = None, **note) -> _Part:
+        return _Part(self, name, stage, **note)
+
+    def _watched(self) -> tuple[str, float, bool]:
+        """What the stall watchdog reads, from its own thread: the current
+        part, since when, and whether a slot is live or a request waits."""
+        part, since = self._part_now
+        busy = bool(
+            self._waiting or self._overflow or self._prefilling
+            or self._active.any()
+        )
+        return part, since, busy
+
+    def _note_part(self, req: "_Request", stage: str, t0: float, t1: float) -> None:
+        """A request's own part of the host path (``slot-wait``,
+        ``ingress``, ``first-write``): a sample of its stage, and the same
+        on the request's timeline and generation span, so one trace shows
+        its wait beside the causes it has.  The event's ``ts`` is the
+        part's end and ``ms`` its length; a request that has ended already
+        (one token, written after its terminal event) keeps ``terminal``
+        last and gets the sample alone."""
+        RECORDER.record_stage(stage, t1 - t0)
+        if not req.u_terminal_metered:
+            self._tl(req, stage, ms=round((t1 - t0) * 1e3, 3))
+
+    def _first_written(self, req: "_Request") -> None:
+        """The consumer's word that its write of the first token returned
+        (the engine's SSE handler calls it through ``submit``'s ``info``):
+        the ``first-write`` stage starts at the scheduler's own stamp."""
+        if req.t_first_token:
+            self._note_part(
+                req, STAGE_FIRST_WRITE, req.t_first_token, time.perf_counter()
+            )
+
+    def stall_snapshot(self) -> dict:
+        """Stalls the watchdog has named since boot (``GET
+        /stats/breakdown``): ``count``, ``longest_s``, the last one's part."""
+        return self._watchdog.snapshot()
 
     # ------------------------------------------- lifecycle timeline feeds
     # (obs/timeline.py; docs/OBSERVABILITY.md "generation forensics").
@@ -4171,6 +4263,7 @@ class GenerationScheduler:
         on_token: "Callable[[int], None] | None" = None,
         adapter: str | None = None,
         info: dict | None = None,
+        t_ingress: float | None = None,
     ) -> np.ndarray:
         """Generate up to ``max_new_tokens`` ids for a 1-D prompt.
 
@@ -4180,7 +4273,12 @@ class GenerationScheduler:
         to decode through (docs/MULTITENANT.md).  ``info`` (optional) is an
         out-param dict stamped with per-request extras on completion —
         today the cascade confidence signal (docs/GRAPHS.md): mean top-2
-        logit margin over delivered tokens, when ``conf_signal`` is on."""
+        logit margin over delivered tokens, when ``conf_signal`` is on; and,
+        from the start, ``first_written``: the caller that writes the
+        tokens out calls it when its write of the first one has returned
+        (the ``first-write`` stage).  ``t_ingress`` (optional) is the
+        ``time.perf_counter`` instant the request entered the serving
+        handler: the ``ingress`` stage ends at this submit's own stamp."""
         if self._closed:
             raise RuntimeError("GenerationScheduler is closed")
         prompt = np.asarray(prompt, np.int32).ravel()
@@ -4234,6 +4332,10 @@ class GenerationScheduler:
         )
         self._begin_tl(req)
         self._tl(req, "queued", span=False, depth=len(self._waiting))
+        if t_ingress is not None:
+            self._note_part(req, STAGE_INGRESS, t_ingress, req.t0)
+        if info is not None:
+            info["first_written"] = partial(self._first_written, req)
         self._waiting.append(req)
         self._wake.set()
         try:
@@ -4367,9 +4469,9 @@ class GenerationScheduler:
             vecs = jax.device_get([v for _, v in placed]) if placed else []
             return placed, errors, vecs
 
-        t0 = time.perf_counter()
-        placed, errors, vecs = await asyncio.to_thread(dispatch_and_fetch)
-        batch_s = time.perf_counter() - t0
+        with self._part("sched:embeds", n=len(reqs)) as wave:
+            placed, errors, vecs = await asyncio.to_thread(dispatch_and_fetch)
+        batch_s = wave.t1 - wave.t0
         total_toks = sum(int(r.prompt.size) for r, _ in placed) or 1
         for (req, _), vec in zip(placed, vecs):
             share_s = batch_s * int(req.prompt.size) / total_toks
@@ -4602,6 +4704,16 @@ class GenerationScheduler:
         e = self._qwait_ewma
         self._qwait_ewma = wait if e is None else (0.8 * e + 0.2 * wait)
         self._qwait_stamp = time.perf_counter()
+
+    def _note_slot_wait(self, req: _Request, taken_t: float) -> None:
+        """``slot-wait``: submit -> taken into an admission batch at a sync
+        point (``taken_t``, the round's own start).  Its own stage: the
+        QoS estimate and the gateway's router read ``queue-wait``'s EWMA,
+        and feeding that would change admission.  Resumed suspend records
+        are skipped as ``_note_queue_wait`` skips them."""
+        if req.imported is not None and req.imported.get("resumed"):
+            return
+        self._note_part(req, STAGE_SLOT_WAIT, req.t0, taken_t)
 
     def request_preempt(self) -> None:
         """Arbiter verb: suspend this deployment's active slots at the
@@ -5367,6 +5479,12 @@ class GenerationScheduler:
         # started (its dispatch, or its predecessor's tokens coming back,
         # whichever is later) plus what the last fetched block took
         pending_t = fetched_t = block_s = 0.0
+        # a sync point in the making: when the tokens of the block before
+        # it were in hand (``fetched_t``), until the next decode block is
+        # dispatched — the time live streams have no block in flight
+        sync_t: float | None = None
+        self._active = active
+        self._watchdog.start(threading.get_ident())
         try:
             while True:
                 if self._closed:
@@ -5431,10 +5549,14 @@ class GenerationScheduler:
                         self._arbiter.poll()
                     if not self._preempt:
                         continue
-                    try:
-                        await asyncio.wait_for(self._wake.wait(), timeout=0.05)
-                    except asyncio.TimeoutError:
-                        pass
+                    sync_t = None
+                    with self._part("idle-park"):
+                        try:
+                            await asyncio.wait_for(
+                                self._wake.wait(), timeout=0.05
+                            )
+                        except asyncio.TimeoutError:
+                            pass
                     continue
                 if (
                     pending is None
@@ -5451,7 +5573,9 @@ class GenerationScheduler:
                     # must never hold the chip.
                     self._arb_release()
                     self._wake.clear()
-                    await self._wake.wait()
+                    sync_t = None
+                    with self._part("idle-park"):
+                        await self._wake.wait()
                     self._reap_queues()
                 if pending is None:
                     # sync point: admissions and dispatch only happen with
@@ -5485,6 +5609,7 @@ class GenerationScheduler:
                             self._waiting.remove(r)
                     while self._overflow and len(batch) < cap_free:
                         batch.append(self._overflow.pop(0))
+                    retried = len(batch)  # taken before, and sent back
                     if self._waiting and len(batch) < cap_free:
                         self._waiting.sort(
                             key=lambda r: (qos.priority_rank(r.priority), r.t0)
@@ -5501,12 +5626,22 @@ class GenerationScheduler:
                         await self._admit_embeds(embeds)
                     live_before = int(active.sum())
                     if batch:
-                        await self._admit_batch(batch, slots, cur, temps, active)
+                        with self._part(
+                            "sched:admit", STAGE_ADMIT_ROUND, n=len(batch)
+                        ) as rnd:
+                            for req in batch[retried:]:
+                                self._note_slot_wait(req, rnd.t0)
+                            await self._admit_batch(
+                                batch, slots, cur, temps, active
+                            )
                     if self._prefilling:
                         # chunked prefill: ONE chunk per sync point — the
                         # admission cost a decode stall can see is bounded
                         # by a chunk, not a prompt (docs/PERFORMANCE.md §7)
-                        await self._advance_prefill(slots, cur, temps, active)
+                        with self._part("sched:advance-prefill"):
+                            await self._advance_prefill(
+                                slots, cur, temps, active
+                            )
                     self._reap_slots(slots, active)
                     if (
                         int(active.sum()) > live_before
@@ -5526,6 +5661,7 @@ class GenerationScheduler:
                         # nothing to dispatch: the grant goes back before
                         # any park or spin below
                         self._arb_release()
+                        sync_t = None
                         if self._prefilling:
                             # chunks still advancing: loop straight back —
                             # each iteration does real device work
@@ -5564,12 +5700,13 @@ class GenerationScheduler:
                                         cause="externals-pinned",
                                     )
                             self._wake.clear()
-                            try:
-                                await asyncio.wait_for(
-                                    self._wake.wait(), timeout=0.05
-                                )
-                            except asyncio.TimeoutError:
-                                pass
+                            with self._part("idle-park"):
+                                try:
+                                    await asyncio.wait_for(
+                                        self._wake.wait(), timeout=0.05
+                                    )
+                                except asyncio.TimeoutError:
+                                    pass
                         continue
                     seed = self._next_seed()
                     if k <= 1:
@@ -5598,41 +5735,46 @@ class GenerationScheduler:
                     # one dispatch yields up to k tokens per slot; the
                     # device enforces per-slot eos + budget so finished
                     # slots stop touching the cache mid-block
-                    eos = np.array(
-                        [
-                            slots[i].eos_id
-                            if slots[i] is not None and slots[i].eos_id is not None
-                            else -1
-                            for i in range(S)
-                        ],
-                        np.int32,
-                    )
-                    remaining = np.array(
-                        [
-                            max(0, slots[i].max_new_tokens - len(slots[i].out))
-                            if slots[i] is not None
-                            else 0
-                            for i in range(S)
-                        ],
-                        np.int32,
-                    )
-                    try:
-                        pending = await asyncio.to_thread(
-                            self.model.step_k_dispatch,
-                            cur, active, temps, seed, eos, remaining, k,
+                    with self._part("sched:dispatch") as sent:
+                        eos = np.array(
+                            [
+                                slots[i].eos_id
+                                if slots[i] is not None
+                                and slots[i].eos_id is not None
+                                else -1
+                                for i in range(S)
+                            ],
+                            np.int32,
                         )
-                    except asyncio.CancelledError:
-                        raise
-                    except Exception as exc:
-                        log.exception(
-                            "decode dispatch failed; failing %d in-flight requests",
-                            int(active.sum()),
+                        remaining = np.array(
+                            [
+                                max(0, slots[i].max_new_tokens - len(slots[i].out))
+                                if slots[i] is not None
+                                else 0
+                                for i in range(S)
+                            ],
+                            np.int32,
                         )
-                        self._arb_release()
-                        self._fail_inflight(slots, active, exc)
-                        continue
+                        try:
+                            pending = await asyncio.to_thread(
+                                self.model.step_k_dispatch,
+                                cur, active, temps, seed, eos, remaining, k,
+                            )
+                        except asyncio.CancelledError:
+                            raise
+                        except Exception as exc:
+                            log.exception(
+                                "decode dispatch failed; failing %d in-flight requests",
+                                int(active.sum()),
+                            )
+                            self._arb_release()
+                            self._fail_inflight(slots, active, exc)
+                            continue
                     carry_dirty = False
-                    pending_t = time.perf_counter()
+                    pending_t = sent.t1
+                    if sync_t is not None:
+                        RECORDER.record_stage(STAGE_SYNC_POINT, pending_t - sync_t)
+                        sync_t = None
                     continue
                 # fetch phase — THE overlap: block N+1 is dispatched straight
                 # from block N's on-device carry.  WHEN is decided from what
@@ -5667,19 +5809,22 @@ class GenerationScheduler:
                         tokens = asyncio.ensure_future(
                             asyncio.to_thread(self.model.step_k_fetch, pending)
                         )
-                        if await self._nobody_came(
-                            max(pending_t, fetched_t) + block_s
-                            - (self._LEAD_S + self._LEAD_SHARE * block_s),
-                            carry_dirty, tokens,
-                        ):
-                            how = "chained-due"
+                        with self._part("sched:hold"):
+                            if await self._nobody_came(
+                                max(pending_t, fetched_t) + block_s
+                                - (self._LEAD_S + self._LEAD_SHARE * block_s),
+                                carry_dirty, tokens,
+                            ):
+                                how = "chained-due"
                     if how is not None:
-                        nxt, outcome = await self._chain(active, k, how)
-                        nxt_t = time.perf_counter()
+                        with self._part("sched:chain") as sent:
+                            nxt, outcome = await self._chain(active, k, how)
+                        nxt_t = sent.t1
                 if tokens is None:
                     tokens = asyncio.to_thread(self.model.step_k_fetch, pending)
                 try:
-                    toks_seq, act_seq = await tokens
+                    with self._part("sched:fetch") as fetch:
+                        toks_seq, act_seq = await tokens
                 except asyncio.CancelledError:
                     raise
                 except Exception as exc:
@@ -5699,9 +5844,8 @@ class GenerationScheduler:
                     self._arb_release()
                     self._fail_inflight(slots, active, exc)
                     continue
-                now = time.perf_counter()
-                block_s = now - max(pending_t, fetched_t)
-                fetched_t = now
+                block_s = fetch.t1 - max(pending_t, fetched_t)
+                fetched_t = fetch.t1
                 if outcome is None:
                     # N's tokens are in hand and nothing is chained: the
                     # sync point if it can do something, else the next
@@ -5716,10 +5860,11 @@ class GenerationScheduler:
                     if outcome is None and not live.any():
                         outcome = "idle"
                     if outcome is None:
-                        nxt, outcome = await self._chain(
-                            active, k, "chained-late"
-                        )
-                        nxt_t = time.perf_counter()
+                        with self._part("sched:chain") as sent:
+                            nxt, outcome = await self._chain(
+                                active, k, "chained-late"
+                            )
+                        nxt_t = sent.t1
                 self.boundaries[outcome] = self.boundaries.get(outcome, 0) + 1
                 if outcome not in self._NO_BREAK:
                     # name WHY the chain broke: the cause lands on every
@@ -5738,11 +5883,14 @@ class GenerationScheduler:
                     # BEFORE host-side delivery so a parked co-tenant's
                     # dispatch overlaps our bookkeeping
                     self._arb_release()
-                self._deliver(toks_seq, act_seq, slots, cur, active)
-                if self._reap_slots(slots, active):
-                    # host-side reap: the chip still thinks those slots are
-                    # live — the next dispatch must rebuild from host state
-                    carry_dirty = True
+                    sync_t = fetched_t
+                with self._part("sched:deliver"):
+                    self._deliver(toks_seq, act_seq, slots, cur, active)
+                    if self._reap_slots(slots, active):
+                        # host-side reap: the chip still thinks those slots
+                        # are live — the next dispatch must rebuild from
+                        # host state
+                        carry_dirty = True
         except asyncio.CancelledError:
             err = RuntimeError("GenerationScheduler closed")
             for ent in self._prefilling:
@@ -5772,6 +5920,8 @@ class GenerationScheduler:
                 self._suspend_store.flush()
             self._arb_release()
             raise
+        finally:
+            self._watchdog.stop()
 
     async def _admit_batch(self, batch, slots, cur, temps, active) -> None:
         free = [
@@ -6239,13 +6389,18 @@ class GenerativeComponent(SeldonComponent):
         temperature: float | None = None,
         eos_id: int | None = None,
         adapter: str | None = None,
+        t_ingress: float | None = None,
+        info: dict | None = None,
     ) -> AsyncIterator[int]:
         """Yield generated token ids as they decode (the streaming serving
         path — neither the reference nor its successor streams at all).
 
         Tokens surface ``decode_block`` at a time per device fetch: deploy
         with a small block (e.g. 4-8) when time-to-first-token matters, the
-        default large block when bulk throughput does.
+        default large block when bulk throughput does.  ``t_ingress`` and
+        ``info`` are ``GenerationScheduler.submit``'s: the instant the
+        request entered its handler, and the out-param whose
+        ``first_written`` the handler calls after its first write.
         """
         q: asyncio.Queue = asyncio.Queue()
         task = asyncio.create_task(
@@ -6260,6 +6415,8 @@ class GenerativeComponent(SeldonComponent):
                 eos_id=self.eos_id if eos_id is None else eos_id,
                 adapter=self.adapter if adapter is None else (adapter or None),
                 on_token=q.put_nowait,
+                t_ingress=t_ingress,
+                info=info,
             )
         )
         task.add_done_callback(lambda t: q.put_nowait(_STREAM_END))

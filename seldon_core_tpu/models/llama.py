@@ -304,47 +304,79 @@ def _layer(x, lp, cfg: Config, positions, attn_fn, kv_hook=None, lora=None,
     "b": (A, ..)}}``) and ``aid (B,)`` the per-row adapter ids — the
     batched multi-LoRA gather (docs/MULTITENANT.md); ``None`` compiles the
     plain base-model layer."""
-    h = _rmsnorm(x, lp["ln_att"], cfg.norm_eps)
-    q = jnp.einsum("ble,ehd->blhd", h, lp["wq"])
-    k = jnp.einsum("ble,ehd->blhd", h, lp["wk"])
-    v = jnp.einsum("ble,ehd->blhd", h, lp["wv"])
-    if lora is not None:
-        if "wq" in lora:
-            q = q + _lora_delta(h, lora["wq"], aid)
-        if "wk" in lora:
-            k = k + _lora_delta(h, lora["wk"], aid)
-        if "wv" in lora:
-            v = v + _lora_delta(h, lora["wv"], aid)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    q, k, v = _qkv(x, lp, cfg, positions, lora, aid)
     if kv_hook is None:
         ka, va, stored = k, v, (k, v)
     else:
         ka, va, stored = kv_hook(k, v)
-    o = attn_fn(q, _gqa_repeat(ka, cfg.n_heads), _gqa_repeat(va, cfg.n_heads))
-    proj = jnp.einsum("blhd,hde->ble", o, lp["wo"])
-    if lora is not None and "wo" in lora:
-        proj = proj + _lora_delta(o, lora["wo"], aid)
-    x = x + proj
+    with jax.named_scope("attn.prompt"):
+        o = attn_fn(
+            q, _gqa_repeat(ka, cfg.n_heads), _gqa_repeat(va, cfg.n_heads)
+        )
+    x = x + _attn_out(o, lp, lora, aid)
     h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
     x = x + _mlp_block(h, lp, lora, aid)
     return x, stored
 
 
+# The named scopes of a layer (``attn.qkv``, ``attn.prompt`` in a prompt's
+# own attention and ``attn.paged`` where the pool is read, ``attn.out``,
+# ``mlp.gate_up``, ``mlp.down``, ``head``) are metadata on the operations of
+# the prefill, suffix and decode programs alike: a profiler trace names a
+# kernel and a weight stream by them, and no program's work changes.
+
+
+def _qkv(x, lp, cfg: Config, positions, lora=None, aid=None):
+    """Attention norm, the three projections (with optional per-row LoRA
+    deltas) and the rotation of ``q`` and ``k``."""
+    with jax.named_scope("attn.qkv"):
+        h = _rmsnorm(x, lp["ln_att"], cfg.norm_eps)
+        q = jnp.einsum("ble,ehd->blhd", h, lp["wq"])
+        k = jnp.einsum("ble,ehd->blhd", h, lp["wk"])
+        v = jnp.einsum("ble,ehd->blhd", h, lp["wv"])
+        if lora is not None:
+            if "wq" in lora:
+                q = q + _lora_delta(h, lora["wq"], aid)
+            if "wk" in lora:
+                k = k + _lora_delta(h, lora["wk"], aid)
+            if "wv" in lora:
+                v = v + _lora_delta(h, lora["wv"], aid)
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    return q, k, v
+
+
+def _attn_out(o, lp, lora=None, aid=None):
+    with jax.named_scope("attn.out"):
+        proj = jnp.einsum("blhd,hde->ble", o, lp["wo"])
+        if lora is not None and "wo" in lora:
+            proj = proj + _lora_delta(o, lora["wo"], aid)
+    return proj
+
+
 def _mlp_block(h, lp, lora=None, aid=None):
     """SwiGLU MLP with optional per-row LoRA deltas on gate/up/down."""
-    gate = h @ lp["w_gate"]
-    up = h @ lp["w_up"]
-    if lora is not None:
-        if "w_gate" in lora:
-            gate = gate + _lora_delta(h, lora["w_gate"], aid)
-        if "w_up" in lora:
-            up = up + _lora_delta(h, lora["w_up"], aid)
-    act = jax.nn.silu(gate) * up
-    down = act @ lp["w_down"]
-    if lora is not None and "w_down" in lora:
-        down = down + _lora_delta(act, lora["w_down"], aid)
+    with jax.named_scope("mlp.gate_up"):
+        gate = h @ lp["w_gate"]
+        up = h @ lp["w_up"]
+        if lora is not None:
+            if "w_gate" in lora:
+                gate = gate + _lora_delta(h, lora["w_gate"], aid)
+            if "w_up" in lora:
+                up = up + _lora_delta(h, lora["w_up"], aid)
+        act = jax.nn.silu(gate) * up
+    with jax.named_scope("mlp.down"):
+        down = act @ lp["w_down"]
+        if lora is not None and "w_down" in lora:
+            down = down + _lora_delta(act, lora["w_down"], aid)
     return down
+
+
+def _head(params, x, cfg: Config):
+    """Final norm and the vocabulary projection -> ``(logits, hidden)``."""
+    with jax.named_scope("head"):
+        h = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        return h @ params["head"], h
 
 
 # ---------------------------------------------------------------------------
@@ -373,8 +405,7 @@ def forward(
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    return x @ params["head"]
+    return _head(params, x, cfg)[0]
 
 
 def init_cache(cfg: Config, batch: int, dtype=jnp.float32) -> dict:
@@ -426,8 +457,7 @@ def prefill(
         "v": jax.lax.dynamic_update_slice(cache["v"], vs.astype(cache["v"].dtype), (0, 0, 0, 0, 0)),
         "pos": jnp.asarray(tokens.shape[1], jnp.int32),
     }
-    x = _rmsnorm(x[:, -1], params["ln_f"], cfg.norm_eps)
-    return x @ params["head"], cache
+    return _head(params, x[:, -1], cfg)[0], cache
 
 
 def _prefill_core(params, tokens, cfg: Config, attn_fn, kv_hook=None,
@@ -724,12 +754,12 @@ def prefill_slot_paged(
     cache["pos"] = cache["pos"].at[slot].set(length)
     cache["table"] = cache["table"].at[slot].set(blocks_row)
     h = jax.lax.dynamic_index_in_dim(x[0], length - 1, axis=0, keepdims=False)
-    h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+    logits, h = _head(params, h, cfg)
     if return_hidden:
         # post-ln_f hidden at the sampled position — the Medusa heads'
         # input (executor/generation.py stashes it per slot)
-        return h @ params["head"], cache, h
-    return h @ params["head"], cache
+        return logits, cache, h
+    return logits, cache
 
 
 def prefill_suffix_paged(
@@ -797,19 +827,7 @@ def prefill_suffix_paged(
         x, ck, cv, cks, cvs = carry
         li, lp = inputs[0], inputs[1]
         ll = inputs[2] if lora is not None else None
-        h = _rmsnorm(x, lp["ln_att"], cfg.norm_eps)
-        q = jnp.einsum("ble,ehd->blhd", h, lp["wq"])
-        k = jnp.einsum("ble,ehd->blhd", h, lp["wk"])
-        v = jnp.einsum("ble,ehd->blhd", h, lp["wv"])
-        if ll is not None:
-            if "wq" in ll:
-                q = q + _lora_delta(h, ll["wq"], aid)
-            if "wk" in ll:
-                k = k + _lora_delta(h, ll["wk"], aid)
-            if "wv" in ll:
-                v = v + _lora_delta(h, ll["wv"], aid)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(x, lp, cfg, positions, ll, aid)
         if quant:
             # attend the dequantized suffix K/V (fake-quant: exactly what
             # the pool will hold) and collect the quantized form to store
@@ -818,25 +836,23 @@ def prefill_suffix_paged(
         def read(pool):
             return _pool_read(pool, li, read_idx, kv_sharded)
 
-        kp = read(ck).reshape(pb, bs, kvh, hd)
-        vp = read(cv).reshape(pb, bs, kvh, hd)
-        if quant:
-            kp = _dequant_kv(kp, read(cks), k.dtype)
-            vp = _dequant_kv(vp, read(cvs), v.dtype)
-        kp = kp.reshape(1, pb * bs, kvh, hd).astype(k.dtype)
-        vp = vp.reshape(1, pb * bs, kvh, hd).astype(v.dtype)
-        k_all = jnp.concatenate([kp, k], axis=1)  # (1, P+Ls, kv, hd)
-        v_all = jnp.concatenate([vp, v], axis=1)
-        kf = _gqa_repeat(k_all, cfg.n_heads)
-        vf = _gqa_repeat(v_all, cfg.n_heads)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, kf) * scale
-        s = jnp.where(mask[None, None], s, jnp.finfo(s.dtype).min)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
-        proj = jnp.einsum("blhd,hde->ble", o, lp["wo"])
-        if ll is not None and "wo" in ll:
-            proj = proj + _lora_delta(o, ll["wo"], aid)
-        x = x + proj
+        with jax.named_scope("attn.paged"):
+            kp = read(ck).reshape(pb, bs, kvh, hd)
+            vp = read(cv).reshape(pb, bs, kvh, hd)
+            if quant:
+                kp = _dequant_kv(kp, read(cks), k.dtype)
+                vp = _dequant_kv(vp, read(cvs), v.dtype)
+            kp = kp.reshape(1, pb * bs, kvh, hd).astype(k.dtype)
+            vp = vp.reshape(1, pb * bs, kvh, hd).astype(v.dtype)
+            k_all = jnp.concatenate([kp, k], axis=1)  # (1, P+Ls, kv, hd)
+            v_all = jnp.concatenate([vp, v], axis=1)
+            kf = _gqa_repeat(k_all, cfg.n_heads)
+            vf = _gqa_repeat(v_all, cfg.n_heads)
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, kf) * scale
+            s = jnp.where(mask[None, None], s, jnp.finfo(s.dtype).min)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+        x = x + _attn_out(o, lp, ll, aid)
         h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
         mlp = _mlp_block(h, lp, ll, aid)
         if quant:
@@ -881,10 +897,10 @@ def prefill_suffix_paged(
     h = jax.lax.dynamic_index_in_dim(
         x[0], length - prefix_len - 1, axis=0, keepdims=False
     )
-    h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
+    logits, h = _head(params, h, cfg)
     if return_hidden:
-        return h @ params["head"], cache, h
-    return h @ params["head"], cache
+        return logits, cache, h
+    return logits, cache
 
 
 def decode_slots_paged(
@@ -1023,19 +1039,7 @@ def _decode_paged_multi(
         x, ck, cv, cks, cvs = carry
         li, lp = inputs[0], inputs[1]
         ll = inputs[2] if lora is not None else None
-        h = _rmsnorm(x, lp["ln_att"], cfg.norm_eps)
-        q = jnp.einsum("ble,ehd->blhd", h, lp["wq"])
-        k = jnp.einsum("ble,ehd->blhd", h, lp["wk"])
-        v = jnp.einsum("ble,ehd->blhd", h, lp["wv"])
-        if ll is not None:
-            if "wq" in ll:
-                q = q + _lora_delta(h, ll["wq"], aid)
-            if "wk" in ll:
-                k = k + _lora_delta(h, ll["wk"], aid)
-            if "wv" in ll:
-                v = v + _lora_delta(h, ll["wv"], aid)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = _qkv(x, lp, cfg, positions, ll, aid)
         if quant:
             qk, sk = _quant_kv(k, sdt)
             qv, sv = _quant_kv(v, sdt)
@@ -1046,54 +1050,52 @@ def _decode_paged_multi(
         else:
             ck = ck.at[li, write_blk, write_off].set(_pool_rows(ck, k))
             cv = cv.at[li, write_blk, write_off].set(_pool_rows(cv, v))
-        if kernel:
-            # fused Pallas read side: table gather + (dequant +) attention
-            # in one VMEM pass over the blocks a live slot holds.  The
-            # kernel is handed the WHOLE carried pool, layers flattened
-            # into blocks (a reshape of leading dimensions: no copy), and
-            # this layer's blocks by offset: a layer cut out of the pool
-            # would be a copy of it (XLA fuses no slice into a kernel's
-            # operand, PERF.md §6)
-            from seldon_core_tpu.ops import paged_decode_attention
+        with jax.named_scope("attn.paged"):
+            if kernel:
+                # fused Pallas read side: table gather + (dequant +) attention
+                # in one VMEM pass over the blocks a live slot holds.  The
+                # kernel is handed the WHOLE carried pool, layers flattened
+                # into blocks (a reshape of leading dimensions: no copy), and
+                # this layer's blocks by offset: a layer cut out of the pool
+                # would be a copy of it (XLA fuses no slice into a kernel's
+                # operand, PERF.md §6)
+                from seldon_core_tpu.ops import paged_decode_attention
 
-            def whole(pool):
-                return pool.reshape((-1,) + pool.shape[2:])
+                def whole(pool):
+                    return pool.reshape((-1,) + pool.shape[2:])
 
-            o = paged_decode_attention(
-                q, whole(ck), whole(cv), read_idx + li * ck.shape[1], pos,
-                k_scale=whole(cks) if quant else None,
-                v_scale=whole(cvs) if quant else None,
-                active=active,
-            )
-        else:
-            # gather each slot's visible blocks:
-            # (S, wb, bs, kv, hd) -> (S, W, ..)
-            def read(pool):
-                return _pool_read(pool, li, read_idx, kv_sharded)
+                o = paged_decode_attention(
+                    q, whole(ck), whole(cv), read_idx + li * ck.shape[1], pos,
+                    k_scale=whole(cks) if quant else None,
+                    v_scale=whole(cvs) if quant else None,
+                    active=active,
+                )
+            else:
+                # gather each slot's visible blocks:
+                # (S, wb, bs, kv, hd) -> (S, W, ..)
+                def read(pool):
+                    return _pool_read(pool, li, read_idx, kv_sharded)
 
-            kw = read(ck).reshape(S, wb, bs, kv, hd)
-            vw = read(cv).reshape(S, wb, bs, kv, hd)
-            if quant:
-                kw = _dequant_kv(kw, read(cks), q.dtype)
-                vw = _dequant_kv(vw, read(cvs), q.dtype)
-            kw = kw.reshape(S, W, kv, hd)
-            vw = vw.reshape(S, W, kv, hd)
-            # grouped-query attention against the *un-repeated* cache:
-            # repeating kv to n_heads here would multiply cache reads by the
-            # group size every decode step, defeating GQA's bandwidth savings
-            groups = cfg.n_heads // cfg.n_kv_heads
-            qg = q.reshape(S, L, cfg.n_kv_heads, groups, cfg.head_dim)
-            s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kw) * scale
-            s = jnp.where(
-                valid[:, None, None, :, :], s, jnp.finfo(s.dtype).min
-            )
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bkgqs,bskd->bqkgd", p, vw)
-            o = o.reshape(S, L, cfg.n_heads, cfg.head_dim)
-        proj = jnp.einsum("blhd,hde->ble", o, lp["wo"])
-        if ll is not None and "wo" in ll:
-            proj = proj + _lora_delta(o, ll["wo"], aid)
-        x = x + proj
+                kw = read(ck).reshape(S, wb, bs, kv, hd)
+                vw = read(cv).reshape(S, wb, bs, kv, hd)
+                if quant:
+                    kw = _dequant_kv(kw, read(cks), q.dtype)
+                    vw = _dequant_kv(vw, read(cvs), q.dtype)
+                kw = kw.reshape(S, W, kv, hd)
+                vw = vw.reshape(S, W, kv, hd)
+                # grouped-query attention against the *un-repeated* cache:
+                # repeating kv to n_heads here would multiply cache reads by the
+                # group size every decode step, defeating GQA's bandwidth savings
+                groups = cfg.n_heads // cfg.n_kv_heads
+                qg = q.reshape(S, L, cfg.n_kv_heads, groups, cfg.head_dim)
+                s = jnp.einsum("bqkgd,bskd->bkgqs", qg, kw) * scale
+                s = jnp.where(
+                    valid[:, None, None, :, :], s, jnp.finfo(s.dtype).min
+                )
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bkgqs,bskd->bqkgd", p, vw)
+                o = o.reshape(S, L, cfg.n_heads, cfg.head_dim)
+        x = x + _attn_out(o, lp, ll, aid)
         h = _rmsnorm(x, lp["ln_mlp"], cfg.norm_eps)
         mlp = _mlp_block(h, lp, ll, aid)
         return (x + mlp, ck, cv, cks, cvs), None
@@ -1118,10 +1120,10 @@ def _decode_paged_multi(
     if quant:
         out["k_scale"] = new_ks
         out["v_scale"] = new_vs
-    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    logits, x = _head(params, x, cfg)
     if return_hidden:
-        return x @ params["head"], out, x
-    return x @ params["head"], out
+        return logits, out, x
+    return logits, out
 
 
 # ---------------------------------------------------------------------------
@@ -1274,8 +1276,7 @@ def decode_slots(
         "v": new_v,
         "pos": jnp.where(active, pos + 1, pos),
     }
-    x = _rmsnorm(x[:, 0], params["ln_f"], cfg.norm_eps)
-    return x @ params["head"], cache
+    return _head(params, x[:, 0], cfg)[0], cache
 
 
 def sample_tokens(

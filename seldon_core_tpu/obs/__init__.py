@@ -10,14 +10,19 @@ from __future__ import annotations
 
 from seldon_core_tpu.obs.spans import (  # noqa: F401
     RECORDER,
+    STAGE_ADMIT_ROUND,
     STAGE_BATCH_ASSEMBLY,
     STAGE_DEVICE_DISPATCH,
     STAGE_DEVICE_STEP,
     STAGE_ENGINE_ROUTE,
+    STAGE_FIRST_WRITE,
     STAGE_GATEWAY_RELAY,
+    STAGE_INGRESS,
     STAGE_NODE,
     STAGE_QUEUE_WAIT,
+    STAGE_SLOT_WAIT,
     STAGE_STREAM_FLUSH,
+    STAGE_SYNC_POINT,
     STAGE_TTFT,
     STAGES,
     Span,

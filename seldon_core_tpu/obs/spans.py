@@ -12,8 +12,10 @@ layer for the TPU serving hot path, with no OTel SDK dependency:
   PROPAGATION is never sampled away, so downstream hops always correlate.
 * **stages** — the flight recorder: fixed-vocabulary per-stage duration
   rings (gateway-relay / engine-route / node / queue-wait / batch-assembly
-  / device-step / stream-flush / ttft) that answer "where did the p99 go"
-  without reconstructing traces.  Stage recording is unconditional and
+  / device-step / stream-flush / ttft, and the parts of a generated
+  request's host path: ingress / slot-wait / admit-round / first-write /
+  sync-point) that answer "where did the p99 go" without reconstructing
+  traces.  Stage recording is unconditional and
   cheap (one deque append), including from executor threads.
 
 Both are served by ``GET /stats/spans`` and ``GET /stats/breakdown`` on the
@@ -51,6 +53,14 @@ STAGE_DEVICE_STEP = "device-step"
 STAGE_DEVICE_DISPATCH = "device-dispatch"
 STAGE_STREAM_FLUSH = "stream-flush"
 STAGE_TTFT = "ttft"
+# the host path of a generated request, part by part (generation scheduler
+# and the engine's stream handler): what ``ttft`` and the gap between two
+# decode blocks are made of
+STAGE_SLOT_WAIT = "slot-wait"
+STAGE_ADMIT_ROUND = "admit-round"
+STAGE_SYNC_POINT = "sync-point"
+STAGE_INGRESS = "ingress"
+STAGE_FIRST_WRITE = "first-write"
 
 STAGES = (
     STAGE_GATEWAY_RELAY,
@@ -62,6 +72,11 @@ STAGES = (
     STAGE_DEVICE_DISPATCH,
     STAGE_STREAM_FLUSH,
     STAGE_TTFT,
+    STAGE_SLOT_WAIT,
+    STAGE_ADMIT_ROUND,
+    STAGE_SYNC_POINT,
+    STAGE_INGRESS,
+    STAGE_FIRST_WRITE,
 )
 
 

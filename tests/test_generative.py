@@ -754,6 +754,57 @@ class TestOverlapPinnedEqual:
         assert int(out[0]) in top
 
 
+import contextlib
+
+
+@contextlib.contextmanager
+def _parts_entered(monkeypatch):
+    """Every ``jax.profiler.TraceAnnotation`` entered meanwhile, by name and
+    in order: the scheduler's and the handler's parts, and the model's
+    dispatch labels between them."""
+    import jax
+
+    entered: list[str] = []
+
+    class Recorded:
+        def __init__(self, name, **note):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    with monkeypatch.context() as m:
+        m.setattr(jax.profiler, "TraceAnnotation", Recorded)
+        yield entered
+
+
+def _by_boundary(entered: list[str]) -> list[list[str]]:
+    """The parts entered, cut after each ``sched:deliver`` (one a fetched
+    block: a boundary's decision, its fetch and its delivery)."""
+    out: list[list[str]] = [[]]
+    for name in entered:
+        out[-1].append(name)
+        if name == "sched:deliver":
+            out.append([])
+    return out
+
+
+def _stage_counts() -> dict:
+    """Samples recorded so far, by stage of the flight recorder."""
+    from seldon_core_tpu.obs import RECORDER, STAGES
+
+    seen = RECORDER.breakdown()
+    return {s: seen.get(s, {}).get("count", 0) for s in STAGES}
+
+
+def _stage_delta(before: dict) -> dict:
+    return {s: n - before[s] for s, n in _stage_counts().items() if n != before[s]}
+
+
 class TestBlockBoundaryRule:
     """When block N+1 is chained off block N's device carry is decided from
     what could be admitted at N's end (docs/PERFORMANCE.md §1): a full house
@@ -927,33 +978,68 @@ class TestBlockBoundaryRule:
         s1, s2 = self._serve(seq, [[5, 9, 2], [30, 7]], max_new=9)
         assert np.array_equal(o1, s1) and np.array_equal(o2, s2[:5])
 
-    def test_a_late_estimate_gives_way_to_the_tokens(self):
+    def test_a_late_estimate_gives_way_to_the_tokens(self, monkeypatch):
         """The held decision falls when the block in flight is expected to
-        end; blocks that turn ten times shorter end long before that, so
-        the fetch comes back first, the next block is chained with the
-        tokens in hand, and the estimate starts again from that block:
-        the hold never makes the chip wait for a stale estimate."""
+        end; a block that ends long before that brings its tokens back
+        first, the next block is chained with the tokens in hand, and the
+        estimate starts again from that block: the hold never makes the
+        chip wait for a stale estimate.  Held to the boundaries' outcomes
+        and the order of their parts, not to the clock: a block meant to
+        outlast its hold does not hand its tokens over until its successor
+        has been dispatched, and the one meant to end early hands them
+        over at once."""
+        import threading
         import time
 
         comp = self._component("llama", True)
         comp.model.warmup()
         sched, model = comp.scheduler, comp.model
-        fetch = model.step_k_fetch
-        handed_over = []
+        fetch, chain = model.step_k_fetch, model.step_k_continue
+        chained = threading.Condition()
+        fetched = continued = 0
+        outlasts_its_hold = {2, 3, 5, 6, 7, 8, 9}  # of ten blocks
 
-        def fetch_at_a_pace(handle):
-            time.sleep(0.2 if len(handed_over) < 3 else 0.02)
-            out = fetch(handle)
-            handed_over.append(time.perf_counter())
+        def counted_chain(*args, **kw):
+            nonlocal continued
+            out = chain(*args, **kw)
+            with chained:
+                continued += 1
+                chained.notify_all()
             return out
 
+        def fetch_at_a_pace(handle):
+            nonlocal fetched
+            fetched += 1
+            n = fetched
+            if n == 1:
+                time.sleep(0.2)  # the first estimate: long beside a hop
+            elif n in outlasts_its_hold:
+                with chained:  # block n + 1 is the n-th chained dispatch
+                    assert chained.wait_for(lambda: continued >= n, timeout=30)
+            return fetch(handle)
+
         model.step_k_fetch = fetch_at_a_pace
-        out = self._serve(comp, [[5, 9, 2]], max_new=41)[0]  # ten blocks
-        assert out.size == 41 and len(handed_over) == 10
-        snap = sched.boundary_snapshot()
-        assert snap["chained_late"] >= 1 and snap["chained_due"] >= 4, snap
-        # the seven short blocks took their own time, not the long ones'
-        assert handed_over[-1] - handed_over[2] < 7 * 0.02 + 0.2
+        model.step_k_continue = counted_chain
+        with _parts_entered(monkeypatch) as entered:
+            out = self._serve(comp, [[5, 9, 2]], max_new=41)[0]  # ten blocks
+        assert out.size == 41 and fetched == 10
+        # block 1 has no estimate to hold by and block 4 ends before its
+        # hold does: both chain with the tokens in hand; the others fall
+        # due, the three after block 4 by the estimate that block left
+        assert sched.boundary_snapshot() == {
+            "chained_early": 0, "chained_due": 7, "chained_late": 2,
+            "idle": 1, "sync": {},
+        }
+        kinds = [
+            [p for p in b if p in ("sched:hold", "sched:chain", "sched:fetch")]
+            for b in _by_boundary(entered)
+        ]
+        due = ["sched:hold", "sched:chain", "sched:fetch"]
+        gave_way = ["sched:hold", "sched:fetch", "sched:chain"]
+        assert kinds[:10] == [
+            ["sched:fetch", "sched:chain"], due, due, gave_way,
+            due, due, due, due, due, ["sched:fetch"],
+        ], kinds
         seq = self._serve(self._component("llama", False), [[5, 9, 2]], max_new=41)[0]
         assert np.array_equal(out, seq)
 
@@ -999,6 +1085,176 @@ class TestBlockBoundaryRule:
                 assert np.array_equal(a, b), (a.tolist(), b.tolist())
             snap = comp.scheduler.boundary_snapshot()
             assert (snap["chained_early"] > 0) is early, (max_new, snap)
+
+
+class TestHostPathStages:
+    """The block boundary and a request's way to its first byte are timed
+    where they happen (docs/OBSERVABILITY.md, the flight recorder's
+    ``slot-wait`` / ``admit-round`` / ``sync-point`` / ``ingress`` /
+    ``first-write`` and the ``sched:*`` parts on the profiler's clock).
+    Presence and order only: no test here reads a duration's size."""
+
+    ADDED = {
+        "sched:fetch", "sched:deliver", "sched:admit", "sched:advance-prefill",
+        "sched:embeds", "sched:dispatch", "sched:chain", "sched:hold",
+        "idle-park", "engine:first-write",
+    }
+
+    @staticmethod
+    def _trace_label():
+        """``benchmark/trace.py``'s ``LABEL``: the names its reduction pairs
+        with device programs in dispatch order."""
+        import importlib.util
+        import os
+
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "trace.py",
+        )
+        spec = importlib.util.spec_from_file_location("bench_trace", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod.LABEL
+
+    def test_a_boundary_that_admits_leaves_one_sync_point_and_a_chained_one_none(
+        self, monkeypatch
+    ):
+        """Four requests on two slots with fixed budgets: two admission
+        rounds, four waits for a slot, six boundaries chained (no sync
+        point), one that admits the second wave (exactly one), one that
+        dispatches nothing (none); ``queue-wait`` belongs to the batcher
+        and the QoS estimate, and a generative request leaves it alone."""
+        rule = TestBlockBoundaryRule
+        comp = rule._component("llama", True)
+        before = _stage_counts()
+        with _parts_entered(monkeypatch) as entered:
+            rule._serve(comp, rule.PROMPTS, max_new=14)
+        assert comp.scheduler.boundary_snapshot() == {
+            "chained_early": 6, "chained_due": 0, "chained_late": 0,
+            "idle": 1, "sync": {"admission": 1},
+        }
+        got = _stage_delta(before)
+        assert got.pop("ttft") == 4 and got.pop("device-step") == 8
+        assert got == {"slot-wait": 4, "admit-round": 2, "sync-point": 1}
+        # at the sync point the parts come in the order the loop runs them
+        sched = [p for p in entered if p.startswith("sched:")]
+        at = sched.index("sched:admit", 1)  # the second round's
+        assert sched[at - 2:at + 2] == [
+            "sched:fetch", "sched:deliver", "sched:admit", "sched:dispatch"
+        ], sched
+        # a chained boundary: the next block goes out before this one's fetch
+        assert sched[2:5] == ["sched:chain", "sched:fetch", "sched:deliver"], sched
+        # no name this adds is one the trace reduction pairs with a program
+        label = self._trace_label()
+        added = {p for p in entered if not label.match(p)}
+        assert added and added <= self.ADDED, added
+        assert any(label.match(p) for p in entered)  # the dispatch labels stay
+
+    def test_the_sequential_loop_meets_a_sync_point_at_every_block(self):
+        rule = TestBlockBoundaryRule
+        comp = rule._component("llama", False)
+        before = _stage_counts()
+        rule._serve(comp, rule.PROMPTS[:2], max_new=6)
+        got = _stage_delta(before)
+        # blocks of 4 and 1 after the prefill's token: the first boundary is
+        # followed by a dispatch, the last by none
+        assert got["sync-point"] == 1 and got["admit-round"] == 1
+        assert got["slot-wait"] == 2 and "queue-wait" not in got
+
+    def test_a_served_stream_leaves_one_sample_of_each_request_stage(
+        self, monkeypatch
+    ):
+        """Through the engine's handler: one ``ingress``, one
+        ``slot-wait``, one ``first-write`` and one ``admit-round`` a
+        stream, each request-scoped one on the request's timeline too, in
+        the order they happen."""
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.app import EngineApp
+        from seldon_core_tpu.engine.service import PredictionService
+        from seldon_core_tpu.graph.spec import PredictorSpec
+        from seldon_core_tpu.utils.tracectx import new_traceparent, parse_traceparent
+
+        tp = new_traceparent(sampled=True)
+        tid = parse_traceparent(tp)[0]
+
+        async def go():
+            service = PredictionService(
+                PredictorSpec.model_validate(TestStreaming.PREDICTOR)
+            )
+            client = TestClient(TestServer(EngineApp(service).build()))
+            await client.start_server()
+            try:
+                before = _stage_counts()
+                resp = await client.post(
+                    "/api/v0.1/predictions/stream",
+                    json={"tokens": [5, 9, 2, 17]}, headers={"traceparent": tp},
+                )
+                assert resp.status == 200, await resp.text()
+                await resp.read()
+                got = _stage_delta(before)
+                tl = await (await client.get(f"/stats/timeline?trace={tid}")).json()
+                bd = await (await client.get("/stats/breakdown")).json()
+                return got, tl["timeline"], bd
+            finally:
+                await client.close()
+
+        with _parts_entered(monkeypatch) as entered:
+            got, timeline, breakdown = run(go())
+        for stage in ("ingress", "slot-wait", "admit-round", "first-write"):
+            assert got.get(stage) == 1, (stage, got)
+        assert "queue-wait" not in got and "sync-point" not in got
+        assert entered.count("engine:first-write") == 1
+        names = [e["name"] for e in timeline[-1]["events"]]
+        assert names[0] == "queued" and names[-1] == "terminal"
+        order = [n for n in names if n in (
+            "ingress", "slot-wait", "admit", "first-write", "terminal"
+        )]
+        assert order == ["ingress", "slot-wait", "admit", "first-write", "terminal"]
+        for e in timeline[-1]["events"]:
+            if e["name"] in ("ingress", "slot-wait", "first-write"):
+                assert e["attrs"]["ms"] >= 0
+        # and the stall ledger is there, empty
+        (unit,) = breakdown["generation"].values()
+        assert unit["stalls"] == {"count": 0, "longest_s": 0.0, "last_part": None}
+
+    def test_a_stall_names_the_part_and_an_idle_park_none(self, caplog, monkeypatch):
+        """A fetch that takes 1.3 s with a slot live: one line under 1,200
+        characters that names ``sched:fetch``, one more when it ends, and
+        the ledger counts it.  (The park of an idle scheduler is no stall:
+        tests/test_obs.py::TestStallWatchdog.)"""
+        import logging
+        import time
+
+        from seldon_core_tpu.obs import stall
+
+        # a look every 50 ms: six of them fall inside the 0.3 s the fetch
+        # lasts past the second, however late a loaded host wakes a thread
+        monkeypatch.setattr(stall, "WAKE_S", 0.05)
+        rule = TestBlockBoundaryRule
+        comp = rule._component("llama", True)
+        comp.model.warmup()
+        fetch = comp.model.step_k_fetch
+
+        def slow_fetch(handle):
+            time.sleep(1.3)
+            return fetch(handle)
+
+        comp.model.step_k_fetch = slow_fetch
+        with caplog.at_level(logging.WARNING, logger="seldon_core_tpu.obs.stall"):
+            (out,) = rule._serve(comp, [[5, 9, 2]], max_new=4)  # one block
+        assert out.size == 4
+        lines = [r.getMessage() for r in caplog.records
+                 if r.name == "seldon_core_tpu.obs.stall"]
+        first = [ln for ln in lines if ln.startswith("stall unit=")]
+        ended = [ln for ln in lines if ln.startswith("stall-end unit=")]
+        assert len(first) == 1 and len(first[0]) < 1200, lines
+        assert " part=sched:fetch " in first[0] and "slow_fetch" in first[0]
+        for field in ("watchdog_late=", "loop_lag_last=", "gc_full=", "loop=["):
+            assert field in first[0], first[0]
+        assert len(ended) <= 1 and all("part=sched:fetch" in ln for ln in ended)
+        snap = comp.scheduler.stall_snapshot()
+        assert snap["count"] == 1 and snap["last_part"] == "sched:fetch"
 
 
 class TestStreaming:

@@ -714,6 +714,134 @@ class TestProfilerLifecycle:
         assert captured, "jax.profiler produced no trace files"
 
 
+class TestProfilerOffTheLoop:
+    def test_stop_answers_beside_other_requests_and_conflicts_hold(
+        self, tmp_path, monkeypatch
+    ):
+        """``stop_trace`` collects and writes the whole trace: it runs on a
+        thread, so a ``/ready`` issued while it is at work is answered
+        before it returns; a start while a trace runs, a start while it is
+        being stopped and a second stop are each a 409."""
+        import threading
+
+        import jax
+
+        began, release = threading.Event(), threading.Event()
+        on_threads = []
+
+        def start_trace(out_dir):
+            on_threads.append(threading.current_thread().name)
+
+        def stop_trace():
+            on_threads.append(threading.current_thread().name)
+            began.set()
+            release.wait(30)
+
+        monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+        monkeypatch.setattr(jax.profiler, "stop_trace", stop_trace)
+        target = str(tmp_path / "capture")
+
+        async def go():
+            client = await _engine_client()
+            loop_thread = threading.current_thread().name
+            try:
+                r1 = await client.post("/profile/start", json={"dir": target})
+                r2 = await client.post("/profile/start", json={"dir": target})
+                stop = asyncio.ensure_future(client.post("/profile/stop"))
+                while not began.is_set():
+                    await asyncio.sleep(0.005)
+                ready = await client.get("/ready")
+                await ready.read()
+                in_flight = not stop.done()  # the trace is still being written
+                r3 = await client.post("/profile/start", json={"dir": target})
+                r4 = await client.post("/profile/stop")
+                release.set()
+                r5 = await stop
+                r6 = await client.post("/profile/stop")
+                return (loop_thread, in_flight,
+                        [r.status for r in (r1, r2, r3, r4, r5, r6)])
+            finally:
+                release.set()
+                await client.close()
+
+        loop_thread, in_flight, statuses = run(go())
+        assert in_flight, "/ready waited for stop_trace: it ran on the loop"
+        assert statuses == [200, 409, 409, 409, 200, 409]
+        assert len(on_threads) == 2 and loop_thread not in on_threads
+
+
+class TestStallWatchdog:
+    """obs/stall.py on hand-made states: what the watchdog writes for a
+    part that lasts, for a park, and for a wake of its own that came late
+    (tests/test_generative.py serves a slow fetch through it)."""
+
+    @staticmethod
+    def _lines(caplog):
+        return [r.getMessage() for r in caplog.records
+                if r.name == "seldon_core_tpu.obs.stall"]
+
+    def _watchdog(self, state):
+        import threading
+
+        from seldon_core_tpu.obs.stall import StallWatchdog
+
+        wd = StallWatchdog("unit", lambda: state[0])
+        wd._loop_thread = threading.get_ident()
+        return wd
+
+    def test_a_part_that_lasts_is_one_line_and_one_more_at_its_end(self, caplog):
+        import logging
+
+        state = [("sched:fetch", 100.0, True)]
+        wd = self._watchdog(state)
+        with caplog.at_level(logging.WARNING, logger="seldon_core_tpu.obs.stall"):
+            wd._look(100.9, 0.0)   # under a second: nothing yet
+            wd._look(101.2, 0.001)
+            wd._look(101.45, 0.0)  # the same stall goes on: no second line
+            state[0] = ("sched:loop", 102.5, True)
+            wd._look(102.6, 0.0)
+            wd._look(102.85, 0.0)
+        first, end = self._lines(caplog)
+        assert first.startswith("stall unit=unit part=sched:fetch for=1.200s ")
+        assert "watchdog_late=0.001s " in first and len(first) < 1200
+        assert "test_obs.py" in first  # this thread stands in for the loop's
+        assert end == "stall-end unit=unit part=sched:fetch lasted=2.500s"
+        assert wd.snapshot() == {
+            "count": 1, "longest_s": 2.5, "last_part": "sched:fetch"
+        }
+
+    def test_a_park_or_a_scheduler_with_nothing_to_do_is_no_stall(self, caplog):
+        import logging
+
+        state = [("idle-park", 100.0, True)]
+        wd = self._watchdog(state)
+        with caplog.at_level(logging.WARNING, logger="seldon_core_tpu.obs.stall"):
+            wd._look(160.0, 0.0)
+            state[0] = ("sched:loop", 100.0, False)  # no slot live, nobody waits
+            wd._look(160.0, 0.0)
+        assert not self._lines(caplog) and wd.snapshot()["count"] == 0
+
+    def test_a_late_wake_says_the_whole_process_stood_still(self, caplog):
+        import logging
+
+        # the loop moved on before the watchdog could look: the part is new
+        state = [("sched:deliver", 103.99, True)]
+        wd = self._watchdog(state)
+        with caplog.at_level(logging.WARNING, logger="seldon_core_tpu.obs.stall"):
+            wd._look(104.0, 2.75)
+        (line,) = self._lines(caplog)
+        assert "part=sched:deliver for=2.750s watchdog_late=2.750s" in line
+        assert "the whole process stood still" in line
+        assert wd.snapshot()["count"] == 1
+
+    def test_the_line_stays_under_its_limit_whatever_the_threads(self, monkeypatch):
+        from seldon_core_tpu.obs import stall
+
+        monkeypatch.setattr(stall, "_where", lambda frame, depth=1: "x" * 400)
+        wd = self._watchdog([("sched:admit", 0.0, True)])
+        assert len(wd._line("sched:admit", 1.5, 0.0)) < stall.LINE_MAX
+
+
 class TestAlwaysOnProbes:
     def test_eventloop_lag_and_drop_gauges_in_prometheus(self):
         """The always-on counters are scrapeable: event-loop lag gauge
