@@ -36,17 +36,25 @@ top-``topk``, the router's softmax and top-k run in float32
 a key or an expert, which is not rounding noise).
 
 The paged pool is uniform and holds a THIRD per-token array beside K and V:
-the index keys ``ik (layers, blocks, block, DI)`` under the same table, so
-prefix reuse shares a block's index keys with its K/V.  Prefill, suffix
-prefill and decode write a token's index key where they write its K/V.
+the index keys ``ik (layers, blocks, DI, block)`` under the same table (a
+block transposed, its tokens along the lanes: ``init_paged_cache``; only
+``_ik_write``, ``_ik_by_token`` and ``_select_rows`` know which way round a
+block lies), so prefix reuse shares a block's index keys with its K/V.
+Prefill, suffix prefill and decode write a token's index key where they
+write its K/V.
 
-Decode, a layer: score the slot's index keys up to ``pos`` (an XLA gather
-of the table's blocks: 128 B a key), take the exact top-``topk``
-(``lax.top_k``), and attend over those rows of the pool alone, addressed
-through the table (``ops/sparse_attention.py::sparse_decode_attention``: an
-XLA gather of the rows; Mosaic takes no copy of one row of a tiled pool).
-A static window of ``topk`` or fewer goes the dense way (with ``kernel``
-through ``ops/paged_attention.py``).  Prefill
+Decode, a layer: with ``kernel`` the selection is one Pallas kernel, a slot
+a grid step (``ops/sparse_attention.py::select_decode_topk``): the slot's
+LIVE blocks of index keys copied from the pool by table entry, scored in
+VMEM, the ``topk``-th score found by bisection, the selected positions
+packed to the front, ascending; nothing but the positions goes to HBM.
+Without it, the XLA lines the kernel is held to: the whole static window's
+keys gathered, scored, masked past ``pos`` and sorted (``lax.top_k``).
+Either way the slot then attends those rows of the pool alone, addressed
+through the table (``sparse_decode_attention``: an XLA gather of the rows;
+Mosaic takes no copy of one row of a tiled pool).  A static window of
+``topk`` or fewer goes the dense way (with ``kernel`` through
+``ops/paged_attention.py``).  Prefill
 (``seq_impl="flash"``): a rung of ``topk`` or fewer is causal attention
 (every key is selected); a longer one takes its queries ``QUERY_CHUNK`` at a
 time — ``select_topk_mask`` makes a chunk's selection (scores in tiles that
@@ -62,7 +70,7 @@ prompt; in a decode step the kernel that streams only the experts some token
 chose (``_experts_touched``: 8 slots x top-8 touch a third of 128), and
 ``_experts_dense`` where a step would touch nearly all of them anyway.
 ``experts_held`` means what it means there.  ``COUNTERS`` keeps that
-family's names and adds the selection's four.
+family's names and adds the selection's five.
 """
 
 from __future__ import annotations
@@ -90,6 +98,8 @@ from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
 from seldon_core_tpu.ops.sparse_attention import (
     index_scores,
     masked_flash_attention,
+    select_decode_topk,
+    select_decode_topk_reference,
     select_topk_mask,
     sparse_decode_attention,
 )
@@ -107,10 +117,11 @@ COUNTERS = _MOE_COUNTERS + (
     "dsa.keys_selected",          # decode: keys attended, likewise
     "dsa.prefill_keys_scored",    # prefill: (query, key) pairs scored, in units of 1,024, layers summed
     "dsa.prefill_keys_selected",  # prefill: pairs attended, likewise
+    "dsa.key_blocks_read",        # decode: pool blocks of index keys read, as the selection itself counts them, likewise
 )
 _STEPS, _P_TOKENS = 4, 7  # "moe.steps", "moe.prefill_tokens"
-_SCORED, _SELECTED, _P_SCORED, _P_SELECTED = range(
-    len(_MOE_COUNTERS), len(_MOE_COUNTERS) + 4
+_SCORED, _SELECTED, _P_SCORED, _P_SELECTED, _BLOCKS_READ = range(
+    len(_MOE_COUNTERS), len(_MOE_COUNTERS) + 5
 )
 # per-token arrays of the paged pool beside "k" and "v"
 POOL_EXTRA = ("ik",)
@@ -395,39 +406,61 @@ def _attend_prompt(q, k, v, index, cfg: Config, seq_impl: str):
     return _attend(q, k, v, index, pos, pos, jnp.ones(pos.shape, bool), cfg)
 
 
-def _decode_attention(q, qi, wi, ck, cv, cik, li, read_blk, pos, active, n_sel,
-                      cfg: Config, *, sparse: bool, kernel: bool):
-    """One decode query a slot over layer ``li`` of the pools ``ck``, ``cv``,
-    ``cik (layers, blocks, block, ...)``, the step's own token written
-    already.  ``q (S, 1, H, D)``, ``qi (S, 1, HI, DI)``, ``wi (S, 1, HI)``;
-    ``read_blk (S, wb)`` the table's blocks of the static window.
-    ``sparse``: score the window's index keys, take the exact top
-    ``index_topk`` and attend those rows alone; else every seen key, through
-    the paged kernel with ``kernel``.  Returns ``(S, H, D)``."""
+def _ik_write(cik, li, blk, ki, off=None):
+    """Write index keys into layer ``li`` of their pool AS IT IS CARRIED,
+    ``(layers, blocks, DI, block)``: a block transposed, its tokens along
+    the lanes (``init_paged_cache`` says why).  Whole blocks ``blk`` from
+    ``ki (len(blk) * block, DI)``, a prompt's rows; or with ``off`` one
+    token a block, ``ki (S, DI)`` at ``blk[s], off[s]``: a decode step's."""
+    ki = ki.astype(cik.dtype)
+    if off is not None:
+        return cik.at[li, blk, :, off].set(ki)
+    blocks = ki.reshape(-1, cik.shape[3], ki.shape[-1])
+    return cik.at[li, blk].set(_ik_by_token(blocks))
+
+
+def _ik_by_token(ik):
+    """Blocks of index keys as the pool carries them, ``(..., DI, block)``,
+    seen by token, ``(..., block, DI)`` — and back: its own inverse.  With
+    ``_ik_write`` and the kernel's view of a block (``_select_rows``), all
+    that knows which way round a block lies."""
+    return jnp.swapaxes(ik, -1, -2)
+
+
+def _select_rows(qi, wi, cik, li, read_blk, pos, active, cfg: Config, *,
+                 kernel: bool):
+    """A decode step's selection on layer ``li``: ``(rows (S, index_topk)
+    int32, blocks_read (S,) int32)`` — the pool rows (``ck.reshape(-1,
+    kvd)``'s) of the exact top ``index_topk`` seen keys of each slot, and
+    the blocks of index keys read to find them.  ``cik`` the index keys'
+    pool as carried.  ``kernel``: one Pallas kernel over each slot's LIVE
+    blocks, rows by position; else the XLA lines over the whole static
+    window, best first."""
+    nb, di, bs = cik.shape[1:]
+    with jax.named_scope("attn.select"):
+        select = select_decode_topk if kernel else select_decode_topk_reference
+        return select(
+            qi[:, 0].astype(cik.dtype), wi[:, 0], cik.reshape((-1, di, bs)),
+            read_blk + li * nb, jnp.where(active, pos, -1),
+            topk=cfg.index_topk, score_dtype=_score_dtype(cfg),
+        )
+
+
+def _decode_read(q, ck, cv, li, read_blk, pos, active, n_sel, rows, *,
+                 kernel: bool):
+    """One decode query a slot over layer ``li`` of the pools ``ck``, ``cv``
+    ``(layers, blocks, block, kvd)``: the first ``n_sel`` of the selected
+    pool ``rows`` alone; with ``rows`` None every seen key, through the paged
+    kernel with ``kernel``.  ``q (S, 1, H, D)`` -> ``(S, H, D)``."""
     S = q.shape[0]
     nb, bs, kvd = ck.shape[1:]
-    wb = read_blk.shape[1]
-    W = wb * bs
     flat = (ck.shape[0] * nb * bs, kvd)
-    if sparse:
-        with jax.named_scope("attn.index"):
-            keys = cik[li, read_blk].reshape(S, W, -1)
-            scores = jax.vmap(
-                lambda a, b, c: index_scores(a, b, c, _score_dtype(cfg))
-            )(qi.astype(cik.dtype), wi, keys)[:, 0]
-            scores = jnp.where(
-                jnp.arange(W)[None, :] <= pos[:, None], scores, -jnp.inf
-            )
-        with jax.named_scope("attn.select"):
-            _, idx = lax.top_k(scores, cfg.index_topk)  # (S, topk), best first
-            blk = jnp.take_along_axis(read_blk, idx // bs, axis=1)
-            rows = (blk + li * nb) * bs + idx % bs
-    elif not kernel:
+    if rows is None and not kernel:
         rows = (
             ((read_blk + li * nb) * bs)[:, :, None] + jnp.arange(bs)
-        ).reshape(S, W)
+        ).reshape(S, -1)
     with jax.named_scope("attn.sparse"):
-        if sparse or not kernel:
+        if rows is not None:
             return sparse_decode_attention(
                 q[:, 0], ck.reshape(flat), cv.reshape(flat), rows, n_sel
             )
@@ -437,6 +470,27 @@ def _decode_attention(q, qi, wi, ck, cv, cik, li, read_blk, pos, active, n_sel,
             q, ck.reshape((-1, bs, kvd)), cv.reshape((-1, bs, kvd)),
             read_blk + li * nb, pos, active=active,
         )[:, 0]
+
+
+def _decode_attention(q, qi, wi, ck, cv, cik, li, read_blk, pos, active, n_sel,
+                      cfg: Config, *, sparse: bool, kernel: bool):
+    """One decode query a slot over layer ``li`` of the pools ``ck``, ``cv``,
+    ``cik (layers, blocks, block, ...)`` — every pool BY TOKEN — the step's
+    own token written already.  ``q (S, 1, H, D)``, ``qi (S, 1, HI, DI)``,
+    ``wi (S, 1, HI)``; ``read_blk (S, wb)`` the table's blocks of the static
+    window.  ``sparse``: ``_select_rows`` then ``_decode_read`` of those
+    rows; else every seen key.  Returns ``(S, H, D)``.  The two parts as
+    ``decode_slots_paged`` composes them, for a caller that holds its index
+    keys by token (the benchmark's reference kind): the served step calls
+    the parts on the pool as it carries it."""
+    rows = None
+    if sparse:
+        rows, _ = _select_rows(
+            qi, wi, _ik_by_token(cik), li, read_blk, pos, active, cfg, kernel=kernel
+        )
+    return _decode_read(
+        q, ck, cv, li, read_blk, pos, active, n_sel, rows, kernel=kernel
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -586,8 +640,12 @@ def init_paged_cache(
     """The uniform pool of ``models/llama.py`` with a third per-token array:
     ``k`` and ``v`` ``(layers, blocks, block_size, kv_heads * head_dim)``, a
     row holding its kv heads side by side, and the index keys ``ik (layers,
-    blocks, block_size, index_dim)``, all under one table.  ``counters`` are
-    ``COUNTERS``, uint32, wrapping."""
+    blocks, index_dim, block_size)``, all under one table.  A block of index
+    keys is carried TRANSPOSED, its tokens along the lanes: 64 wide by
+    tokens it is no whole tile (XLA keeps it in HBM this way round whatever
+    the shape says), and the decode step's selection copies whole blocks
+    from the pool as they lie (``ops/sparse_attention.py::
+    select_decode_topk``).  ``counters`` are ``COUNTERS``, uint32, wrapping."""
     if kv_dtype is not None:
         raise TypeError(
             f"keye_vl2 has no int8 KV pool (kv_cache_dtype={kv_dtype!r}): the "
@@ -606,7 +664,9 @@ def init_paged_cache(
     return {
         "k": jnp.zeros(rows + (cfg.n_kv_heads * cfg.head_dim,), dtype),
         "v": jnp.zeros(rows + (cfg.n_kv_heads * cfg.head_dim,), dtype),
-        "ik": jnp.zeros(rows + (cfg.index_dim,), dtype),
+        "ik": jnp.zeros(
+            (cfg.n_layers, n_blocks, cfg.index_dim, block_size), dtype
+        ),
         "pos": jnp.zeros((n_slots,), jnp.int32),
         "table": jnp.zeros((n_slots, cfg.max_seq // block_size), jnp.int32),
         "counters": jnp.zeros((len(COUNTERS),), jnp.uint32),
@@ -663,7 +723,7 @@ def prefill_slot_paged(
         qi, wi, ki = _index(h, lp, cfg, pos)
         ck = _write_prompt(ck, li, phys, k, bs)
         cv = _write_prompt(cv, li, phys, v, bs)
-        cik = _write_prompt(cik, li, phys, ki, bs)
+        cik = _ik_write(cik, li, phys, ki)
         index = (qi, wi, ki.astype(cik.dtype)) if cfg.selects else None
         o = _attend_prompt(q, k, v, index, cfg, seq_impl)
         x, ctr = _after_attention(x, o, lp, cfg, real, ctr, decode=False)
@@ -727,17 +787,22 @@ def prefill_suffix_paged(
         q, k, v = _qkv(h, lp, cfg, qpos)
         qi, wi, ki = _index(h, lp, cfg, qpos)
 
-        def behind(pool, new):
-            """[the prefix's rows of ``pool`` ++ the suffix's own, as they
-            will be stored]."""
-            old = pool[li, read_idx].reshape((pb * bs,) + new.shape[1:])
-            return jnp.concatenate([old, new.astype(pool.dtype)]).astype(new.dtype)
+        def behind(old, new):
+            """[the prefix's blocks ``old`` (by token), flattened ++ the
+            suffix's own rows, as they will be stored]."""
+            old = old.reshape((pb * bs,) + new.shape[1:])
+            return jnp.concatenate([old, new.astype(old.dtype)]).astype(new.dtype)
 
-        index = (qi, wi, behind(cik, ki)) if cfg.selects else None
-        o = _attend(q, behind(ck, k), behind(cv, v), index, qpos, kpos, kvalid, cfg)
+        index = None
+        if cfg.selects:
+            index = (qi, wi, behind(_ik_by_token(cik[li, read_idx]), ki))
+        o = _attend(
+            q, behind(ck[li, read_idx], k), behind(cv[li, read_idx], v), index,
+            qpos, kpos, kvalid, cfg,
+        )
         ck = _write_prompt(ck, li, suffix_blocks, k, bs)
         cv = _write_prompt(cv, li, suffix_blocks, v, bs)
-        cik = _write_prompt(cik, li, suffix_blocks, ki, bs)
+        cik = _ik_write(cik, li, suffix_blocks, ki)
         x, ctr = _after_attention(x, o, lp, cfg, real, ctr, decode=False)
         return x, ck, cv, cik, ctr
 
@@ -759,9 +824,11 @@ def decode_slots_paged(
     """One decode step for every slot against the paged cache (the contract
     of ``llama.decode_slots_paged``).  ``window`` (static) bounds the rows
     scored; past ``index_topk`` of them a slot attends the rows it selects
-    (gathered in XLA).  ``kernel`` (static) takes the dense way, a window of
-    ``index_topk`` or fewer, through the Pallas paged decode-attention
-    kernel (``ops/paged_attention.py``)."""
+    (gathered in XLA).  ``kernel`` (static) makes that selection in the
+    Pallas kernel over each slot's live blocks
+    (``ops/sparse_attention.py::select_decode_topk``), and takes the dense
+    way, a window of ``index_topk`` or fewer, through the paged
+    decode-attention kernel (``ops/paged_attention.py``)."""
     del adapter_ids, kv_sharded
     _no_lora(lora)
     pos, table = cache["pos"], cache["table"]
@@ -793,10 +860,15 @@ def decode_slots_paged(
         qi, wi, ki = _index(h[:, None], lp, cfg, pos[:, None])
         ck = ck.at[li, write_blk, write_off].set(k.reshape(S, kvd).astype(ck.dtype))
         cv = cv.at[li, write_blk, write_off].set(v.reshape(S, kvd).astype(cv.dtype))
-        cik = cik.at[li, write_blk, write_off].set(ki[:, 0].astype(cik.dtype))
-        o = _decode_attention(
-            q, qi, wi, ck, cv, cik, li, read_blk, pos, active, n_sel, cfg,
-            sparse=sparse, kernel=kernel,
+        cik = _ik_write(cik, li, write_blk, ki[:, 0], write_off)
+        rows = None
+        if sparse:
+            rows, read = _select_rows(
+                qi, wi, cik, li, read_blk, pos, active, cfg, kernel=kernel
+            )
+            ctr = _bump(ctr, _BLOCKS_READ, jnp.sum(read))
+        o = _decode_read(
+            q, ck, cv, li, read_blk, pos, active, n_sel, rows, kernel=kernel
         )
         x, ctr = _after_attention(
             x, o, lp, cfg, active, ctr, decode=True, stacks=stacks, li=li

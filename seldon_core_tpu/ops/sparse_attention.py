@@ -4,14 +4,21 @@
 
     I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s])          (float32)
 
-and the two kernels here are the parts of a prompt's attention which XLA
-alone would pay for in HBM traffic; the decode read is XLA's.
+and the kernels here are the parts which XLA alone would pay for in HBM
+traffic: a prompt's selection and attention, and a decode step's selection;
+the decode read of the selected rows is XLA's.
 
 * :func:`sparse_decode_attention` — the decode read: the slot's selected
   rows gathered from the paged pool (``(rows, KV * D)``: every layer's
   blocks flattened, a row one token's kv heads side by side) and attended.
   Pool rows that were not selected are never read.  (Its docstring says
   why Mosaic cannot copy one row of the pool.)
+* :func:`select_decode_topk` — a decode step's selection, a slot a grid
+  step: the slot's live blocks of index keys are copied from the pool by
+  table entry and scored in VMEM, the ``topk``-th largest score is found by
+  the same bisection, and the selected positions are packed to the front
+  of the row and leave as pool rows; the window is not gathered and nothing
+  is sorted.
 * :func:`select_topk_mask` — a prompt's selection.  For a strip of queries
   the index scores against every key up to the strip's last are made tile
   by tile into VMEM and never leave it; the ``topk``-th largest score of a
@@ -110,6 +117,297 @@ def sparse_decode_attention(q, k_rows, v_rows, rows, count):
     return o.reshape(S, H, D).astype(q.dtype)
 
 
+def _kth_largest_key(counts, topk: int, shape, bits: int):
+    """``(thr, ties)``: the ``topk``-th largest of the ordered-int keys
+    (``_score_key``) that ``counts`` sees, and the places left for keys AT
+    it.  ``counts(*tests)`` gives, for each test of a tile of keys, how many
+    keys pass, an int32 array of ``shape`` (a row each, or a slot's one).
+    The value is found from the top, ``bits`` of it a sweep (``2 ** bits -
+    1`` candidates ride one sweep of the scratch): the largest candidate
+    with ``topk`` keys at or above it, ``INT_MIN`` where fewer are seen
+    (everything seen is selected).  Both selection kernels' search: a
+    prompt's strip takes a bit a sweep (a sweep is a strip's rows by tiles
+    of keys: the compares are the cost), a decode slot two (its sweep is a
+    chain of a reduction and a broadcast: the sweeps are)."""
+    thr = jnp.where(
+        counts(lambda k: k >= 0)[0] >= topk,
+        jnp.zeros(shape, jnp.int32), jnp.full(shape, INT_MIN, jnp.int32),
+    )
+
+    def sweep(thr, low, nbits):
+        cands = [
+            thr | jnp.left_shift(jnp.int32(j), low) for j in range(1, 1 << nbits)
+        ]
+        found = counts(*[lambda k, c=c: k >= c for c in cands])
+        for c, n in zip(cands, found):
+            thr = jnp.where(n >= topk, c, thr)  # ascending: the last that holds
+        return thr
+
+    whole, rest = divmod(31, bits)
+    thr = jax.lax.fori_loop(
+        0, whole, lambda i, thr: sweep(thr, 31 - bits * (i + 1), bits), thr
+    )
+    if rest:
+        thr = sweep(thr, 0, rest)
+    return thr, topk - counts(lambda k: k > thr)[0]
+
+
+# ---------------------------------------------------------------------------
+# decode: the selection of one query a slot, over the slot's live index keys
+# ---------------------------------------------------------------------------
+
+# pool blocks scored together: the sublanes of one int32 tile, so that a
+# group's scores are whole tiles of the scratch
+_GROUP = 8
+
+
+def _flat_rank(m):
+    """``(R, B) int32``: how many of ``m (R, B) bool`` are set before each
+    place, rows after one another: within a row by a triangular product, the
+    rows before by a second one over the rows' totals.  Zeros and ones and
+    totals of at most ``B``: exact in one bfloat16 pass up to 256, in
+    float32 at the highest precision past it."""
+    R, B = m.shape
+    dims = (((1,), (0,)), ((), ()))
+
+    def before(n, transposed):
+        i, j = (jax.lax.broadcasted_iota(jnp.int32, (n, n), d) for d in (0, 1))
+        return j < i if transposed else i < j
+
+    within = jax.lax.dot_general(
+        m.astype(jnp.bfloat16), before(B, False).astype(jnp.bfloat16), dims,
+        preferred_element_type=jnp.float32, precision=jax.lax.Precision.DEFAULT,
+    )
+    dt, prec = (
+        (jnp.bfloat16, jax.lax.Precision.DEFAULT) if B <= 256
+        else (jnp.float32, jax.lax.Precision.HIGHEST)
+    )
+    total = jnp.sum(m.astype(jnp.float32), axis=1, keepdims=True)
+    rows = jax.lax.dot_general(
+        before(R, True).astype(dt), jnp.broadcast_to(total, (R, B)).astype(dt),
+        dims, preferred_element_type=jnp.float32, precision=prec,
+    )
+    return (within + rows).astype(jnp.int32)
+
+
+def _shift_flat(x, s):
+    """``y[i] = x[i + s]`` over ``x (R, B)`` read row after row (what falls
+    off the front comes round to the back, where the caller has nothing)."""
+    R, B = x.shape
+    if s % B == 0:
+        return pltpu.roll(x, (R - s // B) % R, 0)
+    z = pltpu.roll(x, B - s, 1)  # z[r, l] = x[r, (l + s) % B]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, B), 1)
+    return jnp.where(lane < B - s, z, pltpu.roll(z, R - 1, 0))
+
+
+def _decode_select_kernel(
+    table_ref,  # (S, WB) int32 scalar-prefetch: the pool block of each column
+    pos_ref,  # (S,) int32 scalar-prefetch: the slot's position, under 0: none
+    qi_ref,  # (1, HI, DI) the slot's index queries
+    wi_ref,  # (1, HI, 1) float32 head weights
+    ikt_hbm,  # (NB, DI, BS) the pool's index keys, transposed: left in HBM
+    out_ref,  # (1, KR, BS) int32: the pool rows of the selected keys
+    read_ref,  # (1, 1, 128) int32: the pool blocks this slot's copies brought in
+    kbuf,  # (2, G, DI, BS)
+    sem,  # DMA (2,)
+    keys_scr,  # (R, BS) int32: the slot's scores as ordered ints, a block a row
+    *, topk, score_dtype,
+):
+    s_i = pl.program_id(0)
+    G, BS = kbuf.shape[1], kbuf.shape[3]
+    R = keys_scr.shape[0]
+    pos = pos_ref[s_i]
+    n_live = jnp.minimum(pos // BS + 1, table_ref.shape[1])  # blocks with a seen key
+    n_g = (n_live + G - 1) // G
+    cdt, prec = mxu_operands(qi_ref.dtype)
+    q = qi_ref[0].astype(cdt)
+    wi = wi_ref[0].astype(score_dtype)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
+
+    def copies(w, buf, go):
+        """Start (``go``) or await the copies of group ``w``: its live
+        blocks, each by its table entry, into tile ``buf``.  Returns how
+        many it started or awaited."""
+
+        def one(b, n):
+            cp = pltpu.make_async_copy(
+                ikt_hbm.at[table_ref[s_i, b]], kbuf.at[buf, b - w * G], sem.at[buf]
+            )
+            cp.start() if go else cp.wait()
+            return n + 1
+
+        return jax.lax.fori_loop(
+            w * G, jnp.minimum(w * G + G, n_live), one, jnp.int32(0)
+        )
+
+    @pl.when(n_g > 0)
+    def _prime():
+        copies(0, 0, True)
+
+    def score(w, read):
+        cur = w % 2
+
+        @pl.when(w + 1 < n_g)
+        def _ahead():
+            copies(w + 1, 1 - cur, True)
+
+        read = read + copies(w, cur, False)
+        for g in range(G):
+            # (the MXU accumulates in float32; the control rounds after)
+            s = jax.lax.dot_general(
+                q, kbuf[cur, g].astype(cdt), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            ).astype(score_dtype)  # (HI, BS)
+            acc = jnp.sum(wi * jnp.maximum(s, 0), axis=0, keepdims=True)
+            key = _score_key(acc.astype(jnp.float32))
+            # a block of the group past the live ones was not copied: what
+            # the tile held before is hidden by the same test
+            at = (w * G + g) * BS + lane
+            keys_scr[pl.ds(w * G + g, 1), :] = jnp.where(at <= pos, key, INT_MIN)
+        return read
+
+    read = jax.lax.fori_loop(0, n_g, score, jnp.int32(0))
+    read_ref[0] = jnp.zeros(read_ref.shape[1:], jnp.int32) + read
+
+    def counts(*tests):
+        """(1, 1) int32 each: the slot's keys that pass each of ``tests``,
+        in one sweep."""
+
+        def one(w, accs):
+            key = keys_scr[pl.ds(pl.multiple_of(w * G, G), G), :]
+            return tuple(a + t(key).astype(jnp.int32) for a, t in zip(accs, tests))
+
+        zero = jnp.zeros((G, BS), jnp.int32)
+        accs = jax.lax.fori_loop(0, n_g, one, (zero,) * len(tests))
+        return [jnp.sum(a, keepdims=True) for a in accs]
+
+    thr, ties = _kth_largest_key(counts, topk, (1, 1), bits=2)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (R, BS), 0)
+    at = row * BS + jax.lax.broadcasted_iota(jnp.int32, (R, BS), 1)
+    seen = at <= pos  # rows past the live groups hold another slot's keys
+    key = keys_scr[...]
+    eq = (key == thr) & seen
+    sel = ((key > thr) & seen) | (eq & (_flat_rank(eq) < ties))
+    # a selected key moves forward by the places not selected before it, one
+    # bit of that distance a pass from the lowest (the order is kept, so no
+    # two meet); it carries the distance, and where it lands says the rest
+    code = jnp.where(sel, 2 * (at - _flat_rank(sel)) + 1, 0)
+    for k in range((R * BS - 1).bit_length()):
+        moving = (code & (2 << k)) != 0
+        code = jnp.where(moving, 0, code) | _shift_flat(
+            jnp.where(moving, code, 0), 1 << k
+        )
+    KR = out_ref.shape[1]
+    took = code[:KR] != 0
+    p = at[:KR] + (code[:KR] >> 1)  # the position that landed at each place
+    blk = p // BS
+
+    def entry(b, base):
+        return jnp.where(blk == b, table_ref[s_i, b], base)
+
+    # the pool row of a position, through the table: only a live block holds one
+    base = jax.lax.fori_loop(0, n_live, entry, jnp.zeros((KR, BS), jnp.int32))
+    out_ref[0] = jnp.where(took, base * BS + p % BS, 0)
+
+
+def select_decode_topk(
+    qi: jax.Array, wi: jax.Array, ikt_pages: jax.Array, table: jax.Array,
+    pos: jax.Array, *, topk: int, score_dtype=jnp.float32,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """``(rows (S, topk) int32, blocks_read (S,) int32)``: the pool rows of
+    the keys each slot's decode query selects, and the pool blocks of index
+    keys the kernel brought in for the slot (its own count of the copies it
+    awaited: the live blocks).  The rows come in the order of their
+    positions — the ``min(topk, pos + 1)`` seen keys (``s <= pos``, inside the table's window) with the largest
+    index scores, ties to the lower positions; the rest of a row is 0 (a row
+    of the pool: in bounds).  The pool row of position ``s`` is ``table[s //
+    BS] * BS + s % BS``, what ``sparse_decode_attention`` takes.  ``qi (S,
+    HI, DI)``, ``wi (S, HI)``;
+    ``ikt_pages (NB, DI, BS)`` the pool's index keys as they are carried,
+    every layer's blocks in one row of blocks and a block transposed (its
+    ``BS`` tokens along the lanes: a block of 64-wide keys by tokens is no
+    whole tile, and Mosaic copies whole tiles); ``table (S, WB)`` the pool
+    block of each of the window's columns.  A slot whose ``pos`` is under 0
+    (one that is not active) reads nothing and selects nothing.
+
+    One grid step a slot: its live blocks are copied by table entry into
+    VMEM, ``_GROUP`` at a time with the next group in flight, and scored
+    there; the threshold is the prompt kernel's search over the scores' bit
+    patterns (``_kth_largest_key``); the selected positions are packed to the front in VMEM by
+    shifts of powers of two and turned into pool rows through the table.
+    Nothing but those rows' numbers goes to HBM, and blocks past ``pos`` are
+    not read."""
+    S, HI, DI = qi.shape
+    BS = ikt_pages.shape[2]
+    WB = table.shape[1]
+    G = _GROUP
+    R = -(-WB // G) * G
+    KR = -(-int(topk) // BS)
+    if KR > R:
+        raise ValueError(f"topk {topk} is more than the window's {WB * BS} keys")
+    pos = jnp.minimum(jnp.asarray(pos, jnp.int32), WB * BS - 1)
+    kernel = functools.partial(
+        _decode_select_kernel, topk=int(topk), score_dtype=score_dtype
+    )
+
+    def slot_block(s, t, p):
+        return (s, 0, 0)
+
+    out, read = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, HI, DI), slot_block),
+                pl.BlockSpec((1, HI, 1), slot_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, KR, BS), slot_block),
+                pl.BlockSpec((1, 1, 128), slot_block),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((2, G, DI, BS), ikt_pages.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((R, BS), jnp.int32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((S, KR, BS), jnp.int32),
+            jax.ShapeDtypeStruct((S, 1, 128), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=_interpret(interpret),
+    )(
+        jnp.asarray(table, jnp.int32), pos, qi,
+        wi.astype(jnp.float32)[:, :, None], ikt_pages,
+    )
+    return out.reshape(S, KR * BS)[:, : int(topk)], read[:, 0, 0]
+
+
+def select_decode_topk_reference(qi, wi, ikt_pages, table, pos, *, topk,
+                                 score_dtype=jnp.float32):
+    """The XLA way: the whole window's index keys gathered, scored, masked
+    past ``pos`` and sorted (``lax.top_k``): ``(S, topk)`` pool rows, best
+    first, the lower position first among equals; and the blocks gathered a
+    slot, the window's ``WB`` whatever ``pos`` says."""
+    S = qi.shape[0]
+    BS = ikt_pages.shape[2]
+    W = table.shape[1] * BS
+    keys = jnp.swapaxes(ikt_pages[table], 2, 3).reshape(S, W, -1)
+    scores = jax.vmap(
+        lambda a, b, c: index_scores(a, b, c, score_dtype)
+    )(qi[:, None], wi[:, None], keys)[:, 0]
+    scores = jnp.where(jnp.arange(W)[None, :] <= pos[:, None], scores, -jnp.inf)
+    idx = jax.lax.top_k(scores, int(topk))[1]
+    rows = jnp.take_along_axis(table, idx // BS, axis=1) * BS + idx % BS
+    return rows, jnp.full((S,), table.shape[1], jnp.int32)
+
+
 # ---------------------------------------------------------------------------
 # prefill: the selection of a strip of queries, as a mask
 # ---------------------------------------------------------------------------
@@ -148,30 +446,19 @@ def _select_kernel(
 
     jax.lax.fori_loop(0, hi, score, 0)
 
-    def count(test):
-        """(bq, 1) int32: keys of each row that pass ``test``."""
+    def counts(*tests):
+        """(bq, 1) int32 each: keys of each row that pass each of ``tests``."""
 
-        def one(kt, acc):
+        def one(kt, accs):
             at = pl.ds(pl.multiple_of(kt * bk, bk), bk)
-            return acc + test(keys_scr[:, at]).astype(jnp.int32)
+            key = keys_scr[:, at]
+            return tuple(a + t(key).astype(jnp.int32) for a, t in zip(accs, tests))
 
-        acc = jax.lax.fori_loop(0, hi, one, jnp.zeros((bq, bk), jnp.int32))
-        return jnp.sum(acc, axis=1, keepdims=True)
+        zero = jnp.zeros((bq, bk), jnp.int32)
+        accs = jax.lax.fori_loop(0, hi, one, (zero,) * len(tests))
+        return [jnp.sum(a, axis=1, keepdims=True) for a in accs]
 
-    # the topk-th largest key of each row, bit by bit from the top: the
-    # largest value with at least topk keys at or above it (INT_MIN where
-    # the row has fewer: everything it may see is selected)
-    thr = jnp.where(
-        count(lambda k: k >= 0) >= topk,
-        jnp.zeros((bq, 1), jnp.int32), jnp.full((bq, 1), INT_MIN, jnp.int32),
-    )
-
-    def bit(i, thr):
-        cand = thr | jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(count(lambda k: k >= cand) >= topk, cand, thr)
-
-    thr = jax.lax.fori_loop(0, 31, bit, thr)
-    ties = topk - count(lambda k: k > thr)  # places left for keys AT thr
+    thr, ties = _kth_largest_key(counts, topk, (bq, 1), bits=1)
     # exclusive running count of a tile's ties, by a triangular product
     tri = (
         jax.lax.broadcasted_iota(jnp.int32, (bk, bk), 0)

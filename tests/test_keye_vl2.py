@@ -175,6 +175,20 @@ class TestAgainstReference:
         )
         np.testing.assert_allclose(got, off, atol=TOL, rtol=0)
 
+    @pytest.mark.parametrize("length", [5, 13])
+    def test_the_selection_kernel_gives_what_the_xla_lines_give(self, length):
+        """The decode step with ``kernel`` (the selection as one Pallas
+        kernel over the slot's live blocks) against the same step the XLA
+        way (the window gathered, scored and sorted), over steps that cross
+        ``index_topk`` (8: from 5) and block boundaries (every 4)."""
+        short = np.random.default_rng(4).integers(1, 256, length)
+        cfg = _cfg()
+        params = _params(cfg)
+        seq, got = _served_logits(cfg, params, short, steps=14, kernel=True)
+        seq_x, want = _served_logits(cfg, params, short, steps=14, kernel=False)
+        np.testing.assert_array_equal(seq, seq_x)
+        np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
     @pytest.mark.parametrize("window", [8, 32])
     def test_a_static_window_of_topk_or_fewer_goes_the_dense_way(self, window):
         """``window`` 8 (= ``index_topk``) reads every row through the
@@ -305,7 +319,7 @@ class TestCache:
     def test_a_third_array_under_the_same_table(self):
         cfg = _cfg()
         cache = m.init_paged_cache(cfg, 2, 40, BS, jnp.bfloat16)
-        assert cache["ik"].shape == (2, 40, BS, cfg.index_dim)
+        assert cache["ik"].shape == (2, 40, cfg.index_dim, BS)  # a block transposed
         assert cache["ik"].dtype == cache["k"].dtype == jnp.bfloat16
         assert cache["k"].shape == (2, 40, BS, cfg.n_kv_heads * cfg.head_dim)
         # the published sizes: 2 x 4 x 128 + 64 values a token a layer
@@ -319,17 +333,54 @@ class TestCache:
         _, whole = _prefill(cfg, params, prompt)
         _, spans = _prefill(cfg, params, prompt, chunks=(0, 16, 37))
         row = _slot_row()
+
+        def by_token(cache, name):  # (layers, blocks, block, ...)
+            a = cache[name]
+            return np.asarray(m._ik_by_token(a) if name == "ik" else a)
+
         for name in ("k", "v", "ik"):
-            a = np.asarray(whole[name])[:, row[:9]].reshape(2, 36, -1)
-            b = np.asarray(spans[name])[:, row[:9]].reshape(2, 36, -1)
-            assert np.abs(a).max() > 0
+            a = by_token(whole, name)[:, row[:9]].reshape(2, 36, -1)
+            b = by_token(spans, name)[:, row[:9]].reshape(2, 36, -1)
+            assert np.abs(a[:, :30]).min(axis=-1).max() > 0
             np.testing.assert_allclose(a, b, atol=TOL, rtol=0)
-        # a decode step's token lands at its position, on every array
+        # a decode step's token lands at its position, on every array, and
+        # nowhere else in its block
         _, _, after = _decode(cfg, params, whole, 5, 1)
         for name in ("k", "v", "ik"):
-            was = np.asarray(whole[name])[:, row[9], 1]
-            now = np.asarray(after[name])[:, row[9], 1]
-            assert np.abs(now - was).max() > 0
+            was = by_token(whole, name)[:, row[9]]
+            now = by_token(after, name)[:, row[9]]
+            assert np.abs(now[:, 1] - was[:, 1]).max() > 0
+            np.testing.assert_array_equal(now[:, [0, 2, 3]], was[:, [0, 2, 3]])
+
+    @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+    def test_the_by_token_call_is_the_served_parts_on_the_pool_as_carried(
+            self, prompt, kernel):
+        """``_decode_attention`` takes its index keys by token (the
+        benchmark's reference kind builds them so): it gives what the two
+        parts the served step calls give on the pool as it is carried."""
+        cfg = _cfg()
+        _, cache = _prefill(cfg, _params(cfg), prompt)
+        ks = jax.random.split(jax.random.PRNGKey(8), 3)
+        q = jax.random.normal(ks[0], (2, 1, cfg.n_heads, cfg.head_dim))
+        qi = jax.random.normal(ks[1], (2, 1, cfg.index_heads, cfg.index_dim))
+        wi = jax.random.normal(ks[2], (2, 1, cfg.index_heads))
+        pos = jnp.asarray([0, len(prompt) - 1])
+        active = jnp.asarray([False, True])
+        n_sel = jnp.where(active, jnp.minimum(pos + 1, cfg.index_topk), 0)
+        at = (1, cache["table"], pos, active)
+        got = m._decode_attention(
+            q, qi, wi, cache["k"], cache["v"], m._ik_by_token(cache["ik"]), *at,
+            n_sel, cfg, sparse=True, kernel=kernel,
+        )
+        rows, read = m._select_rows(qi, wi, cache["ik"], *at, cfg, kernel=kernel)
+        want = m._decode_read(
+            q, cache["k"], cache["v"], *at, n_sel, rows, kernel=kernel
+        )
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+        assert np.abs(np.asarray(got[1])).max() > 0
+        # 37 tokens in blocks of 4: 10 live blocks of the active slot, none
+        # of the other; the XLA lines gather the table's 16 for both
+        assert np.asarray(read).tolist() == ([0, 10] if kernel else [16, 16])
 
 
 class TestCounters:
@@ -350,6 +401,34 @@ class TestCounters:
         assert c["dsa.keys_scored"] == 2 * 117
         assert c["dsa.keys_selected"] == 2 * 3 * 8
         assert c["moe.pairs_routed"] == 2 * 3 * 4
+
+    @pytest.mark.parametrize("kernel,blocks", [
+        # the kernel visits the live blocks of the one active slot: positions
+        # 37, 38, 39 in blocks of 4 are 10 each, on 2 layers
+        (True, 2 * 3 * 10),
+        # the XLA way gathers the window of both slots: 2 x 16 blocks
+        (False, 2 * 3 * 2 * 16),
+    ])
+    def test_index_key_blocks_read_are_counted_by_the_way_taken(
+            self, prompt, kernel, blocks):
+        cfg = _cfg()
+        params = _params(cfg)
+        _, cache = _prefill(cfg, params, prompt)
+        _, _, cache = _decode(cfg, params, cache, 5, 3, kernel=kernel)
+        c = dict(zip(m.COUNTERS, np.asarray(cache["counters"]).tolist()))
+        assert m.COUNTERS[-1] == "dsa.key_blocks_read"
+        assert c["dsa.key_blocks_read"] == blocks
+        # what a step has to score is what it was, whichever way
+        assert c["dsa.keys_scored"] == 2 * 117
+        assert c["dsa.keys_selected"] == 2 * 3 * 8
+
+    def test_a_window_that_selects_nothing_reads_no_index_keys(self, prompt):
+        cfg = _cfg()
+        params = _params(cfg)
+        _, cache = _prefill(cfg, params, np.asarray(prompt[:5]))
+        _, _, cache = _decode(cfg, params, cache, 5, 2, window=8, kernel=True)
+        c = dict(zip(m.COUNTERS, np.asarray(cache["counters"]).tolist()))
+        assert c["dsa.key_blocks_read"] == 0 and c["dsa.keys_scored"] == 2 * (6 + 7)
 
     def test_a_prompt_counts_its_pairs_in_units_of_1024(self):
         ctr = jnp.zeros((len(m.COUNTERS),), jnp.uint32)

@@ -748,3 +748,114 @@ class TestSparseAttention:
             np.asarray(got), np.asarray(want.reshape(s, h, d)), atol=2e-5, rtol=2e-5
         )
         assert not np.asarray(got)[1].any()  # a slot that selects nothing
+
+
+# (name, slots' positions (under 0: inactive), block, table columns, topk,
+#  pool dtype, score dtype, what the index data is)
+DECODE_SELECT_CASES = [
+    ("float32 pool", (4, 50, 79), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("bfloat16 pool", (52, 133, 29, 142, 175), 16, 11, 40, jnp.bfloat16, jnp.float32, "normal"),
+    ("ties: every score equal", (70, 33), 16, 5, 24, jnp.float32, jnp.float32, "zero"),
+    ("ties: few levels", (79, 41, 60), 16, 5, 24, jnp.float32, jnp.float32, "levels"),
+    ("ties: negative zero", (79, 30), 16, 5, 24, jnp.float32, jnp.float32, "negative zero"),
+    ("fewer seen than topk", (0, 7, 22, 23), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("pos at a block's last token", (31, 47, 63), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("pos at a block's first token", (32, 48, 64), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("pos one past a block's first", (33, 49, 65), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("an inactive slot", (60, -1, 45), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("every slot inactive", (-1, -1), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("a window of 3 blocks", (47, 20, 33), 16, 3, 8, jnp.float32, jnp.float32, "normal"),
+    ("a window of 11 blocks", (175, 90, 128), 16, 11, 40, jnp.float32, jnp.float32, "normal"),
+    ("the window's last token, and past it", (79, 200), 16, 5, 24, jnp.float32, jnp.float32, "normal"),
+    ("topk no whole blocks", (79, 50), 16, 5, 20, jnp.float32, jnp.float32, "normal"),
+    ("topk under a block", (40, 5), 16, 4, 8, jnp.float32, jnp.float32, "normal"),
+    ("blocks of 128", (1000, 300, 129), 128, 9, 256, jnp.bfloat16, jnp.float32, "normal"),
+    ("bfloat16 scores, the control", (79, 41, 60), 16, 5, 24, jnp.bfloat16, jnp.bfloat16, "small integers"),
+    ("bfloat16 scores over a float32 pool", (79, 30), 16, 5, 24, jnp.float32, jnp.bfloat16, "small integers"),
+]
+
+
+class TestDecodeSelection:
+    """``ops/sparse_attention.py::select_decode_topk`` (interpret mode): the
+    decode step's selection as one kernel, held to the XLA lines it replaces
+    (``select_decode_topk_reference``: the window gathered, scored, masked
+    and sorted) — the same SET of pool rows, as many of them real as are
+    seen up to ``topk``, each once, the rest of a row in bounds."""
+
+    HI, DI = 4, 8
+
+    def _inputs(self, case):
+        _, pos, bs, wb, topk, dtype, score_dtype, data = case
+        S = len(pos)
+        ks = jax.random.split(jax.random.PRNGKey(len(case[0])), 4)
+        nb = 2 * S * wb + 3
+        qi = jax.random.normal(ks[0], (S, self.HI, self.DI))
+        wi = jax.random.normal(ks[1], (S, self.HI))
+        ik = jax.random.normal(ks[2], (nb, self.DI, bs))
+        if data == "zero":
+            qi = qi * 0
+        elif data == "levels":  # few distinct scores: ties at every level
+            qi, wi, ik = jnp.round(qi), jnp.round(wi), jnp.round(ik)
+        elif data == "negative zero":  # every product rectified to zero
+            qi, ik, wi = -jnp.abs(qi), jnp.abs(ik), -jnp.abs(wi)
+        elif data == "small integers":  # exact in bfloat16 in any order
+            qi, ik = jnp.sign(jnp.round(qi)), jnp.sign(jnp.round(ik))
+            wi = jnp.abs(jnp.sign(jnp.round(wi))) * 2
+        # a slot's blocks lie scattered over the pool, out of order
+        table = jax.random.permutation(ks[3], nb)[: S * wb].reshape(S, wb)
+        return (qi.astype(dtype), wi, ik.astype(dtype), table,
+                jnp.asarray(pos, jnp.int32))
+
+    @pytest.mark.parametrize(
+        "case", DECODE_SELECT_CASES, ids=[c[0] for c in DECODE_SELECT_CASES]
+    )
+    def test_the_kernel_selects_the_set_the_xla_lines_select(self, case):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        _, pos, bs, wb, topk, _, score_dtype, data = case
+        args = self._inputs(case)
+        kw = dict(topk=topk, score_dtype=score_dtype)
+        got, read = map(np.asarray, sa.select_decode_topk(*args, **kw))
+        want, gathered = map(np.asarray, sa.select_decode_topk_reference(*args, **kw))
+        assert got.shape == want.shape == (len(pos), topk) and got.dtype == np.int32
+        # the kernel's own count of the blocks it brought in: the live ones
+        # (none for a slot that is not active); the XLA lines' the window
+        live = [0 if p < 0 else min(p // bs + 1, wb) for p in pos]
+        np.testing.assert_array_equal(read, live)
+        np.testing.assert_array_equal(gathered, [wb] * len(pos))
+        table = np.asarray(args[3])
+        for s, p in enumerate(pos):
+            n = min(topk, min(p, wb * bs - 1) + 1)  # seen, up to topk: the real ones
+            np.testing.assert_array_equal(np.sort(got[s, :n]), np.sort(want[s, :n]))
+            assert len(set(got[s, :n].tolist())) == n
+            assert (got[s, n:] == 0).all()  # in bounds, and never read as real
+            if data in ("zero", "negative zero"):  # equal scores: the lowest positions
+                at = np.arange(n)
+                np.testing.assert_array_equal(got[s, :n], table[s, at // bs] * bs + at % bs)
+
+    def test_slots_that_share_blocks_select_from_the_same_keys(self):
+        """Prefix reuse: two slots' tables name the same blocks, and a third
+        slot's scratch rows from the slot before it are not its own."""
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        qi, wi, ik, table, _ = self._inputs(DECODE_SELECT_CASES[0])
+        table = table.at[1].set(table[0])
+        qi = qi.at[1].set(qi[0])
+        wi = wi.at[1].set(wi[0])
+        pos = jnp.asarray([79, 79, 17])
+        got = np.asarray(sa.select_decode_topk(qi, wi, ik, table, pos, topk=24)[0])
+        want = np.asarray(
+            sa.select_decode_topk_reference(qi, wi, ik, table, pos, topk=24)[0]
+        )
+        np.testing.assert_array_equal(got[0], got[1])
+        np.testing.assert_array_equal(  # every seen key, in the order of its position
+            got[2, :18], np.asarray(table)[2, np.arange(18) // 16] * 16 + np.arange(18) % 16
+        )
+        np.testing.assert_array_equal(np.sort(got[0]), np.sort(want[0]))
+
+    def test_a_topk_past_the_window_is_refused(self):
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        qi, wi, ik, table, pos = self._inputs(DECODE_SELECT_CASES[0])
+        with pytest.raises(ValueError, match="more than the window"):
+            sa.select_decode_topk(qi, wi, ik, table[:, :1], pos, topk=200)

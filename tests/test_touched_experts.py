@@ -140,7 +140,7 @@ class TestTheServedStep:
             params, jnp.asarray(prompt, jnp.int32), jnp.int32(22), jnp.int32(1),
             jnp.asarray(row), cache, cfg,
         )
-        assert len(kv.COUNTERS) == 13 and cache["counters"].shape == (13,)
+        assert len(kv.COUNTERS) == 14 and cache["counters"].shape == (14,)
         toks, out = [], []
         nxt = int(np.argmax(logits))
         for _ in range(steps):
@@ -174,7 +174,7 @@ class TestTheServedStep:
         assert kv.COUNTERS[:9] == cm.COUNTERS
         assert kv.COUNTERS[9:] == (
             "dsa.keys_scored", "dsa.keys_selected", "dsa.prefill_keys_scored",
-            "dsa.prefill_keys_selected",
+            "dsa.prefill_keys_selected", "dsa.key_blocks_read",
         )
         assert (kv._STEPS, kv._P_TOKENS, kv._SCORED) == (4, 7, 9)
 

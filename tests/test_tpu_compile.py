@@ -1,5 +1,5 @@
 """The paged decode kernel, the prompt's tiled kernel, the selection's
-kernels and the touched-only expert kernel through the TPU's own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
+kernels (a prompt's and a decode step's) and the touched-only expert kernel through the TPU's own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
 (no chip time; nothing runs).  The interpreter the other tests use accepts
 what Mosaic refuses: a copy or slice off the tiling, too much fast memory.
 
@@ -140,6 +140,45 @@ def test_the_selection_kernels_compile_at_keye_vl2s_widths(one_chip, q_offset, c
         assert compiled.memory_analysis().temp_size_in_bytes < chunk * lk // 8
 
 
+# (name, table columns, pool blocks, pool dtype)
+DECODE_SELECT_CASES = [
+    ("the cell's window of 32,768", 128, 6 * 793, "bfloat16"),
+    ("the reference kind's 12,288", 48, 48, "bfloat16"),
+    ("the reference kind's float32 diagnosis", 48, 48, "float32"),
+]
+
+
+@pytest.mark.parametrize(
+    "case", DECODE_SELECT_CASES, ids=[c[0] for c in DECODE_SELECT_CASES]
+)
+def test_the_decode_selection_kernel_compiles_at_keye_vl2s_widths(one_chip, case):
+    """``select_decode_topk`` at the published sizes (8 slots, 16 index
+    heads of 64, blocks of 256 tokens, top-2,048): whole blocks copied from
+    the pool as it is carried (a block's tokens along the lanes: Mosaic
+    refuses a slice of 64 lanes), dynamic rows of the score scratch, the
+    shifts that pack the selection; the pool goes in as it is."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops import sparse_attention as sa
+
+    _, wb, nb, dt = case
+    dt = jnp.dtype(dt)
+
+    def sds(shape, dtype=dt):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def f(qi, wi, ikt, table, pos):
+        return sa.select_decode_topk(qi, wi, ikt, table, pos, topk=2048, interpret=False)
+
+    compiled = jax.jit(f).lower(
+        sds((8, 16, 64)), sds((8, 16), jnp.float32), sds((nb, 64, 256)),
+        sds((8, wb), jnp.int32), sds((8,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2048 * 4 * 4
+
+
 # (name, tokens, held experts, hidden, expert width, layers)
 EXPERT_CASES = [
     ("keye-vl-2 a whole expert a step", 8, 128, 2048, 768, 6),
@@ -215,6 +254,7 @@ def test_keye_vl2s_decode_program_holds_no_copy_of_a_layers_experts(one_chip, mo
         params, jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip), cache,
         jax.ShapeDtypeStruct((8,), jnp.bool_, sharding=one_chip),
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.as_text().count("tpu_custom_call") == 2  # the experts, the selection
+    # nor of the index keys' pool (156 MB), nor of the window's keys and scores
     layer_experts = 128 * 3 * 2048 * 768 * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < layer_experts // 8
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_experts // 64
