@@ -570,6 +570,15 @@ class GenerativeModel:
         # the disagg KV export reads the slot's prompt blocks through it
         self._slot_row: dict[int, np.ndarray] = {}
 
+        # the pool's per-token arrays, under the one table: ALL of them, as
+        # the family names them (``family.POOL_ARRAYS``; ``k`` and ``v``
+        # where it names none).  The pool's bytes, its reported dtype and its
+        # placement read this list; what moves K/V out of the pool carries k
+        # and v alone and refuses, by name, a family whose list is anything
+        # else (:meth:`_kv_alone`)
+        pool_names = self._pool_names = tuple(
+            getattr(family_mod, "POOL_ARRAYS", ("k", "v"))
+        )
         cache_dtype = dtype if dtype is not None else np.float32
         # a pool that is placed over a mesh keeps its kv-head axis to be
         # split by; on one device a row holds its heads side by side
@@ -731,8 +740,7 @@ class GenerativeModel:
             kv_sh = NamedSharding(mesh, P(None, None, None, "tp", None))
             rep = NamedSharding(mesh, P())
             placed = {
-                "k": jax.device_put(cache["k"], kv_sh),
-                "v": jax.device_put(cache["v"], kv_sh),
+                **{n: jax.device_put(cache[n], kv_sh) for n in pool_names},
                 "pos": jax.device_put(cache["pos"], rep),
                 "table": jax.device_put(cache["table"], rep),
             }
@@ -770,11 +778,6 @@ class GenerativeModel:
                 cache, next(iter(jax.tree.leaves(self.params)[0].devices()))
             )
         self._cache = cache
-        # per-token arrays a family keeps in the pool beside K and V, under
-        # the same table (``family.POOL_EXTRA``): counted with the pool's
-        # bytes; what moves K/V out of the pool carries k and v alone and
-        # refuses such a family by name (:meth:`_kv_alone`)
-        self._pool_extra = tuple(getattr(family_mod, "POOL_EXTRA", ()))
         if self.host_store is not None:
             self._kv_alone("the host-DRAM prefix tier (prefix_dram_gb)")
         self.prefill_buckets = _prefill_buckets(cfg.max_seq, kv_block_size)
@@ -1950,21 +1953,22 @@ class GenerativeModel:
         return len(self._free_blocks)
 
     def _pool_bytes(self) -> int:
-        """HBM bytes of the pool's per-token arrays: K, V and the family's
-        further ones (scales are counted apart)."""
-        return sum(
-            int(self._cache[key].nbytes) for key in ("k", "v") + self._pool_extra
-        )
+        """HBM bytes of the pool's per-token arrays, as the family names
+        them (scales are counted apart)."""
+        return sum(int(self._cache[key].nbytes) for key in self._pool_names)
 
     def _kv_alone(self, what: str) -> None:
-        """Refuse ``what`` for a family whose pool holds more than K and V:
+        """Refuse ``what`` for a family whose pool is not exactly K and V:
         the frames and stores outside the programs carry ``k`` and ``v``
         (and an int8 pool's scales) alone, and a slot moved without its
-        further arrays would decode on garbage."""
-        if self._pool_extra:
+        further arrays — or as K and V it does not have — would decode on
+        garbage."""
+        if self._pool_names != ("k", "v"):
+            other = [n for n in self._pool_names if n not in ("k", "v")]
+            how = "beside K/V" if "k" in self._pool_names else "and no K/V by head"
             raise TypeError(
                 f"generative family {self.family.__name__.rsplit('.', 1)[-1]} "
-                f"keeps {', '.join(self._pool_extra)} beside K/V in its paged "
+                f"keeps {', '.join(other)} {how} in its paged "
                 f"pool; {what} carries k and v alone and is refused"
             )
 
@@ -2876,7 +2880,7 @@ class GenerativeModel:
         included on an int8 pool) — sizes the HBM tier's byte telemetry."""
         return sum(
             int(self._cache[key].nbytes) // self.kv_blocks
-            for key in ("k", "v", "k_scale", "v_scale") + self._pool_extra
+            for key in self._pool_names + ("k_scale", "v_scale")
             if key in self._cache
         )
 
@@ -2885,7 +2889,7 @@ class GenerativeModel:
         fam = self.family
         if hasattr(fam, "paged_kv_slot_bytes"):
             dt = str(self._cache["k_scale"].dtype) if self.kv_dtype else str(
-                self._cache["k"].dtype
+                self._cache[self._pool_names[0]].dtype
             )
             return int(
                 fam.paged_kv_slot_bytes(
@@ -3050,7 +3054,7 @@ class GenerativeModel:
                 if ratio is not None and self.spec_method
                 else {}
             ),
-            "kv_dtype": self.kv_dtype or str(self._cache["k"].dtype),
+            "kv_dtype": self.kv_dtype or str(self._cache[self._pool_names[0]].dtype),
             "kv_bytes_per_slot": self.kv_bytes_per_slot(),
             "kv_slots_per_chip": self.kv_slots_per_chip(),
             # chunked prefill + decode kernel state (docs/PERFORMANCE.md §7)

@@ -246,12 +246,14 @@ def _layernorm(x, w, eps):
     return (xc * lax.rsqrt(var + eps)).astype(x.dtype) * w
 
 
-def _rope_pairs(x, positions, theta):
+def _rope_pairs(x, positions, theta, freqs=None):
     """Interleaved-pair rotary embedding (``rope_gptj``): dims (2i, 2i+1)
     rotate together, all ``head_dim`` of them.  x: (..., L, H, D);
-    positions: (..., L)."""
+    positions: (..., L).  ``freqs (D / 2,)`` replaces ``theta``'s own
+    (``kimi_k2``'s YaRN)."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., L, D/2)
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]

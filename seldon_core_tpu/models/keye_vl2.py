@@ -123,8 +123,8 @@ _STEPS, _P_TOKENS = 4, 7  # "moe.steps", "moe.prefill_tokens"
 _SCORED, _SELECTED, _P_SCORED, _P_SELECTED, _BLOCKS_READ = range(
     len(_MOE_COUNTERS), len(_MOE_COUNTERS) + 5
 )
-# per-token arrays of the paged pool beside "k" and "v"
-POOL_EXTRA = ("ik",)
+# every per-token array of the paged pool, under the one table
+POOL_ARRAYS = ("k", "v", "ik")
 _EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 
 

@@ -17,7 +17,9 @@ import numpy as np
 from jax.sharding import Mesh
 
 from seldon_core_tpu.executor import BucketSpec, CompiledModel, JaxModelComponent
-from seldon_core_tpu.models import bert, cnn, cohere2_moe, keye_vl2, llama, mlp, resnet
+from seldon_core_tpu.models import (
+    bert, cnn, cohere2_moe, keye_vl2, kimi_k2, llama, mlp, resnet,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +95,16 @@ _FAMILIES: dict[str, Family] = {
         presets={
             "keye-vl-2-30b-a3b": keye_vl2.Config,
             "tiny": keye_vl2.Config.tiny,
+        },
+        example_input=lambda c, b: np.ones((b, 16), np.int32),
+        init_in_dtype=True,
+    ),
+    "kimi_k2": Family(
+        "kimi_k2", kimi_k2.Config, kimi_k2.init_params,
+        kimi_k2.apply, kimi_k2.param_logical_axes,
+        presets={
+            "kimi-k2-6": kimi_k2.Config,
+            "tiny": kimi_k2.Config.tiny,
         },
         example_input=lambda c, b: np.ones((b, 16), np.int32),
         init_in_dtype=True,
@@ -276,13 +288,15 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 #     ``lora_adapter_factors`` (adapters), ``truncate_params`` (a layer-
 #     truncated draft), ``paged_kv_slot_bytes`` (the KV ledger's own size of
 #     a slot), ``COUNTERS`` (names of the on-device counters a step returns),
-#     ``POOL_EXTRA`` (names of the per-token arrays its paged pool holds
-#     beside ``k`` and ``v``: counted with the pool, and what moves K/V out
-#     of the pool — handoff, suspend, the host-DRAM tier — refuses it).
+#     ``POOL_ARRAYS`` (the names of ALL the per-token arrays its paged pool
+#     holds under the one table, ``("k", "v")`` where it names none: counted
+#     with the pool, and what moves K/V out of the pool — handoff, suspend,
+#     the host-DRAM tier — refuses a family whose list is not ``k`` and ``v``).
 # A feature asked of a family without its function is refused at build;
 # prefix reuse, chunked prefill and adapters are turned off with a warning.
 GENERATIVE_FAMILIES: dict[str, Any] = {
     "llama": llama, "cohere2_moe": cohere2_moe, "keye_vl2": keye_vl2,
+    "kimi_k2": kimi_k2,
 }
 
 

@@ -41,7 +41,7 @@ NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, block_q, block_k,
-    n_k, causal, scale, window=None
+    n_k, causal, scale, window=None, score_dtype=None
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -70,6 +70,8 @@ def _flash_kernel(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
+        if score_dtype is not None:  # a negative control: the scores rounded
+            s = s.astype(score_dtype).astype(jnp.float32)
         if causal:
             rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -99,7 +101,10 @@ def _flash_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "block_q", "block_k", "interpret", "window"),
+    static_argnames=(
+        "causal", "block_q", "block_k", "interpret", "window", "scale",
+        "score_dtype",
+    ),
 )
 def flash_attention(
     q: jax.Array,
@@ -111,13 +116,26 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool | None = None,
     window: int | None = None,
+    scale: float | None = None,
+    score_dtype=None,
 ) -> jax.Array:
     """``(B, H, S, D)`` attention; blocks clamp to S and must divide it.
     ``window`` (static, causal only) keeps keys ``j > i - window``;
-    ``k``/``v`` of shape ``(B, Hk, Sk, D)`` with ``Hk`` dividing ``H`` are
-    read grouped, never repeated."""
+    ``k`` of shape ``(B, Hk, Sk, D)`` with ``Hk`` dividing ``H`` is read
+    grouped, never repeated.  ``v (B, Hk, Sk, Dv)`` may have a width of its
+    own (latent attention: 192-wide keys under 128-wide values), and the
+    output is ``(B, H, S, Dv)``; ``scale`` (static) is the softmax scale,
+    ``D ** -0.5`` unless given.  ``score_dtype`` (static; a negative control,
+    never served) rounds a tile's scores to that type as they leave the MXU;
+    unset, they stay float32."""
     B, H, S, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
+    Dv = v.shape[3]
+    if k.shape[3] != D or v.shape[:3] != k.shape[:3]:
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape}: q and k share their "
+            "width, k and v their heads and length"
+        )
     if H % Hk:
         raise ValueError(f"{H} query heads do not group over {Hk} key heads")
     if window is not None and not causal:
@@ -134,7 +152,7 @@ def flash_attention(
         interpret = jax.default_backend() == "cpu"
     n_q = S // block_q
     n_k = Sk // block_k
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else float(scale)
 
     kernel = functools.partial(
         _flash_kernel,
@@ -143,6 +161,7 @@ def flash_attention(
         n_k=n_k,
         causal=causal,
         scale=scale,
+        score_dtype=score_dtype,
         window=None if window is None else int(window),
     )
     return pl.pallas_call(
@@ -151,14 +170,14 @@ def flash_attention(
         in_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, qi, ki: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max (col 0)
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom (col 0)
-            pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(q, k, v)

@@ -91,6 +91,60 @@ class TestFlashAttention:
         with pytest.raises(ValueError, match="causal"):
             flash_attention(q, q, q, causal=False, window=8)
 
+    @pytest.mark.parametrize("kv_heads,causal", [(4, True), (2, True), (4, False)])
+    def test_values_of_their_own_width_and_a_stated_scale(self, kv_heads, causal):
+        """Latent attention's prompt: keys 24 wide under values 16 wide, the
+        softmax scale stated (not ``D ** -0.5``), grouped or not, over
+        several key tiles."""
+        rng = np.random.default_rng(5)
+        q = jnp.asarray(rng.normal(size=(1, 4, 128, 24)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(1, kv_heads, 128, 24)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(1, kv_heads, 128, 16)), jnp.float32)
+        out = flash_attention(
+            q, k, v, causal=causal, block_q=64, block_k=32, scale=0.31
+        )
+        assert out.shape == (1, 4, 128, 16)
+        rep = 4 // kv_heads
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, rep, 1)) * 0.31
+        if causal:
+            s = jnp.where(jnp.arange(128)[:, None] >= jnp.arange(128)[None, :], s, -1e30)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), jnp.repeat(v, rep, 1))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+    def test_with_neither_given_a_caller_gets_what_it_got(self):
+        """No ``scale`` and values as wide as the keys: the kernel of before,
+        bit for bit what the scale ``D ** -0.5`` said aloud gives."""
+        rng = np.random.default_rng(6)
+        q, k, v = (
+            jnp.asarray(rng.normal(size=(1, 2, 128, 64)), jnp.bfloat16) for _ in range(3)
+        )
+        plain = flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+        said = flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=64, scale=1.0 / np.sqrt(64)
+        )
+        np.testing.assert_array_equal(np.asarray(plain), np.asarray(said))
+        assert plain.shape == q.shape and plain.dtype == q.dtype
+        with pytest.raises(ValueError, match="share their"):
+            flash_attention(q, k[..., :32], v)
+
+    def test_rounded_scores_are_a_control_of_their_own(self):
+        """``score_dtype`` (a negative control, never served) rounds each
+        tile's scores: what the dense form gives with its scores rounded the
+        same way, and no longer what float32 scores give."""
+        rng = np.random.default_rng(7)
+        q, k, v = (
+            jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32) for _ in range(3)
+        )
+        kw = dict(causal=True, block_q=64, block_k=32, scale=0.4)
+        sound = flash_attention(q, k, v, **kw)
+        got = flash_attention(q, k, v, score_dtype=jnp.bfloat16, **kw)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q * 0.4, k)
+        s = s.astype(jnp.bfloat16).astype(jnp.float32)
+        s = jnp.where(jnp.arange(128)[:, None] >= jnp.arange(128)[None, :], s, -1e30)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+        assert np.abs(np.asarray(got) - np.asarray(sound)).max() > 1e-3
+
 
 class TestFlashBlhdAdapter:
     """Direct unit coverage for ``flash_causal_attention_blhd`` — the
@@ -859,3 +913,99 @@ class TestDecodeSelection:
         qi, wi, ik, table, pos = self._inputs(DECODE_SELECT_CASES[0])
         with pytest.raises(ValueError, match="more than the window"):
             sa.select_decode_topk(qi, wi, ik, table[:, :1], pos, topk=200)
+
+
+
+# (name, slots, heads, latent, rotary, block, table columns, rows a step,
+#  positions, active)
+LATENT_READ_CASES = [
+    ("one block", 2, 4, 16, 8, 8, 1, 8, [5, 7], None),
+    ("many blocks, several a step", 3, 4, 16, 8, 4, 9, 8, [33, 17, 2], None),
+    ("many steps of one block", 2, 2, 32, 4, 8, 5, 8, [39, 12], None),
+    ("a slot at position 0", 3, 4, 16, 8, 4, 4, 8, [0, 9, 15], None),
+    ("slots that read nothing", 4, 4, 16, 8, 4, 4, 4, [6, 11, 0, 13], [False, True, False, True]),
+    ("a window that is no whole step", 2, 4, 16, 8, 4, 5, 8, [19, 4], None),
+]
+
+
+class TestLatentDecodeRead:
+    """``ops/mla_attention.py``: a decode step's read of the latent pool, in
+    interpret mode, against its XLA reference and against the expanded
+    mathematics it absorbs."""
+
+    def _inputs(self, case, dtype=jnp.float32):
+        _, S, H, C, R, BS, WB, _, pos, active = case
+        rng = np.random.default_rng(len(case[0]))
+        NB = S * WB + 3
+        ql = jnp.asarray(rng.normal(size=(S, H, C)), dtype)
+        qr = jnp.asarray(rng.normal(size=(S, H, R)), dtype)
+        c = jnp.asarray(rng.normal(size=(NB, BS, C)), dtype)
+        krt = jnp.asarray(rng.normal(size=(NB, R, BS)), dtype)
+        # every slot its own blocks, out of order; block 0 is the sink
+        table = jnp.asarray(
+            rng.permutation(np.arange(1, S * WB + 1)).reshape(S, WB), jnp.int32
+        )
+        act = None if active is None else jnp.asarray(active)
+        return ql, qr, c, krt, table, jnp.asarray(pos, jnp.int32), act
+
+    @pytest.mark.parametrize("case", LATENT_READ_CASES, ids=[c[0] for c in LATENT_READ_CASES])
+    def test_the_kernel_gives_what_the_xla_lines_give(self, case):
+        from seldon_core_tpu.ops import mla_attention as ma
+
+        ql, qr, c, krt, table, pos, act = self._inputs(case)
+        BS, step = case[5], case[7]
+        got, rows = ma.mla_decode_attention(
+            ql, qr, c, krt, table, pos, scale=0.2, active=act, step_rows=step
+        )
+        want, gathered = ma.mla_decode_attention_reference(
+            ql, qr, c, krt, table, pos, scale=0.2, active=act
+        )
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+        # the kernel counts the live blocks it awaited; the lines gather the window
+        live = [(p // BS + 1) * BS for p in case[8]]
+        if case[9] is not None:
+            live = [n if a else 0 for n, a in zip(live, case[9])]
+            assert np.abs(np.asarray(got)[~np.asarray(case[9])]).max() == 0
+        assert np.asarray(rows).tolist() == live
+        assert np.asarray(gathered).tolist() == [case[6] * BS] * case[1]
+
+    def test_it_is_the_expanded_attention_of_the_same_rows(self):
+        """Scores of ``[ql | qr]`` against ``[c | kr]`` and the weighted sum
+        of the same ``c``, written out densely for one slot."""
+        from seldon_core_tpu.ops import mla_attention as ma
+
+        case = LATENT_READ_CASES[1]
+        ql, qr, c, krt, table, pos, _ = self._inputs(case)
+        got, _ = ma.mla_decode_attention(ql, qr, c, krt, table, pos, scale=0.2, step_rows=8)
+        for s in range(case[1]):
+            n = int(pos[s]) + 1
+            rows_c = c[table[s]].reshape(-1, c.shape[-1])[:n]
+            rows_r = jnp.swapaxes(krt[table[s]], 1, 2).reshape(-1, krt.shape[1])[:n]
+            sc = 0.2 * (ql[s] @ rows_c.T + qr[s] @ rows_r.T)
+            want = jax.nn.softmax(sc, -1) @ rows_c
+            np.testing.assert_allclose(np.asarray(got[s]), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("control", [
+        {"rope": False}, {"score_dtype": jnp.bfloat16},
+    ], ids=["no rotary part", "bfloat16 scores"])
+    def test_the_controls_are_the_references_controls_and_another_result(self, control):
+        from seldon_core_tpu.ops import mla_attention as ma
+
+        args = self._inputs(LATENT_READ_CASES[1])[:6]
+        sound, _ = ma.mla_decode_attention(*args, scale=0.2, step_rows=8)
+        got, _ = ma.mla_decode_attention(*args, scale=0.2, step_rows=8, **control)
+        want, _ = ma.mla_decode_attention_reference(*args, scale=0.2, **control)
+        tol = 2e-2 if "score_dtype" in control else 2e-5  # rounded scores round apart
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
+        assert np.abs(np.asarray(got) - np.asarray(sound)).max() > 1e-3
+
+    def test_bfloat16_as_the_pool_holds_it(self):
+        from seldon_core_tpu.ops import mla_attention as ma
+
+        args = self._inputs(LATENT_READ_CASES[1], jnp.bfloat16)[:6]
+        got, _ = ma.mla_decode_attention(*args, scale=0.2, step_rows=8)
+        want, _ = ma.mla_decode_attention_reference(*args, scale=0.2)
+        assert got.dtype == jnp.bfloat16
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=0.03, atol=0.03
+        )

@@ -258,3 +258,67 @@ def test_keye_vl2s_decode_program_holds_no_copy_of_a_layers_experts(one_chip, mo
     # nor of the index keys' pool (156 MB), nor of the window's keys and scores
     layer_experts = 128 * 3 * 2048 * 768 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_experts // 64
+
+
+def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip):
+    """A prompt's expanded latent attention at the 12,288 rung: 64 heads,
+    keys 192 wide (128 + the 64-wide rotary key) under values 128 wide, the
+    softmax scale stated."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.flash_attention import flash_attention
+
+    def sds(width):
+        return jax.ShapeDtypeStruct((1, 64, 12288, width), jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, block_q=512, block_k=512, scale=0.14468,
+            interpret=False,
+        )
+
+    compiled = jax.jit(f).lower(sds(192), sds(192), sds(128)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kimi_k2s_decode_program_makes_no_key_or_value_by_head(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (32 slots, five layers, a
+    latent pool of 1,633 blocks of 256, window 16,384): the latent read and
+    the touched-only expert kernel are in it, the pool goes in as it lies
+    (a block of rotary keys 64 x 256, transposed), and the program's
+    temporaries are megabytes — the window's keys or values by head would be
+    32 x 16,384 x 64 x 128 x 2 B = 8.6 GB, a copy of the pool 2.4 GB."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import kimi_k2 as m
+
+    # the ops ask the backend whether to interpret: this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = m.Config(vocab_size=20480, n_layers=5, experts_held="0:12", max_seq=16384)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32, 1633, 256, jnp.bfloat16)
+    ))
+    step = jax.jit(
+        functools.partial(m.decode_slots_paged, cfg=cfg, window=16384, kernel=True),
+        donate_argnums=(2,),
+    )
+    compiled = step.lower(
+        params, jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip), cache,
+        jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    # the dense layer's read; the expert layers' read and their experts (one scan)
+    assert compiled.as_text().count("tpu_custom_call") == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
