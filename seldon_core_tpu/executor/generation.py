@@ -76,6 +76,7 @@ from seldon_core_tpu.obs.timeline import (
     EVENT_RESUME,
     EVENT_SUSPEND,
 )
+from seldon_core_tpu.ops.flash_attention import TILE_PLANS
 from seldon_core_tpu.utils.tracectx import current_trace_id
 from seldon_core_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -3018,6 +3019,9 @@ class GenerativeModel:
             ),
             "variant_seconds": dict(self.warmup_program_seconds),
             "recent_compiles": list(self._program_events),
+            # the tiled prompt kernel's grid by traced shape (this process's
+            # calls): steps taken, tiles multiplied, tiles masked
+            "tile_plans": {k: dict(v) for k, v in TILE_PLANS.items()},
         }
 
     def spec_snapshot(self) -> dict:

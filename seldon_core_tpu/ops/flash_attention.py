@@ -9,15 +9,33 @@ tiles: memory drops from O(S²) to O(S·D) and the arithmetic intensity
 matches the hardware (guide: /opt/skills/guides/pallas_guide.md; the
 technique is the standard flash-attention tiling).
 
-Layout: ``(B, H, S, D)``.  The grid is ``(B, H, Sq/bq, Sk/bk)`` — TPU
-iterates the last axis fastest, so each query tile accumulates over its
-key tiles in VMEM scratch and writes its output once on the final key
-step.  Causal masking is per-tile (fully-masked tiles skip the matmul
-entirely): tiles wholly after a query tile, and with a sliding ``window``
-(key ``j`` visible to query ``i`` iff ``i - window < j <= i``) tiles wholly
-before it too.  ``k``/``v`` may carry fewer heads than ``q`` (grouped-query
-attention): query head ``h`` reads key head ``h // (H // Hk)`` through the
-block index, so the keys are never repeated in HBM.
+Layout: ``(B, H, S, D)``.  **A causal tile costs what it needs** (PR 44).
+The grid is ``(B, H, steps)``, and a step is one LIVE tile: shapes, ``causal``
+and ``window`` are static, so :func:`_tile_steps` lists the ``(qi, ki)`` pairs
+that hold a visible key — query tile by query tile, key tiles ascending — and
+the list reaches the ``BlockSpec``s' index maps by scalar prefetch.  A tile
+wholly above the diagonal (and, under a sliding ``window`` — key ``j`` visible
+to query ``i`` iff ``i - window < j <= i`` — one wholly before it) is no grid
+step: its K and V are never fetched.  A query tile accumulates over its key
+tiles in VMEM scratch, starts on the step flagged first and writes its output
+on the step flagged last.  Of the live tiles only those that STRADDLE the
+diagonal or the window's lower edge build a mask (two iotas, the compares, a
+select); a tile wholly inside runs the products and the softmax alone.
+:func:`tile_plan` counts the three kinds: a prompt of 12,288 at 512 x 512
+steps 300 tiles of the square's 576 and masks 24 of them.
+
+The running max and denominator are ``(block_q, 128)`` float32 with every lane
+of a row the same, and stay two-dimensional from the scores' reduction to the
+rescale: whole vregs in and out.  Cutting a one-lane column out of them and
+broadcasting it back across lanes every tile (``m_scr[:, 0]``,
+``m_cur[:, None]``) was half the kernel's time, more than the mask and the
+dead steps together (PERF.md §6, PR 44).  The arithmetic and its order are the
+parent's: at the same tile the results are bit for bit what they were, but for
+a row that sees no key, which now gives zeros wherever it lies.
+
+``k``/``v`` may carry fewer heads than ``q`` (grouped-query attention): query
+head ``h`` reads key head ``h // (H // Hk)`` through the block index, so the
+keys are never repeated in HBM.
 
 The kernel is compiled by Mosaic on every backend but the CPU, where it
 runs in Pallas interpret mode so the equivalence tests pin it to the dense
@@ -27,42 +45,100 @@ reference.
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from seldon_core_tpu.ops.paged_attention import mxu_operands
 
+log = logging.getLogger(__name__)
+
 NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
+# the running max starts ABOVE the mask's value: a masked score's exponent is
+# then 0 whatever the row has seen, and a row that sees no key ends at zeros
+M_INIT = NEG_INF / 2
+
+# what a grid step is, as bits of its entry in the prefetched list
+_FIRST, _LAST, _LIVE, _MASKED = 1, 2, 4, 8
+
+# the tile plans of the calls traced in this process, by shape: what
+# ``breakdown.generation.<unit>.programs.tile_plans`` shows
+TILE_PLANS: dict[str, dict[str, int]] = {}
+
+
+@functools.lru_cache(maxsize=None)
+def _tile_steps(S, Sk, block_q, block_k, causal, window):
+    """The grid's steps as three int32 arrays ``(q tile, key tile, kind)``:
+    every tile that holds a visible key, query tile by query tile, key tiles
+    ascending; a query tile that sees no key at all keeps one step that is
+    not live, which writes its zeros."""
+    q_of, k_of, kind = [], [], []
+    for qi in range(S // block_q):
+        r0, r1 = qi * block_q, qi * block_q + block_q - 1
+        row = []
+        for ki in range(Sk // block_k):
+            c0, c1 = ki * block_k, ki * block_k + block_k - 1
+            if causal and c0 > r1:
+                break  # wholly above the diagonal, and so is every later one
+            if window is not None and c1 <= r0 - window:
+                continue  # wholly before the window of the tile's first query
+            masked = causal and c1 > r0
+            if window is not None:
+                masked = masked or c0 <= r1 - window
+            row.append((ki, _LIVE | (_MASKED if masked else 0)))
+        row = row or [(0, 0)]
+        for n, (ki, what) in enumerate(row):
+            q_of.append(qi)
+            k_of.append(ki)
+            kind.append(
+                what | (_FIRST if n == 0 else 0) | (_LAST if n == len(row) - 1 else 0)
+            )
+    return tuple(np.asarray(a, np.int32) for a in (q_of, k_of, kind))
+
+
+def tile_plan(S, Sk, block_q, block_k, causal=True, window=None):
+    """``(stepped, live, masked)``: the grid steps a head takes, those that
+    run the products, and those of them that build a mask — static in the
+    shapes, and what the kernel's grid and bodies are made from."""
+    kind = _tile_steps(S, Sk, block_q, block_k, causal, window)[2]
+    return (
+        len(kind),
+        int(np.count_nonzero(kind & _LIVE)),
+        int(np.count_nonzero(kind & _MASKED)),
+    )
+
+
+def _lanes(x, n):
+    """``x (rows, w)``, its lanes all equal, at width ``n``."""
+    w = x.shape[1]
+    if n == w:
+        return x
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, block_q, block_k,
-    n_k, causal, scale, window=None, score_dtype=None
+    q_of, k_of, kind, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+    block_q, block_k, causal, scale, window=None, score_dtype=None
 ):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    t = pl.program_id(2)
+    qi, ki, what = q_of[t], k_of[t], kind[t]
 
-    @pl.when(ki == 0)
+    @pl.when((what & _FIRST) != 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # tiles where every key position is after every query position are
-    # fully masked: skip their FLOPs entirely; under a window so are tiles
-    # whose last key lies at or before the first query's ``i - window``
-    live = (not causal) or (ki * block_k <= qi * block_q + block_q - 1)
-    if window is not None:
-        live = live & (ki * block_k + block_k - 1 > qi * block_q - window)
+        m_scr[...] = jnp.full_like(m_scr, M_INIT)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     cdt, prec = mxu_operands(q_ref.dtype)
 
-    @pl.when(live)
-    def _tile():
+    def _tile(masked):
         q = (q_ref[0, 0] * scale).astype(cdt)  # (bq, D)
         k = k_ref[0, 0].astype(cdt)  # (bk, D)
         v = v_ref[0, 0].astype(cdt)
@@ -72,31 +148,34 @@ def _flash_kernel(
         )  # (bq, bk)
         if score_dtype is not None:  # a negative control: the scores rounded
             s = s.astype(score_dtype).astype(jnp.float32)
-        if causal:
+        if masked:
             rows = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             cols = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             seen = rows >= cols
             if window is not None:
                 seen = seen & (cols > rows - window)
             s = jnp.where(seen, s, NEG_INF)
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_cur[:, None])
+        m_prev = m_scr[...]  # (bq, w): a row's lanes all equal, as l_scr's
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_cur, block_k))
         alpha = jnp.exp(m_prev - m_cur)
-        l_cur = alpha * l_prev + p.sum(axis=-1)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[...] = alpha * l_scr[...] + p.sum(axis=-1, keepdims=True)
+        m_scr[...] = m_cur
+        acc_scr[...] = acc_scr[...] * _lanes(alpha, acc_scr.shape[1]) + jax.lax.dot_general(
             p.astype(cdt), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )
-        m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
-    @pl.when(ki == n_k - 1)
+    # a tile wholly inside the diagonal (and the window) builds no mask
+    if causal:
+        pl.when((what & _MASKED) != 0)(lambda: _tile(True))
+    pl.when((what & (_LIVE | _MASKED)) == _LIVE)(lambda: _tile(False))
+
+    @pl.when((what & _LAST) != 0)
     def _emit():
-        l = l_scr[:, 0]
+        l = l_scr[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> zeros
-        o_ref[0, 0] = (acc_scr[:] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -127,7 +206,7 @@ def flash_attention(
     output is ``(B, H, S, Dv)``; ``scale`` (static) is the softmax scale,
     ``D ** -0.5`` unless given.  ``score_dtype`` (static; a negative control,
     never served) rounds a tile's scores to that type as they leave the MXU;
-    unset, they stay float32."""
+    unset, they stay float32.  A row that sees no key gives zeros."""
     B, H, S, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
     Dv = v.shape[3]
@@ -150,37 +229,54 @@ def flash_attention(
         )
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
-    n_q = S // block_q
-    n_k = Sk // block_k
     scale = 1.0 / math.sqrt(D) if scale is None else float(scale)
+    window = None if window is None else int(window)
+
+    steps = _tile_steps(S, Sk, block_q, block_k, causal, window)
+    stepped, live, masked = tile_plan(S, Sk, block_q, block_k, causal, window)
+    # traced once a shape: the line a warm-up's compile of a rung leaves
+    TILE_PLANS[f"S{S}:Sk{Sk}:{block_q}x{block_k}:w{window}"] = {
+        "stepped": stepped, "live": live, "masked": masked,
+    }
+    log.info(
+        "flash_attention S=%d Sk=%d H=%d D=%d Dv=%d tile %dx%d window=%s: "
+        "tile plan stepped=%d live=%d masked=%d of %d",
+        S, Sk, H, D, Dv, block_q, block_k, window, stepped, live, masked,
+        (S // block_q) * (Sk // block_k),
+    )
 
     kernel = functools.partial(
         _flash_kernel,
         block_q=block_q,
         block_k=block_k,
-        n_k=n_k,
         causal=causal,
         scale=scale,
         score_dtype=score_dtype,
-        window=None if window is None else int(window),
+        window=window,
     )
+    lanes = 128 if block_k % 128 == 0 else block_k  # the statistics' width
     return pl.pallas_call(
         kernel,
-        grid=(B, H, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, qi, ki: (b, h // g, ki, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, qi, ki: (b, h, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B, H, stepped),
+            in_specs=[
+                pl.BlockSpec((1, 1, block_q, D), lambda b, h, t, qo, ko, kd: (b, h, qo[t], 0)),
+                pl.BlockSpec((1, 1, block_k, D), lambda b, h, t, qo, ko, kd: (b, h // g, ko[t], 0)),
+                pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, t, qo, ko, kd: (b, h // g, ko[t], 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, block_q, Dv), lambda b, h, t, qo, ko, kd: (b, h, qo[t], 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, lanes), jnp.float32),  # running max, every lane
+                pltpu.VMEM((block_q, lanes), jnp.float32),  # running denom, every lane
+                pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max (col 0)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running denom (col 0)
-            pltpu.VMEM((block_q, Dv), jnp.float32),  # output accumulator
-        ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(jnp.asarray(a) for a in steps), q, k, v)
 
 
 def _fit_block(s: int, preferred: int = 128) -> int:

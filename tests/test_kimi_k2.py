@@ -513,6 +513,12 @@ class TestServedPath:
         assert model.spec_snapshot()["kv_dtype"] == "bfloat16"
         model.warmup()
         warmed = xla_compile_count()
+        if seq_impl == "flash":  # each rung's compile left its tile plan
+            plans = model.program_snapshot()["tile_plans"]
+            for b in model.prefill_buckets:
+                assert plans[f"S{b}:Sk{b}:{b}x{b}:wNone"] == {
+                    "stepped": 1, "live": 1, "masked": 1,
+                }
         tok = model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=12)
         cur, active = np.zeros(2, np.int32), np.zeros(2, bool)
         cur[0], active[0] = int(tok), True

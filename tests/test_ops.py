@@ -146,6 +146,98 @@ class TestFlashAttention:
         assert np.abs(np.asarray(got) - np.asarray(sound)).max() > 1e-3
 
 
+def _dense_seen(q, k, v, seen, scale):
+    """The dense form under a visibility matrix ``seen (S, Sk)``, grouped
+    keys repeated, a row that sees no key left at zeros."""
+    rep = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, rep, 1)) * scale
+    p = jnp.where(seen, jax.nn.softmax(jnp.where(seen, s, -1e30), -1), 0.0)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, jnp.repeat(v, rep, 1))
+
+
+class TestFlashTilePlan:
+    """PR 44: a causal tile costs what it needs.  The grid steps the live
+    tiles alone, and only a tile that straddles the diagonal or the window's
+    edge builds a mask."""
+
+    @pytest.mark.parametrize("S,block,want", [
+        (6144, 512, (78, 78, 12)),
+        (8192, 512, (136, 136, 16)),
+        (12288, 512, (300, 300, 24)),   # of the square's 576: 276 never stepped
+        (12288, 1024, (78, 78, 12)),
+        (512, 512, (1, 1, 1)),
+    ])
+    def test_counts_of_a_causal_prompt(self, S, block, want):
+        from seldon_core_tpu.ops.flash_attention import tile_plan
+
+        assert tile_plan(S, S, block, block, True, None) == want
+        n = S // block
+        assert tile_plan(S, S, block, block, False, None) == (n * n, n * n, 0)
+
+    @pytest.mark.parametrize("S,Sk,bq,bk,window", [
+        (2048, 2048, 256, 256, 600),    # the window's edge cuts tiles
+        (2048, 2048, 256, 128, 512),    # the edge on a tile boundary
+        (6144, 6144, 512, 512, 4096),   # Command A+'s rung between 4,096 and 8,192
+        (1024, 1024, 128, 256, None),   # key tiles wider than query tiles
+        (512, 256, 64, 64, 32),         # query tiles that see no key at all
+    ])
+    def test_counts_are_the_visibility_matrixs(self, S, Sk, bq, bk, window):
+        """Against the dense matrix: live = tiles that hold a visible pair,
+        masked = those of them that also hold a hidden one, stepped = live +
+        one step for a query tile that sees nothing (it writes its zeros)."""
+        from seldon_core_tpu.ops.flash_attention import tile_plan
+
+        i, j = np.arange(S)[:, None], np.arange(Sk)[None, :]
+        seen = j <= i
+        if window is not None:
+            seen &= j > i - window
+        tiles = seen.reshape(S // bq, bq, Sk // bk, bk)
+        live = tiles.any(axis=(1, 3))
+        masked = live & ~tiles.all(axis=(1, 3))
+        blind = int((~live.any(axis=1)).sum())
+        assert tile_plan(S, Sk, bq, bk, True, window) == (
+            int(live.sum()) + blind, int(live.sum()), int(masked.sum())
+        )
+
+    @pytest.mark.parametrize("case,S,H,Hk,D,Dv,window,blocks", [
+        ("latent widths, grouped", 256, 4, 2, 192, 128, None, (64, 64)),
+        ("one tile", 128, 2, 2, 32, 32, None, (128, 128)),
+        ("no multiple of the preferred tile", 192, 2, 1, 32, 32, None, (96, 96)),
+        ("a window over many tiles", 1024, 2, 1, 32, 32, 300, (128, 128)),
+        ("key tiles wider than query tiles", 512, 2, 2, 32, 32, 200, (64, 256)),
+        ("query tiles taller than key tiles", 512, 1, 1, 32, 16, None, (256, 64)),
+    ])
+    def test_matches_dense(self, case, S, H, Hk, D, Dv, window, blocks):
+        rng = np.random.default_rng(len(case))
+        q = jnp.asarray(rng.normal(size=(1, H, S, D)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(1, Hk, S, D)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(1, Hk, S, Dv)), jnp.float32)
+        out = flash_attention(
+            q, k, v, block_q=blocks[0], block_k=blocks[1], window=window, scale=0.11
+        )
+        i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+        seen = (j <= i) if window is None else (j <= i) & (j > i - window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(_dense_seen(q, k, v, seen, 0.11)),
+            rtol=2e-5, atol=2e-5,
+        )
+
+    def test_a_row_that_sees_no_key_gives_zeros(self):
+        """Queries past the keys' window: whole query tiles without a live key
+        tile (they step once and write zeros), and rows of a live tile that
+        see nothing in it."""
+        rng = np.random.default_rng(9)
+        q = jnp.asarray(rng.normal(size=(1, 2, 256, 32)), jnp.float32)
+        k = jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(1, 2, 128, 32)), jnp.float32)
+        out = np.asarray(flash_attention(q, k, v, block_q=32, block_k=32, window=32))
+        i, j = jnp.arange(256)[:, None], jnp.arange(128)[None, :]
+        seen = (j <= i) & (j > i - 32)
+        want = np.asarray(_dense_seen(q, k, v, seen, 32 ** -0.5))
+        np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+        assert not out[:, :, 159:].any() and out[:, :, :159].any(axis=-1).all()
+
+
 class TestFlashBlhdAdapter:
     """Direct unit coverage for ``flash_causal_attention_blhd`` — the
     model-zoo entry (``seq_impl=flash``) — against the dense reference

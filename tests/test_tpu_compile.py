@@ -282,6 +282,57 @@ def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _kimi_k2_as_served(one_chip, monkeypatch):
+    """``(module, cfg, params, cache, arg)`` of the cell's deployment as
+    shapes on the described chip: five layers, 12 held experts, 32 slots over
+    a latent pool of 1,633 blocks of 256."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import kimi_k2 as m
+
+    # the ops ask the backend whether to interpret: this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = m.Config(vocab_size=20480, n_layers=5, experts_held="0:12", max_seq=16384)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32, 1633, 256, jnp.bfloat16)
+    ))
+    return m, cfg, params, cache, arg
+
+
+def test_kimi_k2s_prompt_program_fits_at_the_12288_rung(one_chip, monkeypatch):
+    """``prefill:b12288`` whole at the served shapes (five layers, 64 heads,
+    the latent pool of 1,633 blocks): the tiled kernel is in it at both call
+    sites, and the temporaries are no larger than the 1.60 GB recorded at
+    PR 43 (PERF.md §6; 1,601,896,448 B by this compile of the parent, some
+    tens of KB more here: the grid's prefetched lists)."""
+    import functools
+
+    import jax
+
+    m, cfg, params, cache, arg = _kimi_k2_as_served(one_chip, monkeypatch)
+    prefill = jax.jit(
+        functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"),
+        donate_argnums=(5,),
+    )
+    compiled = prefill.lower(
+        params, arg((1, 12288)), arg(()), arg(()), arg((64,)), cache
+    ).compile()
+    # the dense layer's call site and the expert layers' scan
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.605e9
+
+
 def test_kimi_k2s_decode_program_makes_no_key_or_value_by_head(one_chip, monkeypatch):
     """The whole decode step at the served shapes (32 slots, five layers, a
     latent pool of 1,633 blocks of 256, window 16,384): the latent read and
@@ -294,31 +345,12 @@ def test_kimi_k2s_decode_program_makes_no_key_or_value_by_head(one_chip, monkeyp
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models import kimi_k2 as m
-
-    # the ops ask the backend whether to interpret: this process runs on the CPU
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = m.Config(vocab_size=20480, n_layers=5, experts_held="0:12", max_seq=16384)
-
-    def shapes(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
-        )
-
-    params = shapes(jax.eval_shape(
-        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
-    ))
-    cache = shapes(jax.eval_shape(
-        lambda: m.init_paged_cache(cfg, 32, 1633, 256, jnp.bfloat16)
-    ))
+    m, cfg, params, cache, arg = _kimi_k2_as_served(one_chip, monkeypatch)
     step = jax.jit(
         functools.partial(m.decode_slots_paged, cfg=cfg, window=16384, kernel=True),
         donate_argnums=(2,),
     )
-    compiled = step.lower(
-        params, jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip), cache,
-        jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip),
-    ).compile()
+    compiled = step.lower(params, arg((32,)), cache, arg((32,), jnp.bool_)).compile()
     # the dense layer's read; the expert layers' read and their experts (one scan)
     assert compiled.as_text().count("tpu_custom_call") == 3
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
