@@ -763,7 +763,9 @@ class EngineApp:
         flush_s = 0.0  # cumulative socket-write time -> stream-flush stage
         marks: dict = {}  # the scheduler's ``first_written`` lands here
         try:
-            gen = unit.stream(
+            # a decode block's tokens for this stream go out as one write:
+            # the same events, byte for byte, in one piece
+            gen = unit.stream_bursts(
                 prompt,
                 max_new_tokens=max_new,
                 temperature=temperature,
@@ -771,11 +773,14 @@ class EngineApp:
                 t_ingress=t_in,
                 info=marks,
             )
-            async for tok in gen:
-                out.append(tok)
-                event = f"data: {json.dumps({'token': tok})}\n\n".encode()
+            async for toks in gen:
+                first = not out
+                out.extend(toks)
+                event = "".join(
+                    f"data: {json.dumps({'token': tok})}\n\n" for tok in toks
+                ).encode()
                 t_w = time.perf_counter()
-                if len(out) > 1:
+                if not first:
                     await resp.write(event)
                 else:
                     # the ``first-write`` stage: the scheduler's first-token

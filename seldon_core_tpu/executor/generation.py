@@ -580,6 +580,11 @@ class GenerativeModel:
         pool_names = self._pool_names = tuple(
             getattr(family_mod, "POOL_ARRAYS", ("k", "v"))
         )
+        # the cache's per-SLOT arrays (``family.SLOT_ARRAYS``; none where it
+        # names none): state that is not a row a token and lies in no block
+        # of the pool.  Counted with a slot's bytes and as ``slot_state``;
+        # whatever moves or shares a slot's cache refuses them by name too
+        self._slot_names = tuple(getattr(family_mod, "SLOT_ARRAYS", ()))
         cache_dtype = dtype if dtype is not None else np.float32
         # a pool that is placed over a mesh keeps its kv-head axis to be
         # split by; on one device a row holds its heads side by side
@@ -1416,6 +1421,7 @@ class GenerativeModel:
                 "weights": self.param_bytes,
                 "kv_pool": kv_bytes,
                 "kv_scales": scale_bytes,
+                **self._slot_state_class(),
                 "adapter_pool": self.lora_bytes,
                 # learned speculation (docs/MULTITENANT.md "draft-model
                 # HBM accounting"): resident head block / draft weights /
@@ -1958,12 +1964,28 @@ class GenerativeModel:
         them (scales are counted apart)."""
         return sum(int(self._cache[key].nbytes) for key in self._pool_names)
 
+    def _slot_state_bytes(self) -> int:
+        """HBM bytes of the cache's per-slot arrays, as the family names
+        them: 0 for a family whose cache is all rows a token."""
+        return sum(int(self._cache[key].nbytes) for key in self._slot_names)
+
+    def _slot_state_class(self) -> dict:
+        """The ledger's ``slot_state`` class, for a family that has any."""
+        return {"slot_state": self._slot_state_bytes()} if self._slot_names else {}
+
     def _kv_alone(self, what: str) -> None:
-        """Refuse ``what`` for a family whose pool is not exactly K and V:
-        the frames and stores outside the programs carry ``k`` and ``v``
-        (and an int8 pool's scales) alone, and a slot moved without its
-        further arrays — or as K and V it does not have — would decode on
-        garbage."""
+        """Refuse ``what`` for a family whose cache is not exactly K and V
+        under the table: the frames and stores outside the programs carry
+        ``k`` and ``v`` (and an int8 pool's scales) alone, and a slot moved
+        without its further arrays — the pool's, or state it keeps per slot
+        — or as K and V it does not have, would decode on garbage."""
+        if self._slot_names:
+            raise TypeError(
+                f"generative family {self.family.__name__.rsplit('.', 1)[-1]} "
+                f"keeps {', '.join(self._slot_names)} per slot beside its "
+                f"paged pool ({', '.join(self._pool_names)}): state that no "
+                f"block holds; {what} carries k and v alone and is refused"
+            )
         if self._pool_names != ("k", "v"):
             other = [n for n in self._pool_names if n not in ("k", "v")]
             how = "beside K/V" if "k" in self._pool_names else "and no K/V by head"
@@ -2966,6 +2988,7 @@ class GenerativeModel:
                 "weights": self.param_bytes,
                 "kv_pool": kv_bytes,
                 "kv_scales": scale_bytes,
+                **self._slot_state_class(),
                 "adapter_pool": self.lora_bytes,
                 "prefix_dram": (
                     self.host_store.bytes if self.host_store is not None else 0
@@ -2997,6 +3020,7 @@ class GenerativeModel:
             ("kv_pool", kv_bytes),
             ("kv_scales", scale_bytes),
             ("adapter_pool", self.lora_bytes),
+            *self._slot_state_class().items(),
         ):
             m.kv_bytes.labels(self.name, cls).set(val)
         m.kv_prefix_evictions.labels(self.name).set(snap["prefix_evictions"])
@@ -6389,7 +6413,7 @@ class GenerativeComponent(SeldonComponent):
         )
         return self._pad_rows(outs)
 
-    async def stream(
+    async def stream_bursts(
         self,
         prompt: np.ndarray,
         *,
@@ -6399,15 +6423,19 @@ class GenerativeComponent(SeldonComponent):
         adapter: str | None = None,
         t_ingress: float | None = None,
         info: dict | None = None,
-    ) -> AsyncIterator[int]:
-        """Yield generated token ids as they decode (the streaming serving
-        path — neither the reference nor its successor streams at all).
+    ) -> AsyncIterator[list[int]]:
+        """Yield generated token ids as they decode, the ones that are
+        ready at a time (the streaming serving path — neither the reference
+        nor its successor streams at all).
 
         Tokens surface ``decode_block`` at a time per device fetch: deploy
         with a small block (e.g. 4-8) when time-to-first-token matters, the
-        default large block when bulk throughput does.  ``t_ingress`` and
-        ``info`` are ``GenerationScheduler.submit``'s: the instant the
-        request entered its handler, and the out-param whose
+        default large block when bulk throughput does.  A caller that
+        writes a burst out in one piece pays one write a block, not one a
+        token: with many slots live the per-token writes are most of what
+        the event loop does, beside the scheduler that shares it.
+        ``t_ingress`` and ``info`` are ``GenerationScheduler.submit``'s: the
+        instant the request entered its handler, and the out-param whose
         ``first_written`` the handler calls after its first write.
         """
         q: asyncio.Queue = asyncio.Queue()
@@ -6430,21 +6458,37 @@ class GenerativeComponent(SeldonComponent):
         task.add_done_callback(lambda t: q.put_nowait(_STREAM_END))
         served = 0
         try:
-            while True:
+            item = None
+            while item is not _STREAM_END:
                 item = await q.get()
-                if item is _STREAM_END:
-                    break
-                served += 1
-                yield int(item)
+                burst: list[int] = []
+                while item is not _STREAM_END:
+                    burst.append(int(item))
+                    if q.empty():
+                        break
+                    item = q.get_nowait()
+                if burst:
+                    served += len(burst)
+                    yield burst
             # surface a failed submit (bad prompt, closed scheduler) —
             # and tokens the hook delivered between our last get and the
             # sentinel
             result = task.result()
-            for tok in result[served:]:
-                yield int(tok)
+            if len(result) > served:
+                yield [int(tok) for tok in result[served:]]
         finally:
             if not task.done():
                 task.cancel()
+
+    async def stream(self, prompt: np.ndarray, **options) -> AsyncIterator[int]:
+        """:meth:`stream_bursts` a token at a time."""
+        bursts = self.stream_bursts(prompt, **options)
+        try:
+            async for burst in bursts:
+                for tok in burst:
+                    yield tok
+        finally:
+            await bursts.aclose()
 
     async def predict_raw(self, p):
         from seldon_core_tpu.contract.payload import DataKind, Payload

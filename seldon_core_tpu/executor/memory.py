@@ -31,7 +31,7 @@ from seldon_core_tpu.runtime import settings
 #: preempted whole-slot suspend records (docs/PACKING.md) occupy host
 #: DRAM, not chip memory
 CLASSES = (
-    "weights", "kv_pool", "kv_scales", "adapter_pool",
+    "weights", "kv_pool", "kv_scales", "slot_state", "adapter_pool",
     "spec_heads", "draft_weights", "draft_kv",
     "prefix_dram", "suspend_dram",
 )

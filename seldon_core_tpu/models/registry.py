@@ -18,7 +18,7 @@ from jax.sharding import Mesh
 
 from seldon_core_tpu.executor import BucketSpec, CompiledModel, JaxModelComponent
 from seldon_core_tpu.models import (
-    bert, cnn, cohere2_moe, keye_vl2, kimi_k2, llama, mlp, resnet,
+    bert, cnn, cohere2_moe, jamba, keye_vl2, kimi_k2, llama, mlp, resnet,
 )
 
 
@@ -105,6 +105,16 @@ _FAMILIES: dict[str, Family] = {
         presets={
             "kimi-k2-6": kimi_k2.Config,
             "tiny": kimi_k2.Config.tiny,
+        },
+        example_input=lambda c, b: np.ones((b, 16), np.int32),
+        init_in_dtype=True,
+    ),
+    "jamba": Family(
+        "jamba", jamba.Config, jamba.init_params,
+        jamba.apply, jamba.param_logical_axes,
+        presets={
+            "jamba2-3b": jamba.Config,
+            "tiny": jamba.Config.tiny,
         },
         example_input=lambda c, b: np.ones((b, 16), np.int32),
         init_in_dtype=True,
@@ -291,12 +301,24 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 #     ``POOL_ARRAYS`` (the names of ALL the per-token arrays its paged pool
 #     holds under the one table, ``("k", "v")`` where it names none: counted
 #     with the pool, and what moves K/V out of the pool — handoff, suspend,
-#     the host-DRAM tier — refuses a family whose list is not ``k`` and ``v``).
+#     the host-DRAM tier — refuses a family whose list is not ``k`` and ``v``),
+#     ``SLOT_ARRAYS`` (the names of the arrays of its cache that hold state
+#     PER SLOT and not by token — a recurrent or state-space state, which no
+#     block of the pool holds; ``()`` where it names none.  Counted: with a
+#     slot's bytes through the family's ``paged_kv_slot_bytes``, and as
+#     ``slot_state`` in the memory ledger and the pool's snapshot.  Refused,
+#     by name: whatever moves or shares a slot's cache outside the programs
+#     — handoff export and import, suspend and preemption, the host-DRAM and
+#     peer prefix tiers (``_kv_alone``) — since a slot moved without its state
+#     would decode on garbage; the family's ``init_paged_cache`` refuses a
+#     mesh, and it brings no ``prefill_suffix_paged`` and no
+#     ``decode_slots_spec_paged``: a shared prefix would need the state at the
+#     prefix's end and a rejected draft a rewind of it).
 # A feature asked of a family without its function is refused at build;
 # prefix reuse, chunked prefill and adapters are turned off with a warning.
 GENERATIVE_FAMILIES: dict[str, Any] = {
     "llama": llama, "cohere2_moe": cohere2_moe, "keye_vl2": keye_vl2,
-    "kimi_k2": kimi_k2,
+    "kimi_k2": kimi_k2, "jamba": jamba,
 }
 
 

@@ -1323,6 +1323,43 @@ class TestStreaming:
 
         run(go())
 
+    def test_a_blocks_tokens_come_as_one_burst_and_the_same_events(self):
+        """``stream_bursts`` hands over the tokens that are ready together (a
+        prompt's first token alone, then a decode block's), ``stream`` is
+        the same tokens one at a time, and the SSE body is still one event
+        a token, byte for byte."""
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.app import EngineApp
+        from seldon_core_tpu.engine.service import PredictionService
+        from seldon_core_tpu.graph.spec import PredictorSpec
+
+        async def go():
+            service = PredictionService(
+                PredictorSpec.model_validate(self.PREDICTOR)
+            )
+            client = TestClient(TestServer(EngineApp(service).build()))
+            await client.start_server()
+            try:
+                unit = service.generative_units()[0]
+                bursts = [b async for b in unit.stream_bursts([5, 9, 2, 17])]
+                single = [t async for t in unit.stream([5, 9, 2, 17])]
+                assert [t for b in bursts for t in b] == single
+                assert len(single) == 6 and len(bursts[0]) == 1
+                assert max(len(b) for b in bursts) == 2  # decode_block
+                resp = await client.post(
+                    "/api/v0.1/predictions/stream",
+                    json={"tokens": [5, 9, 2, 17]},
+                )
+                want = "".join(
+                    f"data: {json.dumps({'token': t})}\n\n" for t in single
+                ) + f"data: {json.dumps({'done': True, 'tokens': single})}\n\n"
+                assert await resp.text() == want
+            finally:
+                await client.close()
+
+        run(go())
+
     def test_stream_rejects_batch_and_non_generative(self):
         from aiohttp.test_utils import TestClient, TestServer
 

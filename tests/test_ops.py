@@ -1101,3 +1101,147 @@ class TestLatentDecodeRead:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=0.03, atol=0.03
         )
+
+
+# (name, tokens, channels, real length, chunk): across chunk edges, a length
+# that is no multiple of the chunk, a real length inside the last chunk, a
+# whole chunk of padding, a prompt shorter than a group of sixteen
+SCAN_CASES = [
+    ("two chunks and a part", 72, 256, 72, 32),
+    ("a length inside the last chunk", 96, 256, 70, 32),
+    ("a chunk of padding behind the length", 96, 128, 40, 32),
+    ("whole chunks", 64, 128, 64, 32),
+    ("shorter than a group", 5, 128, 3, 256),
+    ("one token", 16, 128, 1, 16),
+]
+
+
+class TestSelectiveScan:
+    """``ops/selective_scan.py``: the prompt's recurrence as a Pallas kernel
+    (interpret mode here) against its ``lax.scan`` reference against the
+    plain loop."""
+
+    @staticmethod
+    def _inputs(T, di, n=16, seed=0, dtype=jnp.float32):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+        c = jax.random.normal(ks[0], (T, di)).astype(dtype)
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (T, di)) - 3.0)
+        b = jax.random.normal(ks[2], (T, n))
+        cc = jax.random.normal(ks[3], (T, n))
+        a = -jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32)[:, None], (n, di))
+        d = 1.0 + 0.1 * jax.random.normal(ks[4], (di,))
+        return c, dt, b, cc, a, d
+
+    @staticmethod
+    def _plain(c, dt, b, cc, a, d, length):
+        """The recurrence as the papers write it, a Python loop in numpy
+        float64, the state ``(channels, state index)``."""
+        c, dt, b, cc, a, d = (np.asarray(x, np.float64) for x in (c, dt, b, cc, a, d))
+        s = np.zeros((c.shape[1], a.shape[0]))
+        ys = []
+        for t in range(length):
+            s = np.exp(dt[t][:, None] * a.T) * s + (dt[t] * c[t])[:, None] * b[t][None, :]
+            ys.append(s @ cc[t] + d * c[t])
+        return np.stack(ys), s.T
+
+    @pytest.mark.parametrize("case", SCAN_CASES, ids=[c[0] for c in SCAN_CASES])
+    def test_the_kernel_the_reference_and_the_plain_loop_agree(self, case):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        _, T, di, length, chunk = case
+        args = self._inputs(T, di)
+        y, s = ss.selective_scan(*args, length, chunk=chunk, tile=128)
+        yr, sr = ss.selective_scan_reference(*args, length)
+        yp, sp = self._plain(*args, length)
+        assert y.shape == (T, di) and s.shape == (16, di) and s.dtype == jnp.float32
+        for got_y, got_s in ((y, s), (yr, sr)):
+            np.testing.assert_allclose(np.asarray(got_y[:length]), yp, rtol=2e-5, atol=2e-5)
+            np.testing.assert_allclose(np.asarray(got_s), sp, rtol=2e-5, atol=2e-5)
+        assert np.isfinite(np.asarray(y)).all()  # padding's rows are finite
+
+    def test_padding_moves_neither_the_state_nor_the_real_rows(self):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        args = self._inputs(96, 128)
+        y, s = ss.selective_scan(*args, 40, chunk=32, tile=128)
+        short = tuple(x[:48] if x.shape[0] == 96 else x for x in args)
+        y2, s2 = ss.selective_scan(*short, 40, chunk=16, tile=128)
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(s2))
+        np.testing.assert_array_equal(np.asarray(y[:40]), np.asarray(y2[:40]))
+        # a chunk wholly past the length is not computed: zeros
+        assert not np.asarray(y[64:]).any()
+
+    def test_a_steps_update_is_one_more_token_of_the_scan(self):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        c, dt, b, cc, a, d = self._inputs(33, 128)
+        y, s = ss.selective_scan(c, dt, b, cc, a, d, 33, chunk=16, tile=128)
+        _, s32 = ss.selective_scan(c, dt, b, cc, a, d, 32, chunk=16, tile=128)
+        # four "slots" at once, the state of the first 32 tokens in slot 2
+        slots = jnp.zeros((4, 16, 128)).at[2].set(s32)
+        y1, s1 = ss.selective_step(
+            slots, jnp.tile(c[32], (4, 1)), jnp.tile(dt[32], (4, 1)),
+            jnp.tile(b[32], (4, 1)), jnp.tile(cc[32], (4, 1)), a, d,
+        )
+        np.testing.assert_allclose(np.asarray(s1[2]), np.asarray(s), rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(y1[2]), np.asarray(y[32]), rtol=1e-5, atol=1e-5)
+        # a step of 0 leaves a state as it was
+        _, still = ss.selective_step(slots, c[:4], jnp.zeros((4, 128)), b[:4], cc[:4], a, d)
+        np.testing.assert_array_equal(np.asarray(still), np.asarray(slots))
+
+    def test_bfloat16_activations_keep_a_float32_state(self):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        args = self._inputs(48, 256, dtype=jnp.bfloat16)
+        y, s = ss.selective_scan(*args, 45, chunk=16, tile=128)
+        yr, sr = ss.selective_scan_reference(*args, 45)
+        assert y.dtype == jnp.bfloat16 and s.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(s), np.asarray(sr), rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(
+            np.asarray(y[:45], np.float32), np.asarray(yr[:45], np.float32),
+            rtol=0.02, atol=0.02,
+        )
+
+    def test_the_control_rounds_the_products_and_gives_another_state(self):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        args = self._inputs(64, 128)
+        _, sound = ss.selective_scan(*args, 64, chunk=32, tile=128)
+        _, got = ss.selective_scan(*args, 64, chunk=32, tile=128, product_dtype=jnp.bfloat16)
+        _, want = ss.selective_scan_reference(*args, 64, product_dtype=jnp.bfloat16)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2, atol=2e-2)
+        err = np.linalg.norm(np.asarray(got - sound)) / np.linalg.norm(np.asarray(sound))
+        assert 1e-3 < err < 5e-2
+
+    @pytest.mark.parametrize("slots,channels", [(2, 128), (8, 256), (16, 128), (32, 256)])
+    def test_the_update_in_place_is_the_step_of_one_layer(self, slots, channels):
+        """``selective_update``: the layer named is updated in the carried
+        array as ``selective_step`` would update it taken out, the other
+        layers are not touched, and the array is the call's own (aliased)."""
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        c, dt, b, cc, a, d = self._inputs(slots, channels, seed=slots)
+        states = jax.random.normal(jax.random.PRNGKey(7), (3, slots, 16, channels))
+        kept = np.asarray(states)
+        want_y, want_s = ss.selective_step(states[1], c, dt, b, cc, a, d)
+        update = jax.jit(
+            lambda st, li: ss.selective_update(st, li, c, dt, b, cc, a, d, tile=128),
+            donate_argnums=(0,),
+        )
+        got, y = update(states, jnp.int32(1))
+        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(want_s), rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got[0]), kept[0])
+        np.testing.assert_array_equal(np.asarray(got[2]), kept[2])
+
+    def test_slots_that_are_no_whole_groups_are_refused(self):
+        from seldon_core_tpu.ops import selective_scan as ss
+
+        assert ss.update_group(128) == 16 and ss.update_group(24) == 8
+        assert ss.update_group(3) == 3 and ss.update_group(100) is None
+        with pytest.raises(ValueError, match="100 slots"):
+            ss.selective_update(
+                jnp.zeros((1, 100, 16, 128)), 0, jnp.zeros((100, 128)),
+                jnp.zeros((100, 128)), jnp.zeros((100, 16)), jnp.zeros((100, 16)),
+                jnp.zeros((16, 128)), jnp.zeros((128,)),
+            )

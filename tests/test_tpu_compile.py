@@ -1,5 +1,5 @@
 """The paged decode kernel, the prompt's tiled kernel, the selection's
-kernels (a prompt's and a decode step's) and the touched-only expert kernel through the TPU's own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
+kernels (a prompt's and a decode step's) the touched-only expert kernel and the selective scan through the TPU's own compiler, at the widths the benchmark's cells serve, for a v5e that is described and not attached
 (no chip time; nothing runs).  The interpreter the other tests use accepts
 what Mosaic refuses: a copy or slice off the tiling, too much fast memory.
 
@@ -354,3 +354,111 @@ def test_kimi_k2s_decode_program_makes_no_key_or_value_by_head(one_chip, monkeyp
     # the dense layer's read; the expert layers' read and their experts (one scan)
     assert compiled.as_text().count("tpu_custom_call") == 3
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rung", [256, 4096], ids=["rung 256", "rung 4096"])
+def test_the_selective_scan_kernel_compiles_at_jambas_widths(one_chip, rung):
+    """``ops/selective_scan.py`` at AI21-Jamba2-3B's widths (5,120 channels,
+    a state of 16, bfloat16 activations under a float32 ``D_t``), the
+    smallest and the largest rung of the served ladder: the dynamic group of
+    sixteen rows, the static lane of a token's ``B`` and ``C`` and the sum
+    down the sublanes are Mosaic's to refuse."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.ops.selective_scan import selective_scan
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def f(c, dt, b, cc, a, d, length):
+        return selective_scan(c, dt, b, cc, a, d, length, interpret=False)
+
+    compiled = jax.jit(f).lower(
+        sds((rung, 5120), jnp.bfloat16), sds((rung, 5120)), sds((rung, 16)),
+        sds((rung, 16)), sds((16, 5120)), sds((5120,)), sds((), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # nothing of (tokens, state, channels) is made: that is 335 MB at 1,024
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def _jamba_as_served(one_chip, monkeypatch):
+    """``(module, cfg, params, cache, arg)`` of the cell's deployment as
+    shapes on the described chip: the whole model, 28 layers at the
+    published widths, 128 slots over a pool of 1,153 blocks of 256."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import jamba as m
+
+    # the ops ask the backend whether to interpret: this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(m.Config(), max_seq=4096)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 128, 1153, 256, jnp.bfloat16)
+    ))
+    return m, cfg, params, cache, arg
+
+
+def test_jambas_prompt_program_fits_and_names_its_kernels_once(one_chip, monkeypatch):
+    """``prefill:b1024`` whole at the served shapes: the recurrence's kernel
+    and the tiled attention are in it once each (one scan over the 28 layers,
+    branching on the layer's kind), the arguments are the issue's 7.55 GB
+    and the temporaries tens of MB: no copy of the slots' state (1.09 GB),
+    of the convolution tails (102 MB: a tail written a tap at a time) or of
+    the pool through a branch."""
+    import functools
+
+    import jax
+
+    m, cfg, params, cache, arg = _jamba_as_served(one_chip, monkeypatch)
+    prefill = jax.jit(
+        functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"),
+        donate_argnums=(5,),
+    )
+    compiled = prefill.lower(
+        params, arg((1, 1024)), arg(()), arg(()), arg((16,)), cache
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "ssm.scan" in text
+    memory = compiled.memory_analysis()
+    assert 7.5e9 < memory.argument_size_in_bytes < 7.6e9
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
+def test_jambas_decode_program_updates_the_slots_state_in_place(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (128 slots, 28 layers, the
+    state of 26 of them 1.09 GB, window 4,096): the paged kernel reads 20
+    query rows on one 128-lane key-value head at each attention layer, the
+    update kernel takes the whole carried array aliased to its result, and
+    the program's temporaries are megabytes — a copy of one layer's state for
+    all slots would be 42 MB, of the whole state 1.09 GB."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    m, cfg, params, cache, arg = _jamba_as_served(one_chip, monkeypatch)
+    step = jax.jit(
+        functools.partial(m.decode_slots_paged, cfg=cfg, window=4096, kernel=True),
+        donate_argnums=(2,),
+    )
+    compiled = step.lower(params, arg((128,)), cache, arg((128,), jnp.bool_)).compile()
+    # the two attention layers' reads, and the update in place in each of
+    # the three runs of state-space layers
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5 and text.count("ssm.update") >= 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
