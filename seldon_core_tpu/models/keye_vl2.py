@@ -60,9 +60,12 @@ Mosaic takes no copy of one row of a tiled pool).  A static window of
 time — ``select_topk_mask`` makes a chunk's selection (scores in tiles that
 never leave VMEM, the exact ``topk``-th score of a row by bisection, ties to
 the lower positions) as one int8 a pair, and ``masked_flash_attention`` runs
-the tiled attention under it.  The other way — gathering a query's ``topk``
-rows — would move ``topk`` x 2 KB x 2 for every query and layer (200 GB a
-24k prompt) and was not built.
+the tiled attention under it: eight query heads of a kv head a step, over the
+key tiles that start at or before the strip's last query alone, its running
+max and denominator whole vregs and a score selected once (PERF.md §6, PR 44
+and PR 46: what a tile does and why).  The other way — gathering a query's
+``topk`` rows — would move ``topk`` x 2 KB x 2 for every query and layer
+(200 GB a 24k prompt) and was not built.
 
 Expert products are ``cohere2_moe``'s under a softmax route, chosen by its
 one rule of static shapes (``experts_plan``): ``_experts_grouped`` in a

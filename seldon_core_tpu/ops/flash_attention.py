@@ -72,14 +72,17 @@ TILE_PLANS: dict[str, dict[str, int]] = {}
 
 
 @functools.lru_cache(maxsize=None)
-def _tile_steps(S, Sk, block_q, block_k, causal, window):
+def _tile_steps(S, Sk, block_q, block_k, causal, window, q_offset=0):
     """The grid's steps as three int32 arrays ``(q tile, key tile, kind)``:
     every tile that holds a visible key, query tile by query tile, key tiles
     ascending; a query tile that sees no key at all keeps one step that is
-    not live, which writes its zeros."""
+    not live, which writes its zeros.  Query ``i`` stands at the keys'
+    position ``q_offset + i`` (a later chunk of a prompt over every key so
+    far: ``ops/sparse_attention.py::masked_flash_attention``)."""
     q_of, k_of, kind = [], [], []
     for qi in range(S // block_q):
-        r0, r1 = qi * block_q, qi * block_q + block_q - 1
+        r0 = q_offset + qi * block_q
+        r1 = r0 + block_q - 1
         row = []
         for ki in range(Sk // block_k):
             c0, c1 = ki * block_k, ki * block_k + block_k - 1
@@ -101,11 +104,11 @@ def _tile_steps(S, Sk, block_q, block_k, causal, window):
     return tuple(np.asarray(a, np.int32) for a in (q_of, k_of, kind))
 
 
-def tile_plan(S, Sk, block_q, block_k, causal=True, window=None):
+def tile_plan(S, Sk, block_q, block_k, causal=True, window=None, q_offset=0):
     """``(stepped, live, masked)``: the grid steps a head takes, those that
     run the products, and those of them that build a mask — static in the
     shapes, and what the kernel's grid and bodies are made from."""
-    kind = _tile_steps(S, Sk, block_q, block_k, causal, window)[2]
+    kind = _tile_steps(S, Sk, block_q, block_k, causal, window, q_offset)[2]
     return (
         len(kind),
         int(np.count_nonzero(kind & _LIVE)),

@@ -29,7 +29,27 @@ the decode read of the selected rows is XLA's.
 * :func:`masked_flash_attention` — the tiled attention of
   ``ops/flash_attention.py`` under that mask, the query heads of one kv
   head together in a step so that the mask and the keys are read once a
-  group, not once a head.
+  group, not once a head.  **A masked tile costs what a selected tile
+  needs** (PR 46, as PR 44 did for the tiled kernel; PERF.md §6): the grid
+  is ``(KV, steps)`` and a step is one LIVE tile — a key tile that starts
+  at or before the strip's last query, from the list
+  ``flash_attention._tile_steps`` makes of the static shapes and
+  ``q_offset``, by scalar prefetch; the running max and denominator are
+  ``(G * block_q, 128)`` with every lane of a row the same and stay
+  two-dimensional from the scores' reduction to the rescale, whole vregs
+  in and out (cutting a one-lane column out and broadcasting it back,
+  three times a tile of 2,048 rows, was 38 % of the kernel's time); and a
+  score is selected once: the running max starts at
+  ``M_INIT``, above the mask's value, so a score left out has the exponent
+  0 whatever its row has seen and a row with nothing selected ends at
+  zeros.  A step works its group's heads in strips of ``_HEADS_A_STRIP``
+  inside a ``fori_loop``, a head's ``(block_q, block_k)`` scores at a time:
+  within a strip the compiler runs one head's products under another's
+  softmax, where the whole group's 2,048 x 512 scores as one array left the
+  MXU waiting on the vector passes.  The sums and their order are the
+  kernel's as it stood — a row's scores, its max, its exponents and sums
+  over the same key tile of ``block_k`` — so the output has the same bits
+  whatever ``block_q`` and the strips are.
 
 Each kernel has its XLA reference (``*_reference``) of the same mathematics;
 on the CPU the kernels run in Pallas interpret mode and the tests hold them
@@ -46,10 +66,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from seldon_core_tpu.ops.flash_attention import (
+    _FIRST,
+    _LAST,
+    M_INIT,
+    TILE_PLANS,
+    _lanes,
+    _tile_steps,
+    tile_plan,
+)
 from seldon_core_tpu.ops.paged_attention import NEG_INF, mxu_operands
 
 INT_MIN = -(2**31)
 _VMEM_LIMIT = 96 << 20
+# the query heads of a kv head that one iteration of the masked kernel's loop
+# works: measured at 8 heads of 512 x 512 (PERF.md §6, PR 46)
+_HEADS_A_STRIP = 4
 
 
 def _interpret(flag):
@@ -549,59 +581,68 @@ def select_topk_mask_reference(qi, wi, ki, *, topk, q_offset=0,
 # ---------------------------------------------------------------------------
 
 def _masked_flash_kernel(
+    q_of, k_of, kind,  # the live tiles: ``flash_attention._tile_steps``
     q_ref,  # (1, G, bq, D): the query heads of one kv head
     k_ref,  # (1, bk, D)
     v_ref,
     mask_ref,  # (bq, bk) int8
     o_ref, m_scr, l_scr, acc_scr,
-    *, bq, bk, n_k, q_offset, scale,
+    *, scale,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    G, D = q_ref.shape[1], q_ref.shape[3]
+    what = kind[pl.program_id(1)]
+    G, bq, D = q_ref.shape[1:]
+    bk = k_ref.shape[1]
 
-    @pl.when(ki == 0)
+    @pl.when((what & _FIRST) != 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, M_INIT)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     cdt, prec = mxu_operands(q_ref.dtype)
+    # every step runs its tile (a strip's first key tile holds position 0)
+    sel = mask_ref[...] != 0  # once a tile, for every head of the group
 
-    # a tile wholly after the strip's last query selects nothing
-    @pl.when(ki * bk <= q_offset + qi * bq + bq - 1)
-    def _tile():
-        q = (q_ref[0] * scale).astype(cdt).reshape(G * bq, D)
+    def head(g):
+        rows = pl.ds(pl.multiple_of(g * bq, bq), bq)
+        q = (q_ref[0, g] * scale).astype(cdt)  # (bq, D)
         k = k_ref[0].astype(cdt)
         v = v_ref[0].astype(cdt)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
-        ).reshape(G, bq, bk)
-        sel = (mask_ref[...] != 0)[None]
-        s = jnp.where(sel, s, NEG_INF).reshape(G * bq, bk)
-        m_prev = m_scr[:, 0]
-        l_prev = l_scr[:, 0]
-        m_cur = jnp.maximum(m_prev, s.max(axis=-1))
-        # a row with nothing selected so far keeps m at NEG_INF, where
-        # exp(s - m) would be 1 for every masked key
-        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_cur[:, None]), 0.0)
+        )  # (bq, bk)
+        # the one select: the running max starts at M_INIT, above NEG_INF, so
+        # a score left out has the exponent 0 whatever its row has seen
+        s = jnp.where(sel, s, NEG_INF)
+        m_prev = m_scr[rows, :]  # (bq, w): a row's lanes all equal, as l_scr's
+        m_cur = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _lanes(m_cur, bk))
         alpha = jnp.exp(m_prev - m_cur)
-        l_cur = alpha * l_prev + p.sum(axis=-1)
-        acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
+        l_scr[rows, :] = alpha * l_scr[rows, :] + p.sum(axis=-1, keepdims=True)
+        m_scr[rows, :] = m_cur
+        acc_scr[rows, :] = acc_scr[rows, :] * _lanes(alpha, D) + jax.lax.dot_general(
             p.astype(cdt), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )
-        m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
 
-    @pl.when(ki == n_k - 1)
+    # the group's heads in strips: within a strip one head's products run
+    # under another's softmax, and the loop keeps the body (and its compile)
+    # at a strip's size
+    per = math.gcd(G, _HEADS_A_STRIP)
+
+    def strip(i, carry):
+        for j in range(per):
+            head(i * per + j)
+        return carry
+
+    jax.lax.fori_loop(0, G // per, strip, 0)
+
+    @pl.when((what & _LAST) != 0)
     def _emit():
-        l = l_scr[:, 0]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l[:, None]).reshape(G, bq, D).astype(
-            o_ref.dtype
-        )
+        l = _lanes(l_scr[...], D)
+        safe_l = jnp.where(l == 0.0, 1.0, l)  # nothing selected -> zeros
+        o_ref[0] = (acc_scr[...] / safe_l).reshape(G, bq, D).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -609,53 +650,58 @@ def _masked_flash_kernel(
 )
 def masked_flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array, *,
-    q_offset: int = 0, block_q: int = 256, block_k: int = 512,
+    q_offset: int = 0, block_q: int = 512, block_k: int = 512,
     interpret: bool | None = None,
 ) -> jax.Array:
     """``q (H, Lq, D)`` at positions ``q_offset ..`` over ``k``, ``v (KV,
     Lk, D)`` at ``0 .. Lk - 1`` where ``mask (Lq, Lk) int8`` is set — a
     mask that selects nothing after a query's own position (the causal
-    bound lets whole tiles be skipped).  All heads share the mask; a row
-    with nothing selected gives zeros.  Returns ``(H, Lq, D)``."""
+    bound: a tile wholly after a strip's last query is no grid step).  All
+    heads share the mask; a row with nothing selected gives zeros.  Returns
+    ``(H, Lq, D)``."""
     H, Lq, D = q.shape
     KV, Lk = k.shape[:2]
     G = H // KV
     bq, bk = min(block_q, Lq), min(block_k, Lk)
     if Lq % bq or Lk % bk:
         raise ValueError(f"({Lq}, {Lk}) is not whole tiles of ({bq}, {bk})")
-    n_k = Lk // bk
     q_offset = int(q_offset)
 
-    def last(qi):  # the last key tile a strip of queries can select from
-        return jnp.minimum((q_offset + qi * bq + bq - 1) // bk, n_k - 1)
+    steps = _tile_steps(Lq, Lk, bq, bk, True, None, q_offset)
+    stepped, live, _ = tile_plan(Lq, Lk, bq, bk, True, None, q_offset)
+    # traced once a shape, beside the tiled kernel's
+    TILE_PLANS[f"masked:S{Lq}:Sk{Lk}:{bq}x{bk}:q{q_offset}"] = {
+        "stepped": stepped, "live": live,
+    }
 
-    kernel = functools.partial(
-        _masked_flash_kernel, bq=bq, bk=bk, n_k=n_k, q_offset=q_offset,
-        scale=1.0 / math.sqrt(D),
-    )
+    lanes = 128 if bk % 128 == 0 else bk  # the statistics' width
     out = pl.pallas_call(
-        kernel,
-        grid=(KV, Lq // bq, n_k),
-        in_specs=[
-            pl.BlockSpec((1, G, bq, D), lambda h, qi, ki: (h, 0, qi, 0)),
-            # past a strip's last tile the block stays the same: no copy
-            pl.BlockSpec((1, bk, D), lambda h, qi, ki: (h, jnp.minimum(ki, last(qi)), 0)),
-            pl.BlockSpec((1, bk, D), lambda h, qi, ki: (h, jnp.minimum(ki, last(qi)), 0)),
-            pl.BlockSpec((bq, bk), lambda h, qi, ki: (qi, jnp.minimum(ki, last(qi)))),
-        ],
-        out_specs=pl.BlockSpec((1, G, bq, D), lambda h, qi, ki: (h, 0, qi, 0)),
+        functools.partial(_masked_flash_kernel, scale=1.0 / math.sqrt(D)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(KV, stepped),
+            in_specs=[
+                pl.BlockSpec((1, G, bq, D), lambda h, t, qo, ko, kd: (h, 0, qo[t], 0)),
+                pl.BlockSpec((1, bk, D), lambda h, t, qo, ko, kd: (h, ko[t], 0)),
+                pl.BlockSpec((1, bk, D), lambda h, t, qo, ko, kd: (h, ko[t], 0)),
+                pl.BlockSpec((bq, bk), lambda h, t, qo, ko, kd: (qo[t], ko[t])),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, G, bq, D), lambda h, t, qo, ko, kd: (h, 0, qo[t], 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((G * bq, lanes), jnp.float32),  # running max, every lane
+                pltpu.VMEM((G * bq, lanes), jnp.float32),  # running denom, every lane
+                pltpu.VMEM((G * bq, D), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((KV, G, Lq, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G * bq, 128), jnp.float32),
-            pltpu.VMEM((G * bq, 128), jnp.float32),
-            pltpu.VMEM((G * bq, D), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=_interpret(interpret),
-    )(q.reshape(KV, G, Lq, D), k, v, mask)
+    )(*(jnp.asarray(a) for a in steps), q.reshape(KV, G, Lq, D), k, v, mask)
     return out.reshape(H, Lq, D)
 
 

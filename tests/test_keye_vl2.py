@@ -491,6 +491,27 @@ class TestServedPath:
         assert snap["dsa.keys_selected"] >= 4 * 2 * 8  # steps x layers x topk
         assert snap["dsa.keys_scored"] > snap["dsa.keys_selected"]
 
+    def test_a_long_prompt_leaves_the_masked_kernels_tile_plan(self, prompt, monkeypatch):
+        """A prompt longer than ``index_topk`` through ``seq_impl="flash"``:
+        each chunk's ``masked_flash_attention`` shape leaves the grid it was
+        compiled with — live tiles alone, by the shapes — where
+        ``breakdown.generation.<unit>.programs.tile_plans`` shows it."""
+        monkeypatch.setattr(m, "QUERY_CHUNK", 16)  # the rung of 64 in four chunks
+        model = self._component(seq_impl="flash", decode_kernel=True).model
+        assert len(prompt) > model.cfg.index_topk
+        model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=4)
+        plans = model.program_snapshot()["tile_plans"]
+        for q0 in (0, 16, 32, 48):
+            lq, lk = 16, q0 + 16
+            bq, bk = min(512, lq), min(512, lk)  # the kernel's own tiles
+            live = sum(
+                ki * bk <= q0 + qi * bq + bq - 1
+                for qi in range(lq // bq) for ki in range(lk // bk)
+            )
+            assert plans[f"masked:S{lq}:Sk{lk}:{bq}x{bk}:q{q0}"] == {
+                "stepped": live, "live": live,
+            }
+
     def test_prefix_reuse_shares_index_keys_with_the_blocks(self, prompt):
         comp = self._component(kv_prefix_reuse=True)
         model = comp.model

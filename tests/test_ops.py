@@ -199,6 +199,80 @@ class TestFlashTilePlan:
             int(live.sum()) + blind, int(live.sum()), int(masked.sum())
         )
 
+    @staticmethod
+    def _steps_by_tile(S, Sk, bq, bk, window, q_offset=0):
+        """The step list from each tile's own visibility matrix: ``(query
+        tile, key tile, live, masked, first, last)``, key tiles ascending."""
+        want = []
+        for qi in range(S // bq):
+            i = q_offset + qi * bq + np.arange(bq)[:, None]
+            row = []
+            for ki in range(Sk // bk):
+                j = ki * bk + np.arange(bk)[None, :]
+                seen = j <= i
+                if window is not None:
+                    seen &= j > i - window
+                if seen.any():
+                    row.append((ki, True, not seen.all()))
+            row = row or [(0, False, False)]
+            want += [
+                (qi, ki, live, masked, n == 0, n == len(row) - 1)
+                for n, (ki, live, masked) in enumerate(row)
+            ]
+        return want
+
+    @staticmethod
+    def _steps_as_tuples(steps):
+        from seldon_core_tpu.ops.flash_attention import _FIRST, _LAST, _LIVE, _MASKED
+
+        return [
+            (int(q), int(k), bool(w & _LIVE), bool(w & _MASKED),
+             bool(w & _FIRST), bool(w & _LAST))
+            for q, k, w in zip(*steps)
+        ]
+
+    @pytest.mark.parametrize("S,window", [
+        (6144, None), (8192, None), (12288, None),   # Kimi-K2.6's rungs
+        (4096, 4096), (6144, 4096), (8192, 4096),    # Command A+'s, windowed
+    ])
+    def test_the_steps_of_a_whole_prompt_are_what_they_were(self, S, window):
+        """``q_offset`` defaults to 0, and the other callers' lists (512 x
+        512 tiles) are each tile's own visibility, as before it came."""
+        from seldon_core_tpu.ops.flash_attention import _tile_steps
+
+        steps = _tile_steps(S, S, 512, 512, True, window)
+        assert all(a.dtype == np.int32 for a in steps)
+        assert self._steps_as_tuples(steps) == self._steps_by_tile(S, S, 512, 512, window)
+        for a, b in zip(steps, _tile_steps(S, S, 512, 512, True, window, 0)):
+            assert np.array_equal(a, b)
+
+    @pytest.mark.parametrize("Lq,q_offset,bq,bk", [
+        (8192, 0, 512, 512), (8192, 8192, 512, 512), (8192, 16384, 512, 512),
+        (4096, 8192, 512, 512),       # Keye-VL-2.0's chunks of 24,576 and 12,288
+        (8192, 16384, 256, 512),      # and at the tiles it had before PR 46
+        (64, 40, 8, 16),              # an offset that is no multiple of a tile
+        (32, 24, 16, 8), (48, 0, 16, 32),
+    ])
+    def test_a_later_chunks_live_tiles(self, Lq, q_offset, bq, bk):
+        """Queries at ``q_offset ..`` over every key so far: a key tile is
+        live iff it starts at or before the strip's last query."""
+        from seldon_core_tpu.ops.flash_attention import _LIVE, _tile_steps, tile_plan
+
+        Lk = -(-(q_offset + Lq) // bk) * bk
+        q_of, k_of, kind = _tile_steps(Lq, Lk, bq, bk, True, None, q_offset)
+        want = [
+            (qi, ki) for qi in range(Lq // bq) for ki in range(Lk // bk)
+            if ki * bk <= q_offset + qi * bq + bq - 1
+        ]
+        assert list(zip(q_of.tolist(), k_of.tolist())) == want
+        assert (kind & _LIVE).all()   # a strip always sees position 0
+        stepped, live, _ = tile_plan(Lq, Lk, bq, bk, True, None, q_offset)
+        assert stepped == live == len(want)
+        if Lq <= 64:   # and by each tile's own visibility, flags and all
+            assert self._steps_as_tuples((q_of, k_of, kind)) == self._steps_by_tile(
+                Lq, Lk, bq, bk, None, q_offset
+            )
+
     @pytest.mark.parametrize("case,S,H,Hk,D,Dv,window,blocks", [
         ("latent widths, grouped", 256, 4, 2, 192, 128, None, (64, 64)),
         ("one tile", 128, 2, 2, 32, 32, None, (128, 128)),
@@ -772,6 +846,86 @@ class TestPoolRead:
         assert len(found) == (4 if pool == "int8" else 2), found
 
 
+def _parents_masked_flash_attention(q, k, v, mask, *, q_offset, block_q, block_k):
+    """``masked_flash_attention`` as it stood before PR 46, kept to hold the
+    kernel's bits: one-lane statistics cut out of the scratch and broadcast
+    back, two selects a score, the square grid with clamped index maps."""
+    import functools
+    import math
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from seldon_core_tpu.ops.paged_attention import NEG_INF, mxu_operands
+
+    def kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr,
+               *, bq, bk, n_k, scale):
+        qi, ki = pl.program_id(1), pl.program_id(2)
+        G, D = q_ref.shape[1], q_ref.shape[3]
+
+        @pl.when(ki == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        cdt, prec = mxu_operands(q_ref.dtype)
+
+        @pl.when(ki * bk <= q_offset + qi * bq + bq - 1)
+        def _tile():
+            qs = (q_ref[0] * scale).astype(cdt).reshape(G * bq, D)
+            s = jax.lax.dot_general(
+                qs, k_ref[0].astype(cdt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            ).reshape(G, bq, bk)
+            s = jnp.where((mask_ref[...] != 0)[None], s, NEG_INF).reshape(G * bq, bk)
+            m_prev = m_scr[:, 0]
+            l_prev = l_scr[:, 0]
+            m_cur = jnp.maximum(m_prev, s.max(axis=-1))
+            p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_cur[:, None]), 0.0)
+            alpha = jnp.exp(m_prev - m_cur)
+            l_cur = alpha * l_prev + p.sum(axis=-1)
+            acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
+                p.astype(cdt), v_ref[0].astype(cdt), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+            m_scr[:] = jnp.broadcast_to(m_cur[:, None], m_scr.shape)
+            l_scr[:] = jnp.broadcast_to(l_cur[:, None], l_scr.shape)
+
+        @pl.when(ki == n_k - 1)
+        def _emit():
+            l = l_scr[:, 0]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_scr[:] / safe_l[:, None]).reshape(G, bq, D).astype(o_ref.dtype)
+
+    H, Lq, D = q.shape
+    KV, Lk = k.shape[:2]
+    G, bq, bk = H // KV, block_q, block_k
+    n_k = Lk // bk
+
+    def last(qi):
+        return jnp.minimum((q_offset + qi * bq + bq - 1) // bk, n_k - 1)
+
+    return pl.pallas_call(
+        functools.partial(kernel, bq=bq, bk=bk, n_k=n_k, scale=1.0 / math.sqrt(D)),
+        grid=(KV, Lq // bq, n_k),
+        in_specs=[
+            pl.BlockSpec((1, G, bq, D), lambda h, qi, ki: (h, 0, qi, 0)),
+            pl.BlockSpec((1, bk, D), lambda h, qi, ki: (h, jnp.minimum(ki, last(qi)), 0)),
+            pl.BlockSpec((1, bk, D), lambda h, qi, ki: (h, jnp.minimum(ki, last(qi)), 0)),
+            pl.BlockSpec((bq, bk), lambda h, qi, ki: (qi, jnp.minimum(ki, last(qi)))),
+        ],
+        out_specs=pl.BlockSpec((1, G, bq, D), lambda h, qi, ki: (h, 0, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((KV, G, Lq, D), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((G * bq, 128), jnp.float32),
+            pltpu.VMEM((G * bq, 128), jnp.float32),
+            pltpu.VMEM((G * bq, D), jnp.float32),
+        ],
+        interpret=True,
+    )(q.reshape(KV, G, Lq, D), k, v, mask).reshape(H, Lq, D)
+
+
 class TestSparseAttention:
     """``ops/sparse_attention.py``: the two prompt kernels in interpret mode
     against their XLA references, and the decode read (XLA: a gather of the
@@ -833,23 +987,40 @@ class TestSparseAttention:
         if case != "two levels":
             assert np.asarray(got)[:, : self.TOPK].all()
 
-    @pytest.mark.parametrize("q_offset,dtype", [
-        (0, jnp.float32), (64, jnp.float32), (64, jnp.bfloat16),
+    def _qkv(self, lq, lk, h, kv, d, dtype):
+        ks = jax.random.split(jax.random.PRNGKey(2), 3)
+        return (
+            jax.random.normal(ks[0], (h, lq, d)).astype(dtype),
+            jax.random.normal(ks[1], (kv, lk, d)).astype(dtype),
+            jax.random.normal(ks[2], (kv, lk, d)).astype(dtype),
+        )
+
+    # (queries, q_offset, query heads, kv heads, (block_q, block_k))
+    MASKED_SHAPES = {
+        "no offset": (32, 0, 8, 2, (16, 32)),
+        "two kv heads": (32, 64, 8, 2, (16, 32)),
+        # eight heads a step, four and five key tiles a strip, the offset no
+        # multiple of a key tile
+        "a group of eight": (24, 40, 8, 1, (8, 16)),
+        "from the first key": (32, 0, 16, 2, (8, 16)),
+    }
+
+    @pytest.mark.parametrize("case,dtype", [
+        ("no offset", jnp.float32),
+        ("two kv heads", jnp.float32), ("two kv heads", jnp.bfloat16),
+        ("a group of eight", jnp.float32), ("a group of eight", jnp.bfloat16),
     ])
-    def test_tiled_attention_under_the_mask(self, q_offset, dtype):
+    def test_tiled_attention_under_the_mask(self, case, dtype):
         from seldon_core_tpu.ops import sparse_attention as sa
 
-        lq, h, kv, d = 32, 8, 2, 16
+        lq, q_offset, h, kv, (bq, bk) = self.MASKED_SHAPES[case]
         lk = q_offset + lq
-        ks = jax.random.split(jax.random.PRNGKey(2), 3)
-        q = jax.random.normal(ks[0], (h, lq, d)).astype(dtype)
-        k = jax.random.normal(ks[1], (kv, lk, d)).astype(dtype)
-        v = jax.random.normal(ks[2], (kv, lk, d)).astype(dtype)
+        q, k, v = self._qkv(lq, lk, h, kv, 16, dtype)
         mask = sa.select_topk_mask_reference(
             *self._index(lq, lk), topk=self.TOPK, q_offset=q_offset
         )
         got = sa.masked_flash_attention(
-            q, k, v, mask, q_offset=q_offset, block_q=16, block_k=32
+            q, k, v, mask, q_offset=q_offset, block_q=bq, block_k=bk
         )
         want = sa.masked_attention_reference(q, k, v, mask)
         tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -866,6 +1037,66 @@ class TestSparseAttention:
         mask = jnp.zeros((16, 32), jnp.int8).at[3:, 0].set(1)
         out = np.asarray(sa.masked_flash_attention(q, kv, kv, mask, block_q=8, block_k=16))
         assert not out[:, :3].any() and np.allclose(out[:, 3:], 1.0)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_a_rows_first_selected_key_may_lie_in_a_later_tile(self, dtype):
+        """The running max starts at ``M_INIT``: over live tiles that hold
+        nothing of a row's it stays zeros (no exponent of a masked score is
+        1), and from the row's first selected key on it is the reference's."""
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        lq, q_offset, bk = 16, 48, 16
+        lk = q_offset + lq   # four key tiles, every one live for every row
+        q, k, v = self._qkv(lq, lk, 8, 1, 16, dtype)
+        first = np.asarray([0, 17, 33, 50, 16, 47, 48, 63] * 2)  # tile 0 .. 3
+        mask = (np.arange(lk)[None, :] >= first[:, None]) & (
+            np.arange(lk)[None, :] <= q_offset + np.arange(lq)[:, None]
+        ) & (np.arange(lk)[None, :] % 3 != 1)
+        mask[7] = mask[15] = False   # 63 lies after rows 7's and 15's own place
+        mask[15, 63] = True
+        mask = jnp.asarray(mask, jnp.int8)
+        got = np.asarray(sa.masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, block_q=8, block_k=bk
+        ), np.float32)
+        want = np.asarray(sa.masked_attention_reference(q, k, v, mask), np.float32)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+        assert not got[:, 7].any() and got[:, 15].any()
+        # one key selected: the row is that key's value
+        np.testing.assert_allclose(
+            got[:, 15], np.asarray(v, np.float32)[0, 63][None].repeat(8, 0), atol=tol
+        )
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("case", list(MASKED_SHAPES))
+    def test_the_masked_kernel_gives_its_parents_bits(self, case, dtype):
+        """Whole-vreg statistics, one select a score and the grid over live
+        tiles change layouts and which steps run, not the sums or their
+        order (PERF.md §6, PR 46): equal to the kernel as it stood, bit for
+        bit, rows with nothing selected and late first keys among them."""
+        from seldon_core_tpu.ops import sparse_attention as sa
+
+        lq, q_offset, h, kv, (bq, bk) = self.MASKED_SHAPES[case]
+        lk = q_offset + lq
+        q, k, v = self._qkv(lq, lk, h, kv, 16, dtype)
+        mask = sa.select_topk_mask_reference(
+            *self._index(lq, lk), topk=self.TOPK, q_offset=q_offset
+        )
+        mask = mask.at[3].set(0).at[5, : lk - 8].set(0)
+        got = sa.masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, block_q=bq, block_k=bk
+        )
+        want = _parents_masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, block_q=bq, block_k=bk
+        )
+        assert got.dtype == want.dtype == dtype
+        assert np.array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+        assert not np.asarray(got, np.float32)[:, 3].any()
+        # a row's sums run over a key tile: a taller query tile, the same bits
+        taller = sa.masked_flash_attention(
+            q, k, v, mask, q_offset=q_offset, block_q=lq, block_k=bk
+        )
+        assert np.array_equal(np.asarray(taller, np.float32), np.asarray(want, np.float32))
 
     def test_the_decode_read_attends_the_selected_rows_alone(self):
         from seldon_core_tpu.ops import sparse_attention as sa
