@@ -33,11 +33,11 @@ shapes in one place (:func:`experts_plan`).  A prefill (thousands of tokens)
 sorts its (token, expert) pairs by expert and runs grouped products
 (``lax.ragged_dot``) over the held pairs alone, in chunks whose count
 follows the pairs actually held.  A decode step (a few tokens) is bound by
-reading expert weights: where its tokens are expected to touch nearly every
-held expert (32 slots over 16 held) it runs every held expert over every
-token, densely, reading each once either way; where they touch a fraction
-(8 slots x top-8 over 128 held: a third) a Pallas kernel streams the touched
-experts alone (``ops/touched_experts.py``).
+reading expert weights, and a Pallas kernel streams the experts its tokens
+chose and no other (``ops/touched_experts.py``): a third of the 128 that 8
+slots x top-8 hold, 12 of the 16 that 30 live slots share.  Every held
+expert over every token, densely, is what is left where the kernel cannot be
+handed the stacks (a mesh, a caller without them).
 
 The paged pool is uniform: every layer keeps every token's K/V, and a
 sliding layer READS only the blocks that hold its window (the window saves
@@ -59,11 +59,6 @@ from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
 
 # tokens in one call above which the held experts' products are grouped
 GROUPED_FROM = 256
-# a call of fewer tokens streams only the experts its tokens chose
-# (ops/touched_experts.py) where it is expected to touch no more than this
-# share of the held experts, and every held expert densely above it
-# (:func:`experts_plan`; PERF.md §6, PR 39 has the measurement behind it)
-TOUCHED_SHARE_MAX = 0.6
 # rows of (token, expert) pairs one grouped pass takes
 GROUP_CHUNK = 4096
 # query rows one pass of the XLA attention scores at once
@@ -361,27 +356,19 @@ def _route(h2, w_router, cfg: Config):
     return idx.astype(jnp.int32), w.astype(jnp.float32)
 
 
-def expected_touched_share(n_tokens: int, experts_per_tok: int, n_experts: int) -> float:
-    """The share of the experts that ``n_tokens`` tokens, each choosing
-    ``experts_per_tok`` of ``n_experts`` evenly, are expected to touch."""
-    return 1.0 - (1.0 - experts_per_tok / n_experts) ** n_tokens
-
-
-def experts_plan(n_tokens: int, experts_per_tok: int, n_experts: int, *,
-                 kernel: bool = True) -> str:
+def experts_plan(n_tokens: int, *, kernel: bool = True) -> str:
     """Which formulation runs the held experts' products for a call of
     ``n_tokens`` tokens, from static shapes alone: ``"grouped"`` for a
     prompt (:data:`GROUPED_FROM` tokens or more); else ``"touched"`` — the
-    kernel that streams only the experts some token chose — where the call
-    is expected to touch at most :data:`TOUCHED_SHARE_MAX` of them (8 slots
-    x top-8 of 128: 0.40), and ``"dense"`` where it touches nearly all it
-    holds anyway (32 x top-8 of 128: 0.87).  ``kernel`` says the kernel can
-    be handed the stacks: every layer's are at hand, and on one device (it
-    is not offered stacks sharded over a mesh); without it, dense."""
+    kernel that streams only the experts some token chose — wherever the
+    kernel can be handed the stacks (``kernel``: every layer's are at hand,
+    and on one device; it is not offered stacks sharded over a mesh), and
+    ``"dense"`` where it cannot.  The kernel reads a byte as fast as the
+    dense products and never more of them (PERF.md §6, PR 47: level where a
+    call touches every expert it holds, ahead by what it skips elsewhere)."""
     if n_tokens >= GROUPED_FROM:
         return "grouped"
-    share = expected_touched_share(n_tokens, experts_per_tok, n_experts)
-    return "touched" if kernel and share <= TOUCHED_SHARE_MAX else "dense"
+    return "touched" if kernel else "dense"
 
 
 def _tokens_on_experts(local, held, count: int):
@@ -401,9 +388,9 @@ def _combine_weights(local, held, w, count: int):
 
 
 def _experts_dense(h2, lp, local, held, w):
-    """Every held expert over every token (decode): the step reads each
-    held expert's weights once whichever tokens chose it.  -> (T, E) f32.
-    Also what a mesh-sharded expert stack runs."""
+    """Every held expert over every token: the call reads each held
+    expert's weights once whichever tokens chose it.  -> (T, E) f32.  What
+    a mesh-sharded expert stack runs, and a caller with no stack at hand."""
     cw = _combine_weights(local, held, w, lp["we_gate"].shape[0])
     g = jnp.einsum("te,xef->xtf", h2, lp["we_gate"])
     u = jnp.einsum("te,xef->xtf", h2, lp["we_up"])
@@ -412,8 +399,8 @@ def _experts_dense(h2, lp, local, held, w):
 
 
 def _experts_touched(h2, stacks, li, local, held, w):
-    """The held experts that at least one token chose, and no other
-    (decode, few tokens over many held experts): what
+    """The held experts that at least one token chose, and no other (a
+    call of under :data:`GROUPED_FROM` tokens): what
     :func:`_experts_dense` sums, less the terms whose weight is 0, through
     the kernel that streams an expert by the list of those touched
     (``ops/touched_experts.py``).  ``stacks`` and ``li`` as
@@ -518,9 +505,7 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li,
     (:func:`_experts_grouped` says why it wants those); ``sharded`` (static)
     says the stacks lie over a mesh."""
     first, count = cfg.held
-    plan = experts_plan(
-        h2.shape[0], cfg.experts_per_tok, cfg.n_experts, kernel=not sharded
-    )
+    plan = experts_plan(h2.shape[0], kernel=not sharded)
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
         local = idx - first

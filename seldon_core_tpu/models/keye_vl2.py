@@ -71,7 +71,7 @@ Expert products are ``cohere2_moe``'s under a softmax route, chosen by its
 one rule of static shapes (``experts_plan``): ``_experts_grouped`` in a
 prompt; in a decode step the kernel that streams only the experts some token
 chose (``_experts_touched``: 8 slots x top-8 touch a third of 128), and
-``_experts_dense`` where a step would touch nearly all of them anyway.
+``_experts_dense`` for a caller that hands ``_moe`` no stack.
 ``experts_held`` means what it means there.  ``COUNTERS`` keeps that
 family's names and adds the selection's five.
 """
@@ -520,9 +520,7 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool,
     ``lp``'s); a caller without them gets the dense products where the
     touched-only kernel would have run."""
     first, count = cfg.held
-    plan = experts_plan(
-        h2.shape[0], cfg.experts_per_tok, cfg.n_experts, kernel=stacks is not None
-    )
+    plan = experts_plan(h2.shape[0], kernel=stacks is not None)
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
         local = idx - first

@@ -506,9 +506,7 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool,
     kernel wants those and not ``lp``'s); a caller without them gets the
     dense products."""
     first, count = cfg.held
-    plan = experts_plan(
-        h2.shape[0], cfg.experts_per_tok, cfg.n_experts, kernel=stacks is not None
-    )
+    plan = experts_plan(h2.shape[0], kernel=stacks is not None)
     if stacks is None and plan == "grouped":
         stacks, li = {k: lp[k][None] for k in _EXPERT_KEYS}, 0
     with jax.named_scope("moe.route"):

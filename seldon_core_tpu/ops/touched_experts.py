@@ -2,11 +2,12 @@
 only the experts some token chose.
 
 ``models/cohere2_moe.py::_experts_dense`` runs every held expert over every
-token: right where a step's tokens touch nearly all the experts a chip
-holds, and a waste where a chip holds 128 and 8 tokens x top-8 touch a third
-— the step is bound by reading expert weights, and two thirds of what it
-reads are multiplied by a combine weight of exactly 0.  This kernel computes
-the same sum with those terms left out::
+token: a waste wherever some held expert has no token — a chip holds 128 and
+8 tokens x top-8 touch a third, or holds 16 and 30 tokens touch 12 — since
+the step is bound by reading expert weights, and what it reads of the others
+is multiplied by a combine weight of exactly 0.  This kernel computes the
+same sum with those terms left out, and reads a byte as fast as the dense
+products where every held expert is touched::
 
     out[t] = sum over the touched experts x of
              cw[t, x] * (silu(h2[t] @ gate_x) * (h2[t] @ up_x)) @ down_x

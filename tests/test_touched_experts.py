@@ -3,6 +3,8 @@ mode on the CPU) against ``cohere2_moe._experts_dense`` on the same hidden
 state, weights and routing; the one rule of static shapes that chooses
 between them; and the counter that says which ran (``moe.experts_read``)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -15,43 +17,51 @@ from seldon_core_tpu.ops import touched_experts as te
 E, F = 64, 256
 
 
-def _routing(case, T, K, X, rng):
-    """-> (local (T, K), held (T, K) bool) of one named case."""
+def _routing(kind, T, K, X, rng):
+    """-> (local (T, K), held (T, K) bool) of one kind of routing."""
     local = np.stack([rng.permutation(X)[:K] for _ in range(T)])
     held = np.ones((T, K), bool)
-    if case == "no live token":
+    if kind == "no live token":
         held[:] = False
-    elif case == "every pair on one expert":
+    elif kind == "one expert":
         local[:] = X - 3
-    elif case == "pairs whose expert is not held":
+    elif kind == "a share":
         # ids as _moe forms them for a share: idx - first, some outside
         local = local - X // 2
         local[:, 0] = X + 5
         held = (local >= 0) & (local < X)
-    elif case == "an inactive slot's pairs masked out":
+    elif kind == "inactive slots":
         held[T // 2] = False
         held[0] = False
     return jnp.asarray(local, jnp.int32), jnp.asarray(held)
 
 
-# (case, tokens, top-k, held experts, layers, layer, bytes a grid step may take)
+# (case, routing, tokens, top-k, held experts, layers, layer, bytes a grid step may take)
 CASES = [
-    ("random routing at the cell's ratio", 8, 4, 32, 1, 0, None),  # 8 x 4 of 32 ~ 8 x 8 of 128 x 2
-    ("no live token", 8, 4, 32, 1, 0, None),
-    ("every pair on one expert", 8, 4, 32, 1, 0, None),
-    ("every held expert touched", 8, 4, 8, 1, 0, None),  # T x K >= X
-    ("pairs whose expert is not held", 8, 4, 16, 1, 0, None),
-    ("an inactive slot's pairs masked out", 8, 4, 32, 1, 0, None),
-    ("a layer other than the first of several", 8, 4, 16, 3, 2, None),
-    ("an F of more than one tile", 8, 4, 16, 2, 1, 3 * E * 128 * 4),
-    ("rows that are no whole tile", 5, 2, 16, 1, 0, None),
+    ("random routing at the cell's ratio", "random", 8, 4, 32, 1, 0, None),  # 8 x 4 of 32 ~ 8 x 8 of 128 x 2
+    ("no live token", "no live token", 8, 4, 32, 1, 0, None),
+    ("every pair on one expert", "one expert", 8, 4, 32, 1, 0, None),
+    ("every held expert touched", "random", 8, 4, 8, 1, 0, None),  # T x K >= X
+    ("pairs whose expert is not held", "a share", 8, 4, 16, 1, 0, None),
+    ("an inactive slot's pairs masked out", "inactive slots", 8, 4, 32, 1, 0, None),
+    ("a layer other than the first of several", "random", 8, 4, 16, 3, 2, None),
+    ("an F of more than one tile", "random", 8, 4, 16, 2, 1, 3 * E * 128 * 4),
+    ("rows that are no whole tile", "random", 5, 2, 16, 1, 0, None),
+    # since PR 47 the rule sends a prompt rung under GROUPED_FROM rows here too
+    ("32 slots over 16 held", "a share", 32, 4, 16, 2, 1, None),
+    ("64 rows, every held expert touched", "random", 64, 4, 16, 1, 0, None),
+    ("64 rows, some touched", "a share", 64, 2, 16, 2, 1, None),
+    ("64 rows, no live token", "no live token", 64, 2, 16, 1, 0, None),
+    ("128 rows, every held expert touched", "random", 128, 2, 16, 2, 1, None),
+    ("128 rows, some touched", "a share", 128, 2, 16, 1, 0, None),
+    ("128 rows, no live token", "no live token", 128, 2, 16, 1, 0, None),
 ]
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
 def test_the_kernel_gives_what_the_dense_products_give(case, dtype, monkeypatch):
-    name, T, K, X, L, li, step_bytes = case
+    name, routing, T, K, X, L, li, step_bytes = case
     dt = jnp.dtype(dtype)
     rng = np.random.default_rng(len(name) + T + X)
     if step_bytes is not None:
@@ -64,12 +74,12 @@ def test_the_kernel_gives_what_the_dense_products_give(case, dtype, monkeypatch)
     h2 = jnp.asarray(rng.normal(size=(T, E)), dt)
     w = rng.random((T, K)).astype(np.float32)
     w = jnp.asarray(w / w.sum(-1, keepdims=True))
-    local, held = _routing(name, T, K, X, rng)
+    local, held = _routing(routing, T, K, X, rng)
     lp = {k: v[li] for k, v in stacks.items()}
     want = np.asarray(cm._experts_dense(h2, lp, local, held, w))
     got = np.asarray(cm._experts_touched(h2, stacks, li, local, held, w))
     assert got.shape == (T, E) and got.dtype == np.float32
-    if name == "no live token":
+    if routing == "no live token":
         assert not got.any() and not want.any()
         return
     assert np.abs(want).max() > 0.05
@@ -96,28 +106,22 @@ def test_the_list_holds_the_touched_first_and_the_last_of_them_after(touched):
     assert set(ids[len(touched):]) <= {touched[-1] if touched else 0}
 
 
-# (tokens, top-k, experts, the kernel can be handed the stacks, the plan)
+# (tokens, the kernel can be handed the stacks, the plan)
 PLANS = [
-    (8, 8, 128, True, "touched"),   # Keye-VL-2.0's cell: 8 slots over 128 held
-    (32, 8, 128, True, "dense"),    # Command A+'s: 32 slots, 16 held of 128
-    (1, 8, 128, True, "touched"),
-    (8, 8, 128, False, "dense"),    # stacks over a mesh, or not at hand
-    (cm.GROUPED_FROM - 1, 8, 128, True, "dense"),
-    (cm.GROUPED_FROM, 8, 128, True, "grouped"),
-    (4096, 8, 128, True, "grouped"),
-    (24576, 8, 128, False, "grouped"),
+    (8, True, "touched"),    # Keye-VL-2.0's cell: 8 slots over 128 held
+    (32, True, "touched"),   # Command A+'s: 32 slots over 16 held, 12-14 touched
+    (1, True, "touched"),
+    (8, False, "dense"),     # stacks over a mesh, or not at hand
+    (cm.GROUPED_FROM - 1, True, "touched"),  # the largest prompt rung under the grouped ones
+    (cm.GROUPED_FROM - 1, False, "dense"),
+    (cm.GROUPED_FROM, True, "grouped"),
+    (24576, False, "grouped"),
 ]
 
 
-@pytest.mark.parametrize("T,K,N,kernel,plan", PLANS)
-def test_one_rule_of_static_shapes_chooses_the_plan(T, K, N, kernel, plan):
-    assert cm.experts_plan(T, K, N, kernel=kernel) == plan
-
-
-def test_the_rule_is_the_expected_share_against_one_constant():
-    assert cm.expected_touched_share(8, 8, 128) == pytest.approx(0.403, abs=1e-3)
-    assert cm.expected_touched_share(32, 8, 128) == pytest.approx(0.873, abs=1e-3)
-    assert 0.403 < cm.TOUCHED_SHARE_MAX < 0.873
+@pytest.mark.parametrize("T,kernel,plan", PLANS)
+def test_one_rule_of_static_shapes_chooses_the_plan(T, kernel, plan):
+    assert cm.experts_plan(T, kernel=kernel) == plan
 
 
 class TestTheServedStep:
@@ -125,12 +129,13 @@ class TestTheServedStep:
 
     BS = 4
 
-    def _steps(self, monkeypatch, share_max, steps=3):
-        monkeypatch.setattr(cm, "TOUCHED_SHARE_MAX", share_max)
+    def _steps(self, monkeypatch, kernel, steps=3):
+        if not kernel:
+            # what a caller without the stacks tells the rule
+            monkeypatch.setattr(
+                kv, "experts_plan", lambda T, kernel: cm.experts_plan(T, kernel=False)
+            )
         cfg = kv.Config.tiny(max_seq=64)
-        assert cm.experts_plan(2, cfg.experts_per_tok, cfg.n_experts) == (
-            "touched" if share_max else "dense"
-        )
         params = kv.init_params(jax.random.PRNGKey(3), cfg, jnp.float32)
         cache = kv.init_paged_cache(cfg, 2, 40, self.BS, jnp.float32)
         prompt = np.random.default_rng(0).integers(1, 256, (1, 24))
@@ -155,8 +160,8 @@ class TestTheServedStep:
         return cfg, toks, np.stack(out), counters
 
     def test_the_same_tokens_and_logits_by_either_plan(self, monkeypatch):
-        cfg, toks_k, logits_k, c_k = self._steps(monkeypatch, cm.TOUCHED_SHARE_MAX)
-        _, toks_d, logits_d, c_d = self._steps(monkeypatch, 0.0)
+        cfg, toks_k, logits_k, c_k = self._steps(monkeypatch, True)
+        _, toks_d, logits_d, c_d = self._steps(monkeypatch, False)
         assert toks_k == toks_d
         np.testing.assert_allclose(logits_k, logits_d, rtol=0, atol=2e-5)
         # the kernel read the experts touched and no other; the dense
@@ -179,22 +184,46 @@ class TestTheServedStep:
         assert (kv._STEPS, kv._P_TOKENS, kv._SCORED) == (4, 7, 9)
 
 
-def test_command_a_plus_counts_every_held_expert_read_in_a_dense_step():
-    """``cohere2_moe`` at 32 slots stays on the dense products, and its
-    counter says so: held x layers x steps."""
-    cfg = cm.Config.tiny(max_seq=64, experts_held="4:8")
-    S = 32
-    assert cm.experts_plan(S, cfg.experts_per_tok, cfg.n_experts) == "dense"
-    params = cm.init_params(jax.random.PRNGKey(1), cfg, jnp.float32)
-    cache = cm.init_paged_cache(cfg, S, 2 * S + 1, 4, jnp.float32)
-    table = np.zeros((S, 64 // 4), np.int32)
-    table[:, :2] = np.arange(1, 2 * S + 1).reshape(S, 2)  # two blocks a slot
-    cache["table"] = jnp.asarray(table)
-    active = jnp.asarray(np.arange(S) % 4 != 0)
-    _, cache = cm.decode_slots_paged(
-        params, jnp.arange(S, dtype=jnp.int32) + 1, cache, active, cfg, window=8,
-    )
-    c = dict(zip(cm.COUNTERS, np.asarray(cache["counters"]).tolist()))
-    assert c["moe.steps"] == 1
-    assert c["moe.experts_read"] == 8 * cfg.n_layers
-    assert 0 < c["moe.experts_touched"] <= c["moe.experts_read"]
+class TestCommandAPlusAt32Slots:
+    """``cohere2_moe.decode_slots_paged`` at 32 slots over 8 held experts:
+    the kernel on one device, ``_experts_dense`` for stacks over a mesh."""
+
+    S, STEPS = 32, 2
+
+    @staticmethod
+    @functools.cache
+    def _steps(sharded):
+        S, STEPS = TestCommandAPlusAt32Slots.S, TestCommandAPlusAt32Slots.STEPS
+        cfg = cm.Config.tiny(max_seq=64, experts_held="4:8")
+        params = cm.init_params(jax.random.PRNGKey(1), cfg, jnp.float32)
+        cache = cm.init_paged_cache(cfg, S, 2 * S + 1, 4, jnp.float32)
+        table = np.zeros((S, 64 // 4), np.int32)
+        table[:, :2] = np.arange(1, 2 * S + 1).reshape(S, 2)  # two blocks a slot
+        cache["table"] = jnp.asarray(table)
+        active = jnp.asarray(np.arange(S) % 4 != 0)
+        nxt, toks, out = jnp.arange(S, dtype=jnp.int32) + 1, [], []
+        for _ in range(STEPS):
+            lg, cache = cm.decode_slots_paged(
+                params, nxt, cache, active, cfg, window=8, kv_sharded=sharded,
+            )
+            out.append(np.asarray(lg)[np.asarray(active)])
+            nxt = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            toks.append(np.asarray(nxt)[np.asarray(active)].tolist())
+        counters = dict(zip(cm.COUNTERS, np.asarray(cache["counters"]).tolist()))
+        return cfg, toks, np.stack(out), counters
+
+    def test_the_kernel_reads_the_experts_touched_and_no_other(self):
+        cfg, _, _, c = self._steps(sharded=False)
+        assert c["moe.steps"] == self.STEPS
+        assert 0 < c["moe.experts_touched"] <= 8 * cfg.n_layers * self.STEPS
+        assert c["moe.experts_read"] == c["moe.experts_touched"]
+
+    def test_stacks_over_a_mesh_read_every_held_expert_to_the_same_tokens(self):
+        cfg, toks_d, logits_d, c_d = self._steps(sharded=True)
+        _, toks_k, logits_k, c_k = self._steps(sharded=False)
+        assert c_d["moe.experts_read"] == 8 * cfg.n_layers * self.STEPS
+        assert toks_k == toks_d
+        np.testing.assert_allclose(logits_k, logits_d, rtol=0, atol=2e-5)
+        for name in cm.COUNTERS:
+            if name != "moe.experts_read":
+                assert c_k[name] == c_d[name], name

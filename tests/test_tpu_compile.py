@@ -179,16 +179,19 @@ def test_the_decode_selection_kernel_compiles_at_keye_vl2s_widths(one_chip, case
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2048 * 4 * 4
 
 
-# (name, tokens, held experts, hidden, expert width, layers)
+# (name, tokens, held experts, hidden, expert width, layers, columns of F a step)
 EXPERT_CASES = [
-    ("keye-vl-2 a whole expert a step", 8, 128, 2048, 768, 6),
-    ("command-a-plus F in tiles", 32, 16, 4096, 4096, 4),
+    ("keye-vl-2 a whole expert a step", 8, 128, 2048, 768, 6, 768),
+    ("command-a-plus F in tiles", 32, 16, 4096, 4096, 4, 512),
+    # the largest prompt rung under the grouped products (PR 47)
+    ("command-a-plus a prompt rung of 128 rows", 128, 16, 4096, 4096, 4, 512),
+    ("kimi-k2.6 a prompt rung of 128 rows", 128, 12, 7168, 2048, 4, 256),
 ]
 
 
 @pytest.mark.parametrize("case", EXPERT_CASES, ids=[c[0] for c in EXPERT_CASES])
 def test_the_touched_only_expert_kernel_compiles_for_v5e(one_chip, case):
-    """``ops/touched_experts.py`` at the widths of both expert cells: three
+    """``ops/touched_experts.py`` at the widths of the expert cells: three
     blocks of 3.1 MB double-buffered under a stated VMEM limit, and ``F`` in
     tiles where one matrix is 33.5 MB; the stacks go in as they are."""
     import jax
@@ -196,7 +199,7 @@ def test_the_touched_only_expert_kernel_compiles_for_v5e(one_chip, case):
 
     from seldon_core_tpu.ops.touched_experts import f_tile, touched_expert_products
 
-    _, T, X, E, F, L = case
+    _, T, X, E, F, L, tile = case
 
     def sds(shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -214,7 +217,7 @@ def test_the_touched_only_expert_kernel_compiles_for_v5e(one_chip, case):
         sds((L * X, F, E)),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    assert f_tile(E, F, 2) == (F if F == 768 else 512)
+    assert f_tile(E, F, 2) == tile
     assert compiled.memory_analysis().temp_size_in_bytes < E * F * 2
 
 
@@ -258,6 +261,48 @@ def test_keye_vl2s_decode_program_holds_no_copy_of_a_layers_experts(one_chip, mo
     # nor of the index keys' pool (156 MB), nor of the window's keys and scores
     layer_experts = 128 * 3 * 2048 * 768 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_experts // 64
+
+
+def test_command_a_plus_decode_program_streams_its_experts_through_the_kernel(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (32 slots, four layers of
+    16 held experts, a pool of 769 blocks of 256, window 8,192): every
+    layer's experts run through the touched-only kernel (PR 47), handed the
+    stack of every layer, and no matrix of a layer's experts (0.54 GB of its
+    1.6 GB) is copied."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import cohere2_moe as m
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = m.Config(vocab_size=32768, n_layers=4, experts_held="0:16", max_seq=8192)
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
+        )
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 32, 769, 256, jnp.bfloat16)
+    ))
+    step = jax.jit(
+        functools.partial(m.decode_slots_paged, cfg=cfg, window=8192, kernel=True),
+        donate_argnums=(2,),
+    )
+    compiled = step.lower(
+        params, jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip), cache,
+        jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    # one period of four layers, unrolled: a paged read and the experts in each
+    assert compiled.as_text().count("tpu_custom_call") == 2 * cfg.n_layers
+    # 0.41 GB with the dense products too: the relayout of wq / wo (ROADMAP A4),
+    # under one matrix of one layer's 16 experts
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 4096 * 4096 * 2
 
 
 def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip):
