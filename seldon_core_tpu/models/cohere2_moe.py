@@ -28,16 +28,8 @@ in ``params``; expert ``e`` of layer ``l`` has the same values whichever
 share holds it (its key is folded from ``(l, e)``), so the shares of one
 layer add up to the uncut layer (``tests/test_cohere2_moe.py``).
 
-The held experts' products have three formulations, chosen from static
-shapes in one place (:func:`experts_plan`).  A prefill (thousands of tokens)
-sorts its (token, expert) pairs by expert and runs grouped products
-(``lax.ragged_dot``) over the held pairs alone, in chunks whose count
-follows the pairs actually held.  A decode step (a few tokens) is bound by
-reading expert weights, and a Pallas kernel streams the experts its tokens
-chose and no other (``ops/touched_experts.py``): a third of the 128 that 8
-slots x top-8 hold, 12 of the 16 that 30 live slots share.  Every held
-expert over every token, densely, is what is left where the kernel cannot be
-handed the stacks (a mesh, a caller without them).
+The held experts' products are ``models/moe.py``'s, chosen by its one rule
+of static shapes (``moe.experts_plan``) behind this family's sigmoid route.
 
 The paged pool is uniform: every layer keeps every token's K/V, and a
 sliding layer READS only the blocks that hold its window (the window saves
@@ -54,13 +46,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from seldon_core_tpu.models import moe, paged
 from seldon_core_tpu.models.common import annotate_params
-from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
+from seldon_core_tpu.models.layers import flash_prompt, layernorm, rope_pairs
+from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
 
-# tokens in one call above which the held experts' products are grouped
-GROUPED_FROM = 256
-# rows of (token, expert) pairs one grouped pass takes
-GROUP_CHUNK = 4096
 # query rows one pass of the XLA attention scores at once
 ATTN_Q_CHUNK = 128
 # the XLA decode read gathers the window of this many slots at once, where
@@ -68,19 +58,7 @@ ATTN_Q_CHUNK = 128
 DECODE_SLOT_CHUNK = 8
 DECODE_GATHER_BYTES = 256 << 20
 
-COUNTERS = (
-    "moe.pairs_routed",          # decode: (token, expert) pairs chosen, layers summed
-    "moe.pairs_held",            # decode: of those, pairs whose expert is held here
-    "moe.experts_touched",       # decode: held experts with >= 1 token, summed over layers and steps
-    "moe.max_tokens_on_expert",  # decode: the busiest held expert's tokens, summed over layers and steps
-    "moe.steps",                 # decode steps counted
-    "moe.prefill_pairs_routed",  # prefill: pairs chosen (real tokens only)
-    "moe.prefill_pairs_held",
-    "moe.prefill_tokens",
-    "moe.experts_read",          # decode: held experts whose weights the step streamed (touched ones under the
-                                 # touched-only kernel, every held one densely), summed over layers and steps
-)
-_EXPERTS_READ = COUNTERS.index("moe.experts_read")
+COUNTERS = moe.COUNTERS  # the contract reads ``family_mod.COUNTERS``
 
 
 @dataclasses.dataclass(frozen=True)
@@ -110,22 +88,14 @@ class Config:
                 f"n_layers {self.n_layers} is not whole periods of "
                 f"layer_pattern {self.layer_pattern}"
             )
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_experts:
-            raise ValueError(
-                f"experts_held {self.experts_held!r} is not a range of the "
-                f"{self.n_experts} experts"
-            )
+        moe.held_range(self.experts_held, self.n_experts)  # or refused
         if self.n_heads % self.n_kv_heads or self.head_dim % 2:
             raise ValueError("n_heads must group over n_kv_heads; head_dim even")
 
     @property
     def held(self) -> tuple[int, int]:
         """(first, count) of the routed experts this share holds."""
-        if not self.experts_held:
-            return 0, self.n_experts
-        first, _, count = str(self.experts_held).partition(":")
-        return int(first), int(count)
+        return moe.held_range(self.experts_held, self.n_experts)
 
     @classmethod
     def tiny(cls, max_seq: int = 64, **kw) -> "Config":
@@ -232,40 +202,14 @@ def param_logical_axes(params):
 # building blocks
 # ---------------------------------------------------------------------------
 
-def _layernorm(x, w, eps):
-    """Cohere's LayerNorm: mean subtracted, no bias; statistics in float32."""
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    xc = xf - mean
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    return (xc * lax.rsqrt(var + eps)).astype(x.dtype) * w
-
-
-def _rope_pairs(x, positions, theta, freqs=None):
-    """Interleaved-pair rotary embedding (``rope_gptj``): dims (2i, 2i+1)
-    rotate together, all ``head_dim`` of them.  x: (..., L, H, D);
-    positions: (..., L).  ``freqs (D / 2,)`` replaces ``theta``'s own
-    (``kimi_k2``'s YaRN)."""
-    d = x.shape[-1]
-    if freqs is None:
-        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., L, D/2)
-    cos = jnp.cos(angles)[..., None, :]
-    sin = jnp.sin(angles)[..., None, :]
-    xp = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-    a, b = xp[..., 0], xp[..., 1]
-    out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
 def _qkv(h, lp, cfg: Config, positions, full: bool):
     """Projections of ``h (..., L, E)``; RoPE on a sliding layer only."""
     q = jnp.einsum("...le,ehd->...lhd", h, lp["wq"])
     k = jnp.einsum("...le,ehd->...lhd", h, lp["wk"])
     v = jnp.einsum("...le,ehd->...lhd", h, lp["wv"])
     if not full:
-        q = _rope_pairs(q, positions, cfg.rope_theta)
-        k = _rope_pairs(k, positions, cfg.rope_theta)
+        q = rope_pairs(q, positions, cfg.rope_theta)
+        k = rope_pairs(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -321,22 +265,6 @@ def _attend(q, k, v, qpos, kpos, window, kvalid=None):
     return out.reshape(lq, nh, d)
 
 
-def _attend_flash(q, k, v, window):
-    """The prompt's own attention through the Pallas tiled kernel
-    (``ops/flash_attention.py``): causal, the sliding window inside it,
-    keys read grouped.  q: (L, H, D); k, v: (L, KV, D) at positions 0..L-1."""
-    from seldon_core_tpu.ops.flash_attention import flash_attention
-
-    L = q.shape[0]
-    blk = min(512, L)
-    out = flash_attention(
-        q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
-        v.transpose(1, 0, 2)[None], causal=True, block_q=blk, block_k=blk,
-        window=window,
-    )
-    return out[0].transpose(1, 0, 2)
-
-
 # ---------------------------------------------------------------------------
 # the expert layer
 # ---------------------------------------------------------------------------
@@ -356,189 +284,24 @@ def _route(h2, w_router, cfg: Config):
     return idx.astype(jnp.int32), w.astype(jnp.float32)
 
 
-def experts_plan(n_tokens: int, *, kernel: bool = True) -> str:
-    """Which formulation runs the held experts' products for a call of
-    ``n_tokens`` tokens, from static shapes alone: ``"grouped"`` for a
-    prompt (:data:`GROUPED_FROM` tokens or more); else ``"touched"`` — the
-    kernel that streams only the experts some token chose — wherever the
-    kernel can be handed the stacks (``kernel``: every layer's are at hand,
-    and on one device; it is not offered stacks sharded over a mesh), and
-    ``"dense"`` where it cannot.  The kernel reads a byte as fast as the
-    dense products and never more of them (PERF.md §6, PR 47: level where a
-    call touches every expert it holds, ahead by what it skips elsewhere)."""
-    if n_tokens >= GROUPED_FROM:
-        return "grouped"
-    return "touched" if kernel else "dense"
-
-
-def _tokens_on_experts(local, held, count: int):
-    """(count,): the tokens on each held expert."""
-    return jnp.sum(
-        (local[..., None] == jnp.arange(count)) & held[..., None], axis=(0, 1)
-    )
-
-
-def _combine_weights(local, held, w, count: int):
-    """(T, X) float32: a token's weight on each held expert, 0 where not
-    chosen."""
-    onehot = local[..., None] == jnp.arange(count)  # (T, K, X)
-    return jnp.sum(
-        jnp.where(onehot & held[..., None], w[..., None], 0.0), axis=1
-    )
-
-
-def _experts_dense(h2, lp, local, held, w):
-    """Every held expert over every token: the call reads each held
-    expert's weights once whichever tokens chose it.  -> (T, E) f32.  What
-    a mesh-sharded expert stack runs, and a caller with no stack at hand."""
-    cw = _combine_weights(local, held, w, lp["we_gate"].shape[0])
-    g = jnp.einsum("te,xef->xtf", h2, lp["we_gate"])
-    u = jnp.einsum("te,xef->xtf", h2, lp["we_up"])
-    d = jnp.einsum("xtf,xfe->xte", jax.nn.silu(g) * u, lp["we_down"])
-    return jnp.einsum("xte,tx->te", d.astype(jnp.float32), cw)
-
-
-def _experts_touched(h2, stacks, li, local, held, w):
-    """The held experts that at least one token chose, and no other (a
-    call of under :data:`GROUPED_FROM` tokens): what
-    :func:`_experts_dense` sums, less the terms whose weight is 0, through
-    the kernel that streams an expert by the list of those touched
-    (``ops/touched_experts.py``).  ``stacks`` and ``li`` as
-    :func:`_experts_grouped` takes them, and for its reason.  -> (T, E) f32."""
-    from seldon_core_tpu.ops.touched_experts import touched_expert_products, touched_list
-
-    T, K = local.shape
-    n_layers, count = stacks["we_gate"].shape[:2]
-    ids, n = touched_list(
-        _tokens_on_experts(local, held, count) > 0, min(count, T * K)
-    )
-    flat = [
-        stacks[k].reshape((n_layers * count,) + stacks[k].shape[2:])
-        for k in ("we_gate", "we_up", "we_down")
-    ]
-    return touched_expert_products(
-        h2, _combine_weights(local, held, w, count), ids, n, *flat,
-        base=li * count,
-    )
-
-
-def _experts_grouped(h2, stacks, li, local, held, w, chunk: int = GROUP_CHUNK):
-    """The held (token, expert) pairs alone, sorted by expert, through
-    grouped products (prefill).  Pairs are taken ``chunk`` rows at a
-    pass and the passes follow the pairs actually held, so no routing is
-    dropped and none is paid for that is not there.  -> (T, E) f32.
-
-    ``stacks`` are the expert weights of EVERY layer, ``(layers, held, ..)``,
-    and ``li`` this layer: the grouped product runs over all ``layers *
-    held`` groups with the other layers' groups empty.  A layer cut out of
-    the stack first is a copy of its 16 experts (half a gigabyte a matrix)
-    on every call — the grouped product is a kernel, and XLA fuses no slice
-    into a kernel's operand."""
-    T, K = local.shape
-    n_layers, count = stacks["we_gate"].shape[:2]
-    M = T * K
-    R = min(chunk, M)
-    key = jnp.where(held, local, count).reshape(M)  # pairs not held sort last
-    order = jnp.argsort(key, stable=True)
-    tok = (order // K).astype(jnp.int32)  # token of each sorted pair
-    w_sorted = w.reshape(M)[order]
-    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
-    ends = jnp.cumsum(sizes)
-    starts = ends - sizes
-    n_held = ends[-1]
-    flat = {
-        k: stacks[k].reshape((n_layers * count,) + stacks[k].shape[2:])
-        for k in ("we_gate", "we_up", "we_down")
-    }
-
-    def body(i, out):
-        r0 = i * R
-        rows = r0 + jnp.arange(R)
-        live = rows < n_held
-        t = tok[jnp.minimum(rows, M - 1)]
-        xg = h2[t]  # (R, E)
-        gs = jnp.clip(ends - r0, 0, R) - jnp.clip(starts - r0, 0, R)
-        gs = lax.dynamic_update_slice(
-            jnp.zeros((n_layers * count,), jnp.int32), gs, (li * count,)
-        )
-        g = lax.ragged_dot(xg, flat["we_gate"], gs)
-        u = lax.ragged_dot(xg, flat["we_up"], gs)
-        d = lax.ragged_dot(jax.nn.silu(g) * u, flat["we_down"], gs)
-        wr = w_sorted[jnp.minimum(rows, M - 1)]
-        # rows past the pairs held belong to no group: whatever the grouped
-        # product left there is replaced, not scaled
-        y = jnp.where(live[:, None], d.astype(jnp.float32) * wr[:, None], 0.0)
-        return out.at[t].add(y)
-
-    out = jnp.zeros((T, h2.shape[1]), jnp.float32)
-    return lax.fori_loop(0, (n_held + R - 1) // R, body, out)
-
-
-def _count_routing(counters, local, held, tok_mask, per_tok: int, count: int,
-                   decode: bool, plan: str = "dense"):
-    """``counters`` with one expert layer's routing added (``COUNTERS``'
-    first four and the experts ``plan`` read in a decode step, the prefill
-    pair in a prompt)."""
-    if counters is None:
-        return None
-    n_tok = jnp.sum(tok_mask).astype(jnp.uint32)
-    n_held = jnp.sum(held).astype(jnp.uint32)
-    n_routed = n_tok * jnp.uint32(per_tok)
-    if decode:
-        per = _tokens_on_experts(local, held, count)
-        touched = jnp.sum(per > 0).astype(jnp.uint32)
-        read = touched if plan == "touched" else jnp.uint32(count)
-        add = jnp.zeros_like(counters).at[jnp.arange(4)].add(jnp.stack([
-            n_routed, n_held, touched, jnp.max(per).astype(jnp.uint32),
-        ])).at[_EXPERTS_READ].add(read)
-    else:
-        add = jnp.zeros_like(counters).at[jnp.arange(5, 7)].add(
-            jnp.stack([n_routed, n_held])
-        )
-    return counters + add
-
-
 def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool, stacks, li,
          sharded: bool = False):
     """``h2 (T, E)`` -> (routed + shared (T, E) float32, counters).  ``lp``
     is this layer's weights, ``stacks`` every layer's and ``li`` the layer
-    (:func:`_experts_grouped` says why it wants those); ``sharded`` (static)
-    says the stacks lie over a mesh."""
-    first, count = cfg.held
-    plan = experts_plan(h2.shape[0], kernel=not sharded)
+    (``moe.experts_grouped`` says why it wants those: both kernels run over
+    the carried stack); ``sharded`` (static) says the stacks lie over a
+    mesh."""
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
-        local = idx - first
-        held = (local >= 0) & (local < count) & tok_mask[:, None]
-    with jax.named_scope("moe.experts"):
-        if plan == "grouped":
-            routed = _experts_grouped(h2, stacks, li, local, held, w)
-        elif plan == "touched":
-            routed = _experts_touched(h2, stacks, li, local, held, w)
-        else:
-            routed = _experts_dense(h2, lp, local, held, w)
-    with jax.named_scope("moe.shared"):
-        g = jnp.einsum("te,jef->jtf", h2, lp["ws_gate"])
-        u = jnp.einsum("te,jef->jtf", h2, lp["ws_up"])
-        shared = jnp.einsum(
-            "jtf,jfe->te", jax.nn.silu(g) * u, lp["ws_down"],
-            preferred_element_type=jnp.float32,
-        ) / cfg.n_shared_experts
-    counters = _count_routing(
-        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode, plan
+    return moe.routed_experts(
+        h2, lp, idx, w, cfg.held, tok_mask, counters, decode=decode,
+        kernel=not sharded, stacks=stacks, li=li, shared="mean",
     )
-    return routed + shared, counters
-
-
-def _bump(counters, index: int, by):
-    if counters is None:
-        return None
-    return counters.at[index].add(jnp.asarray(by, jnp.uint32))
 
 
 def _head(params, h, cfg: Config):
     with jax.named_scope("head"):
-        h = _layernorm(h, params["ln_f"], cfg.norm_eps)
+        h = layernorm(h, params["ln_f"], cfg.norm_eps)
         logits = jnp.einsum("...e,ve->...v", h, params["tok_emb"])
         if cfg.logit_scale != 1.0:
             logits = logits * cfg.logit_scale
@@ -571,8 +334,8 @@ def _scan_layers(params, cfg: Config, carry, layer_fn):
     return carry
 
 
-def _residual(x, attn, moe):
-    return (x.astype(jnp.float32) + attn.astype(jnp.float32) + moe).astype(x.dtype)
+def _residual(x, attn, ffn):
+    return (x.astype(jnp.float32) + attn.astype(jnp.float32) + ffn).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -588,12 +351,12 @@ def forward(params: dict, tokens: jax.Array, cfg: Config) -> jax.Array:
         mask = jnp.ones((L,), bool)
 
         def layer(x, li, full, lp):
-            h = _layernorm(x, lp["ln"], cfg.norm_eps)
+            h = layernorm(x, lp["ln"], cfg.norm_eps)
             q, k, v = _qkv(h, lp, cfg, pos, full)
             o = _attend(q, k, v, pos, pos, None if full else cfg.sliding_window)
             attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
-            moe, _ = _moe(h, lp, cfg, mask, None, decode=False, stacks=params["layers"], li=li)
-            return _residual(x, attn, moe)
+            ffn, _ = _moe(h, lp, cfg, mask, None, decode=False, stacks=params["layers"], li=li)
+            return _residual(x, attn, ffn)
 
         x = _scan_layers(params, cfg, params["tok_emb"][toks], layer)
         return _head(params, x, cfg)[0]
@@ -622,17 +385,10 @@ def init_paged_cache(
     family has no pool split by head).
     ``counters`` are the routing counters (``COUNTERS``), uint32, wrapping."""
     del kv_sharded
-    if cfg.max_seq % block_size:
-        raise ValueError(
-            f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
-        )
     shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads * cfg.head_dim)
     return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-        "table": jnp.zeros((n_slots, cfg.max_seq // block_size), jnp.int32),
-        "counters": jnp.zeros((len(COUNTERS),), jnp.uint32),
+        **paged.bookkeeping(cfg.max_seq, n_slots, block_size, len(COUNTERS)),
+        "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
     }
 
 
@@ -640,11 +396,9 @@ def paged_kv_slot_bytes(
     cfg: Config, block_size: int, *, kv_dtype: str | None = None, dtype="float32"
 ) -> int:
     """HBM bytes one max_seq slot costs in the paged pool."""
-    import numpy as _np
-
     del block_size, kv_dtype
-    itemsize = 2 if str(dtype) in ("bfloat16", "bf16") else _np.dtype(dtype).itemsize
-    return cfg.max_seq * 2 * cfg.n_kv_heads * cfg.head_dim * itemsize * cfg.n_layers
+    per_token = 2 * cfg.n_kv_heads * cfg.head_dim * cfg.n_layers
+    return paged.slot_bytes(cfg.max_seq, per_token, dtype)
 
 
 def window_blocks(cfg: Config, block_size: int, queries: int = 1) -> int:
@@ -671,12 +425,6 @@ def window_read(table, pos, cfg: Config, block_size: int, queries: int = 1):
     return phys, kpos
 
 
-def _write_prompt(pool, li, phys, rows, bs):
-    """Scatter ``rows (L, KV, D)`` of layer ``li`` into the blocks ``phys``."""
-    lb = rows.shape[0] // bs
-    return pool.at[li, phys].set(rows.reshape(lb, bs, -1).astype(pool.dtype))
-
-
 def prefill_slot_paged(
     params: dict, tokens: jax.Array, length: jax.Array, slot: jax.Array,
     blocks_row: jax.Array, cache: dict, cfg: Config, *, mesh=None,
@@ -688,8 +436,7 @@ def prefill_slot_paged(
     runs the prompt's attention through the Pallas tiled kernel with the
     window inside it; ``"dense"`` through chunked XLA attention."""
     del adapter_id
-    if lora is not None:
-        raise TypeError("cohere2_moe has no LoRA path")
+    paged.no_lora("cohere2_moe", lora)
     bs = cache["k"].shape[2]
     lp_ = tokens.shape[1]
     pos = jnp.arange(lp_)
@@ -700,47 +447,30 @@ def prefill_slot_paged(
     def layer(carry, li, full, lp):
         x, ck, cv, ctr = carry
         window = None if full else cfg.sliding_window
-        h = _layernorm(x, lp["ln"], cfg.norm_eps)
+        h = layernorm(x, lp["ln"], cfg.norm_eps)
         with jax.named_scope("attn.full" if full else "attn.window"):
             q, k, v = _qkv(h, lp, cfg, pos, full)
-            ck = _write_prompt(ck, li, phys, k, bs)
-            cv = _write_prompt(cv, li, phys, v, bs)
+            ck = paged.write_prompt(ck, li, phys, k, bs)
+            cv = paged.write_prompt(cv, li, phys, v, bs)
             if seq_impl == "flash":
-                o = _attend_flash(q, k, v, window)
+                o = flash_prompt(q, k, v, window=window)
             else:
                 o = _attend(q, k, v, pos, pos, window)
             attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
-        moe, ctr = _moe(
+        ffn, ctr = _moe(
             h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li,
             sharded=mesh is not None,
         )
-        return _residual(x, attn, moe), ck, cv, ctr
+        return _residual(x, attn, ffn), ck, cv, ctr
 
-    ctr = _bump(cache.get("counters"), 7, length)
+    ctr = paged.bump(cache.get("counters"), moe.PREFILL_TOKENS, length)
     x, new_k, new_v, ctr = _scan_layers(
         params, cfg, (x, cache["k"], cache["v"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - 1, new_k, new_v, ctr, slot, length,
-        blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - 1, {"k": new_k, "v": new_v}, ctr, slot,
+        length, blocks_row, return_hidden, _head,
     )
-
-
-def _finish_prefill(params, cfg, cache, x, at, new_k, new_v, ctr, slot, length,
-                    blocks_row, return_hidden):
-    cache = dict(cache)
-    cache.update(
-        k=new_k, v=new_v,
-        pos=cache["pos"].at[slot].set(length),
-        table=cache["table"].at[slot].set(blocks_row),
-    )
-    if ctr is not None:
-        cache["counters"] = ctr
-    h = lax.dynamic_index_in_dim(x, at, axis=0, keepdims=False)
-    logits, h = _head(params, h, cfg)
-    if return_hidden:
-        return logits, cache, h
-    return logits, cache
 
 
 def prefill_suffix_paged(
@@ -755,8 +485,7 @@ def prefill_suffix_paged(
     over [the prefix read from the pool ++ the suffix]; a sliding layer
     masks what lies before its window."""
     del adapter_id
-    if lora is not None:
-        raise TypeError("cohere2_moe has no LoRA path")
+    paged.no_lora("cohere2_moe", lora)
     bs = cache["k"].shape[2]
     ls = tokens.shape[1]
     pb = max(1, int(prefix_window) // bs)
@@ -772,7 +501,7 @@ def prefill_suffix_paged(
     def layer(carry, li, full, lp):
         x, ck, cv, ctr = carry
         window = None if full else cfg.sliding_window
-        h = _layernorm(x, lp["ln"], cfg.norm_eps)
+        h = layernorm(x, lp["ln"], cfg.norm_eps)
         with jax.named_scope("attn.full" if full else "attn.window"):
             q, k, v = _qkv(h, lp, cfg, qpos, full)
             kp = ck[li, read_idx].reshape((pb * bs,) + k.shape[1:])  # (P, KV, D)
@@ -783,21 +512,21 @@ def prefill_suffix_paged(
                 qpos, kpos, window, kvalid,
             )
             attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
-            ck = _write_prompt(ck, li, suffix_blocks, k, bs)
-            cv = _write_prompt(cv, li, suffix_blocks, v, bs)
-        moe, ctr = _moe(
+            ck = paged.write_prompt(ck, li, suffix_blocks, k, bs)
+            cv = paged.write_prompt(cv, li, suffix_blocks, v, bs)
+        ffn, ctr = _moe(
             h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li,
             sharded=kv_sharded,
         )
-        return _residual(x, attn, moe), ck, cv, ctr
+        return _residual(x, attn, ffn), ck, cv, ctr
 
-    ctr = _bump(cache.get("counters"), 7, length - prefix_len)
+    ctr = paged.bump(cache.get("counters"), moe.PREFILL_TOKENS, length - prefix_len)
     x, new_k, new_v, ctr = _scan_layers(
         params, cfg, (x, cache["k"], cache["v"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - prefix_len - 1, new_k, new_v, ctr,
-        slot, length, blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - prefix_len - 1, {"k": new_k, "v": new_v},
+        ctr, slot, length, blocks_row, return_hidden, _head,
     )
 
 
@@ -854,8 +583,7 @@ def _decode_paged_multi(
     the full read on every layer.  ``kv_sharded`` says the deployment lies
     over a tensor-parallel mesh, its expert stacks too."""
     del adapter_ids
-    if lora is not None:
-        raise TypeError("cohere2_moe has no LoRA path")
+    paged.no_lora("cohere2_moe", lora)
     pos, table = cache["pos"], cache["table"]
     S, L = qtokens.shape
     bs = cache["k"].shape[2]
@@ -886,7 +614,7 @@ def _decode_paged_multi(
 
     def layer(carry, li, full, lp):
         x, ck, cv, ctr = carry
-        h = _layernorm(x, lp["ln"], cfg.norm_eps)
+        h = layernorm(x, lp["ln"], cfg.norm_eps)
         with jax.named_scope("attn.full" if full else "attn.window"):
             q, k, v = _qkv(h, lp, cfg, positions, full)
             ck = ck.at[li, write_blk, write_off].set(
@@ -944,13 +672,13 @@ def _decode_paged_multi(
             attn = jnp.einsum(
                 "blhd,hde->ble", o.reshape(S, L, cfg.n_heads, d), lp["wo"]
             )
-        moe, ctr = _moe(
+        ffn, ctr = _moe(
             h.reshape(S * L, -1), lp, cfg, tok_mask, ctr, decode=True,
             stacks=params["layers"], li=li, sharded=kv_sharded,
         )
-        return _residual(x, attn, moe.reshape(x.shape)), ck, cv, ctr
+        return _residual(x, attn, ffn.reshape(x.shape)), ck, cv, ctr
 
-    ctr = _bump(cache.get("counters"), 4, 1)
+    ctr = paged.bump(cache.get("counters"), moe.STEPS, 1)
     x, new_k, new_v, ctr = _scan_layers(
         params, cfg, (x, cache["k"], cache["v"], ctr), layer
     )

@@ -98,11 +98,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from seldon_core_tpu.models.cohere2_moe import _bump
+from seldon_core_tpu.models import paged
 from seldon_core_tpu.models.common import annotate_params
-from seldon_core_tpu.models.keye_vl2 import _add, _write_prompt
-from seldon_core_tpu.models.llama import _rmsnorm
-from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
+from seldon_core_tpu.models.layers import add, flash_prompt, rms_head
+# benchmark/reference/kinds/jamba_decoder.py reads ``_rmsnorm`` here
+from seldon_core_tpu.models.layers import rmsnorm as _rmsnorm
+from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
 from seldon_core_tpu.ops.selective_scan import (
     selective_scan,
     selective_scan_reference,
@@ -493,14 +494,7 @@ def _attend_prompt(q, k, v, seq_impl: str):
     T, H, D = q.shape
     with jax.named_scope("attn.prompt"):
         if seq_impl == "flash":
-            from seldon_core_tpu.ops.flash_attention import flash_attention
-
-            blk = min(512, T)
-            out = flash_attention(
-                q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
-                v.transpose(1, 0, 2)[None], causal=True, block_q=blk, block_k=blk,
-            )
-            return out[0].transpose(1, 0, 2)
+            return flash_prompt(q, k, v)
         kv = k.shape[1]
         qg = q.reshape(T, kv, H // kv, D)
         s = jnp.einsum(
@@ -539,12 +533,12 @@ def _attend_paged(q, ck, cv, ai, read_blk, pos, active, *, kernel: bool):
 def _after_mixer(x, o, lp, cfg: Config):
     """The rest of a layer behind its mixer's output ``o (..., E)``: the
     residual and the SwiGLU MLP, each added to the stream."""
-    x = _add(x, o)
+    x = add(x, o)
     h2 = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
     with jax.named_scope("mlp.gate_up"):
         act = jax.nn.silu(h2 @ lp["w_gate"]) * (h2 @ lp["w_up"])
     with jax.named_scope("mlp.down"):
-        return _add(x, act @ lp["w_down"])
+        return add(x, act @ lp["w_down"])
 
 
 def _attn_out(o, lp):
@@ -554,9 +548,7 @@ def _attn_out(o, lp):
 
 def _head(params, x, cfg: Config):
     """Final norm and the tied head -> ``(logits, hidden)``."""
-    with jax.named_scope("head"):
-        h = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-        return jnp.einsum("...e,ve->...v", h, params["tok_emb"]), h
+    return rms_head(x, params["ln_f"], params["tok_emb"], cfg.norm_eps)
 
 
 def _pick(stack, i):
@@ -662,13 +654,10 @@ def init_paged_cache(
             f"({', '.join(SLOT_ARRAYS)}) has no placement rule and its one "
             "key-value head no axis to split by"
         )
-    if cfg.max_seq % block_size:
-        raise ValueError(
-            f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
-        )
     row = cfg.n_kv_heads * cfg.head_dim
     pool = (cfg.n_attn_layers, n_blocks, block_size, row)
     return {
+        **paged.bookkeeping(cfg.max_seq, n_slots, block_size, len(COUNTERS)),
         "k": jnp.zeros(pool, dtype),
         "v": jnp.zeros(pool, dtype),
         "ssm": jnp.zeros(
@@ -678,9 +667,6 @@ def init_paged_cache(
         "conv": jnp.zeros(
             (cfg.n_ssm_layers, cfg.mamba_d_conv - 1, n_slots, cfg.d_inner), dtype
         ),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-        "table": jnp.zeros((n_slots, cfg.max_seq // block_size), jnp.int32),
-        "counters": jnp.zeros((len(COUNTERS),), jnp.uint32),
     }
 
 
@@ -699,16 +685,8 @@ def paged_kv_slot_bytes(
     """HBM bytes one max_seq slot costs: the two attention layers' K and V
     of every token, and the slot's state."""
     del block_size, kv_dtype
-    per_token = (
-        2 * cfg.n_kv_heads * cfg.head_dim * jnp.dtype(dtype).itemsize
-        * cfg.n_attn_layers
-    )
-    return cfg.max_seq * per_token + slot_state_bytes(cfg, dtype)
-
-
-def _no_lora(lora):
-    if lora is not None:
-        raise TypeError("jamba has no LoRA path")
+    per_token = 2 * cfg.n_kv_heads * cfg.head_dim * cfg.n_attn_layers
+    return paged.slot_bytes(cfg.max_seq, per_token, dtype) + slot_state_bytes(cfg, dtype)
 
 
 def prefill_slot_paged(
@@ -725,7 +703,7 @@ def prefill_slot_paged(
     kernels (the recurrence and the tiled attention); ``"dense"`` through
     their XLA references."""
     del mesh, adapter_id
-    _no_lora(lora)
+    paged.no_lora("jamba", lora)
     bs = cache["k"].shape[2]
     lp_ = tokens.shape[1]
     phys = blocks_row[: lp_ // bs]
@@ -765,8 +743,8 @@ def prefill_slot_paged(
         # back as it found it
         ai, si = jnp.where(attention, i, 0), jnp.where(attention, 0, i)
         blocks = jnp.where(attention, phys, 0)
-        ck = _write_prompt(ck, ai, blocks, kk, bs)
-        cv = _write_prompt(cv, ai, blocks, v, bs)
+        ck = paged.write_prompt(ck, ai, blocks, kk, bs)
+        cv = paged.write_prompt(cv, ai, blocks, v, bs)
         at = (si, slot, 0, 0)
         s = jnp.where(attention, lax.dynamic_slice(cs, at, (1, 1, n, di))[0, 0], s)
         cs = lax.dynamic_update_slice(cs, s[None, None], at)
@@ -783,8 +761,8 @@ def prefill_slot_paged(
     x, ck, cv, cs, ct = _branch_layers(
         params, cfg, (x, cache["k"], cache["v"], cache["ssm"], cache["conv"]), layer
     )
-    ctr = _bump(cache.get("counters"), _P_TOKENS, length)
-    ctr = _bump(ctr, _P_ROWS, lp_)
+    ctr = paged.bump(cache.get("counters"), _P_TOKENS, length)
+    ctr = paged.bump(ctr, _P_ROWS, lp_)
     out = dict(cache)
     out.update(
         k=ck, v=cv, ssm=cs, conv=ct,
@@ -812,20 +790,13 @@ def decode_slots_paged(
     table's columns read; ``kernel`` (static) reads through the Pallas paged
     kernel, each slot's live blocks alone."""
     del adapter_ids, kv_sharded
-    _no_lora(lora)
-    pos, table = cache["pos"], cache["table"]
+    paged.no_lora("jamba", lora)
+    pos = cache["pos"]
     S = tokens.shape[0]
     bs = cache["k"].shape[2]
-    mb = table.shape[1]
-    W = cfg.max_seq if window is None else min(window, cfg.max_seq)
-    wb = max(1, W // bs)
-    # an inactive slot writes to the sink block 0
-    # (models/llama.py::_decode_paged_multi has the reasons)
-    write_blk = jnp.where(
-        active, table[jnp.arange(S), jnp.minimum(pos // bs, mb - 1)], 0
+    write_blk, write_off, read_blk = paged.decode_frame(
+        cache, active, bs, window, cfg.max_seq
     )
-    write_off = pos % bs
-    read_blk = table[:, :wb]
     x = params["tok_emb"][tokens]  # (S, E)
 
     # with the kernels, a layer's states are updated in place in the carried
@@ -859,9 +830,9 @@ def decode_slots_paged(
         params, cfg, (x, cache["k"], cache["v"], cache["ssm"], cache["conv"]),
         ssm, attn,
     )
-    ctr = _bump(cache.get("counters"), _STEPS, 1)
-    ctr = _bump(ctr, _SLOT_STEPS, jnp.sum(active))
-    ctr = _bump(
+    ctr = paged.bump(cache.get("counters"), _STEPS, 1)
+    ctr = paged.bump(ctr, _SLOT_STEPS, jnp.sum(active))
+    ctr = paged.bump(
         ctr, _ROWS_LIVE, cfg.n_attn_layers * jnp.sum(jnp.where(active, pos + 1, 0))
     )
     out = dict(cache)

@@ -31,15 +31,15 @@ rotate-half RoPE over all index dims at the layer's theta; (d)
 ``q_chunk_size`` / ``kv_chunk_size`` are an implementation's scoring tiles,
 ``topk`` counts tokens; (e) the Hadamard rotation and FP8 of DeepSeek's
 indexer are an implementation's and are not served.  The index scores, the
-top-``topk``, the router's softmax and top-k run in float32
-(``cohere2_moe._route`` has the reason: a near-tie flipped by bfloat16 swaps
-a key or an expert, which is not rounding noise).
+top-``topk``, the router's softmax and top-k run in float32 (a near-tie
+flipped by bfloat16 swaps a key or an expert, which is not rounding noise).
 
 The paged pool is uniform and holds a THIRD per-token array beside K and V:
 the index keys ``ik (layers, blocks, DI, block)`` under the same table (a
 block transposed, its tokens along the lanes: ``init_paged_cache``; only
-``_ik_write``, ``_ik_by_token`` and ``_select_rows`` know which way round a
-block lies), so prefix reuse shares a block's index keys with its K/V.
+``paged.write_transposed``, ``paged.by_token`` (here ``_ik_write``,
+``_ik_by_token``) and ``_select_rows`` know which way round a block lies),
+so prefix reuse shares a block's index keys with its K/V.
 Prefill, suffix prefill and decode write a token's index key where they
 write its K/V.
 
@@ -67,13 +67,12 @@ and PR 46: what a tile does and why).  The other way — gathering a query's
 ``topk`` rows — would move ``topk`` x 2 KB x 2 for every query and layer
 (200 GB a 24k prompt) and was not built.
 
-Expert products are ``cohere2_moe``'s under a softmax route, chosen by its
-one rule of static shapes (``experts_plan``): ``_experts_grouped`` in a
-prompt; in a decode step the kernel that streams only the experts some token
-chose (``_experts_touched``: 8 slots x top-8 touch a third of 128), and
-``_experts_dense`` for a caller that hands ``_moe`` no stack.
-``experts_held`` means what it means there.  ``COUNTERS`` keeps that
-family's names and adds the selection's five.
+Expert products are ``models/moe.py``'s under a softmax route, chosen by its
+one rule of static shapes (``moe.experts_plan``): grouped in a prompt; in a
+decode step the kernel that streams only the experts some token chose (8
+slots x top-8 touch a third of 128), and the dense products for a caller
+that hands ``_moe`` no stack.  ``experts_held`` means what it means there.
+``COUNTERS`` keeps that module's names and adds the selection's five.
 """
 
 from __future__ import annotations
@@ -85,19 +84,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from seldon_core_tpu.models.cohere2_moe import (
-    COUNTERS as _MOE_COUNTERS,
-    _bump,
-    _count_routing,
-    _experts_dense,
-    _experts_grouped,
-    _experts_touched,
-    _layernorm,
-    experts_plan,
-)
+from seldon_core_tpu.models import moe, paged
 from seldon_core_tpu.models.common import annotate_params
-from seldon_core_tpu.models.llama import _rmsnorm, _rope
-from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
+from seldon_core_tpu.models.layers import add, layernorm, rms_head, rope
+# benchmark/reference/kinds/keye_vl2_decoder.py reads ``_rmsnorm`` here
+from seldon_core_tpu.models.layers import rmsnorm as _rmsnorm
+from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
+# a block of index keys lies transposed in the pool
+from seldon_core_tpu.models.paged import by_token as _ik_by_token
+from seldon_core_tpu.models.paged import write_transposed as _ik_write
 from seldon_core_tpu.ops.sparse_attention import (
     index_scores,
     masked_flash_attention,
@@ -111,24 +106,20 @@ from seldon_core_tpu.ops.sparse_attention import (
 ATTN_Q_CHUNK = 128
 # queries whose selection (one int8 a key) is alive at once in a prompt
 QUERY_CHUNK = 8192
-# rows of (token, expert) pairs one grouped pass takes: with every expert
-# of a layer held, a pass reads all of them, so passes are few and long
-GROUP_CHUNK = 32768
 
-COUNTERS = _MOE_COUNTERS + (
+COUNTERS = moe.COUNTERS + (
     "dsa.keys_scored",            # decode: index keys scored, layers, slots and steps summed
     "dsa.keys_selected",          # decode: keys attended, likewise
     "dsa.prefill_keys_scored",    # prefill: (query, key) pairs scored, in units of 1,024, layers summed
     "dsa.prefill_keys_selected",  # prefill: pairs attended, likewise
     "dsa.key_blocks_read",        # decode: pool blocks of index keys read, as the selection itself counts them, likewise
 )
-_STEPS, _P_TOKENS = 4, 7  # "moe.steps", "moe.prefill_tokens"
+_STEPS, _P_TOKENS = moe.STEPS, moe.PREFILL_TOKENS
 _SCORED, _SELECTED, _P_SCORED, _P_SELECTED, _BLOCKS_READ = range(
-    len(_MOE_COUNTERS), len(_MOE_COUNTERS) + 5
+    len(moe.COUNTERS), len(moe.COUNTERS) + 5
 )
 # every per-token array of the paged pool, under the one table
 POOL_ARRAYS = ("k", "v", "ik")
-_EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,12 +144,7 @@ class Config:
     select: str = "on"  # "off" attends every key: a control, never served
 
     def __post_init__(self):
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_experts:
-            raise ValueError(
-                f"experts_held {self.experts_held!r} is not a range of the "
-                f"{self.n_experts} experts"
-            )
+        moe.held_range(self.experts_held, self.n_experts)  # or refused
         if self.n_heads % self.n_kv_heads or self.head_dim % 2 or self.index_dim % 2:
             raise ValueError(
                 "n_heads must group over n_kv_heads; head_dim, index_dim even"
@@ -169,10 +155,7 @@ class Config:
     @property
     def held(self) -> tuple[int, int]:
         """(first, count) of the routed experts this share holds."""
-        if not self.experts_held:
-            return 0, self.n_experts
-        first, _, count = str(self.experts_held).partition(":")
-        return int(first), int(count)
+        return moe.held_range(self.experts_held, self.n_experts)
 
     @property
     def selects(self) -> bool:
@@ -197,9 +180,9 @@ class Config:
 
 def init_params(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> dict:
     """Random weights IN ``dtype``, one layer (an expert leaf: one expert of
-    one layer) at a time, as ``cohere2_moe.init_params`` makes them and for
-    its reason; expert ``e`` of layer ``l`` has the same values in every
-    share that holds it."""
+    one layer) at a time, so that the float32 temporary is never larger than
+    that; expert ``e`` of layer ``l`` has the same values in every share
+    that holds it."""
     c = cfg
     first, count = c.held
     keys = jax.random.split(rng, 13)
@@ -289,8 +272,8 @@ def _qkv(h, lp, cfg: Config, positions):
     q = jnp.einsum("...le,ehd->...lhd", h, lp["wq"])
     k = jnp.einsum("...le,ehd->...lhd", h, lp["wk"])
     v = jnp.einsum("...le,ehd->...lhd", h, lp["wv"])
-    q = _rope(_rmsnorm(q, lp["q_norm"], cfg.norm_eps), positions, cfg.rope_theta)
-    k = _rope(_rmsnorm(k, lp["k_norm"], cfg.norm_eps), positions, cfg.rope_theta)
+    q = rope(_rmsnorm(q, lp["q_norm"], cfg.norm_eps), positions, cfg.rope_theta)
+    k = rope(_rmsnorm(k, lp["k_norm"], cfg.norm_eps), positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -300,7 +283,7 @@ def _index(h, lp, cfg: Config, positions):
     ``(..., L, DI)``."""
     with jax.named_scope("attn.index"):
         qi = jnp.einsum("...le,ejd->...ljd", h, lp["wqi"])
-        ki = _layernorm(
+        ki = layernorm(
             jnp.einsum("...le,ed->...ld", h, lp["wki"]), lp["ki_norm_w"],
             cfg.norm_eps,
         ) + lp["ki_norm_b"]
@@ -308,8 +291,8 @@ def _index(h, lp, cfg: Config, positions):
             "...le,ej->...lj", h.astype(jnp.float32),
             lp["wwi"].astype(jnp.float32), precision=lax.Precision.HIGHEST,
         )
-        qi = _rope(qi, positions, cfg.rope_theta)
-        ki = _rope(ki[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+        qi = rope(qi, positions, cfg.rope_theta)
+        ki = rope(ki[..., None, :], positions, cfg.rope_theta)[..., 0, :]
         return qi, wi, ki
 
 
@@ -409,27 +392,6 @@ def _attend_prompt(q, k, v, index, cfg: Config, seq_impl: str):
     return _attend(q, k, v, index, pos, pos, jnp.ones(pos.shape, bool), cfg)
 
 
-def _ik_write(cik, li, blk, ki, off=None):
-    """Write index keys into layer ``li`` of their pool AS IT IS CARRIED,
-    ``(layers, blocks, DI, block)``: a block transposed, its tokens along
-    the lanes (``init_paged_cache`` says why).  Whole blocks ``blk`` from
-    ``ki (len(blk) * block, DI)``, a prompt's rows; or with ``off`` one
-    token a block, ``ki (S, DI)`` at ``blk[s], off[s]``: a decode step's."""
-    ki = ki.astype(cik.dtype)
-    if off is not None:
-        return cik.at[li, blk, :, off].set(ki)
-    blocks = ki.reshape(-1, cik.shape[3], ki.shape[-1])
-    return cik.at[li, blk].set(_ik_by_token(blocks))
-
-
-def _ik_by_token(ik):
-    """Blocks of index keys as the pool carries them, ``(..., DI, block)``,
-    seen by token, ``(..., block, DI)`` — and back: its own inverse.  With
-    ``_ik_write`` and the kernel's view of a block (``_select_rows``), all
-    that knows which way round a block lies."""
-    return jnp.swapaxes(ik, -1, -2)
-
-
 def _select_rows(qi, wi, cik, li, read_blk, pos, active, cfg: Config, *,
                  kernel: bool):
     """A decode step's selection on layer ``li``: ``(rows (S, index_topk)
@@ -516,37 +478,17 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool,
          stacks=None, li=None):
     """``h2 (T, E)`` -> (the held experts' part (T, E) float32, counters).
     ``stacks`` are the expert weights of every layer and ``li`` this layer
-    (``cohere2_moe._experts_grouped`` says why a kernel wants those and not
+    (``moe.experts_grouped`` says why a kernel wants those and not
     ``lp``'s); a caller without them gets the dense products where the
-    touched-only kernel would have run."""
-    first, count = cfg.held
-    plan = experts_plan(h2.shape[0], kernel=stacks is not None)
+    touched-only kernel would have run.  A prompt's grouped product runs
+    over this layer alone, every expert of it held, in long passes."""
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], cfg)
-        local = idx - first
-        held = (local >= 0) & (local < count) & tok_mask[:, None]
-    with jax.named_scope("moe.experts"):
-        if plan == "grouped":
-            # this layer's experts as a stack of one layer: cutting them out
-            # of the carried stack is a copy (1.2 GB a layer at the published
-            # sizes, 3 ms) against a prompt's hundreds of milliseconds, and
-            # the grouped product then runs over 128 groups, not 128 x layers
-            one = {k: lp[k][None] for k in _EXPERT_KEYS}
-            routed = _experts_grouped(
-                h2, one, 0, local, held, w, chunk=GROUP_CHUNK
-            )
-        elif plan == "touched":
-            routed = _experts_touched(h2, stacks, li, local, held, w)
-        else:
-            routed = _experts_dense(h2, lp, local, held, w)
-    counters = _count_routing(
-        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode, plan
+    return moe.routed_experts(
+        h2, lp, idx, w, cfg.held, tok_mask, counters, decode=decode,
+        kernel=stacks is not None, stacks=stacks, li=li, group_alone=True,
+        group_chunk=moe.GROUP_CHUNK_WHOLE,
     )
-    return routed, counters
-
-
-def _add(x, y):
-    return (x.astype(jnp.float32) + y.astype(jnp.float32)).astype(x.dtype)
 
 
 def _after_attention(x, o, lp, cfg: Config, tok_mask, ctr, *, decode: bool,
@@ -554,16 +496,14 @@ def _after_attention(x, o, lp, cfg: Config, tok_mask, ctr, *, decode: bool,
     """The rest of a layer behind its attention ``o (T, H, D)``: the output
     projection and the expert layer (``stacks``, ``li``: :func:`_moe`), each
     added to the stream."""
-    x = _add(x, jnp.einsum("thd,hde->te", o, lp["wo"]))
+    x = add(x, jnp.einsum("thd,hde->te", o, lp["wo"]))
     h2 = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
-    moe, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode, stacks=stacks, li=li)
-    return _add(x, moe), ctr
+    ffn, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode, stacks=stacks, li=li)
+    return add(x, ffn), ctr
 
 
 def _head(params, h, cfg: Config):
-    with jax.named_scope("head"):
-        h = _rmsnorm(h, params["ln_f"], cfg.norm_eps)
-        return jnp.einsum("...e,ve->...v", h, params["head"]), h
+    return rms_head(h, params["ln_f"], params["head"], cfg.norm_eps)
 
 
 def _scan_layers(params, carry, layer_fn):
@@ -595,9 +535,9 @@ def _count_prompt(ctr, cfg: Config, lo, hi):
     k = cfg.index_topk if cfg.selects else cfg.max_seq
     scored = (_pairs(hi) - _pairs(lo)) >> 10
     chosen = (_selected_pairs(hi, k) - _selected_pairs(lo, k)) >> 10
-    ctr = _bump(ctr, _P_TOKENS, hi - lo)
-    ctr = _bump(ctr, _P_SCORED, scored * cfg.n_layers)
-    return _bump(ctr, _P_SELECTED, chosen * cfg.n_layers)
+    ctr = paged.bump(ctr, _P_TOKENS, hi - lo)
+    ctr = paged.bump(ctr, _P_SCORED, scored * cfg.n_layers)
+    return paged.bump(ctr, _P_SELECTED, chosen * cfg.n_layers)
 
 
 # ---------------------------------------------------------------------------
@@ -657,20 +597,14 @@ def init_paged_cache(
             "keye_vl2 has no pool split over a mesh: its index keys have one "
             "head and its decode read is single-device"
         )
-    if cfg.max_seq % block_size:
-        raise ValueError(
-            f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
-        )
     rows = (cfg.n_layers, n_blocks, block_size)
     return {
+        **paged.bookkeeping(cfg.max_seq, n_slots, block_size, len(COUNTERS)),
         "k": jnp.zeros(rows + (cfg.n_kv_heads * cfg.head_dim,), dtype),
         "v": jnp.zeros(rows + (cfg.n_kv_heads * cfg.head_dim,), dtype),
         "ik": jnp.zeros(
             (cfg.n_layers, n_blocks, cfg.index_dim, block_size), dtype
         ),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-        "table": jnp.zeros((n_slots, cfg.max_seq // block_size), jnp.int32),
-        "counters": jnp.zeros((len(COUNTERS),), jnp.uint32),
     }
 
 
@@ -679,23 +613,9 @@ def paged_kv_slot_bytes(
 ) -> int:
     """HBM bytes one max_seq slot costs in the paged pool: K, V and the
     index key of every token on every layer."""
-    import numpy as _np
-
     del block_size, kv_dtype
-    itemsize = 2 if str(dtype) in ("bfloat16", "bf16") else _np.dtype(dtype).itemsize
-    per_token = 2 * cfg.n_kv_heads * cfg.head_dim + cfg.index_dim
-    return cfg.max_seq * per_token * itemsize * cfg.n_layers
-
-
-def _write_prompt(pool, li, phys, rows, bs):
-    """Scatter ``rows (L, ...)`` of layer ``li`` into the blocks ``phys``."""
-    lb = rows.shape[0] // bs
-    return pool.at[li, phys].set(rows.reshape(lb, bs, -1).astype(pool.dtype))
-
-
-def _no_lora(lora):
-    if lora is not None:
-        raise TypeError("keye_vl2 has no LoRA path")
+    per_token = (2 * cfg.n_kv_heads * cfg.head_dim + cfg.index_dim) * cfg.n_layers
+    return paged.slot_bytes(cfg.max_seq, per_token, dtype)
 
 
 def prefill_slot_paged(
@@ -709,7 +629,7 @@ def prefill_slot_paged(
     runs the selection and the attention through the Pallas kernels;
     ``"dense"`` through chunked XLA."""
     del mesh, adapter_id
-    _no_lora(lora)
+    paged.no_lora("keye_vl2", lora)
     bs = cache["k"].shape[2]
     lp_ = tokens.shape[1]
     pos = jnp.arange(lp_)
@@ -722,8 +642,8 @@ def prefill_slot_paged(
         h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
         q, k, v = _qkv(h, lp, cfg, pos)
         qi, wi, ki = _index(h, lp, cfg, pos)
-        ck = _write_prompt(ck, li, phys, k, bs)
-        cv = _write_prompt(cv, li, phys, v, bs)
+        ck = paged.write_prompt(ck, li, phys, k, bs)
+        cv = paged.write_prompt(cv, li, phys, v, bs)
         cik = _ik_write(cik, li, phys, ki)
         index = (qi, wi, ki.astype(cik.dtype)) if cfg.selects else None
         o = _attend_prompt(q, k, v, index, cfg, seq_impl)
@@ -734,27 +654,10 @@ def prefill_slot_paged(
     x, ck, cv, cik, ctr = _scan_layers(
         params, (x, cache["k"], cache["v"], cache["ik"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - 1, (ck, cv, cik), ctr, slot, length,
-        blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - 1, {"k": ck, "v": cv, "ik": cik}, ctr,
+        slot, length, blocks_row, return_hidden, _head,
     )
-
-
-def _finish_prefill(params, cfg, cache, x, at, pools, ctr, slot, length,
-                    blocks_row, return_hidden):
-    cache = dict(cache)
-    cache.update(
-        k=pools[0], v=pools[1], ik=pools[2],
-        pos=cache["pos"].at[slot].set(length),
-        table=cache["table"].at[slot].set(blocks_row),
-    )
-    if ctr is not None:
-        cache["counters"] = ctr
-    h = lax.dynamic_index_in_dim(x, at, axis=0, keepdims=False)
-    logits, h = _head(params, h, cfg)
-    if return_hidden:
-        return logits, cache, h
-    return logits, cache
 
 
 def prefill_suffix_paged(
@@ -769,7 +672,7 @@ def prefill_suffix_paged(
     queries score and attend over [the prefix read from the pool ++ the
     suffix], in XLA."""
     del adapter_id, kv_sharded
-    _no_lora(lora)
+    paged.no_lora("keye_vl2", lora)
     bs = cache["k"].shape[2]
     ls = tokens.shape[1]
     pb = max(1, int(prefix_window) // bs)
@@ -801,8 +704,8 @@ def prefill_suffix_paged(
             q, behind(ck[li, read_idx], k), behind(cv[li, read_idx], v), index,
             qpos, kpos, kvalid, cfg,
         )
-        ck = _write_prompt(ck, li, suffix_blocks, k, bs)
-        cv = _write_prompt(cv, li, suffix_blocks, v, bs)
+        ck = paged.write_prompt(ck, li, suffix_blocks, k, bs)
+        cv = paged.write_prompt(cv, li, suffix_blocks, v, bs)
         cik = _ik_write(cik, li, suffix_blocks, ki)
         x, ctr = _after_attention(x, o, lp, cfg, real, ctr, decode=False)
         return x, ck, cv, cik, ctr
@@ -811,9 +714,10 @@ def prefill_suffix_paged(
     x, ck, cv, cik, ctr = _scan_layers(
         params, (x, cache["k"], cache["v"], cache["ik"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - prefix_len - 1, (ck, cv, cik), ctr,
-        slot, length, blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - prefix_len - 1,
+        {"k": ck, "v": cv, "ik": cik}, ctr, slot, length, blocks_row,
+        return_hidden, _head,
     )
 
 
@@ -831,28 +735,21 @@ def decode_slots_paged(
     way, a window of ``index_topk`` or fewer, through the paged
     decode-attention kernel (``ops/paged_attention.py``)."""
     del adapter_ids, kv_sharded
-    _no_lora(lora)
-    pos, table = cache["pos"], cache["table"]
+    paged.no_lora("keye_vl2", lora)
+    pos = cache["pos"]
     S = tokens.shape[0]
-    nb, bs = cache["k"].shape[1:3]
-    mb = table.shape[1]
-    W = cfg.max_seq if window is None else min(window, cfg.max_seq)
-    wb = max(1, W // bs)
-    W = wb * bs
+    bs = cache["k"].shape[2]
+    write_blk, write_off, read_blk = paged.decode_frame(
+        cache, active, bs, window, cfg.max_seq
+    )
+    W = read_blk.shape[1] * bs
     kvd = cfg.n_kv_heads * cfg.head_dim
     topk = cfg.index_topk
     sparse = cfg.selects and W > topk
-    # an inactive slot writes to the sink block 0
-    # (models/llama.py::_decode_paged_multi has the reasons)
-    write_blk = jnp.where(
-        active, table[jnp.arange(S), jnp.minimum(pos // bs, mb - 1)], 0
-    )
-    write_off = pos % bs
-    read_blk = table[:, :wb]
     n_seen = jnp.where(active, jnp.minimum(pos + 1, W), 0)
     n_sel = jnp.minimum(n_seen, topk) if sparse else n_seen
     x = params["tok_emb"][tokens]  # (S, E)
-    stacks = {k: params["layers"][k] for k in _EXPERT_KEYS}
+    stacks = {k: params["layers"][k] for k in moe.EXPERT_KEYS}
 
     def layer(carry, li, lp):
         x, ck, cv, cik, ctr = carry
@@ -867,7 +764,7 @@ def decode_slots_paged(
             rows, read = _select_rows(
                 qi, wi, cik, li, read_blk, pos, active, cfg, kernel=kernel
             )
-            ctr = _bump(ctr, _BLOCKS_READ, jnp.sum(read))
+            ctr = paged.bump(ctr, _BLOCKS_READ, jnp.sum(read))
         o = _decode_read(
             q, ck, cv, li, read_blk, pos, active, n_sel, rows, kernel=kernel
         )
@@ -876,9 +773,9 @@ def decode_slots_paged(
         )
         return x, ck, cv, cik, ctr
 
-    ctr = _bump(cache.get("counters"), _STEPS, 1)
-    ctr = _bump(ctr, _SCORED, jnp.sum(n_seen) * cfg.n_layers)
-    ctr = _bump(ctr, _SELECTED, jnp.sum(n_sel) * cfg.n_layers)
+    ctr = paged.bump(cache.get("counters"), _STEPS, 1)
+    ctr = paged.bump(ctr, _SCORED, jnp.sum(n_seen) * cfg.n_layers)
+    ctr = paged.bump(ctr, _SELECTED, jnp.sum(n_sel) * cfg.n_layers)
     x, ck, cv, cik, ctr = _scan_layers(
         params, (x, cache["k"], cache["v"], cache["ik"], ctr), layer
     )

@@ -49,15 +49,15 @@ columns); (b) RMSNorm on both latents with ``rms_norm_eps``; (c) ``b`` is
 drawn from the seed, small against the scores' spread
 (:data:`ROUTER_BIAS_STD`), so that it moves some choices and not all; (d) ``ep_size``, ``seq_aux``, ``moe_layer_freq`` 1
 describe training or say nothing of a layer.  The router's product, sigmoid,
-bias, top-8 and normalisation run in float32 (``cohere2_moe._route`` has the
-reason).
+bias, top-8 and normalisation run in float32 (a near-tie at the 8th place
+flipped by bfloat16 swaps an expert, which is not rounding noise).
 
 The paged pool is uniform, one table, one kind of block, and holds NOTHING by
 head: ``c (layers, blocks, block, kv_lora_rank)`` and ``kr (layers, blocks,
 qk_rope_dim, block)`` — a block of rotary keys TRANSPOSED, its tokens along
-the lanes (``init_paged_cache`` says why; only ``_kr_write`` and
-``_kr_by_token``, which are ``keye_vl2``'s of its index keys, know which way
-round a block lies).  576 values a token a layer, no padding.  Prefix reuse
+the lanes (``init_paged_cache`` says why; only ``paged.write_transposed``
+and ``paged.by_token``, here ``_kr_write`` and ``_kr_by_token``, know which
+way round a block lies).  576 values a token a layer, no padding.  Prefix reuse
 shares a block's latents with nothing further.
 
 Two attentions for one model.  A prompt up-projects its latents to keys and
@@ -71,9 +71,9 @@ blocks copied by table entry, a latent row read once), else the XLA lines it
 is held to.  Two kinds of layer in one stack: ``params["dense_layers"]`` (the
 leading ``n_dense_layers``, a SwiGLU) and ``params["layers"]`` (the expert
 layers), each a scan of its own; the pool's layer axis runs over both.
-``experts_held`` means what it means in ``cohere2_moe``, whose expert
-products (:func:`~seldon_core_tpu.models.cohere2_moe.experts_plan`) these are.
-``COUNTERS`` keeps that family's names, counted over the expert layers, and
+``experts_held`` means what it means in ``models/moe.py``, whose expert
+products (:func:`~seldon_core_tpu.models.moe.experts_plan`) these are.
+``COUNTERS`` keeps that module's names, counted over the expert layers, and
 adds the latent cache's three.
 """
 
@@ -86,24 +86,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from seldon_core_tpu.models.cohere2_moe import (
-    COUNTERS as _MOE_COUNTERS,
-    _bump,
-    _count_routing,
-    _experts_dense,
-    _experts_grouped,
-    _experts_touched,
-    _rope_pairs,
-    experts_plan,
-)
+from seldon_core_tpu.models import moe, paged
 from seldon_core_tpu.models.common import annotate_params
-# a block of a per-token array carried TRANSPOSED in the pool is that
-# family's index keys and this one's rotary keys alike
-from seldon_core_tpu.models.keye_vl2 import _add, _head, _write_prompt
-from seldon_core_tpu.models.keye_vl2 import _ik_by_token as _kr_by_token
-from seldon_core_tpu.models.keye_vl2 import _ik_write as _kr_write
-from seldon_core_tpu.models.llama import _rmsnorm
-from seldon_core_tpu.models.llama import sample_tokens  # noqa: F401  (contract)
+from seldon_core_tpu.models.layers import add, flash_prompt, rms_head, rope_pairs
+# benchmark/reference/kinds/kimi_k2_decoder.py reads ``_rmsnorm`` and
+# ``_kr_by_token`` here (a block of rotary keys lies transposed in the pool)
+from seldon_core_tpu.models.layers import rmsnorm as _rmsnorm
+from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
+from seldon_core_tpu.models.paged import by_token as _kr_by_token
+from seldon_core_tpu.models.paged import write_transposed as _kr_write
 
 # query rows one pass of the XLA expanded attention scores at once
 ATTN_Q_CHUNK = 128
@@ -118,18 +109,17 @@ MLP_CHUNK = 4096
 # §6, PR 43)
 ROUTER_BIAS_STD = 0.0025
 
-COUNTERS = _MOE_COUNTERS + (
+COUNTERS = moe.COUNTERS + (
     "mla.rows_read",              # decode: latent rows the step's read brought in, as the read itself counts them,
                                   # layers, slots and steps summed
     "mla.prefill_rows_expanded",  # prefill: latent rows a prompt or suffix program up-projected, layers summed
     "mla.rows_live",              # decode: latent rows the step HAS to read, from the live slots' positions alone
                                   # (a slot at position p attends p + 1 rows a layer), layers, slots and steps summed
 )
-_STEPS, _P_TOKENS = 4, 7  # "moe.steps", "moe.prefill_tokens"
-_ROWS_READ, _P_EXPANDED, _ROWS_LIVE = (len(_MOE_COUNTERS) + i for i in range(3))
+_STEPS, _P_TOKENS = moe.STEPS, moe.PREFILL_TOKENS
+_ROWS_READ, _P_EXPANDED, _ROWS_LIVE = (len(moe.COUNTERS) + i for i in range(3))
 # every per-token array of the paged pool: there is no "k" and no "v"
 POOL_ARRAYS = ("c", "kr")
-_EXPERT_KEYS = ("we_gate", "we_up", "we_down")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -167,12 +157,7 @@ class Config:
     prompt_score_dtype: str = "float32"  # "bfloat16" rounds a prompt's scores
 
     def __post_init__(self):
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_experts:
-            raise ValueError(
-                f"experts_held {self.experts_held!r} is not a range of the "
-                f"{self.n_experts} experts"
-            )
+        moe.held_range(self.experts_held, self.n_experts)  # or refused
         if self.qk_rope_dim % 2 or not 0 <= self.n_dense_layers < self.n_layers:
             raise ValueError(
                 "qk_rope_dim even; n_dense_layers leading layers of n_layers, "
@@ -184,10 +169,7 @@ class Config:
     @property
     def held(self) -> tuple[int, int]:
         """(first, count) of the routed experts this share holds."""
-        if not self.experts_held:
-            return 0, self.n_experts
-        first, _, count = str(self.experts_held).partition(":")
-        return int(first), int(count)
+        return moe.held_range(self.experts_held, self.n_experts)
 
     @property
     def n_moe_layers(self) -> int:
@@ -229,9 +211,9 @@ class Config:
 
 def init_params(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> dict:
     """Random weights IN ``dtype``, one layer (an expert leaf: one expert of
-    one layer) at a time, as ``cohere2_moe.init_params`` makes them and for
-    its reason; expert ``e`` of expert layer ``l`` has the same values in
-    every share that holds it.  The router's bias ``b`` is drawn with a
+    one layer) at a time, so that the float32 temporary is never larger than
+    that; expert ``e`` of expert layer ``l`` has the same values in every
+    share that holds it.  The router's bias ``b`` is drawn with a
     standard deviation of :data:`ROUTER_BIAS_STD` (assumed (c))."""
     c = cfg
     first, count = c.held
@@ -361,7 +343,7 @@ def _rope_y(x, positions, cfg: Config):
     published, and a graph that states another is refused here."""
     if cfg.rope_factor > 1 and cfg.rope_mscale != cfg.rope_mscale_all_dim:
         raise ValueError("rope_mscale other than rope_mscale_all_dim is not served")
-    return _rope_pairs(x, positions, cfg.rope_theta, freqs=yarn_freqs(cfg))
+    return rope_pairs(x, positions, cfg.rope_theta, freqs=yarn_freqs(cfg))
 
 
 def _latents(h, lp, cfg: Config, positions):
@@ -432,15 +414,9 @@ def _attend_prompt(qn, qr, c, kr, lp, cfg: Config, seq_impl: str):
     rounded = _prompt_score_dtype(cfg)
     with jax.named_scope("attn.prompt"):
         if seq_impl == "flash":
-            from seldon_core_tpu.ops.flash_attention import flash_attention
-
-            blk = min(512, L)
-            out = flash_attention(
-                q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
-                v.transpose(1, 0, 2)[None], causal=True, block_q=blk,
-                block_k=blk, scale=cfg.softmax_scale, score_dtype=rounded,
+            return flash_prompt(
+                q, k, v, scale=cfg.softmax_scale, score_dtype=rounded
             )
-            return out[0].transpose(1, 0, 2)
         pos = jnp.arange(L)
         return _attend(
             q, k, v, pos, pos, jnp.ones((L,), bool), cfg.softmax_scale, rounded
@@ -502,35 +478,15 @@ def _moe(h2, lp, cfg: Config, tok_mask, counters, *, decode: bool,
          stacks=None, li=None):
     """``h2 (T, E)`` -> (routed + shared (T, E) float32, counters).
     ``stacks`` are the expert weights of every expert layer and ``li`` this
-    one's place among them (``cohere2_moe._experts_grouped`` says why a
-    kernel wants those and not ``lp``'s); a caller without them gets the
-    dense products."""
-    first, count = cfg.held
-    plan = experts_plan(h2.shape[0], kernel=stacks is not None)
-    if stacks is None and plan == "grouped":
-        stacks, li = {k: lp[k][None] for k in _EXPERT_KEYS}, 0
+    one's place among them (``moe.experts_grouped`` says why a kernel wants
+    those and not ``lp``'s); a caller without them gets the dense
+    products."""
     with jax.named_scope("moe.route"):
         idx, w = _route(h2, lp["w_router"], lp["b_router"], cfg)
-        local = idx - first
-        held = (local >= 0) & (local < count) & tok_mask[:, None]
-    with jax.named_scope("moe.experts"):
-        if plan == "grouped":
-            routed = _experts_grouped(h2, stacks, li, local, held, w)
-        elif plan == "touched":
-            routed = _experts_touched(h2, stacks, li, local, held, w)
-        else:
-            routed = _experts_dense(h2, lp, local, held, w)
-    with jax.named_scope("moe.shared"):
-        g = jnp.einsum("te,jef->jtf", h2, lp["ws_gate"])
-        u = jnp.einsum("te,jef->jtf", h2, lp["ws_up"])
-        shared = jnp.einsum(
-            "jtf,jfe->te", jax.nn.silu(g) * u, lp["ws_down"],
-            preferred_element_type=jnp.float32,
-        )
-    counters = _count_routing(
-        counters, local, held, tok_mask, cfg.experts_per_tok, count, decode, plan
+    return moe.routed_experts(
+        h2, lp, idx, w, cfg.held, tok_mask, counters, decode=decode,
+        kernel=stacks is not None, stacks=stacks, li=li, shared="sum",
     )
-    return routed + shared, counters
 
 
 def _mlp_dense(h2, lp):
@@ -558,12 +514,16 @@ def _after_attention(x, o, lp, cfg: Config, tok_mask, ctr, *, dense: bool,
     layer, ``stacks`` and ``li`` as :func:`_moe` takes them), each added to
     the stream."""
     with jax.named_scope("attn.out"):
-        x = _add(x, jnp.einsum("thd,hde->te", o, lp["wo"]))
+        x = add(x, jnp.einsum("thd,hde->te", o, lp["wo"]))
     h2 = _rmsnorm(x, lp["ln2"], cfg.norm_eps)
     if dense:
-        return _add(x, _mlp_dense(h2, lp)), ctr
-    moe, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode, stacks=stacks, li=li)
-    return _add(x, moe), ctr
+        return add(x, _mlp_dense(h2, lp)), ctr
+    ffn, ctr = _moe(h2, lp, cfg, tok_mask, ctr, decode=decode, stacks=stacks, li=li)
+    return add(x, ffn), ctr
+
+
+def _head(params, h, cfg: Config):
+    return rms_head(h, params["ln_f"], params["head"], cfg.norm_eps)
 
 
 def _scan_layers(params, cfg: Config, carry, layer_fn):
@@ -648,20 +608,14 @@ def init_paged_cache(
             "kimi_k2 has no pool split over a mesh: its latents have no head "
             "axis to split by and its decode read is single-device"
         )
-    if cfg.max_seq % block_size:
-        raise ValueError(
-            f"max_seq {cfg.max_seq} must be a multiple of block_size {block_size}"
-        )
     return {
+        **paged.bookkeeping(cfg.max_seq, n_slots, block_size, len(COUNTERS)),
         "c": jnp.zeros(
             (cfg.n_layers, n_blocks, block_size, cfg.kv_lora_rank), dtype
         ),
         "kr": jnp.zeros(
             (cfg.n_layers, n_blocks, cfg.qk_rope_dim, block_size), dtype
         ),
-        "pos": jnp.zeros((n_slots,), jnp.int32),
-        "table": jnp.zeros((n_slots, cfg.max_seq // block_size), jnp.int32),
-        "counters": jnp.zeros((len(COUNTERS),), jnp.uint32),
     }
 
 
@@ -670,17 +624,9 @@ def paged_kv_slot_bytes(
 ) -> int:
     """HBM bytes one max_seq slot costs in the paged pool: the latent and
     the rotary key of every token on every layer."""
-    import numpy as _np
-
     del block_size, kv_dtype
-    itemsize = 2 if str(dtype) in ("bfloat16", "bf16") else _np.dtype(dtype).itemsize
-    per_token = cfg.kv_lora_rank + cfg.qk_rope_dim
-    return cfg.max_seq * per_token * itemsize * cfg.n_layers
-
-
-def _no_lora(lora):
-    if lora is not None:
-        raise TypeError("kimi_k2 has no LoRA path")
+    per_token = (cfg.kv_lora_rank + cfg.qk_rope_dim) * cfg.n_layers
+    return paged.slot_bytes(cfg.max_seq, per_token, dtype)
 
 
 def prefill_slot_paged(
@@ -694,20 +640,20 @@ def prefill_slot_paged(
     pool, the attention runs in the expanded form.  ``seq_impl="flash"``
     through the tiled Pallas kernel; ``"dense"`` through chunked XLA."""
     del mesh, adapter_id
-    _no_lora(lora)
+    paged.no_lora("kimi_k2", lora)
     bs = cache["c"].shape[2]
     lp_ = tokens.shape[1]
     pos = jnp.arange(lp_)
     real = pos < length
     phys = blocks_row[: lp_ // bs]
     x = params["tok_emb"][tokens[0]]  # (Lp, E)
-    stacks = {k: params["layers"][k] for k in _EXPERT_KEYS}
+    stacks = {k: params["layers"][k] for k in moe.EXPERT_KEYS}
 
     def layer(carry, li, mi, lp, dense):
         x, cc, ckr, ctr = carry
         h = _rmsnorm(x, lp["ln1"], cfg.norm_eps)
         qn, qr, c, kr = _latents(h, lp, cfg, pos)
-        cc = _write_prompt(cc, li, phys, c, bs)
+        cc = paged.write_prompt(cc, li, phys, c, bs)
         ckr = _kr_write(ckr, li, phys, kr)
         # attend what the pool now holds: the latents as stored
         o = _attend_prompt(
@@ -719,32 +665,15 @@ def prefill_slot_paged(
         )
         return x, cc, ckr, ctr
 
-    ctr = _bump(cache.get("counters"), _P_TOKENS, length)
-    ctr = _bump(ctr, _P_EXPANDED, lp_ * cfg.n_layers)
+    ctr = paged.bump(cache.get("counters"), _P_TOKENS, length)
+    ctr = paged.bump(ctr, _P_EXPANDED, lp_ * cfg.n_layers)
     x, cc, ckr, ctr = _scan_layers(
         params, cfg, (x, cache["c"], cache["kr"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - 1, (cc, ckr), ctr, slot, length,
-        blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - 1, {"c": cc, "kr": ckr}, ctr, slot,
+        length, blocks_row, return_hidden, _head,
     )
-
-
-def _finish_prefill(params, cfg, cache, x, at, pools, ctr, slot, length,
-                    blocks_row, return_hidden):
-    cache = dict(cache)
-    cache.update(
-        c=pools[0], kr=pools[1],
-        pos=cache["pos"].at[slot].set(length),
-        table=cache["table"].at[slot].set(blocks_row),
-    )
-    if ctr is not None:
-        cache["counters"] = ctr
-    h = lax.dynamic_index_in_dim(x, at, axis=0, keepdims=False)
-    logits, h = _head(params, h, cfg)
-    if return_hidden:
-        return logits, cache, h
-    return logits, cache
 
 
 def prefill_suffix_paged(
@@ -760,7 +689,7 @@ def prefill_suffix_paged(
     own, and the suffix queries attend [prefix ++ suffix] in the expanded
     form, in XLA."""
     del adapter_id, kv_sharded
-    _no_lora(lora)
+    paged.no_lora("kimi_k2", lora)
     bs = cache["c"].shape[2]
     ls = tokens.shape[1]
     pb = max(1, int(prefix_window) // bs)
@@ -772,7 +701,7 @@ def prefill_suffix_paged(
     )
     real = qpos < length
     x = params["tok_emb"][tokens[0]]
-    stacks = {k: params["layers"][k] for k in _EXPERT_KEYS}
+    stacks = {k: params["layers"][k] for k in moe.EXPERT_KEYS}
 
     def layer(carry, li, mi, lp, dense):
         x, cc, ckr, ctr = carry
@@ -794,7 +723,7 @@ def prefill_suffix_paged(
                 jnp.concatenate([qn, qr], axis=-1), k, v, qpos, kpos, kvalid,
                 cfg.softmax_scale, _prompt_score_dtype(cfg),
             )
-        cc = _write_prompt(cc, li, suffix_blocks, c, bs)
+        cc = paged.write_prompt(cc, li, suffix_blocks, c, bs)
         ckr = _kr_write(ckr, li, suffix_blocks, kr)
         x, ctr = _after_attention(
             x, o, lp, cfg, real, ctr, dense=dense, decode=False,
@@ -802,14 +731,14 @@ def prefill_suffix_paged(
         )
         return x, cc, ckr, ctr
 
-    ctr = _bump(cache.get("counters"), _P_TOKENS, length - prefix_len)
-    ctr = _bump(ctr, _P_EXPANDED, (pb * bs + ls) * cfg.n_layers)
+    ctr = paged.bump(cache.get("counters"), _P_TOKENS, length - prefix_len)
+    ctr = paged.bump(ctr, _P_EXPANDED, (pb * bs + ls) * cfg.n_layers)
     x, cc, ckr, ctr = _scan_layers(
         params, cfg, (x, cache["c"], cache["kr"], ctr), layer
     )
-    return _finish_prefill(
-        params, cfg, cache, x, length - prefix_len - 1, (cc, ckr), ctr,
-        slot, length, blocks_row, return_hidden,
+    return paged.finish_prefill(
+        params, cfg, cache, x, length - prefix_len - 1, {"c": cc, "kr": ckr},
+        ctr, slot, length, blocks_row, return_hidden, _head,
     )
 
 
@@ -824,22 +753,15 @@ def decode_slots_paged(
     columns read; ``kernel`` (static) reads through the Pallas kernel
     (``ops/mla_attention.py``), each slot's live blocks alone."""
     del adapter_ids, kv_sharded
-    _no_lora(lora)
-    pos, table = cache["pos"], cache["table"]
+    paged.no_lora("kimi_k2", lora)
+    pos = cache["pos"]
     S = tokens.shape[0]
     bs = cache["c"].shape[2]
-    mb = table.shape[1]
-    W = cfg.max_seq if window is None else min(window, cfg.max_seq)
-    wb = max(1, W // bs)
-    # an inactive slot writes to the sink block 0
-    # (models/llama.py::_decode_paged_multi has the reasons)
-    write_blk = jnp.where(
-        active, table[jnp.arange(S), jnp.minimum(pos // bs, mb - 1)], 0
+    write_blk, write_off, read_blk = paged.decode_frame(
+        cache, active, bs, window, cfg.max_seq
     )
-    write_off = pos % bs
-    read_blk = table[:, :wb]
     x = params["tok_emb"][tokens]  # (S, E)
-    stacks = {k: params["layers"][k] for k in _EXPERT_KEYS}
+    stacks = {k: params["layers"][k] for k in moe.EXPERT_KEYS}
 
     def layer(carry, li, mi, lp, dense):
         x, cc, ckr, ctr = carry
@@ -851,15 +773,15 @@ def decode_slots_paged(
             qn[:, 0], qr[:, 0], cc, ckr, li, lp, read_blk, pos, active, cfg,
             kernel=kernel,
         )
-        ctr = _bump(ctr, _ROWS_READ, jnp.sum(rows))
+        ctr = paged.bump(ctr, _ROWS_READ, jnp.sum(rows))
         x, ctr = _after_attention(
             x, o, lp, cfg, active, ctr, dense=dense, decode=True,
             stacks=stacks, li=mi,
         )
         return x, cc, ckr, ctr
 
-    ctr = _bump(cache.get("counters"), _STEPS, 1)
-    ctr = _bump(ctr, _ROWS_LIVE, cfg.n_layers * jnp.sum(jnp.where(active, pos + 1, 0)))
+    ctr = paged.bump(cache.get("counters"), _STEPS, 1)
+    ctr = paged.bump(ctr, _ROWS_LIVE, cfg.n_layers * jnp.sum(jnp.where(active, pos + 1, 0)))
     x, cc, ckr, ctr = _scan_layers(
         params, cfg, (x, cache["c"], cache["kr"], ctr), layer
     )

@@ -31,6 +31,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from seldon_core_tpu.models.common import annotate_params
+from seldon_core_tpu.models.layers import rmsnorm as _rmsnorm
+from seldon_core_tpu.models.layers import rope as _rope
+from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
 from seldon_core_tpu.parallel.ring import ring_self_attention
 
 
@@ -258,24 +261,6 @@ def _lora_delta(h, la, aid):
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
-
-def _rmsnorm(x, w, eps):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w
-
-
-def _rope(x, positions, theta):
-    """x: (..., L, H, D); positions: (..., L) int32."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., L, D/2)
-    cos = jnp.cos(angles)[..., None, :]
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
-
 
 def _gqa_repeat(kv, n_heads):
     """(B, L, Hkv, D) -> (B, L, H, D) by repeating each kv head."""
@@ -1277,30 +1262,6 @@ def decode_slots(
         "pos": jnp.where(active, pos + 1, pos),
     }
     return _head(params, x[:, 0], cfg)[0], cache
-
-
-def sample_tokens(
-    logits: jax.Array, temperature: jax.Array, key: jax.Array, top_k: int = 0
-) -> jax.Array:
-    """Per-row sampling, fused into the compiled device step: ``temperature
-    (S,)`` <= 0 means greedy; ``top_k`` (STATIC — one compiled program per
-    value) restricts sampling to the k highest logits.
-
-    This runs inside the jitted prefill/decode programs so only ``(S,)``
-    token ids ever cross the host boundary — never ``(S, vocab)`` logits.
-    ``top_k=1`` reduces to greedy (a pinned-equal test holds it there).
-    """
-    greedy = jnp.argmax(logits, axis=-1)
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    f32 = logits.astype(jnp.float32)
-    if top_k and int(top_k) > 0:
-        k = min(int(top_k), logits.shape[-1])
-        vals, idx = jax.lax.top_k(f32, k)  # (S, k) descending
-        local = jax.random.categorical(key, vals / temp, axis=-1)  # (S,)
-        sampled = jnp.take_along_axis(idx, local[:, None], axis=-1)[:, 0]
-    else:
-        sampled = jax.random.categorical(key, f32 / temp, axis=-1)
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
 def generate(

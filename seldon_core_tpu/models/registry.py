@@ -284,7 +284,15 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 
 
 # The generative contract: what ``executor/generation.py::GenerativeModel``
-# reads of a family module.
+# reads of a family module.  A family builds from three neutral modules and
+# imports no other family (``tests/test_models.py`` holds that):
+# ``models/layers.py`` (norms, rotary embeddings, the residual add, the
+# RMSNorm head, ``sample_tokens``), ``models/paged.py`` (the frame of a paged
+# cache: the bookkeeping arrays, a prompt's writes, ``finish_prefill``, a
+# slot's bytes, the counters' add, ``no_lora``) and ``models/moe.py`` (the
+# routed expert layer: ``routed_experts`` behind the family's own router, its
+# ``COUNTERS`` leading the family's); docs/GENERATIVE.md lists what a new
+# family touches.
 #   required: ``init_params``, ``param_logical_axes`` (under a mesh),
 #     ``init_paged_cache``, ``prefill_slot_paged``, ``decode_slots_paged``,
 #     ``sample_tokens`` (a ``top_k`` argument where top-k sampling is asked

@@ -1,7 +1,7 @@
 """The routed experts of a decode step as a Pallas TPU kernel that streams
 only the experts some token chose.
 
-``models/cohere2_moe.py::_experts_dense`` runs every held expert over every
+``models/moe.py::experts_dense`` runs every held expert over every
 token: a waste wherever some held expert has no token — a chip holds 128 and
 8 tokens x top-8 touch a third, or holds 16 and 30 tokens touch 12 — since
 the step is bound by reading expert weights, and what it reads of the others
@@ -17,7 +17,7 @@ matrices would not fit fast memory twice): its ``gate`` and ``up (E, F)``
 and ``down (F, E)`` come into VMEM by the (scalar-prefetched) list of
 touched ids, the next expert's in flight meanwhile; the products run for all
 ``T`` rows with float32 accumulation, row ``t`` is scaled by ``cw[t, x]`` —
-0 for a row that did not choose ``x``, as in ``_experts_dense`` — and added
+0 for a row that did not choose ``x``, as in ``experts_dense`` — and added
 into a float32 ``(T, E)`` accumulator that stays resident.  The grid's
 static bound is the most experts ``T`` tokens can touch; a step past the
 number touched names the block the step before it named, so the pipeline
@@ -27,7 +27,7 @@ block travels before the body can say no.)
 
 Operands are bfloat16 as served (float32 in the tests: computed at HIGHEST
 precision), every accumulation and the combine are float32, and the product
-behind ``down`` stays float32 where ``_experts_dense`` rounds it to the
+behind ``down`` stays float32 where ``experts_dense`` rounds it to the
 operand dtype: nowhere lower precision than the dense formulation.
 
 The stacks are those of EVERY layer, ``(layers * held, ...)``, and the layer
@@ -36,7 +36,7 @@ of its experts on every call (XLA fuses no slice into a kernel's operand).
 The kernel is single-device: it is not offered expert stacks sharded over a
 mesh, as the paged decode kernel is not offered a sharded pool.  Compiled by
 Mosaic on every backend but the CPU, where it runs in Pallas interpret mode
-(``tests/test_touched_experts.py`` pins it to ``_experts_dense``).
+(``tests/test_touched_experts.py`` pins it to ``experts_dense``).
 """
 
 from __future__ import annotations
@@ -113,7 +113,7 @@ def touched_expert_products(
     interpret: bool | None = None,
 ) -> jax.Array:
     """``h2 (T, E)`` through the experts ``ids[:n]`` of one layer -> ``(T,
-    E)`` float32, the sum ``_experts_dense`` forms over the experts with a
+    E)`` float32, the sum ``experts_dense`` forms over the experts with a
     nonzero column of ``cw``.
 
     ``cw (T, X)`` float32: row ``t``'s combine weight on each of the layer's

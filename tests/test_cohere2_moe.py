@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from seldon_core_tpu.models import cohere2_moe as m
+from seldon_core_tpu.models import moe
 
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "benchmark", "reference")
@@ -40,10 +41,16 @@ def _ref_kw(cfg):
     )
 
 
+def _plan():
+    """The expert plan's thresholds in force (a test shrinks them)."""
+    return moe.GROUPED_FROM, moe.GROUP_CHUNK
+
+
 @functools.lru_cache(maxsize=None)
-def _jitted(cfg, which, **static):
+def _jitted(cfg, which, plan=None, **static):
     """One compiled program for each (configuration, entry point): the tests
-    share them, as serving does."""
+    share them, as serving does.  ``plan`` (:func:`_plan`) is part of the
+    key alone: a program traced under other thresholds is another program."""
     fn = {
         "prefill": m.prefill_slot_paged, "suffix": m.prefill_suffix_paged,
         "decode": m.decode_slots_paged, "multi": m._decode_paged_multi,
@@ -82,7 +89,7 @@ def _prefill(cfg, params, prompt, *, seq_impl="dense", chunks=None, slot=1,
         padded = np.zeros((1, bucket), np.int32)
         padded[0, : b - a] = prompt[a:b]
         if a == 0:
-            logits, cache = _jitted(cfg, "prefill", seq_impl=seq_impl)(
+            logits, cache = _jitted(cfg, "prefill", _plan(), seq_impl=seq_impl)(
                 params, jnp.asarray(padded), jnp.int32(b), jnp.int32(slot),
                 row, cache,
             )
@@ -94,7 +101,7 @@ def _prefill(cfg, params, prompt, *, seq_impl="dense", chunks=None, slot=1,
             while pw < a:
                 pw *= 2
             logits, cache = _jitted(
-                cfg, "suffix", prefix_window=min(pw, cfg.max_seq)
+                cfg, "suffix", _plan(), prefix_window=min(pw, cfg.max_seq)
             )(
                 params, jnp.asarray(padded), jnp.int32(a), jnp.int32(b),
                 jnp.int32(slot), row, jnp.asarray(sb), cache,
@@ -108,7 +115,7 @@ def _decode(cfg, params, cache, first, steps, **kw):
     fed, out, nxt = [], [], int(first)
     for _ in range(steps):
         fed.append(nxt)
-        lg, cache = _jitted(cfg, "decode", window=cfg.max_seq, **kw)(
+        lg, cache = _jitted(cfg, "decode", _plan(), window=cfg.max_seq, **kw)(
             params, jnp.asarray([0, nxt], jnp.int32), cache, active,
         )
         out.append(np.asarray(lg[1]))
@@ -133,8 +140,8 @@ class TestAgainstReference:
         if experts == "grouped":
             # the prefill's formulation at a prompt of 37: sorted pairs,
             # grouped products, three passes of 32 rows
-            monkeypatch.setattr(m, "GROUPED_FROM", 8)
-            monkeypatch.setattr(m, "GROUP_CHUNK", 32)
+            monkeypatch.setattr(moe, "GROUPED_FROM", 8)
+            monkeypatch.setattr(moe, "GROUP_CHUNK", 32)
         cfg = _cfg()
         params = _params(cfg)
         logits, cache = _prefill(cfg, params, prompt, seq_impl=seq_impl)
@@ -155,8 +162,8 @@ class TestAgainstReference:
         and in one of 64, grouped experts on.  Padding is masked, so the
         logits, the first token and the prompt's K/V rows are the same,
         and the float32 reference's."""
-        monkeypatch.setattr(m, "GROUPED_FROM", 8)
-        monkeypatch.setattr(m, "GROUP_CHUNK", 32)
+        monkeypatch.setattr(moe, "GROUPED_FROM", 8)
+        monkeypatch.setattr(moe, "GROUP_CHUNK", 32)
         cfg = _cfg()
         params = _params(cfg)
         (got, c48), (want, c64) = (

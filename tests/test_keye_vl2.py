@@ -16,8 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models import cohere2_moe
 from seldon_core_tpu.models import keye_vl2 as m
+from seldon_core_tpu.models import moe, paged
 
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "benchmark", "reference")
@@ -308,9 +308,9 @@ class TestShareTiesToTheModel:
         h = jax.random.normal(jax.random.PRNGKey(4), (40, cfg.hidden))
         mask = jnp.arange(40) < 33
         dense, _ = m._moe(h, lp, cfg, mask, None, decode=False)
-        # the one rule both families' ``_moe`` ask lives in ``cohere2_moe``
-        monkeypatch.setattr(cohere2_moe, "GROUPED_FROM", 8)
-        monkeypatch.setattr(m, "GROUP_CHUNK", 64)
+        # the one rule every family's ``_moe`` asks, and this family's chunk
+        monkeypatch.setattr(moe, "GROUPED_FROM", 8)
+        monkeypatch.setattr(moe, "GROUP_CHUNK_WHOLE", 64)
         grouped, _ = m._moe(h, lp, cfg, mask, None, decode=False)
         np.testing.assert_allclose(grouped, dense, atol=TOL, rtol=0)
 
@@ -336,7 +336,7 @@ class TestCache:
 
         def by_token(cache, name):  # (layers, blocks, block, ...)
             a = cache[name]
-            return np.asarray(m._ik_by_token(a) if name == "ik" else a)
+            return np.asarray(paged.by_token(a) if name == "ik" else a)
 
         for name in ("k", "v", "ik"):
             a = by_token(whole, name)[:, row[:9]].reshape(2, 36, -1)
@@ -369,7 +369,7 @@ class TestCache:
         n_sel = jnp.where(active, jnp.minimum(pos + 1, cfg.index_topk), 0)
         at = (1, cache["table"], pos, active)
         got = m._decode_attention(
-            q, qi, wi, cache["k"], cache["v"], m._ik_by_token(cache["ik"]), *at,
+            q, qi, wi, cache["k"], cache["v"], paged.by_token(cache["ik"]), *at,
             n_sel, cfg, sparse=True, kernel=kernel,
         )
         rows, read = m._select_rows(qi, wi, cache["ik"], *at, cfg, kernel=kernel)

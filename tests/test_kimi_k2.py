@@ -17,8 +17,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from seldon_core_tpu.models import cohere2_moe
 from seldon_core_tpu.models import kimi_k2 as m
+from seldon_core_tpu.models import moe
 
 sys.path.insert(
     0, os.path.join(os.path.dirname(__file__), "..", "benchmark", "reference")
@@ -376,12 +376,12 @@ class TestShareTiesToTheModel:
         cfg = _cfg()
         params = _params(cfg)
         lp = {k: v[1] for k, v in params["layers"].items()}
-        stacks = {k: params["layers"][k] for k in m._EXPERT_KEYS}
+        stacks = {k: params["layers"][k] for k in moe.EXPERT_KEYS}
         h = jax.random.normal(jax.random.PRNGKey(4), (40, cfg.hidden))
         mask = jnp.arange(40) < 33
         dense, _ = m._moe(h, lp, cfg, mask, None, decode=False)
-        monkeypatch.setattr(cohere2_moe, "GROUPED_FROM", 8)
-        monkeypatch.setattr(cohere2_moe, "GROUP_CHUNK", 64)
+        monkeypatch.setattr(moe, "GROUPED_FROM", 8)
+        monkeypatch.setattr(moe, "GROUP_CHUNK", 64)
         for kw in ({}, {"stacks": stacks, "li": 1}):
             grouped, _ = m._moe(h, lp, cfg, mask, None, decode=False, **kw)
             np.testing.assert_allclose(grouped, dense, atol=TOL, rtol=0)
@@ -459,7 +459,7 @@ class TestCounters:
         _, cache = _prefill(cfg, params, prompt)
         _, _, cache = _decode(cfg, params, cache, 5, 3, kernel=kernel)
         c = dict(zip(m.COUNTERS, np.asarray(cache["counters"]).tolist()))
-        assert m.COUNTERS[:9] == cohere2_moe.COUNTERS
+        assert m.COUNTERS[:9] == moe.COUNTERS
         assert m.COUNTERS[9:] == (
             "mla.rows_read", "mla.prefill_rows_expanded", "mla.rows_live",
         )

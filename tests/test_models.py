@@ -195,3 +195,28 @@ class TestRoofline:
                 roofline.chip_hbm_bandwidth(dev("tpu", kind))
         assert roofline.chip_peak_flops(dev("cpu", "cpu")) is None
         assert roofline.chip_hbm_bandwidth() is None  # the CPU test backend
+
+
+@pytest.mark.parametrize("family", sorted(registry.GENERATIVE_FAMILIES))
+def test_a_family_imports_layers_and_no_sibling(family):
+    """A family's module builds from the neutral modules (``models/layers.py``,
+    ``paged.py``, ``moe.py``) and imports from no other family of
+    ``GENERATIVE_FAMILIES``: an edit to one family's file then changes no
+    other family's programs."""
+    import ast
+
+    mod = registry.GENERATIVE_FAMILIES[family]
+    assert mod.__name__ == f"seldon_core_tpu.models.{family}"
+    siblings = set(registry.GENERATIVE_FAMILIES) - {family}
+    with open(mod.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            base = "." * node.level + (node.module or "")
+            imported.add(base)
+            imported |= {f"{base}.{a.name}" for a in node.names}
+    reached = {part for name in imported for part in name.split(".")}
+    assert not reached & siblings, sorted(reached & siblings)

@@ -1,5 +1,5 @@
 """The touched-only expert kernel (ops/touched_experts.py, Pallas interpret
-mode on the CPU) against ``cohere2_moe._experts_dense`` on the same hidden
+mode on the CPU) against ``moe.experts_dense`` on the same hidden
 state, weights and routing; the one rule of static shapes that chooses
 between them; and the counter that says which ran (``moe.experts_read``)."""
 
@@ -12,6 +12,7 @@ import pytest
 
 from seldon_core_tpu.models import cohere2_moe as cm
 from seldon_core_tpu.models import keye_vl2 as kv
+from seldon_core_tpu.models import moe
 from seldon_core_tpu.ops import touched_experts as te
 
 E, F = 64, 256
@@ -76,8 +77,8 @@ def test_the_kernel_gives_what_the_dense_products_give(case, dtype, monkeypatch)
     w = jnp.asarray(w / w.sum(-1, keepdims=True))
     local, held = _routing(routing, T, K, X, rng)
     lp = {k: v[li] for k, v in stacks.items()}
-    want = np.asarray(cm._experts_dense(h2, lp, local, held, w))
-    got = np.asarray(cm._experts_touched(h2, stacks, li, local, held, w))
+    want = np.asarray(moe.experts_dense(h2, lp, local, held, w))
+    got = np.asarray(moe.experts_touched(h2, stacks, li, local, held, w))
     assert got.shape == (T, E) and got.dtype == np.float32
     if routing == "no live token":
         assert not got.any() and not want.any()
@@ -112,16 +113,16 @@ PLANS = [
     (32, True, "touched"),   # Command A+'s: 32 slots over 16 held, 12-14 touched
     (1, True, "touched"),
     (8, False, "dense"),     # stacks over a mesh, or not at hand
-    (cm.GROUPED_FROM - 1, True, "touched"),  # the largest prompt rung under the grouped ones
-    (cm.GROUPED_FROM - 1, False, "dense"),
-    (cm.GROUPED_FROM, True, "grouped"),
+    (moe.GROUPED_FROM - 1, True, "touched"),  # the largest prompt rung under the grouped ones
+    (moe.GROUPED_FROM - 1, False, "dense"),
+    (moe.GROUPED_FROM, True, "grouped"),
     (24576, False, "grouped"),
 ]
 
 
 @pytest.mark.parametrize("T,kernel,plan", PLANS)
 def test_one_rule_of_static_shapes_chooses_the_plan(T, kernel, plan):
-    assert cm.experts_plan(T, kernel=kernel) == plan
+    assert moe.experts_plan(T, kernel=kernel) == plan
 
 
 class TestTheServedStep:
@@ -132,8 +133,9 @@ class TestTheServedStep:
     def _steps(self, monkeypatch, kernel, steps=3):
         if not kernel:
             # what a caller without the stacks tells the rule
+            plan = moe.experts_plan
             monkeypatch.setattr(
-                kv, "experts_plan", lambda T, kernel: cm.experts_plan(T, kernel=False)
+                moe, "experts_plan", lambda T, kernel: plan(T, kernel=False)
             )
         cfg = kv.Config.tiny(max_seq=64)
         params = kv.init_params(jax.random.PRNGKey(3), cfg, jnp.float32)
@@ -186,7 +188,7 @@ class TestTheServedStep:
 
 class TestCommandAPlusAt32Slots:
     """``cohere2_moe.decode_slots_paged`` at 32 slots over 8 held experts:
-    the kernel on one device, ``_experts_dense`` for stacks over a mesh."""
+    the kernel on one device, ``moe.experts_dense`` for stacks over a mesh."""
 
     S, STEPS = 32, 2
 
