@@ -100,7 +100,9 @@ from jax import lax
 
 from seldon_core_tpu.models import paged
 from seldon_core_tpu.models.common import annotate_params
-from seldon_core_tpu.models.layers import add, flash_prompt, rms_head
+from seldon_core_tpu.models.layers import add, rms_head
+# benchmark/reference/kinds/jamba_decoder.py reads ``_attend_prompt`` here
+from seldon_core_tpu.models.layers import attend_prompt as _attend_prompt
 # benchmark/reference/kinds/jamba_decoder.py reads ``_rmsnorm`` here
 from seldon_core_tpu.models.layers import rmsnorm as _rmsnorm
 from seldon_core_tpu.models.layers import sample_tokens  # noqa: F401  (contract)
@@ -488,48 +490,6 @@ def _qkv(h, lp):
     return q, k, v
 
 
-def _attend_prompt(q, k, v, seq_impl: str):
-    """A whole prompt's causal attention: ``q (T, H, D)`` over ``k``, ``v
-    (T, KV, D)`` read grouped.  -> (T, H, D)."""
-    T, H, D = q.shape
-    with jax.named_scope("attn.prompt"):
-        if seq_impl == "flash":
-            return flash_prompt(q, k, v)
-        kv = k.shape[1]
-        qg = q.reshape(T, kv, H // kv, D)
-        s = jnp.einsum(
-            "tkgd,ukd->kgtu", qg, k, preferred_element_type=jnp.float32
-        ) / math.sqrt(D)
-        seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
-        p = jax.nn.softmax(jnp.where(seen, s, jnp.finfo(jnp.float32).min), axis=-1)
-        return jnp.einsum("kgtu,ukd->tkgd", p.astype(v.dtype), v).reshape(T, H, D)
-
-
-def _attend_paged(q, ck, cv, ai, read_blk, pos, active, *, kernel: bool):
-    """One decode query a slot over attention layer ``ai`` of the pools as
-    carried, the step's own row written already.  ``q (S, H, D)`` -> the
-    same."""
-    from seldon_core_tpu.ops.paged_attention import (
-        paged_decode_attention,
-        paged_decode_attention_reference,
-    )
-
-    nb, bs, d = ck.shape[1:]
-    with jax.named_scope("attn.paged"):
-        if kernel:
-            # the whole carried pool, layers flattened into blocks, this
-            # layer's by offset (models/llama.py::_decode_paged_multi says why)
-            return paged_decode_attention(
-                q[:, None], ck.reshape(-1, bs, d), cv.reshape(-1, bs, d),
-                read_blk + ai * nb, pos, active=active,
-            )[:, 0]
-        kv = d // q.shape[-1]
-        return paged_decode_attention_reference(
-            q[:, None], ck[ai].reshape(nb, bs, kv, -1),
-            cv[ai].reshape(nb, bs, kv, -1), read_blk, pos,
-        )[:, 0]
-
-
 def _after_mixer(x, o, lp, cfg: Config):
     """The rest of a layer behind its mixer's output ``o (..., E)``: the
     residual and the SwiGLU MLP, each added to the stream."""
@@ -823,7 +783,7 @@ def decode_slots_paged(
         q, k, v = _qkv(_rmsnorm(x, lp["ln1"], cfg.norm_eps), lp)
         ck = ck.at[ai, write_blk, write_off].set(k.reshape(S, -1).astype(ck.dtype))
         cv = cv.at[ai, write_blk, write_off].set(v.reshape(S, -1).astype(cv.dtype))
-        o = _attend_paged(q, ck, cv, ai, read_blk, pos, active, kernel=kernel)
+        o = paged.attend_paged(q, ck, cv, ai, read_blk, pos, active, kernel=kernel)
         return _after_mixer(x, _attn_out(o, lp), lp, cfg), ck, cv, cs, ct
 
     x, ck, cv, cs, ct = _run_layers(

@@ -1,11 +1,13 @@
 """What every decoder family shares: the norms, the rotary embeddings, the
-float32 residual add, the RMSNorm head over a ``(vocab, hidden)`` weight and
-on-device sampling.  A family module (``models/<family>.py``) builds from
+float32 residual add, a prompt's plain causal attention, the RMSNorm head
+over a ``(vocab, hidden)`` weight and on-device sampling.  A family module (``models/<family>.py``) builds from
 here, from ``models/paged.py`` (the frame of a paged cache) and from
 ``models/moe.py`` (the routed expert layer); it imports no other family.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +77,26 @@ def flash_prompt(q, k, v, **kw):
         v.transpose(1, 0, 2)[None], causal=True, block_q=blk, block_k=blk, **kw,
     )
     return out[0].transpose(1, 0, 2)
+
+
+def attend_prompt(q, k, v, seq_impl: str):
+    """A whole prompt's causal grouped-query attention under ``attn.prompt``:
+    ``q (T, H, D)`` over ``k``, ``v (T, KV, D)`` at positions ``0..T-1``,
+    through the tiled kernel (``seq_impl="flash"``) or in plain XLA, scores
+    and softmax float32.  -> (T, H, D).  For a family whose keys need no
+    window and no selection (``jamba``, ``zaya``)."""
+    T, H, D = q.shape
+    with jax.named_scope("attn.prompt"):
+        if seq_impl == "flash":
+            return flash_prompt(q, k, v)
+        kv = k.shape[1]
+        qg = q.reshape(T, kv, H // kv, D)
+        s = jnp.einsum(
+            "tkgd,ukd->kgtu", qg, k, preferred_element_type=jnp.float32
+        ) / math.sqrt(D)
+        seen = jnp.arange(T)[None, :] <= jnp.arange(T)[:, None]
+        p = jax.nn.softmax(jnp.where(seen, s, jnp.finfo(jnp.float32).min), axis=-1)
+        return jnp.einsum("kgtu,ukd->tkgd", p.astype(v.dtype), v).reshape(T, H, D)
 
 
 def rms_head(h, norm_w, vocab_w, eps):

@@ -1,8 +1,9 @@
-"""The routed expert layer of ``cohere2_moe``, ``keye_vl2`` and ``kimi_k2``:
-which product runs a share's held experts for a call, the three products,
-and what the on-device counters then say.  A family brings its router (its
-``_route``: sigmoid, softmax, sigmoid plus a bias) and asks
-:func:`routed_experts`; it decides nothing else.
+"""The routed expert layer of ``cohere2_moe``, ``keye_vl2``, ``kimi_k2`` and
+``zaya``: which product runs a share's held experts for a call, the three
+products, and what the on-device counters then say.  A family brings its
+router (its ``_route``: sigmoid, softmax, sigmoid plus a bias; ``zaya``'s MLP
+whose top-1 may be an index no share holds, a token that skips the layer)
+and asks :func:`routed_experts`; it decides nothing else.
 
 The held experts' products have three formulations, chosen from static
 shapes in one place (:func:`experts_plan`).  A prefill (thousands of tokens)
@@ -33,7 +34,8 @@ GROUP_CHUNK_WHOLE = 32768
 # the names a family's ``COUNTERS`` start with, in this order: the programs
 # index them by position, ``/stats/summary`` reads them by name
 COUNTERS = (
-    "moe.pairs_routed",          # decode: (token, expert) pairs chosen, layers summed
+    "moe.pairs_routed",          # decode: (token, choice) pairs, layers summed: live tokens x experts_per_tok,
+                                 # a choice that is no expert (``zaya``'s no-op) among them
     "moe.pairs_held",            # decode: of those, pairs whose expert is held here
     "moe.experts_touched",       # decode: held experts with >= 1 token, summed over layers and steps
     "moe.max_tokens_on_expert",  # decode: the busiest held expert's tokens, summed over layers and steps
@@ -191,7 +193,9 @@ def _count_routing(counters, local, held, tok_mask, per_tok: int, count: int,
                    decode: bool, plan: str):
     """``counters`` with one expert layer's routing added (``COUNTERS``'
     first four and the experts ``plan`` read in a decode step, the prefill
-    pair in a prompt)."""
+    pair in a prompt).  Routed counts every real token's ``per_tok``
+    choices, held the pairs whose expert this share holds: at top-1 with a
+    choice that is no expert the two part by the tokens that skipped."""
     if counters is None:
         return None
     n_tok = jnp.sum(tok_mask).astype(jnp.uint32)

@@ -1,14 +1,15 @@
 """The frame of a paged cache, as every family but ``llama`` builds it (that
 one keeps its own pool code: int8 scales, the mesh's layout): the
 bookkeeping arrays beside a family's pools, the writes of a prompt's rows
-into its blocks, a per-token array carried with its blocks TRANSPOSED, the
-tail of a prefill program, a slot's bytes, the on-device counters' add and
+into its blocks, a per-token array carried with its blocks TRANSPOSED, a
+decode step's read of a layer of a K/V pool, the tail of a prefill program, a slot's bytes, the on-device counters' add and
 the refusal of what no such family serves.  The pools themselves — their
 names, shapes and what reads them — are the family's.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -88,6 +89,34 @@ def decode_frame(cache, active, bs: int, window, max_seq: int):
         active, table[jnp.arange(S), jnp.minimum(pos // bs, mb - 1)], 0
     )
     return write_blk, pos % bs, table[:, : max(1, W // bs)]
+
+
+def attend_paged(q, ck, cv, li, read_blk, pos, active, *, kernel: bool):
+    """One decode query a slot over layer ``li`` (traced or not) of the pools
+    ``ck``, ``cv (layers, blocks, block, kv_heads * head_dim)`` as carried,
+    the step's own row written already, under ``attn.paged``.  ``q (S, H,
+    D)`` -> the same.  ``kernel`` (static) reads through the Pallas paged
+    kernel, each slot's live blocks alone; else the gathered window."""
+    from seldon_core_tpu.ops.paged_attention import (
+        paged_decode_attention,
+        paged_decode_attention_reference,
+    )
+
+    nb, bs, row = ck.shape[1:]
+    with jax.named_scope("attn.paged"):
+        if kernel:
+            # the whole carried pool, layers flattened into blocks, this
+            # layer's by offset (models/llama.py::_decode_paged_multi says why)
+            return paged_decode_attention(
+                q[:, None], ck.reshape(-1, bs, row), cv.reshape(-1, bs, row),
+                read_blk + li * nb, pos, active=active,
+            )[:, 0]
+        kv = row // q.shape[-1]
+        layer = [
+            lax.dynamic_index_in_dim(c, li, keepdims=False).reshape(nb, bs, kv, -1)
+            for c in (ck, cv)
+        ]
+        return paged_decode_attention_reference(q[:, None], *layer, read_blk, pos)[:, 0]
 
 
 def finish_prefill(params, cfg, cache, x, at, pools: dict, ctr, slot, length,

@@ -18,7 +18,7 @@ from jax.sharding import Mesh
 
 from seldon_core_tpu.executor import BucketSpec, CompiledModel, JaxModelComponent
 from seldon_core_tpu.models import (
-    bert, cnn, cohere2_moe, jamba, keye_vl2, kimi_k2, llama, mlp, resnet,
+    bert, cnn, cohere2_moe, jamba, keye_vl2, kimi_k2, llama, mlp, resnet, zaya,
 )
 
 
@@ -115,6 +115,16 @@ _FAMILIES: dict[str, Family] = {
         presets={
             "jamba2-3b": jamba.Config,
             "tiny": jamba.Config.tiny,
+        },
+        example_input=lambda c, b: np.ones((b, 16), np.int32),
+        init_in_dtype=True,
+    ),
+    "zaya": Family(
+        "zaya", zaya.Config, zaya.init_params,
+        zaya.apply, zaya.param_logical_axes,
+        presets={
+            "zaya1-8b": zaya.Config,
+            "tiny": zaya.Config.tiny,
         },
         example_input=lambda c, b: np.ones((b, 16), np.int32),
         init_in_dtype=True,
@@ -286,10 +296,10 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 # The generative contract: what ``executor/generation.py::GenerativeModel``
 # reads of a family module.  A family builds from three neutral modules and
 # imports no other family (``tests/test_models.py`` holds that):
-# ``models/layers.py`` (norms, rotary embeddings, the residual add, the
-# RMSNorm head, ``sample_tokens``), ``models/paged.py`` (the frame of a paged
-# cache: the bookkeeping arrays, a prompt's writes, ``finish_prefill``, a
-# slot's bytes, the counters' add, ``no_lora``) and ``models/moe.py`` (the
+# ``models/layers.py`` (norms, rotary embeddings, the residual add, a prompt's
+# plain attention, the RMSNorm head, ``sample_tokens``), ``models/paged.py``
+# (the frame of a paged cache: the bookkeeping arrays, a prompt's writes, a
+# decode step's read of a K/V pool, ``finish_prefill``, a slot's bytes, the counters' add, ``no_lora``) and ``models/moe.py`` (the
 # routed expert layer: ``routed_experts`` behind the family's own router, its
 # ``COUNTERS`` leading the family's); docs/GENERATIVE.md lists what a new
 # family touches.
@@ -311,7 +321,8 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 #     with the pool, and what moves K/V out of the pool — handoff, suspend,
 #     the host-DRAM tier — refuses a family whose list is not ``k`` and ``v``),
 #     ``SLOT_ARRAYS`` (the names of the arrays of its cache that hold state
-#     PER SLOT and not by token — a recurrent or state-space state, which no
+#     PER SLOT and not by token — a recurrent or state-space state, or the
+#     tail a short causal convolution needs of the tokens before, which no
 #     block of the pool holds; ``()`` where it names none.  Counted: with a
 #     slot's bytes through the family's ``paged_kv_slot_bytes``, and as
 #     ``slot_state`` in the memory ledger and the pool's snapshot.  Refused,
@@ -326,7 +337,7 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 # prefix reuse, chunked prefill and adapters are turned off with a warning.
 GENERATIVE_FAMILIES: dict[str, Any] = {
     "llama": llama, "cohere2_moe": cohere2_moe, "keye_vl2": keye_vl2,
-    "kimi_k2": kimi_k2, "jamba": jamba,
+    "kimi_k2": kimi_k2, "jamba": jamba, "zaya": zaya,
 }
 
 

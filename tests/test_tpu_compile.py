@@ -41,6 +41,8 @@ CASES = [
     ("mistral-7b-l8 int8 w64", 32, 1, 32, 128, 8 * 4097, 16, 8, 4, True, None),
     ("command-a-plus full layer", 32, 1, 128, 128, 4 * 769, 256, 8, 32, False, None),
     ("command-a-plus window layer", 32, 1, 128, 128, 4 * 769, 256, 8, 17, False, 4096),
+    # 8 query rows on 2 key-value heads of 128: a pool row 256 wide
+    ("zaya1-8b-l20", 48, 1, 8, 128, 20 * 769, 256, 2, 16, False, None),
 ]
 
 
@@ -186,6 +188,8 @@ EXPERT_CASES = [
     # the largest prompt rung under the grouped products (PR 47)
     ("command-a-plus a prompt rung of 128 rows", 128, 16, 4096, 4096, 4, 512),
     ("kimi-k2.6 a prompt rung of 128 rows", 128, 12, 7168, 2048, 4, 256),
+    # top-1: an expert of 25.2 MB is two grid steps
+    ("zaya1-8b two tiles an expert", 48, 16, 2048, 2048, 20, 1024),
 ]
 
 
@@ -507,3 +511,103 @@ def test_jambas_decode_program_updates_the_slots_state_in_place(one_chip, monkey
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 5 and text.count("ssm.update") >= 3
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def _zaya_as_served(one_chip, monkeypatch):
+    """``(module, cfg, params, cache, arg)`` of the cell's deployment as
+    shapes on the described chip: 20 of 40 blocks at the published widths,
+    every expert and the whole vocabulary, 48 slots over a pool of 769 blocks
+    of 256."""
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models import zaya as m
+
+    # the ops ask the backend whether to interpret: this process runs on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = m.Config(n_layers=20, max_seq=4096)
+
+    def arg(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    params = shapes(jax.eval_shape(
+        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+    ))
+    cache = shapes(jax.eval_shape(
+        lambda: m.init_paged_cache(cfg, 48, 769, 256, jnp.bfloat16)
+    ))
+    return m, cfg, params, cache, arg
+
+
+def test_zayas_prompt_program_fits_at_the_2048_rung(one_chip, monkeypatch):
+    """``prefill:b2048`` whole at the served shapes: the tiled attention is
+    in it once (one scan over the 20 blocks) beside the grouped products'
+    four calls (their metadata and gate, up, down), the arguments are the
+    issue's 13.4 GB, and the temporaries hold no copy of a block's experts
+    (403 MB) or of the pool (4.03 GB)."""
+    import functools
+
+    import jax
+
+    m, cfg, params, cache, arg = _zaya_as_served(one_chip, monkeypatch)
+    prefill = jax.jit(
+        functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"),
+        donate_argnums=(5,),
+    )
+    compiled = prefill.lower(
+        params, arg((1, 2048)), arg(()), arg(()), arg((16,)), cache
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 5 and text.count("flash_attention)/pallas_call") == 1
+    for scope in ("cca.qk", "cca.conv", "cca.mix", "cca.v", "attn.prompt", "cca.out",
+                  "router.down", "router.mlp", "moe.route", "moe.experts", "res.scale", "head"):
+        assert scope in text, scope
+    memory = compiled.memory_analysis()
+    assert 13.3e9 < memory.argument_size_in_bytes < 13.5e9
+    assert memory.temp_size_in_bytes < 400 << 20
+
+
+def test_zayas_decode_program_reads_the_pool_and_the_experts_through_kernels(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (48 slots, 20 blocks,
+    window 4,096): the paged kernel reads 8 query rows on 2 key-value heads
+    and the touched-only kernel streams the chosen experts, each once in the
+    scan's body; the temporaries are the float32 logits (50 MB) and little
+    more — no copy of the pool, of a block's experts or of the tails."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    m, cfg, params, cache, arg = _zaya_as_served(one_chip, monkeypatch)
+    step = jax.jit(
+        functools.partial(m.decode_slots_paged, cfg=cfg, window=4096, kernel=True),
+        donate_argnums=(2,),
+    )
+    compiled = step.lower(params, arg((48,)), cache, arg((48,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "attn.paged" in text and "moe.experts" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 160 << 20
+
+
+def test_sampling_over_zayas_vocabulary_compiles(one_chip):
+    """Arg-max and top-k over rows of 262,272 float32 logits, four times the
+    longest row another cell has."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from seldon_core_tpu.models.zaya import sample_tokens
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    for top_k in (0, 40):
+        compiled = jax.jit(functools.partial(sample_tokens, top_k=top_k)).lower(
+            arg((48, 262272), jnp.float32), arg((48,), jnp.float32), arg((2,), jnp.uint32)
+        ).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
