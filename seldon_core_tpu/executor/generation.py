@@ -77,6 +77,7 @@ from seldon_core_tpu.obs.timeline import (
     EVENT_SUSPEND,
 )
 from seldon_core_tpu.ops.flash_attention import TILE_PLANS
+from seldon_core_tpu.ops.paged_attention import blocks_per_step
 from seldon_core_tpu.utils.tracectx import current_trace_id
 from seldon_core_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -3101,6 +3102,8 @@ class GenerativeModel:
             "decode_read": "kernel" if self.decode_kernel else "gather",
             "kv_blocks_live": self.kv_blocks_live,
             "kv_blocks_window": self.kv_blocks_window,
+            # the tile the kernel ran: key rows a step, from the pool's shape
+            "decode_tile_rows": self.decode_tile_rows(),
             # the family's own device counters (routing of an expert
             # layer), as of the last fetched decode block
             "counters": self.counters_snapshot(),
@@ -3253,6 +3256,19 @@ class GenerativeModel:
         vec = self.embed_dispatch(prompt)
         # sct: host-sync-ok unbatched embed fetch
         return np.asarray(jax.device_get(vec), np.float32)
+
+    def decode_tile_rows(self) -> int | None:
+        """Key rows one step of the paged decode kernel attends for this
+        unit's K/V pool: what ``ops/paged_attention.py::blocks_per_step``,
+        the function the kernel itself asks, says of the pool's own block
+        size, row and dtype.  None where the decode read is the gather, or
+        the pool holds no K by head (``kimi_k2``'s latent rows are read by
+        a kernel of their own)."""
+        k = self._cache.get("k")
+        if not self.decode_kernel or k is None:
+            return None
+        bs, row = k.shape[2:]  # one device: a row holds its heads side by side
+        return blocks_per_step(bs, row * k.dtype.itemsize) * bs
 
     def _note_read(self, active: np.ndarray, window: int) -> None:
         """Bump the decode read's two block counters for one dispatch."""

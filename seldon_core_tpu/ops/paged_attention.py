@@ -7,10 +7,13 @@ which materializes the gathered ``(S, W, kv, hd)`` window of EVERY slot at
 the batch-wide window (and, under int8, its dequantized copy) in HBM between
 ops.  This kernel fuses the whole read side and reads what a live slot
 holds: one grid step is one slot; the slot's live blocks are copied by their
-(scalar-prefetched) table entries from the pool in HBM into a VMEM tile of
-:data:`STEP_ROWS` key rows (16 blocks of 16 tokens, one of 256: derived from
-the block size the pool shows), the next step's blocks — this slot's next
-tile or the next live slot's first — in flight while this one is attended;
+(scalar-prefetched) table entries from the pool in HBM into a VMEM tile
+sized in BYTES (:func:`blocks_per_step`: as many whole blocks as make about
+:data:`STEP_BYTES` a pool, never fewer than :data:`STEP_ROWS` key rows nor
+more than :data:`STEP_ROWS_MAX` — 16 blocks of 16 tokens or one of 256 at
+2-KB rows, four of 256 at 512-B rows: read off the pool's own shape and
+dtype), the next step's blocks — this slot's next tile or the next live
+slot's first — in flight while this one is attended;
 int8 blocks dequantize in VMEM against their per-(position, head) scales;
 attention runs the online-softmax recurrence over one tile at a time.  Pool
 bytes are read once, nothing intermediate touches HBM, a block past the
@@ -53,7 +56,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30  # large-but-finite: -inf * 0 = nan would poison the rescale
-STEP_ROWS = 256  # key rows attended in one step (PERF.md §6, PR 29)
+# A step's tile (PERF.md §6, PR 29 and PR 51).  What a step costs beside its
+# bytes is fixed — two copies started and awaited a block, the scalar reads,
+# the loop, a score tile's latency — and a copy is only as fast as what is
+# queued behind it, so the tile is a size in bytes and its rows follow the
+# pool's row.  On the chip (PR 50's sweep, the kernel alone at the slots and
+# contexts of the cells that have such rows): 512-B rows read 398 GB/s by
+# live rows at 256 rows a step, 637 at 1,024 and 646 at 2,048; 256-B rows
+# 195 at 256, 418 at 2,048 and 394 at 4,096, where the score tile is mostly
+# masked rows
+STEP_BYTES = 512 * 1024  # one pool's tile a step (K and V: twice that in flight)
+STEP_ROWS = 256  # the floor: key rows a step attends at 2-KB rows and wider
+STEP_ROWS_MAX = 2048  # the cap: a wider score tile stops paying
 # rows of the kernel's second scalar operand, one column a slot (+ one)
 _POS, _FIRST, _BLO, _BHI, _WLO, _NW, _NXT = range(7)
 
@@ -69,10 +83,18 @@ def mxu_operands(dtype) -> tuple:
     return jnp.float32, jax.lax.Precision.HIGHEST
 
 
-def blocks_per_step(block_size: int) -> int:
-    """Pool blocks one step of the kernel attends together: as many as make
-    :data:`STEP_ROWS` key rows (16 at 16-token blocks, 1 at 256)."""
-    return max(1, STEP_ROWS // int(block_size))
+def blocks_per_step(block_size: int, row_bytes: int) -> int:
+    """Pool blocks one step of the kernel attends together, from what the
+    operand shows: its block size and the bytes of a pool row (``KV * D`` x
+    the pool dtype's item size).  As many whole blocks as make a tile of
+    :data:`STEP_BYTES` a pool, never fewer than :data:`STEP_ROWS` key rows
+    (so 2-KB rows keep PR 29's 256: 16 blocks of 16 tokens, one of 256) and
+    never more than :data:`STEP_ROWS_MAX`; at least one block.  512-B rows
+    (2 kv heads of 128 in bfloat16) travel four blocks of 256 a step, 1 MB of
+    K and V in flight as at 2-KB rows.  The sweep behind the target and the
+    cap: PERF.md §6, PR 51 (the table of PR 50's sweep)."""
+    rows = min(max(STEP_ROWS, STEP_BYTES // int(row_bytes)), STEP_ROWS_MAX)
+    return max(1, rows // int(block_size))
 
 
 def _paged_kernel(
@@ -279,7 +301,7 @@ def paged_decode_attention(
         raise ValueError(f"H {H} must be a multiple of kv heads {KV}")
     groups = H // KV
     R = L * groups
-    G = blocks_per_step(BS)
+    G = blocks_per_step(BS, KVD * k_pages.dtype.itemsize)
     quant = k_scale is not None
     if interpret is None:
         interpret = jax.default_backend() == "cpu"

@@ -24,6 +24,7 @@ from seldon_core_tpu.executor.generation import (
     GenerativeModel,
 )
 from seldon_core_tpu.models import llama
+from seldon_core_tpu.ops.paged_attention import STEP_ROWS_MAX
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +72,10 @@ class TestTheProgramChooses:
         assert model.decode_kernel is (read == "kernel")
         assert model.spec_snapshot()["decode_read"] == read
         assert ("kernel" in model.variant_sfx) == (read == "kernel")
+        # the tile the kernel runs at: the tiny pool's float32 rows of 2 kv
+        # heads x 8 are 64 B, so the rule's cap; no kernel, no tile
+        assert model.spec_snapshot()["decode_tile_rows"] == (
+            STEP_ROWS_MAX if read == "kernel" else None)
         # one device: a row holds its heads side by side; a mesh: a head axis
         assert model._cache["k"].ndim == (5 if mesh else 4)
 
@@ -95,6 +100,45 @@ class TestTheProgramChooses:
             family_mod=types.SimpleNamespace(__name__="no_kernel", **fam),
         )
         assert model.decode_kernel is False
+
+
+class TestTheTileOfEachCellsPool:
+    """``decode_tile_rows`` on the benchmark's own graphs: each generative
+    configuration's pool as its family makes it at the cell's slots, blocks
+    and dtype (shapes alone: nothing is allocated), asked what
+    ``/stats/summary`` asks.  On the chip an unset ``decode_kernel`` is the
+    kernel on one device and the gather on a mesh (``TestTheProgramChooses``)."""
+
+    @pytest.mark.parametrize("config,rows", [
+        ("mistral-7b-l8", 256),  # 2-KB rows, blocks of 16: PR 29's tile
+        ("mistral-7b-tp4", None),  # a mesh reads by gather
+        ("command-a-plus-l4-ep8", 256),  # 2-KB rows, blocks of 256
+        ("keye-vl-2-30b-a3b-l6", 512),  # 1-KB rows (read under ``topk`` only)
+        ("kimi-k2-6-l5-ep32", None),  # latent rows: no K by head
+        ("ai21-jamba2-3b", 2048),  # 256-B rows: the cap
+        ("zaya1-8b-l20", 1024),  # 512-B rows: 512 KB a pool
+    ])
+    def test_the_tile_follows_the_cells_own_pool(self, config, rows):
+        import inspect
+        import os
+        import types
+
+        from seldon_core_tpu.models import registry
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "benchmark", "configs", config + ".json")) as f:
+            p = json.load(f)["graph"]["parameters"]
+        serving = set(inspect.signature(registry.build_generative_component).parameters)
+        cfg = registry.resolve_config(p["family"], None, **{
+            k: v for k, v in p.items() if k not in serving and k != "family"})
+        mod = registry.GENERATIVE_FAMILIES[p["family"]]
+        mesh = "mesh" in p
+        cache = jax.eval_shape(lambda: mod.init_paged_cache(
+            cfg, p["n_slots"], p.get("kv_blocks", 64), p["kv_block_size"],
+            jnp.dtype(p["dtype"]), **({"kv_sharded": True} if mesh else {})))
+        unit = types.SimpleNamespace(
+            _cache=cache, decode_kernel=p.get("decode_kernel", not mesh))
+        assert GenerativeModel.decode_tile_rows(unit) == rows
 
 
 class TestOneProgramForEveryWindow:

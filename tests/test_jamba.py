@@ -147,6 +147,28 @@ class TestAgainstReference:
         # two attention layers, a slot at position p attends p + 1 rows
         assert ctr["attn.rows_live"] == 2 * sum(range(L + 1, len(seq) + 1))
 
+    def test_the_paged_read_at_several_blocks_a_step(self):
+        """The family's call site (``models/paged.py::attend_paged``) at a
+        tile the row's BYTES size: one key-value head of 256 in float32 is
+        a 1-KB row, so a step of the kernel attends 512 rows, eight blocks
+        of 64, and the contexts here run from 500 to 529: the last steps'
+        read is a whole tile and one live block of the next."""
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
+
+        cfg = m.Config.tiny(max_seq=640, hidden=256, n_heads=1, n_layers=4)
+        bs, L = 64, 500
+        params = _params(cfg)
+        cache = m.init_paged_cache(cfg, 2, 24, bs, jnp.float32)
+        tile = bs * blocks_per_step(bs, cache["k"].shape[-1] * 4)
+        seq = np.random.default_rng(1).integers(1, 256, 530)
+        assert cache["k"].shape[2:] == (bs, 256) and tile == 512 and L < tile < len(seq)
+        last, cache = _prefill(
+            cfg, params, seq[:L], cache=cache, seq_impl="flash",
+            row=_slot_row(n_blocks=10, width=10, first=3))
+        steps, cache = _decode(cfg, params, cache, seq[L:], kernel=True)
+        got = np.concatenate([np.asarray(last)[None], steps])
+        assert np.abs(got - _reference(cfg, params, seq)[L - 1:]).max() < TOL
+
     @pytest.mark.parametrize("control,least", [
         (dict(dt_bias="off"), 0.05),
         (dict(ssm_padding="moves"), 0.01),
@@ -391,6 +413,7 @@ class TestServedPath:
 
     @pytest.mark.parametrize("seq_impl,kernel", [("dense", False), ("flash", True)])
     def test_generates_what_the_family_computes(self, seq, seq_impl, kernel):
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
         from seldon_core_tpu.utils.device import xla_compile_count
 
         prompt = seq[:37]
@@ -425,7 +448,13 @@ class TestServedPath:
         want = want[len(prompt) - 1:]
         deficit = want.max(-1) - want[np.arange(len(served)), served]
         assert deficit.max() < 0.5 and (deficit > 0.05).sum() <= 2
-        ctr = model.spec_snapshot()["counters"]
+        snap = model.spec_snapshot()
+        # the tile the kernel ran: bfloat16 rows of 16 values are 32 B, so
+        # the rule's cap, in blocks of 4; no kernel, no tile
+        assert snap["decode_read"] == ("kernel" if kernel else "gather")
+        assert snap["decode_tile_rows"] == (
+            4 * blocks_per_step(4, 16 * 2) if kernel else None)
+        ctr = snap["counters"]
         assert ctr["ssm.prefill_tokens"] >= 37 and ctr["ssm.prefill_rows"] >= 40
         assert ctr["ssm.steps"] >= 4 and ctr["ssm.slot_steps"] >= 4
         assert ctr["attn.rows_live"] >= 2 * 4 * 38
@@ -552,6 +581,9 @@ class TestEngineRoutes:
                 assert set(m.COUNTERS) <= set(c)
                 assert c["ssm.prefill_tokens"] >= 2 * 37 and c["ssm.slot_steps"] >= 2 * 19
                 assert unit["kv_bytes_per_slot"] > 0
+                # the CPU's read is the gather: no kernel, so no tile
+                assert unit["decode_read"] == "gather"
+                assert unit["decode_tile_rows"] is None
             finally:
                 await client.close()
 

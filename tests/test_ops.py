@@ -548,19 +548,32 @@ class TestPagedDecodeAttention:
         )
 
     def _blocks_case(self, BS, WB, pos, L, *, quant=False, active=None,
-                     poison=False, seed=0):
+                     poison=False, seed=0, KV=2, D=256, pool=jnp.float32,
+                     blocks=None, scale=0.02):
         """One call at ``BS``-token blocks, every slot with blocks of its
         own, against the reference; an inactive slot reads nothing and gets
         zeros.  ``poison``: every block past a slot's last query, and every
-        block of an inactive slot, holds NaN (an int8 pool: NaN scales)."""
+        block of an inactive slot, holds NaN (an int8 pool: NaN scales).
+        The pool's row is ``KV * D`` values of ``pool`` (int8 under
+        ``quant``): 2 KB unless a case says otherwise, which is the row PR
+        29's tiles were sized at; the queries stay float32, so a bfloat16
+        pool's products are exact and the class's tolerance holds.
+        ``blocks``: the blocks a step the case means to run at; ``scale``:
+        the int8 scales' range (an output is as large as a value and the
+        tolerance is absolute: rows of 512 values up to 12.7 would leave
+        float32 no room at 2e-5)."""
         from seldon_core_tpu.ops import (
             paged_decode_attention,
             paged_decode_attention_reference,
         )
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
 
         rng = np.random.default_rng(seed)
-        S, KV, G, D = len(pos), 2, 2, 16
+        S, G = len(pos), 2
         NB = 1 + S * WB
+        if blocks is not None:
+            row = KV * D * (1 if quant else jnp.dtype(pool).itemsize)
+            assert blocks_per_step(BS, row) == blocks
         q = self._rand(rng, S, L, KV * G, D)
         table = jnp.asarray(
             rng.permutation(NB - 1).reshape(S, WB) + 1, jnp.int32)
@@ -570,11 +583,11 @@ class TestPagedDecodeAttention:
         if quant:
             k = jnp.asarray(rng.integers(-127, 128, (NB, BS, KV * D)), jnp.int8)
             v = jnp.asarray(rng.integers(-127, 128, (NB, BS, KV * D)), jnp.int8)
-            kw["k_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * 0.1, jnp.float32)
-            kw["v_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * 0.1, jnp.float32)
+            kw["k_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * scale, jnp.float32)
+            kw["v_scale"] = jnp.asarray(rng.random((NB, BS, KV)) * scale, jnp.float32)
         else:
-            k = self._rand(rng, NB, BS, KV * D)
-            v = self._rand(rng, NB, BS, KV * D)
+            k = self._rand(rng, NB, BS, KV * D).astype(pool)
+            v = self._rand(rng, NB, BS, KV * D).astype(pool)
         ref = paged_decode_attention_reference(
             q, k.reshape(NB, BS, KV, D), v.reshape(NB, BS, KV, D), table,
             pos, **kw)
@@ -597,14 +610,73 @@ class TestPagedDecodeAttention:
             out[act], np.asarray(ref)[act], rtol=2e-5, atol=2e-5)
         assert not out[~act].any()
 
-    # (block, blocks a step): a step attends 256 rows whatever the block
+    # (block, bytes of a pool row, blocks a step): a tile is 512 KB a pool,
+    # never under 256 rows, never over 2,048, never under one block
+    TILES = [
+        # 2-KB rows (8 kv heads of 128 in bfloat16): PR 29's 256 rows
+        (16, 2048, 16), (64, 2048, 4), (128, 2048, 2), (256, 2048, 1),
+        # 1 KB: 4 kv heads in bfloat16 (Keye-VL-2.0's pool), 8 in int8
+        (16, 1024, 32), (64, 1024, 8), (128, 1024, 4), (256, 1024, 2),
+        # 512 B: 2 kv heads in bfloat16 (ZAYA1-8B), 4 in int8
+        (16, 512, 64), (64, 512, 16), (128, 512, 8), (256, 512, 4),
+        # 256 B: 1 kv head in bfloat16 (Jamba2-3B), 2 in int8
+        (16, 256, 128), (64, 256, 32), (128, 256, 16), (256, 256, 8),
+        # wider than 2 KB: the floor, not the bytes
+        (16, 4096, 16), (64, 4096, 4), (128, 4096, 2), (256, 4096, 1),
+        # 128 B: 1 kv head in int8 — the cap, not the bytes
+        (256, 128, 8),
+        # a block larger than the floor is still one block at the least
+        (512, 2048, 1), (512, 256, 4),
+    ]
+
+    @pytest.mark.parametrize("BS,row,G", TILES)
+    def test_blocks_a_step_follow_the_block_and_the_rows_bytes(self, BS, row, G):
+        from seldon_core_tpu.ops import paged_attention as pa
+
+        assert pa.blocks_per_step(BS, row) == G
+        if row >= 2048 and BS <= 256:
+            # PR 29's tile, unchanged: what the rule returned for the block
+            # alone, so the 2-KB cells' programs are the parent's
+            assert G == max(1, 256 // BS) and G * BS == pa.STEP_ROWS == 256
+
+    @pytest.mark.parametrize("pool,row", [
+        (jnp.float32, 1024), (jnp.bfloat16, 512), (jnp.int8, 256)])
+    def test_the_rule_reads_the_operands_shape_and_dtype(
+            self, monkeypatch, pool, row):
+        """What the kernel hands the rule is the pool's own block size and
+        ``KV * D`` x its item size: nothing a caller passes."""
+        from seldon_core_tpu.ops import paged_attention as pa
+
+        asked = []
+        rule = pa.blocks_per_step
+        monkeypatch.setattr(
+            pa, "blocks_per_step",
+            lambda *a: asked.append(a) or rule(*a))
+        rng = np.random.default_rng(5)
+        q = self._rand(rng, 2, 1, 4, 128)
+        k = (self._rand(rng, 5, 32, 256) * 20).astype(pool)
+        kw = {}
+        if pool == jnp.int8:
+            kw = {n: jnp.ones((5, 32, 2), jnp.float32)
+                  for n in ("k_scale", "v_scale")}
+        pa.paged_decode_attention(
+            q, k, k, jnp.asarray([[1, 2], [3, 4]], jnp.int32),
+            jnp.asarray([40, 7], jnp.int32), **kw)
+        assert asked == [(32, row)]
+
+    # (block, blocks a step) at 2-KB float32 rows: PR 29's cases
     BLOCKS = [(16, 16), (64, 4), (128, 2), (256, 1)]
-
-    @pytest.mark.parametrize("BS,G", BLOCKS)
-    def test_blocks_a_step_follow_the_block_size(self, BS, G):
-        from seldon_core_tpu.ops.paged_attention import blocks_per_step
-
-        assert blocks_per_step(BS) == G and G * BS == 256
+    # (kv heads, pool dtype, int8, blocks of 256 a step) at a head of 128:
+    # KV * D = 256 and 128, the rows of the two newest families
+    NARROW = [
+        (2, jnp.bfloat16, False, 4),  # 512 B: ZAYA1-8B's pool
+        (1, jnp.bfloat16, False, 8),  # 256 B: Jamba2-3B's
+        (1, jnp.float32, False, 4),   # 512 B in float32
+        (2, jnp.int8, True, 8),       # 256 B: int8 with its scales
+        (1, jnp.int8, True, 8),       # 128 B: the cap
+    ]
+    NARROW_IDS = ["kvd256-bf16", "kvd128-bf16", "kvd128-f32", "kvd256-int8",
+                  "kvd128-int8"]
 
     @pytest.mark.parametrize("L", [1, 3])
     @pytest.mark.parametrize("BS,G", BLOCKS)
@@ -616,15 +688,46 @@ class TestPagedDecodeAttention:
         top = WB * BS - L
         self._blocks_case(
             BS, WB, [0, BS - 1, BS, 255, 256, 300, 511, top], L,
-            seed=BS + L)
+            seed=BS + L, blocks=G)
+
+    @pytest.mark.parametrize("L", [1, 3])
+    @pytest.mark.parametrize("KV,pool,quant,G", NARROW, ids=NARROW_IDS)
+    def test_several_blocks_a_step_at_narrow_rows(self, KV, pool, quant, G, L):
+        """The same three steps where the row's bytes, not the block, make
+        the tile: blocks of 256 at ``KV * D`` = 256 and 128.  A slot at
+        position 0, one whose only tile holds one live block of ``G``, one
+        on a tile's last row and one on the next tile's first (that tile
+        holds ONE live block), one in mid-tile, one whose last (short) tile
+        holds one live block, the window's last row."""
+        BS = 256
+        WB = 2 * G + max(1, G // 2)
+        T = G * BS
+        top = WB * BS - L
+        self._blocks_case(
+            BS, WB, [0, 200, T - L, T, T + BS + 7, 2 * T + 5, top], L,
+            quant=quant, KV=KV, D=128, pool=pool, seed=G + L, blocks=G)
 
     @pytest.mark.parametrize("L", [1, 3])
     def test_inactive_slots_read_nothing(self, L):
         self._blocks_case(
             16, 40, [0, 300, 17, 639 - L, 255], L,
-            active=[True, False, True, True, False], seed=3 + L)
+            active=[True, False, True, True, False], seed=3 + L, blocks=16)
 
-    @pytest.mark.parametrize("BS,WB", [(16, 40), (16, 3), (256, 3)])
+    @pytest.mark.parametrize("L", [1, 3])
+    @pytest.mark.parametrize("KV,pool,quant,G", NARROW, ids=NARROW_IDS)
+    def test_inactive_slots_read_nothing_at_narrow_rows(
+            self, KV, pool, quant, G, L):
+        """Inactive slots between live ones whose tiles are full, short and
+        one block of ``G``: the step in flight skips them."""
+        T = G * 256
+        self._blocks_case(
+            256, 2 * G + 1, [0, T + 300, 17, 2 * T + 255 - L, T - 1, T], L,
+            active=[True, False, True, True, False, True],
+            quant=quant, KV=KV, D=128, pool=pool, seed=7 + G + L, blocks=G)
+
+    # an int8 row of 512 values is 512 B: 64 blocks of 16 a step, 4 of 256
+    @pytest.mark.parametrize(
+        "BS,WB", [(16, 40), (16, 3), (256, 3), (16, 150), (256, 10)])
     def test_int8_with_several_blocks_a_step(self, BS, WB):
         top = WB * BS - 2
         self._blocks_case(
@@ -642,21 +745,45 @@ class TestPagedDecodeAttention:
             BS, WB, [0, 5, top // 2, 300, top], 2, quant=quant, poison=True,
             active=[True, True, True, False, True], seed=BS + quant)
 
-    def test_a_sliding_window_over_several_steps(self):
-        """``first`` + ``window`` at 16-token blocks: the window's blocks
-        span three steps of 16, and blocks before its lower edge (NaN
-        here) are not fetched even inside a step that is."""
-        from seldon_core_tpu.ops import paged_decode_attention
+    @pytest.mark.parametrize("KV,pool,quant,G", NARROW, ids=NARROW_IDS)
+    def test_dead_blocks_are_not_read_at_narrow_rows(self, KV, pool, quant, G):
+        """The same at several blocks of 256 a step: a tile's blocks past the
+        slot's last query are not fetched, though the tile is attended."""
+        WB = 2 * G + 1
+        T = G * 256
+        top = WB * 256 - 2
+        self._blocks_case(
+            256, WB, [0, 5, T + 3, 300, T - 2, top], 2, quant=quant,
+            poison=True, active=[True, True, True, False, True, True],
+            KV=KV, D=128, pool=pool, seed=G + quant, blocks=G)
 
+    @pytest.mark.parametrize("BS,KV,D,pool,G,window,pos,MB", [
+        # 16-token blocks of 2-KB rows: the window's blocks span three
+        # steps of 16
+        (16, 2, 256, jnp.float32, 16, 500, [930, 411, 37], 60),
+        # blocks of 256 at 512-B and 256-B rows, four and eight a step: the
+        # window's lower edge falls INSIDE a tile (its first blocks dead)
+        (256, 2, 128, jnp.bfloat16, 4, 1500, [3400, 2100, 300], 14),
+        (256, 1, 128, jnp.bfloat16, 8, 2600, [5800, 4000, 2700], 24),
+    ], ids=["bs16-2KB", "bs256-512B", "bs256-256B"])
+    def test_a_sliding_window_over_several_steps(
+            self, BS, KV, D, pool, G, window, pos, MB):
+        """``first`` + ``window``: the window's blocks span several steps,
+        and blocks before its lower edge (NaN here) are not fetched even
+        inside a step that is."""
+        from seldon_core_tpu.ops import paged_decode_attention
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
+
+        assert blocks_per_step(BS, KV * D * jnp.dtype(pool).itemsize) == G
         rng = np.random.default_rng(11)
-        S, L, KV, G, D, BS, MB, window = 3, 2, 2, 2, 16, 16, 60, 500
+        S, L, GQ = 3, 2, 2
         NB = 1 + S * MB
-        q = self._rand(rng, S, L, KV * G, D)
-        k = self._rand(rng, NB, BS, KV * D)
-        v = self._rand(rng, NB, BS, KV * D)
+        q = self._rand(rng, S, L, KV * GQ, D)
+        k = self._rand(rng, NB, BS, KV * D).astype(pool)
+        v = self._rand(rng, NB, BS, KV * D).astype(pool)
         slot_blocks = jnp.asarray(
             rng.permutation(NB - 1).reshape(S, MB) + 1, jnp.int32)
-        pos = jnp.asarray([930, 411, 37], jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
         nb = -(-(window + L - 2) // BS) + 1  # blocks that cover the window
         # the table starts two blocks below the window's lower edge
         start = jnp.maximum((pos - window + 1) // BS - 2, 0)
@@ -668,11 +795,11 @@ class TestPagedDecodeAttention:
         rows = jnp.arange(MB * BS)[None, None, :]
         seen = (rows <= qpos[..., None]) & (rows > qpos[..., None] - window)
         s = jnp.einsum(
-            "bqkgd,bskd->bkgqs", q.reshape(S, L, KV, G, D), kw) / np.sqrt(D)
+            "bqkgd,bskd->bkgqs", q.reshape(S, L, KV, GQ, D), kw) / np.sqrt(D)
         s = jnp.where(seen[:, None, None], s, -1e30)
         want = jnp.einsum(
             "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1), vw
-        ).reshape(S, L, KV * G, D)
+        ).reshape(S, L, KV * GQ, D)
         # poison what no query sees, by whole blocks
         blk_seen = np.asarray(seen.any(1)).reshape(S, MB, BS).any(-1)
         dead = np.ones(NB, bool)

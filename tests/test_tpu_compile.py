@@ -42,7 +42,13 @@ CASES = [
     ("command-a-plus full layer", 32, 1, 128, 128, 4 * 769, 256, 8, 32, False, None),
     ("command-a-plus window layer", 32, 1, 128, 128, 4 * 769, 256, 8, 17, False, 4096),
     # 8 query rows on 2 key-value heads of 128: a pool row 256 wide
+    # (512-B rows: four blocks a step since PR 51)
     ("zaya1-8b-l20", 48, 1, 8, 128, 20 * 769, 256, 2, 16, False, None),
+    # 20 query rows on one key-value head: 256-B rows, eight blocks a step
+    ("ai21-jamba2-3b", 128, 1, 20, 128, 2 * 1153, 256, 1, 16, False, None),
+    # 1-KB rows (4 kv heads), a context under ``topk``: two blocks a step
+    ("keye-vl-2 under topk", 8, 1, 32, 128, 6 * 793, 256, 4, 128, False, None),
+    ("zaya1-8b-l20 int8", 48, 1, 8, 128, 20 * 769, 256, 2, 16, True, None),
 ]
 
 

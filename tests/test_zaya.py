@@ -147,6 +147,27 @@ class TestAgainstReference:
         # every block, a slot at position p attends p + 1 rows
         assert ctr["attn.rows_live"] == 3 * sum(range(L + 1, len(seq) + 1))
 
+    def test_the_paged_read_at_several_blocks_a_step(self):
+        """The family's call site (``models/paged.py::attend_paged``) at a
+        tile the row's BYTES size: 2 key-value heads of 128 in float32 are
+        1-KB rows, so a step of the kernel attends 512 rows, eight blocks of
+        64, and the contexts here run from 500 to 529: the last steps' read
+        is a whole tile and one live block of the next."""
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
+
+        cfg, bs, L = m.Config.tiny(max_seq=640, head_dim=128), 64, 500
+        params = _params(cfg)
+        cache = m.init_paged_cache(cfg, 2, 24, bs, jnp.float32)
+        tile = bs * blocks_per_step(bs, cache["k"].shape[-1] * 4)
+        seq = np.random.default_rng(1).integers(1, 256, 530)
+        assert cache["k"].shape[2:] == (bs, 256) and tile == 512 and L < tile < len(seq)
+        last, cache = _prefill(
+            cfg, params, seq[:L], cache=cache, seq_impl="flash",
+            row=_slot_row(n_blocks=10, width=10, first=3))
+        steps, cache = _decode(cfg, params, cache, seq[L:], kernel=True)
+        got = np.concatenate([np.asarray(last)[None], steps])
+        assert np.abs(got - _reference(cfg, params, seq)[L - 1:]).max() < TOL
+
     def test_the_no_op_is_chosen_and_counted(self, seq):
         cfg = _cfg()
         _, cache = _served(cfg, _params(cfg), seq, 13)
@@ -461,6 +482,7 @@ class TestServedPath:
         )
 
     def test_generates_what_the_family_computes(self, seq):
+        from seldon_core_tpu.ops.paged_attention import blocks_per_step
         from seldon_core_tpu.utils.device import xla_compile_count
 
         prompt = seq[:37]
@@ -491,7 +513,12 @@ class TestServedPath:
         want = _reference(cfg, model.params, np.concatenate([prompt, served[:-1]]))
         want = want[len(prompt) - 1:]
         assert list(want.argmax(-1)) == served
-        ctr = model.spec_snapshot()["counters"]
+        snap = model.spec_snapshot()
+        # the tile the kernel ran: float32 rows of 32 values are 128 B, so
+        # the rule's cap, in blocks of 4
+        assert snap["decode_read"] == "kernel"
+        assert snap["decode_tile_rows"] == 4 * blocks_per_step(4, 32 * 4) == 2048
+        ctr = snap["counters"]
         assert ctr["moe.prefill_tokens"] >= 37 and ctr["zaya.steps"] >= 4
         assert ctr["attn.rows_live"] >= 3 * 4 * 38
 
@@ -581,6 +608,9 @@ class TestEngineRoutes:
                 assert c["moe.prefill_tokens"] >= 2 * 37 and c["zaya.steps"] >= 19
                 assert c["moe.pairs_routed"] == c["moe.pairs_held"] + c["moe.tokens_skipped"]
                 assert unit["kv_bytes_per_slot"] > 0
+                # the CPU's read is the gather: no kernel, so no tile
+                assert unit["decode_read"] == "gather"
+                assert unit["decode_tile_rows"] is None
             finally:
                 await client.close()
 
