@@ -132,8 +132,12 @@ class GenerativeModel:
     an internal lock serializes them (the scheduler already serializes its
     own calls, but warmup may overlap traffic that arrives before /ready).
 
-    What ``family_mod`` must expose, and what is probed for, is listed at
-    ``models/registry.py::GENERATIVE_FAMILIES``.
+    What ``family_mod`` must expose, and what is probed for (``pack_params``,
+    the family's serving layout, among it), is listed at
+    ``models/registry.py::GENERATIVE_FAMILIES``.  A tree the family's
+    ``pack_params`` changes here is placed by the family's own
+    ``param_logical_axes`` of the packed tree: a caller's ``param_axes``
+    fit the tree it handed in, not the one the programs get.
     """
 
     def __init__(
@@ -445,6 +449,21 @@ class GenerativeModel:
                 return p.astype(dtype) if jnp.issubdtype(dt, jnp.floating) else p
 
             params = jax.tree.map(_cast, params)
+        # the family's serving layout (``pack_params``, optional), before a
+        # program is traced.  The registry packs what it makes itself inside
+        # the weights' own init; a tree handed in canonical is packed here
+        self._params_packed: dict = {}
+        pack = getattr(family_mod, "pack_params", None)
+        if pack is not None:
+            packed = pack(params)
+            if packed is not params and param_axes is not None:
+                param_axes = family_mod.param_logical_axes(packed)
+            params = packed
+            for path in family_mod.PACKED:
+                leaf = params
+                for key in path.split("/"):
+                    leaf = leaf[key]
+                self._params_packed[path] = list(leaf.shape)
         if mesh is not None:
             if param_axes is not None:
                 params = shard_params(params, mesh, param_axes, rules)
@@ -3106,6 +3125,8 @@ class GenerativeModel:
             "decode_tile_rows": self.decode_tile_rows(),
             # which product a prompt's expert layer runs, rung by rung
             "prefill_experts": self.prefill_experts(),
+            # the leaves the family's ``pack_params`` re-laid out at build
+            "params_packed": self.params_packed(),
             # the family's own device counters (routing of an expert
             # layer), as of the last fetched decode block
             "counters": self.counters_snapshot(),
@@ -3289,6 +3310,12 @@ class GenerativeModel:
         return {  # a JSON object's keys are strings
             str(b): moe.PLANS.get(f"prefill:T{b}{share}") for b in self.prefill_buckets
         }
+
+    def params_packed(self) -> dict:
+        """The leaves the family's ``pack_params`` lays out (its ``PACKED``),
+        by path, with the shapes they have in the tree the programs are
+        handed, read once at build; ``{}`` for a family without the hook."""
+        return self._params_packed
 
     def _note_read(self, active: np.ndarray, window: int) -> None:
         """Bump the decode read's two block counters for one dispatch."""

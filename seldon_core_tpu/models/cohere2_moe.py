@@ -31,6 +31,13 @@ layer add up to the uncut layer (``tests/test_cohere2_moe.py``).
 The held experts' products are ``models/moe.py``'s, chosen by its one rule
 of static shapes (``moe.experts_plan``) behind this family's sigmoid route.
 
+``init_params``, ``models/convert.py`` and a checkpoint carry the attention
+projections by head (``wq (layers, E, H, D)``, ``wo (layers, H, D, E)``): the
+canonical tree.  The products read them PACKED (:func:`pack_params`: heads
+folded, the contracted axis last), the layout an engine makes once at build
+and hands its programs; an entry function given the canonical tree packs it
+inside its program.
+
 The paged pool is uniform: every layer keeps every token's K/V, and a
 sliding layer READS only the blocks that hold its window (the window saves
 reads, not memory).  Device counters of the routing ride the cache
@@ -182,9 +189,6 @@ def init_params(rng: jax.Array, cfg: Config, dtype=jnp.float32) -> dict:
 
 
 _AXIS_RULES = [
-    (r"layers/wq", ("layers", "embed", "heads", "head_dim")),
-    (r"layers/w[kv]$", ("layers", "embed", "kv_heads", "head_dim")),
-    (r"layers/wo", ("layers", "heads", "head_dim", "embed")),
     (r"layers/w_router", ("layers", "embed", None)),
     (r"layers/w[es]_(gate|up)", ("layers", None, "embed", "mlp")),
     (r"layers/w[es]_down", ("layers", None, "mlp", "embed")),
@@ -194,19 +198,93 @@ _AXIS_RULES = [
 ]
 
 
+# the attention projections by head, as ``init_params`` makes them, and as
+# ``pack_params`` carries them: ``heads`` stays the axis a mesh splits
+_CANONICAL_AXES = [
+    (r"layers/wq$", ("layers", "embed", "heads", "head_dim")),
+    (r"layers/w[kv]$", ("layers", "embed", "kv_heads", "head_dim")),
+    (r"layers/wo$", ("layers", "heads", "head_dim", "embed")),
+]
+_PACKED_AXES = [
+    (r"layers/w[qo]$", ("layers", "heads", "embed")),
+    (r"layers/w[kv]$", ("layers", "kv_heads", "embed")),
+]
+
+
 def param_logical_axes(params):
-    return annotate_params(params, _AXIS_RULES)
+    """Logical axes of the canonical or the packed tree (a leaf's rank says
+    which, as it does to :func:`pack_params`)."""
+    packed = params["layers"]["wq"].ndim == 3
+    return annotate_params(
+        params, (_PACKED_AXES if packed else _CANONICAL_AXES) + _AXIS_RULES
+    )
+
+
+# ---------------------------------------------------------------------------
+# the serving layout of the attention projections
+# ---------------------------------------------------------------------------
+
+# the leaves ``pack_params`` lays out, by path (``/stats/summary`` lists them
+# with their shapes as ``params_packed``)
+PACKED = ("layers/wq", "layers/wk", "layers/wv", "layers/wo")
+
+
+def pack_params(params: dict) -> dict:
+    """The tree with ``layers/wq``, ``wk``, ``wv`` and ``wo`` as the products
+    read them: ``(layers, heads * head_dim, E)``, the heads folded into one
+    axis and the contracted axis LAST; every other leaf as it is, no value
+    changed.  (A decode block that is handed ``wq`` as ``(layers, E, H, D)``
+    copies it whole into another tiling before its loop and a layer of it
+    again in every step: docs/PERFORMANCE.md, "A weight is carried as its
+    product reads it".)  A tree already packed (``wq`` of rank 3) is returned
+    as it is, so every entry function calls this first: the canonical tree is
+    packed inside the program, a packed one costs nothing.  A leaf it does
+    not change is the same object in both trees."""
+    if params["layers"]["wq"].ndim == 3:
+        return params
+    layers = dict(params["layers"])
+    for name in ("wq", "wk", "wv"):  # (layers, E, heads, D) -> (layers, heads * D, E)
+        w = layers[name]
+        layers[name] = w.reshape(w.shape[:2] + (-1,)).swapaxes(1, 2)
+    wo = layers["wo"]  # (layers, H, D, E): a reshape
+    layers["wo"] = wo.reshape(wo.shape[0], -1, wo.shape[-1])
+    return {**params, "layers": layers}
 
 
 # ---------------------------------------------------------------------------
 # building blocks
 # ---------------------------------------------------------------------------
 
+# the most rows of an in projection whose split into heads is pinned to its
+# result: where the chip's readings cross (PERF.md section 6, PR 56: the
+# prompt rungs to 1,024 are quicker pinned, those from 2,048 quicker free)
+_PIN_ROWS = 1024
+
+
+def _project_in(h, w, cfg: Config):
+    """``h (..., L, E)`` through one layer's packed ``wq`` / ``wk`` / ``wv``
+    ``(heads * D, E)`` -> ``(..., L, heads, D)``."""
+    y = jnp.einsum("...le,fe->...lf", h, w)
+    if y.size // y.shape[-1] <= _PIN_ROWS:
+        # a decode or verify step's rows, a short prompt's: the split into
+        # heads (and RoPE's into pairs) stays on the result.  Left free, XLA
+        # moves it onto the weight, and then cuts the layer out of the stack
+        # and re-tiles it (134 MB of ``wq`` a layer, in every step of a
+        # decode block).  Over more rows the result it re-lays out instead
+        # costs more than that one cut
+        y = lax.optimization_barrier(y)
+    return y.reshape(y.shape[:-1] + (-1, cfg.head_dim))
+
+
+def _project_out(o, wo):
+    """``o (..., L, H, D)`` through one layer's packed ``wo (H * D, E)`` ->
+    ``(..., L, E)``."""
+    return jnp.einsum("...lf,fe->...le", o.reshape(o.shape[:-2] + (-1,)), wo)
+
+
 def _qkv(h, lp, cfg: Config, positions, full: bool):
     """Projections of ``h (..., L, E)``; RoPE on a sliding layer only."""
-    q = jnp.einsum("...le,ehd->...lhd", h, lp["wq"])
-    k = jnp.einsum("...le,ehd->...lhd", h, lp["wk"])
-    v = jnp.einsum("...le,ehd->...lhd", h, lp["wv"])
+    q, k, v = (_project_in(h, lp[n], cfg) for n in ("wq", "wk", "wv"))
     if not full:
         q = rope_pairs(q, positions, cfg.rope_theta)
         k = rope_pairs(k, positions, cfg.rope_theta)
@@ -344,6 +422,7 @@ def _residual(x, attn, ffn):
 
 def forward(params: dict, tokens: jax.Array, cfg: Config) -> jax.Array:
     """Full-sequence logits ``(B, L, V)``, one sequence after the other."""
+    params = pack_params(params)
 
     def one(toks):
         L = toks.shape[0]
@@ -354,7 +433,7 @@ def forward(params: dict, tokens: jax.Array, cfg: Config) -> jax.Array:
             h = layernorm(x, lp["ln"], cfg.norm_eps)
             q, k, v = _qkv(h, lp, cfg, pos, full)
             o = _attend(q, k, v, pos, pos, None if full else cfg.sliding_window)
-            attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
+            attn = _project_out(o, lp["wo"])
             ffn, _ = _moe(h, lp, cfg, mask, None, decode=False, stacks=params["layers"], li=li)
             return _residual(x, attn, ffn)
 
@@ -437,6 +516,7 @@ def prefill_slot_paged(
     window inside it; ``"dense"`` through chunked XLA attention."""
     del adapter_id
     paged.no_lora("cohere2_moe", lora)
+    params = pack_params(params)
     bs = cache["k"].shape[2]
     lp_ = tokens.shape[1]
     pos = jnp.arange(lp_)
@@ -456,7 +536,7 @@ def prefill_slot_paged(
                 o = flash_prompt(q, k, v, window=window)
             else:
                 o = _attend(q, k, v, pos, pos, window)
-            attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
+            attn = _project_out(o, lp["wo"])
         ffn, ctr = _moe(
             h, lp, cfg, real, ctr, decode=False, stacks=params["layers"], li=li,
             sharded=mesh is not None,
@@ -486,6 +566,7 @@ def prefill_suffix_paged(
     masks what lies before its window."""
     del adapter_id
     paged.no_lora("cohere2_moe", lora)
+    params = pack_params(params)
     bs = cache["k"].shape[2]
     ls = tokens.shape[1]
     pb = max(1, int(prefix_window) // bs)
@@ -511,7 +592,7 @@ def prefill_suffix_paged(
                 jnp.concatenate([vp.astype(v.dtype), v]),
                 qpos, kpos, window, kvalid,
             )
-            attn = jnp.einsum("lhd,hde->le", o, lp["wo"])
+            attn = _project_out(o, lp["wo"])
             ck = paged.write_prompt(ck, li, suffix_blocks, k, bs)
             cv = paged.write_prompt(cv, li, suffix_blocks, v, bs)
         ffn, ctr = _moe(
@@ -584,6 +665,7 @@ def _decode_paged_multi(
     over a tensor-parallel mesh, its expert stacks too."""
     del adapter_ids
     paged.no_lora("cohere2_moe", lora)
+    params = pack_params(params)
     pos, table = cache["pos"], cache["table"]
     S, L = qtokens.shape
     bs = cache["k"].shape[2]
@@ -669,9 +751,7 @@ def _decode_paged_multi(
                     (q.reshape(S, L, kvh, g, d), idx, kpos, positions),
                 )
                 o = lax.map(read, chunked)
-            attn = jnp.einsum(
-                "blhd,hde->ble", o.reshape(S, L, cfg.n_heads, d), lp["wo"]
-            )
+            attn = _project_out(o.reshape(S, L, cfg.n_heads, d), lp["wo"])
         ffn, ctr = _moe(
             h.reshape(S * L, -1), lp, cfg, tok_mask, ctr, decode=True,
             stacks=params["layers"], li=li, sharded=kv_sharded,

@@ -153,6 +153,7 @@ def resolve_config(family: str, preset: str | None = None, **overrides) -> Any:
 def _resolve_params(
     fam: Family, cfg: Any, params: Any, checkpoint: str | None, rng: int,
     mesh: Mesh | None = None, rules: Any = None, dtype: Any = None,
+    pack: Callable | None = None,
 ):
     """Explicit params > checkpoint load > fresh init; the compiled wrapper
     casts/shards them at construction.  A fresh init runs under jit — one
@@ -163,25 +164,30 @@ def _resolve_params(
     (The values are the eager, unsharded init's: threefry is
     partitionable.)  A family that makes its weights in the served dtype
     (``init_in_dtype``) is given ``dtype``, and its key as an argument: one
-    compiled init program for every seed."""
+    compiled init program for every seed.  ``pack`` (a generative family's
+    ``pack_params``) lays out what is made HERE, inside the init's own
+    program or on the loaded tree before anyone else names it: the
+    canonical leaves are never on the device beside their packed ones."""
     if params is not None:
         return params
+    if pack is None:
+        pack = lambda tree: tree  # noqa: E731
     if checkpoint is not None:
         from seldon_core_tpu.executor.checkpoint import load_params
 
-        return load_params(checkpoint)
+        return pack(load_params(checkpoint))
 
     if fam.init_in_dtype:
         args = (jax.random.PRNGKey(rng),)
         as_dtype = jnp.float32 if dtype is None else dtype
 
         def init(key):
-            return fam.init_params(key, cfg, as_dtype)
+            return pack(fam.init_params(key, cfg, as_dtype))
     else:
         args = ()
 
         def init():
-            return fam.init_params(jax.random.PRNGKey(rng), cfg)
+            return pack(fam.init_params(jax.random.PRNGKey(rng), cfg))
 
     shardings = None
     if mesh is not None:
@@ -316,6 +322,15 @@ def example_input(family: str, cfg: Any, batch: int = 1) -> np.ndarray:
 #     ``lora_adapter_factors`` (adapters), ``truncate_params`` (a layer-
 #     truncated draft), ``paged_kv_slot_bytes`` (the KV ledger's own size of
 #     a slot), ``COUNTERS`` (names of the on-device counters a step returns),
+#     ``pack_params`` with ``PACKED`` (the family's serving layout:
+#     ``pack_params(params)`` returns the tree with the leaves its products
+#     read, named by path in ``PACKED``, re-laid out as they read them, and a
+#     tree already packed as it is; every entry function of the family takes
+#     either tree, ``init_params`` and a checkpoint keep the canonical one,
+#     ``param_logical_axes`` names the axes of both; a tree made here is
+#     packed inside its own init, one handed in when the model is built, and
+#     ``/stats/summary`` lists the ``PACKED`` leaves with the shapes the
+#     programs are handed as ``params_packed``),
 #     ``POOL_ARRAYS`` (the names of ALL the per-token arrays its paged pool
 #     holds under the one table, ``("k", "v")`` where it names none: counted
 #     with the pool, and what moves K/V out of the pool — handoff, suspend,
@@ -439,7 +454,10 @@ def build_generative_component(
         cfg = resolve_config(family, preset, **overrides)
     elif overrides:
         raise TypeError(f"unknown generative parameters {sorted(overrides)}")
-    params = _resolve_params(fam, cfg, params, checkpoint, rng, mesh, dtype=dtype)
+    params = _resolve_params(
+        fam, cfg, params, checkpoint, rng, mesh, dtype=dtype,
+        pack=getattr(mod, "pack_params", None),
+    )
     model = GenerativeModel(
         cfg,
         params,

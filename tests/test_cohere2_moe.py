@@ -390,6 +390,107 @@ class TestCounters:
         assert c["moe.max_tokens_on_expert"] <= 5 * layers
 
 
+class TestPackedProjections:
+    """``pack_params``: the four attention projections with their heads
+    folded and the contracted axis last, as an engine holds them.  Every
+    entry point takes either tree and computes the same."""
+
+    def test_pack_moves_no_value_and_is_idempotent(self):
+        cfg = _cfg()
+        params = _params(cfg)
+        packed = m.pack_params(params)
+        lay, was = packed["layers"], params["layers"]
+        L, E = cfg.n_layers, cfg.hidden
+        for name in ("wq", "wk", "wv"):
+            want = np.asarray(was[name]).reshape(L, E, -1).transpose(0, 2, 1)
+            np.testing.assert_array_equal(lay[name], want)
+        np.testing.assert_array_equal(
+            lay["wo"], np.asarray(was["wo"]).reshape(L, -1, E)
+        )
+        assert set(m.PACKED) == {"layers/wq", "layers/wk", "layers/wv", "layers/wo"}
+        for name in set(was) - {"wq", "wk", "wv", "wo"}:
+            assert lay[name] is was[name]
+        assert packed["tok_emb"] is params["tok_emb"]
+        assert m.pack_params(packed) is packed
+        # the canonical tree is left as it was
+        assert params["layers"]["wq"].shape == (L, E, cfg.n_heads, cfg.head_dim)
+
+    @pytest.mark.parametrize("packed", [False, True], ids=["canonical", "packed"])
+    def test_every_leaf_has_its_logical_axes(self, packed):
+        cfg = _cfg()
+        params = _params(cfg)
+        if packed:
+            params = m.pack_params(params)
+        axes = m.param_logical_axes(params)["layers"]
+        for name, split in (("wq", "heads"), ("wk", "kv_heads"),
+                            ("wv", "kv_heads"), ("wo", "heads")):
+            assert len(axes[name]) == params["layers"][name].ndim
+            assert axes[name][0] == "layers" and split in axes[name]
+            if packed:  # the axis a mesh splits comes first after the layers
+                assert axes[name] == ("layers", split, "embed")
+
+    def test_on_a_mesh_the_packed_leaves_split_their_heads(self):
+        """Placed by the family's own axes on tp=2, as ``GenerativeModel``
+        places what it packed: the folded heads axis is the one split, a
+        chip's half of ``wq`` its own heads' rows, and ``forward`` gives
+        what one device gives."""
+        from seldon_core_tpu.parallel import best_mesh
+        from seldon_core_tpu.parallel.sharding import shard_params
+
+        cfg = _cfg()
+        packed = m.pack_params(_params(cfg))
+        mesh = best_mesh(2, tp=2)
+        placed = shard_params(packed, mesh, m.param_logical_axes(packed))
+        wq = placed["layers"]["wq"]
+        assert wq.sharding.spec[1] == "tp"
+        half = cfg.n_heads * cfg.head_dim // 2
+        shapes = {s.data.shape for s in wq.addressable_shards}
+        assert shapes == {(cfg.n_layers, half, cfg.hidden)}
+        toks = jnp.asarray(np.arange(1, 17)[None])
+        fwd = jax.jit(functools.partial(m.forward, cfg=cfg))
+        np.testing.assert_allclose(
+            np.asarray(fwd(placed, toks)), np.asarray(fwd(packed, toks)),
+            atol=TOL, rtol=0,
+        )
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_forward_takes_either_tree(self, prompt, dtype):
+        cfg = _cfg()
+        params = _params(cfg, dtype=dtype)
+        fwd = jax.jit(functools.partial(m.forward, cfg=cfg))
+        toks = jnp.asarray(prompt[None])
+        a = np.asarray(fwd(params, toks), np.float32)
+        b = np.asarray(fwd(m.pack_params(params), toks), np.float32)
+        np.testing.assert_allclose(b, a, atol=TOL if dtype == jnp.float32 else 0.05, rtol=0)
+        assert (a.argmax(-1) == b.argmax(-1)).mean() >= (1.0 if dtype == jnp.float32 else 0.9)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_the_paged_entry_points_take_either_tree(self, prompt, dtype):
+        """``prefill_slot_paged``, ``prefill_suffix_paged`` (the prompt in
+        chunks) and ``decode_slots_paged``: the same greedy tokens and
+        logits from the canonical and the packed tree."""
+        cfg = _cfg()
+        params = _params(cfg, dtype=dtype)
+        tol = TOL if dtype == jnp.float32 else 0.05
+        runs = []
+        for tree in (params, m.pack_params(params)):
+            whole, _ = _prefill(cfg, tree, prompt)
+            logits, cache = _prefill(cfg, tree, prompt, chunks=[0, 16, 28, len(prompt)])
+            fed, out, _ = _decode(cfg, tree, cache, np.argmax(logits), 6)
+            runs.append((np.asarray(whole, np.float32), np.asarray(logits, np.float32),
+                         fed, np.stack(out).astype(np.float32)))
+        (w0, l0, fed0, o0), (w1, l1, fed1, o1) = runs
+        np.testing.assert_allclose(w1, w0, atol=tol, rtol=0)
+        np.testing.assert_allclose(l1, l0, atol=tol, rtol=0)
+        assert fed1[0] == fed0[0]
+        np.testing.assert_allclose(o1[:1], o0[:1], atol=tol, rtol=0)
+        if dtype == jnp.float32:
+            assert fed1 == fed0
+            np.testing.assert_allclose(o1, o0, atol=tol, rtol=0)
+
+
 class TestServedPath:
     """Through ``JAX_GENERATIVE``'s own objects: the registry builds the
     family in the served dtype, ``GenerativeModel`` warms it and serves it
@@ -403,6 +504,81 @@ class TestServedPath:
             n_slots=2, decode_block=4, kv_block_size=4, dtype=jnp.bfloat16,
             rng=5, **kw,
         )
+
+    @staticmethod
+    def _canonical(model):
+        """The component's weights as ``init_params`` made them (``rng=5``):
+        what the plain reference reads; the model holds them packed."""
+        return m.init_params(jax.random.PRNGKey(5), model.cfg, jnp.bfloat16)
+
+    def test_the_model_holds_the_projections_packed(self):
+        """``GenerativeModel`` packs once at build: ``params_packed`` names
+        the four leaves with the shapes of the tree the programs are handed,
+        no value changed; a family without the hook reports ``{}``."""
+        from seldon_core_tpu.models.registry import build_generative_component
+
+        model = self._component().model
+        c = model.cfg
+        hd, kvd = c.n_heads * c.head_dim, c.n_kv_heads * c.head_dim
+        want = {
+            "layers/wq": [c.n_layers, hd, c.hidden],
+            "layers/wk": [c.n_layers, kvd, c.hidden],
+            "layers/wv": [c.n_layers, kvd, c.hidden],
+            "layers/wo": [c.n_layers, hd, c.hidden],
+        }
+        assert model.params_packed() == want
+        assert model.spec_snapshot()["params_packed"] == want
+        for path, shape in want.items():
+            assert list(model.params["layers"][path.split("/")[1]].shape) == shape
+        packed = m.pack_params(self._canonical(model))
+        for a, b in zip(jax.tree.leaves(model.params), jax.tree.leaves(packed)):
+            np.testing.assert_array_equal(a, b)
+        llama = build_generative_component(
+            "llama", preset="tiny", n_slots=2, kv_block_size=4
+        ).model
+        assert llama.params_packed() == {}
+        assert llama.spec_snapshot()["params_packed"] == {}
+
+    @pytest.mark.parametrize("source", ["init", "checkpoint"])
+    def test_the_registry_packs_what_it_makes_itself(self, source, tmp_path):
+        """A fresh init is packed inside its own jitted program and a
+        checkpoint as it is loaded: the tree ``GenerativeModel`` is handed is
+        packed already (nothing canonical is left on the device for it to
+        replace), and holds ``init_params``' values."""
+        from seldon_core_tpu.executor.checkpoint import save_params
+        from seldon_core_tpu.models import registry
+
+        fam, cfg = registry.get_family("cohere2_moe"), _cfg()
+        canonical = m.init_params(jax.random.PRNGKey(5), cfg, jnp.bfloat16)
+        ckpt = None
+        if source == "checkpoint":
+            ckpt = str(tmp_path / "w.npz")
+            save_params(ckpt, canonical)
+        tree = registry._resolve_params(
+            fam, cfg, None, ckpt, 5, dtype=jnp.bfloat16, pack=m.pack_params
+        )
+        assert m.pack_params(tree) is tree
+        for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(m.pack_params(canonical))):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # and without the hook, the canonical tree as before
+        plain = registry._resolve_params(fam, cfg, None, ckpt, 5, dtype=jnp.bfloat16)
+        assert plain["layers"]["wq"].shape == canonical["layers"]["wq"].shape
+
+    def test_a_tree_handed_in_canonical_is_packed_at_build_and_left_alone(self):
+        """``GenerativeModel`` packs a caller's canonical tree itself; the
+        caller's leaves stay the caller's, and a leaf the pack does not
+        touch is shared."""
+        from seldon_core_tpu.executor.generation import GenerativeModel
+
+        cfg = _cfg()
+        tree = _params(cfg, dtype=jnp.bfloat16)
+        model = GenerativeModel(
+            cfg, tree, family_mod=m, n_slots=2, kv_block_size=4, dtype=jnp.bfloat16,
+        )
+        assert set(model.params_packed()) == set(m.PACKED)
+        assert model.params["layers"]["wq"].ndim == 3
+        assert tree["layers"]["wq"].ndim == 4 and not tree["layers"]["wq"].is_deleted()
+        assert model.params["layers"]["we_up"] is tree["layers"]["we_up"]
 
     def test_weights_are_made_in_the_served_dtype(self):
         from seldon_core_tpu.models import registry
@@ -453,7 +629,7 @@ class TestServedPath:
         # teacher-forced on the served tokens, the float32 reference puts
         # each of them within a small margin of its top logit
         want = np.asarray(ref.logits(
-            model.params, np.concatenate([prompt, served[:-1]]),
+            self._canonical(model), np.concatenate([prompt, served[:-1]]),
             **_ref_kw(model.cfg),
         ))[len(prompt) - 1:]
         deficit = want.max(-1) - want[np.arange(len(served)), served]
@@ -478,7 +654,9 @@ class TestServedPath:
         warmed = xla_compile_count()
         tok = model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=4)
         assert xla_compile_count() == warmed
-        want = np.asarray(ref.logits(model.params, prompt, **_ref_kw(model.cfg)))[-1]
+        want = np.asarray(ref.logits(
+            self._canonical(model), prompt, **_ref_kw(model.cfg)
+        ))[-1]
         assert want.max() - want[int(tok)] < 0.25
         assert model.spec_snapshot()["prefill_rows"] == {
             "real": 37, "padded": 48, "by_rung": {"48": 1},

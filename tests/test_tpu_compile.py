@@ -356,14 +356,9 @@ def test_keye_vl2s_prompt_program_cuts_a_layer_out_for_xlas_grouped_product(one_
     assert compiled.memory_analysis().temp_size_in_bytes < 1.84e9
 
 
-def test_command_a_plus_decode_program_streams_its_experts_through_the_kernel(one_chip, monkeypatch):
-    """The whole decode step at the served shapes (32 slots, four layers of
-    16 held experts, a pool of 769 blocks of 256, window 8,192): every
-    layer's experts run through the touched-only kernel (PR 47), handed the
-    stack of every layer, and no matrix of a layer's experts (0.54 GB of its
-    1.6 GB) is copied."""
-    import functools
-
+def _command_a_plus_as_served(one_chip, monkeypatch, packed=True):
+    """The family, the cell's config and the shapes of its weights (as the
+    engine holds them: ``pack_params``; or the canonical tree) and pool."""
     import jax
     import jax.numpy as jnp
 
@@ -377,12 +372,150 @@ def test_command_a_plus_decode_program_streams_its_experts_through_the_kernel(on
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), tree
         )
 
-    params = shapes(jax.eval_shape(
-        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
-    ))
+    def weights():
+        params = m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
+        return m.pack_params(params) if packed else params
+
+    params = shapes(jax.eval_shape(weights))
     cache = shapes(jax.eval_shape(
         lambda: m.init_paged_cache(cfg, 32, 769, 256, jnp.bfloat16)
     ))
+    return m, cfg, params, cache
+
+
+def _hlo_instructions(text):
+    """{computation: [(name, shape, op, operand names, the rest)]} of an
+    optimised HLO module's text."""
+    import re
+
+    def close(s, i):  # the index of the parenthesis that closes s[i]
+        depth = 0
+        for j in range(i, len(s)):
+            depth += (s[j] == "(") - (s[j] == ")")
+            if depth == 0:
+                return j
+        raise ValueError(s[:80])
+
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+            continue
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line)
+        if not m or cur is None:
+            continue
+        rest = line[m.end():]
+        end = close(rest, 0) + 1 if rest.startswith("(") else rest.index(" ")
+        shape, rest = rest[:end], rest[end:].lstrip()
+        op = rest[:rest.index("(")]
+        args = close(rest, len(op))
+        cur.append((m.group(1), shape, op,
+                    re.findall(r"%([\w.\-]+)", rest[len(op):args + 1]), rest[args + 1:]))
+    return comps
+
+
+def _weight_relayouts(text, pattern, at_least=16 << 20):
+    """The instructions of an optimised HLO module that take an entry
+    parameter whose ``op_name`` matches ``pattern`` — followed through
+    tuples, loops, bitcasts and what such an instruction made of it — are
+    no product (a dot, a convolution, a fusion that holds one) and write
+    ``at_least`` bytes or more to HBM: a weight cut out of its stack or
+    re-tiled on its way to a product.  (A prefetch into the fast memory,
+    ``S(1)``, in the parameter's own tiling is none.)  -> [(name, op, MB)]"""
+    import math
+    import re
+
+    comps = _hlo_instructions(text)
+    entry = re.search(r"ENTRY %([\w.\-]+)", text).group(1)
+    sizes = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1, "pred": 1}
+
+    def nbytes(shape):
+        return sum(
+            sizes.get(dt, 0) * math.prod(int(d) for d in dims.split(",") if d)
+            for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]", shape)
+        )
+
+    def calls(rest):
+        return re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", rest)
+
+    def is_product(op, rest):
+        return op in ("dot", "convolution") or any(
+            is_product(o, r) for c in calls(rest) for _, _, o, _, r in comps[c]
+        )
+
+    found = []
+
+    def walk(comp, labels):
+        for name, shape, op, operands, rest in comps[comp]:
+            hit = [labels[o] for o in operands if o in labels]
+            if not hit:
+                continue
+            if op == "tuple":
+                labels[name] = {
+                    k: labels[o] for k, o in enumerate(operands) if o in labels
+                }
+            elif op == "get-tuple-element":
+                k = int(re.search(r"index=(\d+)", rest).group(1))
+                if isinstance(hit[0], dict) and k in hit[0]:
+                    labels[name] = hit[0][k]
+            elif op in ("bitcast", "optimization-barrier"):
+                labels[name] = hit[0]
+            elif op == "while":
+                labels[name] = hit[0]
+                for c in calls(rest):
+                    arg = next(i[0] for i in comps[c] if i[2] == "parameter")
+                    walk(c, {arg: hit[0]})
+            elif not is_product(op, rest):
+                labels[name] = True  # what it made of the weight is the weight still
+                if (not op.endswith("-start") and "S(1)" not in shape
+                        and nbytes(shape) >= at_least):
+                    found.append((name, op, nbytes(shape) >> 20))
+
+    walk(entry, {
+        name: True for name, _, op, _, rest in comps[entry]
+        if op == "parameter" and re.search(pattern, rest.replace("\\'", "'"))
+    })
+    return found
+
+
+ATTENTION_WEIGHTS = r"op_name=\"params\['layers'\]\['w[qkvo]'\]"
+
+
+def _decode_block(m, cfg):
+    """Sixteen decode steps under one loop, as ``_decode_k``
+    (``executor/generation.py``) scans them, greedy."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def block(params, tokens, active, cache):
+        def body(carry, _):
+            tokens, cache = carry
+            logits, cache = m.decode_slots_paged(
+                params, tokens, cache, active, cfg, window=8192, kernel=True
+            )
+            tokens = jnp.argmax(logits, -1).astype(jnp.int32)
+            return (tokens, cache), tokens
+
+        (tokens, cache), ys = lax.scan(body, (tokens, cache), None, length=16)
+        return ys, tokens, cache
+
+    return block
+
+
+def test_command_a_plus_decode_program_streams_its_experts_through_the_kernel(one_chip, monkeypatch):
+    """The whole decode step at the served shapes (32 slots, four layers of
+    16 held experts, a pool of 769 blocks of 256, window 8,192), on the
+    weights as the engine holds them (``pack_params``): every layer's
+    experts run through the touched-only kernel (PR 47), handed the stack of
+    every layer, and neither a matrix of a layer's experts nor a layer's
+    attention projection is copied."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    m, cfg, params, cache = _command_a_plus_as_served(one_chip, monkeypatch)
     step = jax.jit(
         functools.partial(m.decode_slots_paged, cfg=cfg, window=8192, kernel=True),
         donate_argnums=(2,),
@@ -393,40 +526,55 @@ def test_command_a_plus_decode_program_streams_its_experts_through_the_kernel(on
     ).compile()
     # one period of four layers, unrolled: a paged read and the experts in each
     assert compiled.as_text().count("tpu_custom_call") == 2 * cfg.n_layers
-    # 0.41 GB with the dense products too: the relayout of wq / wo (ROADMAP A4),
-    # under one matrix of one layer's 16 experts
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 4096 * 4096 * 2
+    # what a step of 32 rows needs (this compile of PR 56: 3.4 MB; the
+    # canonical tree's relayout of wq / wo made it 0.41 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("packed", [True, False], ids=["packed", "canonical"])
+def test_command_a_plus_decode_block_takes_its_projections_as_they_lie(one_chip, monkeypatch, packed):
+    """Sixteen steps under one loop, as the engine's ``decode_k:k16:w8192``
+    scans them.  On the packed tree no instruction but the products takes
+    ``wq`` / ``wk`` / ``wv`` / ``wo`` (the canonical tree's block copied
+    ``wq`` and ``wo`` whole before its loop, 1.07 GB, and cut a layer of
+    ``wq`` out and re-tiled it in every step: PERF.md section 6, PR 56) and
+    the block's temporaries are megabytes.  The canonical tree still
+    compiles: the pack happens inside the program, at that old cost."""
+    import jax
+    import jax.numpy as jnp
+
+    m, cfg, params, cache = _command_a_plus_as_served(one_chip, monkeypatch, packed)
+    compiled = jax.jit(_decode_block(m, cfg), donate_argnums=(3,)).lower(
+        params, jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((32,), jnp.bool_, sharding=one_chip), cache,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 * cfg.n_layers
+    relayouts = _weight_relayouts(text, ATTENTION_WEIGHTS)
+    if packed:
+        assert relayouts == []
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    else:  # the helper sees what the packed tree is rid of
+        assert relayouts, relayouts
 
 
 def test_command_a_plus_prompt_program_reads_its_experts_in_place(one_chip, monkeypatch):
     """``prefill:b4096`` whole at the served shapes (four layers of 16 held
-    experts, a pool of 769 blocks of 256): one period of four layers
-    unrolled, each layer's grouped products through
-    ``ops/grouped_experts.py`` over the carried stack (PR 52; no
-    ``ragged-dot`` left), and the temporaries no larger than with
+    experts, a pool of 769 blocks of 256, the weights as the engine holds
+    them): one period of four layers unrolled, each layer's grouped
+    products through ``ops/grouped_experts.py`` over the carried stack
+    (PR 52; no ``ragged-dot`` left), and the temporaries no larger than with
     ``lax.ragged_dot`` (1,432,391,680 B by this compile of PR 51's tree)."""
     import functools
 
     import jax
     import jax.numpy as jnp
 
-    from seldon_core_tpu.models import cohere2_moe as m
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = m.Config(vocab_size=32768, n_layers=4, experts_held="0:16", max_seq=8192)
+    m, cfg, params, cache = _command_a_plus_as_served(one_chip, monkeypatch)
 
     def arg(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def shapes(tree):
-        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
-
-    params = shapes(jax.eval_shape(
-        lambda: m.init_params(jax.random.PRNGKey(0), cfg, jnp.bfloat16)
-    ))
-    cache = shapes(jax.eval_shape(
-        lambda: m.init_paged_cache(cfg, 32, 769, 256, jnp.bfloat16)
-    ))
     prefill = jax.jit(
         functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"),
         donate_argnums=(5,),
