@@ -1,0 +1,6 @@
+"""``ledger.idle_share`` in the cells judged on ``tpot_ms_p95``."""
+import ledger
+
+
+def read(run):
+    return ledger.idle_share(run)

@@ -18,6 +18,7 @@ import argparse
 import asyncio
 import logging
 import os
+import time
 from typing import Any
 
 from aiohttp import web
@@ -946,6 +947,7 @@ class EngineApp:
         snap["packing"] = unit.scheduler.packing_snapshot()
         snap["block_boundaries"] = unit.scheduler.boundary_snapshot()
         snap["stalls"] = unit.scheduler.stall_snapshot()
+        snap["device"] = unit.scheduler.device_snapshot()
         return snap
 
     def _breakdown_payload(self) -> dict:
@@ -1070,6 +1072,19 @@ class EngineApp:
             "stage_hist": RECORDER.stage_histograms(),
         })
 
+    def _tell_device_ledgers(self, state: str) -> None:
+        """Every generative unit's device ledger hears where the profiler
+        is (``obs/device.py``): its seconds are marked, and the stretch
+        between a start returning and a stop entering is ``traced``."""
+        now = time.perf_counter()
+        for svc in (self.service, *self.co_services):
+            try:
+                units = svc.generative_units()
+            except Exception:  # noqa: BLE001 - a service with no graph yet
+                continue
+            for unit in units:
+                unit.scheduler.device.profiler(state, now)
+
     async def profile_start(self, request: web.Request) -> web.Response:
         import jax
 
@@ -1086,6 +1101,7 @@ class EngineApp:
                 status=409,
             )
         self._profile_dir = out_dir
+        self._tell_device_ledgers("start")
         try:
             # the capture dir must exist up front: operators tail it while
             # the trace runs, and a bad path should 500 HERE, not at stop
@@ -1095,7 +1111,9 @@ class EngineApp:
             await asyncio.to_thread(jax.profiler.start_trace, out_dir)
         except Exception as e:
             self._profile_dir = None
+            self._tell_device_ledgers("off")
             return web.json_response({"error": str(e)}, status=500)
+        self._tell_device_ledgers("run")
         return web.json_response({"status": "profiling", "dir": out_dir})
 
     async def profile_stop(self, request: web.Request) -> web.Response:
@@ -1108,6 +1126,7 @@ class EngineApp:
         if self._profile_dir is None or self._profile_stopping:
             return web.json_response({"error": "profiler not running"}, status=409)
         self._profile_stopping = True
+        self._tell_device_ledgers("stop")
         try:
             # off the event loop: ``stop_trace`` collects and writes the
             # whole trace, seconds during which the loop would serve nobody
@@ -1115,6 +1134,7 @@ class EngineApp:
         finally:
             out_dir, self._profile_dir = self._profile_dir, None
             self._profile_stopping = False
+            self._tell_device_ledgers("off")
         return web.json_response({"status": "stopped", "dir": out_dir})
 
     # -- disaggregated prefill/decode (docs/DISAGGREGATION.md) -------------

@@ -69,6 +69,7 @@ from seldon_core_tpu.obs import (
     STAGE_TTFT,
     TIMELINE,
 )
+from seldon_core_tpu.obs.device import DeviceLedger
 from seldon_core_tpu.obs.metering import METER
 from seldon_core_tpu.obs.stall import StallWatchdog
 from seldon_core_tpu.obs.timeline import (
@@ -1326,7 +1327,7 @@ class GenerativeModel:
         self.embeds = 0  # pooled-embedding forwards (docs/GRAPHS.md)
         # per-block confidence stash (cascade routing): the last fetched
         # block's (rows, S) top-2 logit margins, read by the scheduler's
-        # delivery loop exactly like last_block_s — None when conf_signal
+        # delivery loop at the block's one sync — None when conf_signal
         # is off, so the fetch path stays sync-free either way
         self.last_conf_seq: np.ndarray | None = None
         self.prefills_reused = 0  # prefills that skipped a reused prefix
@@ -1563,21 +1564,27 @@ class GenerativeModel:
                 self.name, label, seconds,
             )
 
-    def _record_step(self, step_s: float, tokens_emitted: int) -> None:
+    def _record_step(self, step_s: float) -> None:
         """Flight-recorder + metrics for one decode dispatch (runs on the
-        scheduler's worker thread; all sinks are thread-safe)."""
+        scheduler's worker thread; all sinks are thread-safe).  ``step_s``
+        is the host's wait for the block, which the QoS estimate reads."""
         RECORDER.record_stage(STAGE_DEVICE_STEP, step_s)
         self._m_device_step.observe(step_s)
         from seldon_core_tpu.obs import record_host_sync
 
         record_host_sync(self.name)  # sampled tokens materialized on host
-        if tokens_emitted and step_s > 0:
+
+    def record_mfu(self, tokens_emitted: int, busy_s: float) -> None:
+        """The ``mfu`` gauge over the seconds a decode block OCCUPIED the
+        device (the scheduler's device ledger, obs/device.py), not the
+        host's wait for it."""
+        if tokens_emitted and busy_s > 0:
             from seldon_core_tpu.executor.batcher import _chip_peak
 
             peak = _chip_peak()
             if peak:
                 self._m_mfu.set(
-                    tokens_emitted * self.flops_per_token / step_s / peak
+                    tokens_emitted * self.flops_per_token / busy_s / peak
                 )
 
     # ------------------------------------------------- multi-LoRA adapters
@@ -3416,12 +3423,7 @@ class GenerativeModel:
                 jax.device_get(res)
             )
             self.last_conf_seq = None
-        step_s = time.perf_counter() - t0
-        # usage attribution: in single-step mode (decode_block=1) each
-        # step IS the fused block, so the meter's token-share split reads
-        # the same stash step_k_fetch fills on the fused path
-        self.last_block_s = step_s
-        self._record_step(step_s, int(np.asarray(active, bool).sum()))
+        self._record_step(time.perf_counter() - t0)
         return out
 
     def step_k(
@@ -3585,12 +3587,7 @@ class GenerativeModel:
             DEFAULT_METRICS.spec_accepted_per_step_by_method.labels(
                 self.name, method
             ).set(ratio)
-        step_s = time.perf_counter() - t0
-        # stashed for the delivery loop's usage attribution: this block's
-        # measured device seconds get split across the slots it served by
-        # token share (obs/metering.py) — host bookkeeping at the one sync
-        self.last_block_s = step_s
-        self._record_step(step_s, int(act_np.sum()))
+        self._record_step(time.perf_counter() - t0)
         return np.asarray(toks_np), act_np
 
     def _decode_k_fn(self, k: int, window: int) -> tuple[Any, bool]:
@@ -3617,6 +3614,7 @@ class GenerativeModel:
         window = int(payload.get("window") or self.cfg.max_seq)
         fn, fresh = self._decode_k_fn(k, window)
         label = f"decode_k:k{k}:w{window}{self.variant_sfx}"
+        self.decode_label = label  # the device ledger books the block under it
         with self._lock:
             temps = np.asarray(payload["temperature"], np.float32)
             eos = np.asarray(payload["eos"], np.int32)
@@ -3673,6 +3671,7 @@ class GenerativeModel:
         window = int(payload.get("window") or self.cfg.max_seq)
         fn, fresh = self._decode_k_fn(k, window)
         label = f"decode_k:k{k}:w{window}{self.variant_sfx}"
+        self.decode_label = label  # the device ledger books the block under it
         with self._lock:
             if self._carry is None or self._carry_aux is None:
                 raise RuntimeError(
@@ -4065,7 +4064,9 @@ class _Part:
     "what was the host doing": the profiler's trace (a ``TraceAnnotation``,
     so the part lies on the clock of the device's programs; ``note`` goes
     on it), the stall watchdog (the scheduler's current part and since
-    when) and, where ``stage`` names one, the flight recorder.  ``t0`` and
+    when), the device ledger (``obs/device.py``: an idle gap of the device
+    is shared out over the parts that overlap it) and, where ``stage``
+    names one, the flight recorder.  ``t0`` and
     ``t1`` are the part's two instants on ``time.perf_counter``: the run
     loop reads them where it needs the stamp, and takes none beside them.
     Outside a trace an annotation is a flag test.  No name may match
@@ -4092,6 +4093,7 @@ class _Part:
         self.ann.__exit__(*exc)
         self.t1 = time.perf_counter()
         self.sched._part_now = (self.LOOP, self.t1)
+        self.sched.device.part(self.name, self.t0, self.t1)
         if self.stage is not None:
             RECORDER.record_stage(self.stage, self.t1 - self.t0)
 
@@ -4191,6 +4193,9 @@ class GenerationScheduler:
         # (``_Part``), the slots' live mask, and the watchdog that reads
         # both from a thread of its own while the run task lives
         self._part_now: tuple[str, float] = (_Part.LOOP, 0.0)
+        # the device's time, from the stamps of this loop and its workers:
+        # busy by kind and program, idle by part (``device_snapshot``)
+        self.device = DeviceLedger()
         self._active = np.zeros(0, bool)
         self._watchdog = StallWatchdog(model.name, self._watched)
         # Random base so temperature>0 sampling differs across restarts and
@@ -4234,6 +4239,51 @@ class GenerationScheduler:
             self._note_part(
                 req, STAGE_FIRST_WRITE, req.t_first_token, time.perf_counter()
             )
+
+    @staticmethod
+    def _stamped(fn, *args):
+        """``fn(*args)`` on a worker's thread, with the instant it returned
+        THERE: the device ledger's stamp of a dispatch or a completion,
+        which leaves the run loop's resumption out."""
+        out = fn(*args)
+        return out, time.perf_counter()
+
+    def _sent_block(self, at: float, k: int) -> None:
+        """A decode block went out, its dispatch call returning at ``at``.
+        getattr: duck-typed stand-in models (tests) predate the label."""
+        self.device.sent(
+            at, "decode",
+            getattr(self.model, "decode_label", None) or f"decode_k:k{k}",
+            k, part=self._part_now,
+        )
+
+    def _rungs(self) -> dict:
+        """Prompt dispatches so far by rung (the model's host integers).
+        getattr: duck-typed stand-in models (tests) predate the count."""
+        rows = getattr(self.model, "prefill_rows", None) or {}
+        return dict(rows.get("by_rung") or {})
+
+    def _sent_prompts(self, at: float, before: dict, n: int, waits: bool = True) -> None:
+        """A round of ``n`` prompt-side programs went out back to back, the
+        first dispatch call returning at ``at``: one ``prefill`` interval
+        under its rung's label where it ran one rung (``before``:
+        :meth:`_rungs` ahead of it), ``other`` where it ran no prompt (KV
+        imported, a resumed suspend record)."""
+        ran = {r: c - before.get(r, 0) for r, c in self._rungs().items()
+               if c > before.get(r, 0)}
+        if len(ran) == 1:
+            label = f"prefill:b{next(iter(ran))}"
+        else:
+            label = "prefill:mixed" if ran else "import"
+        self.device.sent(
+            at, "prefill" if ran else "other", label,
+            n=max(n, sum(ran.values())), part=self._part_now, waits=waits,
+        )
+
+    def device_snapshot(self) -> dict:
+        """The device ledger (``GET /stats/breakdown``, ``/stats/summary``:
+        ``generation.<unit>.device``)."""
+        return self.device.snapshot(time.perf_counter(), self._part_now)
 
     def stall_snapshot(self) -> dict:
         """Stalls the watchdog has named since boot (``GET
@@ -4555,18 +4605,26 @@ class GenerationScheduler:
         def dispatch_and_fetch():
             placed: list[tuple[_Request, Any]] = []
             errors: list[tuple[_Request, Exception]] = []
+            sent_at = 0.0
             for req in reqs:
                 try:
                     placed.append((req, self.model.embed_dispatch(req.prompt)))
                 except Exception as e:  # per-request: one bad prompt
                     errors.append((req, e))  # must not fail the wave
+                sent_at = sent_at or time.perf_counter()
             # sct: host-sync-ok embed wave sync point
             vecs = jax.device_get([v for _, v in placed]) if placed else []
-            return placed, errors, vecs
+            return placed, errors, vecs, sent_at, time.perf_counter()
 
-        with self._part("sched:embeds", n=len(reqs)) as wave:
-            placed, errors, vecs = await asyncio.to_thread(dispatch_and_fetch)
-        batch_s = wave.t1 - wave.t0
+        with self._part("sched:embeds", n=len(reqs)):
+            placed, errors, vecs, sent_at, done_at = await asyncio.to_thread(
+                dispatch_and_fetch
+            )
+            self.device.sent(
+                sent_at, "other", "embed", n=len(reqs), part=self._part_now
+            )
+        # the wave's seconds on the device, split by prompt tokens
+        batch_s = self.device.done(done_at)
         total_toks = sum(int(r.prompt.size) for r, _ in placed) or 1
         for (req, _), vec in zip(placed, vecs):
             share_s = batch_s * int(req.prompt.size) / total_toks
@@ -5302,12 +5360,14 @@ class GenerationScheduler:
             reaped += 1
         return reaped
 
-    def _deliver(self, toks_seq, act_seq, slots, cur, active) -> None:
+    def _deliver(self, toks_seq, act_seq, slots, cur, active, block_s=0.0) -> None:
         """Fan one fetched block's ``(k, S)`` tokens out to their requests.
         Completions here (eos / budget) are DEVICE-visible transitions —
         the chip flipped the slot inactive at the same step — so the device
         carry stays consistent and the overlap pipeline keeps running; the
-        freed slot's blocks are only re-reserved at the next sync point."""
+        freed slot's blocks are only re-reserved at the next sync point.
+        ``block_s``: the seconds the block occupied the device (the device
+        ledger's word), which the usage meter shares out."""
         S = len(slots)
         now = time.perf_counter()
         reqs = list(slots)  # completions below null the live entries
@@ -5350,13 +5410,15 @@ class GenerationScheduler:
         # per-adapter served-token ledger (docs/MULTITENANT.md); getattr:
         # duck-typed stand-in models predate multi-LoRA
         note_adapter = getattr(self.model, "note_adapter_tokens", None)
-        # usage attribution (obs/metering.py): this fused block's measured
-        # device seconds (stashed by step_k_fetch at the one host sync)
-        # split across the slots it served BY TOKEN SHARE — a slot that
-        # emitted 3 of the block's 12 tokens is charged 25% of the block.
-        # getattr: duck-typed stand-in models predate the meter.
-        block_s = float(getattr(self.model, "last_block_s", 0.0) or 0.0)
+        # usage attribution (obs/metering.py): the seconds this fused block
+        # occupied the device split across the slots it served BY TOKEN
+        # SHARE — a slot that emitted 3 of the block's 12 tokens is charged
+        # 25% of the block.
         block_tokens = sum(counts)
+        # getattr: duck-typed stand-in models predate the gauge
+        record_mfu = getattr(self.model, "record_mfu", None)
+        if record_mfu is not None:
+            record_mfu(block_tokens, block_s)
         if block_s and not block_tokens:
             # a block that emitted nothing (every slot went inactive at
             # dispatch) still spent the device: charge the base row so
@@ -5528,8 +5590,9 @@ class GenerationScheduler:
         """Dispatch the next block from the device carry -> ``(handle,
         how)``, or ``(None, "dispatch-error")``."""
         try:
-            nxt = await asyncio.to_thread(
-                self.model.step_k_continue, active, self._next_seed(), k
+            nxt, sent_at = await asyncio.to_thread(
+                self._stamped, self.model.step_k_continue, active,
+                self._next_seed(), k,
             )
         except asyncio.CancelledError:
             raise
@@ -5538,6 +5601,7 @@ class GenerationScheduler:
                 "overlapped dispatch failed; falling back to sequential"
             )
             return None, "dispatch-error"
+        self._sent_block(sent_at, k)
         return nxt, how
 
     def boundary_snapshot(self) -> dict:
@@ -5807,21 +5871,32 @@ class GenerationScheduler:
                     if k <= 1:
                         # single-step path (decode_block=1): dispatch, fetch
                         # and deliver inline — no fused block to overlap
-                        try:
-                            toks = await asyncio.to_thread(
-                                self.model.step, cur, active, temps, seed
+                        with self._part("sched:dispatch") as sent:
+                            try:
+                                toks, done_at = await asyncio.to_thread(
+                                    self._stamped, self.model.step,
+                                    cur, active, temps, seed,
+                                )
+                            except asyncio.CancelledError:
+                                raise
+                            except Exception as exc:
+                                log.exception(
+                                    "decode step failed; failing %d in-flight requests",
+                                    int(active.sum()),
+                                )
+                                self._arb_release()
+                                self._fail_inflight(slots, active, exc)
+                                continue
+                            # one call dispatches and fetches: the step has
+                            # the device from the call's start to its end
+                            self.device.sent(
+                                sent.t0, "decode", "decode:k1", 1,
+                                part=self._part_now,
                             )
-                        except asyncio.CancelledError:
-                            raise
-                        except Exception as exc:
-                            log.exception(
-                                "decode step failed; failing %d in-flight requests",
-                                int(active.sum()),
-                            )
-                            self._arb_release()
-                            self._fail_inflight(slots, active, exc)
-                            continue
-                        self._deliver(toks[None], active.copy()[None], slots, cur, active)
+                        self._deliver(
+                            toks[None], active.copy()[None], slots, cur, active,
+                            self.device.done(done_at),
+                        )
                         self._reap_slots(slots, active)
                         # single-step path: every step IS a sync point, so
                         # the grant rotates per step on a packed chip
@@ -5851,8 +5926,8 @@ class GenerationScheduler:
                             np.int32,
                         )
                         try:
-                            pending = await asyncio.to_thread(
-                                self.model.step_k_dispatch,
+                            pending, sent_at = await asyncio.to_thread(
+                                self._stamped, self.model.step_k_dispatch,
                                 cur, active, temps, seed, eos, remaining, k,
                             )
                         except asyncio.CancelledError:
@@ -5865,6 +5940,7 @@ class GenerationScheduler:
                             self._arb_release()
                             self._fail_inflight(slots, active, exc)
                             continue
+                        self._sent_block(sent_at, k)
                     carry_dirty = False
                     pending_t = sent.t1
                     if sync_t is not None:
@@ -5901,9 +5977,9 @@ class GenerationScheduler:
                         # a slot is sure to stay live, and somebody may yet
                         # come for a free one: N's fetch waits on its own
                         # thread while the decision is held
-                        tokens = asyncio.ensure_future(
-                            asyncio.to_thread(self.model.step_k_fetch, pending)
-                        )
+                        tokens = asyncio.ensure_future(asyncio.to_thread(
+                            self._stamped, self.model.step_k_fetch, pending
+                        ))
                         with self._part("sched:hold"):
                             if await self._nobody_came(
                                 max(pending_t, fetched_t) + block_s
@@ -5916,10 +5992,12 @@ class GenerationScheduler:
                             nxt, outcome = await self._chain(active, k, how)
                         nxt_t = sent.t1
                 if tokens is None:
-                    tokens = asyncio.to_thread(self.model.step_k_fetch, pending)
+                    tokens = asyncio.to_thread(
+                        self._stamped, self.model.step_k_fetch, pending
+                    )
                 try:
                     with self._part("sched:fetch") as fetch:
-                        toks_seq, act_seq = await tokens
+                        (toks_seq, act_seq), done_at = await tokens
                 except asyncio.CancelledError:
                     raise
                 except Exception as exc:
@@ -5936,9 +6014,15 @@ class GenerationScheduler:
                             pass
                     pending = None
                     carry_dirty = True
+                    self.device.lost()
                     self._arb_release()
                     self._fail_inflight(slots, active, exc)
                     continue
+                # what the block had of the device: to the worker's stamp
+                # where its wait returned, from its predecessor's or its own
+                # dispatch's (``block_s`` below, on the loop's own stamps,
+                # only times the hold)
+                busy_s = self.device.done(done_at)
                 block_s = fetch.t1 - max(pending_t, fetched_t)
                 fetched_t = fetch.t1
                 if outcome is None:
@@ -5980,7 +6064,7 @@ class GenerationScheduler:
                     self._arb_release()
                     sync_t = fetched_t
                 with self._part("sched:deliver"):
-                    self._deliver(toks_seq, act_seq, slots, cur, active)
+                    self._deliver(toks_seq, act_seq, slots, cur, active, busy_s)
                     if self._reap_slots(slots, active):
                         # host-side reap: the chip still thinks those slots
                         # are live — the next dispatch must rebuild from
@@ -6040,6 +6124,13 @@ class GenerationScheduler:
             errors = []
             starved = []
             chunked = []
+            rungs = self._rungs()
+            stamps = []  # the first device call returned; the tokens in hand
+
+            def dispatched():
+                if not stamps:
+                    stamps.append(time.perf_counter())
+
             for req, slot in zip(batch, free):
                 # duck-typed stand-in models (tests) predate multi-LoRA:
                 # only pass the kwarg when the request actually names one
@@ -6071,6 +6162,7 @@ class GenerationScheduler:
                             first_token=imp["first_token"],
                             **akw, **skw,
                         )
+                        dispatched()
                         placed.append((req, slot, imp["first_token"]))
                         continue
                     if (
@@ -6093,6 +6185,7 @@ class GenerationScheduler:
                         reserve_tokens=req.max_new_tokens,
                         **akw,
                     )
+                    dispatched()
                     placed.append((req, slot, tok_dev))
                 except OutOfKVBlocks:
                     # pool is momentarily full: hold until completions free
@@ -6105,11 +6198,17 @@ class GenerationScheduler:
             # one round trip per admitted batch, not per token
             # sct: host-sync-ok admission sync point
             toks = jax.device_get([t for _, _, t in placed]) if placed else []
-            return placed, toks, errors, starved, chunked
+            stamps.append(time.perf_counter())
+            return placed, toks, errors, starved, chunked, rungs, stamps
 
-        placed, toks, errors, starved, chunked = await asyncio.to_thread(
-            dispatch_and_fetch
+        placed, toks, errors, starved, chunked, rungs, stamps = (
+            await asyncio.to_thread(dispatch_and_fetch)
         )
+        if len(stamps) == 2:
+            # the round on the device: from its first dispatch (or the
+            # predecessor's end) to its first tokens in hand
+            self._sent_prompts(stamps[0], rungs, len(placed))
+            self.device.done(stamps[1])
         # timeline admit events come from host-side reservation bookkeeping
         # (reuse depth, block split) — getattr: stand-in models predate it
         resnap = getattr(self.model, "reservation_snapshot", lambda s: None)
@@ -6244,12 +6343,17 @@ class GenerationScheduler:
         req, slot, plan = ent["req"], ent["slot"], ent["plan"]
         last = ent["i"] == len(plan["payloads"]) - 1
 
+        rungs = self._rungs()
+
         def one_chunk():
             tok_dev = self.model.prefill_chunk_dispatch(plan, ent["i"])
-            return int(tok_dev) if last else None
+            sent_at = time.perf_counter()
+            # only the last chunk is waited for: the others are booked with
+            # whatever the device ledger next hears done
+            return int(tok_dev) if last else None, sent_at, time.perf_counter()
 
         try:
-            tok = await asyncio.to_thread(one_chunk)
+            tok, sent_at, done_at = await asyncio.to_thread(one_chunk)
         except asyncio.CancelledError:
             raise
         except Exception as exc:
@@ -6262,6 +6366,9 @@ class GenerationScheduler:
                 req.future.set_exception(exc)
             self._end_tl(req, "error", stage="prefill", chunks=ent["i"])
             return
+        self._sent_prompts(sent_at, rungs, 1, waits=last)
+        if last:
+            self.device.done(done_at)
         self._tl(
             req, "chunk", i=ent["i"], of=len(plan["payloads"]), last=last
         )

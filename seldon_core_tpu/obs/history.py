@@ -124,6 +124,12 @@ class _Ring:
         if value > self._max[i]:
             self._max[i] = value
 
+    def total(self, bucket: int) -> float:
+        """The sum recorded into absolute bucket ``bucket``: 0.0 where
+        nothing was, or the slot has gone to a later bucket."""
+        i = bucket % self.slots
+        return self._sum[i] if self._bucket[i] == bucket else 0.0
+
     def points(self, now: float, limit: int | None = None) -> list[dict]:
         """Oldest-first [{t, mean, min, max, count}] for live buckets."""
         b_now = int(now // self.step)

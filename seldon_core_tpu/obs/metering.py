@@ -8,8 +8,9 @@ request cost".  The :class:`UsageMeter` is the missing ledger — a
 process-wide table of cumulative usage counters keyed by
 ``(deployment, adapter, qos_class)``:
 
-* **device seconds** — each fused decode block's measured device-step
-  seconds are split across the slots it served *by token share* (a slot
+* **device seconds** — the seconds each fused decode block occupied the
+  device (the scheduler's device ledger, obs/device.py) are split across
+  the slots it served *by token share* (a slot
   that emitted 3 of the block's 12 tokens is charged 25% of the block);
   batcher (non-generative) steps charge their whole measured device time
   to the owning deployment;
@@ -54,7 +55,7 @@ TOP_K_ENV = "SCT_METER_TOP_K"
 # Additions here show up in /stats/usage, the fleet merge, and the
 # seldon_usage_* export without further plumbing.
 FIELDS = (
-    "device_s",            # token-share-attributed device-step seconds
+    "device_s",            # token-share-attributed seconds of device occupancy
     "grant_s",             # arbiter grant-interval wall seconds
     "tokens_prefill",      # prompt tokens actually prefilled on device
     "tokens_decode",       # tokens emitted by fused decode blocks

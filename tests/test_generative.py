@@ -1218,6 +1218,143 @@ class TestHostPathStages:
         (unit,) = breakdown["generation"].values()
         assert unit["stalls"] == {"count": 0, "longest_s": 0.0, "last_part": None}
 
+    def test_a_served_stream_fills_the_device_ledger(self, monkeypatch):
+        """Through the engine, under a trace that is started and stopped
+        (the profiler itself patched out): ``device`` in ``/stats/summary``
+        holds a decode step for every step of every block, both kinds busy,
+        rows that sum to the totals, the seconds the profiler was there
+        marked, and the stretch between start and stop apart."""
+        import jax
+        from aiohttp.test_utils import TestClient, TestServer
+
+        from seldon_core_tpu.engine.app import EngineApp
+        from seldon_core_tpu.engine.service import PredictionService
+        from seldon_core_tpu.graph.spec import PredictorSpec
+
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda out_dir: None)
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+
+        async def go(tmp):
+            service = PredictionService(
+                PredictorSpec.model_validate(TestStreaming.PREDICTOR)
+            )
+            client = TestClient(TestServer(EngineApp(service).build()))
+            await client.start_server()
+            try:
+                first = await client.post(
+                    "/api/v0.1/predictions/stream", json={"tokens": [5, 9, 2]}
+                )
+                await first.read()
+                assert (await client.post("/profile/start", json={"dir": tmp})).status == 200
+                resp = await client.post(
+                    "/api/v0.1/predictions/stream", json={"tokens": [5, 9, 2, 17]}
+                )
+                assert resp.status == 200, await resp.text()
+                await resp.read()
+                assert (await client.post("/profile/stop")).status == 200
+                return await (await client.get("/stats/summary")).json()
+            finally:
+                await client.close()
+
+        import tempfile
+
+        with tempfile.TemporaryDirectory() as tmp:
+            summary = run(go(tmp))
+        (unit,) = summary["breakdown"]["generation"].values()
+        dev, k = unit["device"], 2  # TestStreaming.PREDICTOR's decode_block
+        bounds = unit["block_boundaries"]
+        blocks = sum(
+            sum(v.values()) if isinstance(v, dict) else v for v in bounds.values()
+        )
+        # 6 tokens a stream: the prompt's and 5 more, in blocks of 2, 2 and
+        # 1 (a block's program runs its k steps whatever its budget)
+        assert blocks == 6 and dev["decode_steps"] == blocks * k
+        assert dev["busy_s"]["decode"] > 0 and dev["busy_s"]["prefill"] > 0
+        assert dev["busy_s"]["other"] == 0
+        assert dev["programs"]["prefill:b16"]["n"] == 2
+        (label,) = [p for p in dev["programs"] if p.startswith("decode_k:k2:")]
+        assert dev["programs"][label] == {
+            "n": blocks, "steps": blocks * k,
+            "busy_s": pytest.approx(dev["busy_s"]["decode"], abs=1e-5),
+        }
+        assert dev["columns"][:5] == [
+            "t", "busy_decode_s", "busy_prefill_s", "busy_other_s", "decode_steps"
+        ]
+        rows = dev["seconds"]
+        assert sum(r[4] for r in rows) == pytest.approx(dev["decode_steps"], abs=1e-2)
+        for i, kind in enumerate(("decode", "prefill"), 1):
+            assert sum(r[i] for r in rows) == pytest.approx(dev["busy_s"][kind], abs=1e-4)
+        idle = sum(v for r in rows for v in r[5].values())
+        assert idle == pytest.approx(sum(dev["idle_s"].values()), abs=1e-3)
+        assert "idle-park" in dev["idle_s"]  # between the two streams
+        assert {r[6] for r in rows} <= {0, 1, 2} and any(r[6] for r in rows)
+        traced = dev["traced"]
+        assert traced["running"] is False and traced["wall_s"] > 0
+        assert traced["decode_steps"] == pytest.approx(blocks * k / 2, abs=1e-2)
+        assert traced["busy_s"]["prefill"] > 0
+        busy_idle = sum(traced["busy_s"].values()) + sum(traced["idle_s"].values())
+        assert busy_idle == pytest.approx(traced["wall_s"], abs=1e-4)
+
+    def test_every_dispatch_site_reaches_the_device_ledger(self, monkeypatch):
+        """A prompt, a chained block, a chunked prompt beside a live stream
+        and an embedding: the five parts that send the device work each
+        tell the ledger, under the part's own name."""
+        import jax
+
+        from seldon_core_tpu.executor.generation import (
+            GenerationScheduler,
+            GenerativeModel,
+        )
+        from seldon_core_tpu.models import llama
+
+        cfg = llama.Config.tiny(max_seq=128)
+        model = GenerativeModel(
+            cfg, llama.init_params(jax.random.PRNGKey(0), cfg), n_slots=2,
+            decode_block=4, kv_block_size=16, prefill_chunk=16, embed=True,
+        )
+        sched = GenerationScheduler(model)
+        sites = []
+        sent = sched.device.sent
+
+        def told(at, kind, label, *a, part, **kw):
+            sites.append((part[0], kind))
+            return sent(at, kind, label, *a, part=part, **kw)
+
+        sched.device.sent = told
+
+        async def go():
+            live = asyncio.Event()
+            try:
+                a = asyncio.ensure_future(sched.submit(
+                    np.asarray([5, 9, 2], np.int32), max_new_tokens=40,
+                    on_token=lambda tok: live.set(),
+                ))
+                await live.wait()
+                # longer than a chunk, and a stream is live: paced in chunks
+                b = sched.submit(np.arange(5, 50, dtype=np.int32), max_new_tokens=6)
+                e = sched.submit_embed(np.asarray([1, 2, 3, 4], np.int32))
+                return await asyncio.gather(a, b, e)
+            finally:
+                await sched.close()
+
+        with _parts_entered(monkeypatch) as entered:
+            out_a, out_b, vec = run(go())
+        assert out_a.size == 40 and out_b.size == 6 and vec.shape == (64,)
+        assert model.prefill_chunks >= 2
+        assert set(sites) == {
+            ("sched:admit", "prefill"), ("sched:advance-prefill", "prefill"),
+            ("sched:embeds", "other"), ("sched:dispatch", "decode"),
+            ("sched:chain", "decode"),
+        }
+        assert {p for p, _ in sites} <= set(entered)
+        snap = sched.device_snapshot()
+        # a chunk nobody waits for is booked with the block behind it, as
+        # ``other``: the decode step stays the time of blocks that ran alone
+        mixed = snap["programs"].get("mixed", {"steps": 0})
+        assert snap["decode_steps"] + mixed["steps"] == model.steps
+        assert snap["programs"]["embed"]["n"] == 1
+        assert not sched.device._flying  # every program sent was heard done
+
     def test_a_stall_names_the_part_and_an_idle_park_none(self, caplog, monkeypatch):
         """A fetch that takes 1.3 s with a slot live: one line under 1,200
         characters that names ``sched:fetch``, one more when it ends, and

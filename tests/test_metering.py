@@ -21,6 +21,7 @@ Acceptance bars this suite holds:
 """
 
 import asyncio
+import time
 
 import numpy as np
 import pytest
@@ -40,7 +41,7 @@ from seldon_core_tpu.gateway.store import (
     Endpoint,
 )
 from seldon_core_tpu.models import llama
-from seldon_core_tpu.obs import TIMELINE
+from seldon_core_tpu.obs import RECORDER, TIMELINE
 from seldon_core_tpu.obs.fleet import FleetCollector, _merge_numeric
 from seldon_core_tpu.obs.metering import (
     FIELDS,
@@ -253,8 +254,11 @@ class TestAttributionConservation:
     def test_three_tenant_packed_device_seconds_conserve(self, tiny):
         """3 co-resident deployments time-share one device under the
         arbiter; the meter's per-tenant device-second rows must sum to
-        the wall total of measured fused-block seconds within 1%, paying
-        zero mid-traffic compiles and keeping the sync audit green."""
+        the seconds their decode blocks OCCUPIED the device by each
+        scheduler's device ledger (obs/device.py) within 1%, paying zero
+        mid-traffic compiles and keeping the sync audit green.  The
+        ``device-step`` stage and its histogram, which the QoS estimate
+        reads, stay the host's wait inside the fetch, once a block."""
         from seldon_core_tpu.obs import host_sync_snapshot
 
         cfg, params = tiny
@@ -275,6 +279,7 @@ class TestAttributionConservation:
         # (which the wall total below counts) and its delivery (where the
         # meter charges it) — so a round trip ends once the two are level
         blocks_seen = {"dispatched": 0, "delivered": 0}
+        outs: list = []
 
         def counted(fn, key):
             def wrapped(*args, **kwargs):
@@ -302,21 +307,22 @@ class TestAttributionConservation:
                 scheds["met-bulk-0"].attach_arbiter(arb, priority="batch")
                 scheds["met-bulk-1"].attach_arbiter(arb, priority="batch")
                 try:
-                    outs = await asyncio.gather(*(
+                    outs.clear()
+                    outs.extend(await asyncio.gather(*(
                         s.submit(prompt, max_new_tokens=max_new)
                         for s in scheds.values()
                         for _ in range(2)
-                    ))
+                    )))
                     for _ in range(1000):
                         if blocks_seen["dispatched"] == blocks_seen["delivered"]:
                             break
                         await asyncio.sleep(0.01)
-                    return outs
                 finally:
                     for s in scheds.values():
                         await s.close()
 
-            return run(go())
+            run(go())
+            return scheds
 
         round_trip()  # warmup: all programs compile off the clock
         METER.reset()
@@ -324,21 +330,40 @@ class TestAttributionConservation:
         syncs_before = {
             n: host_sync_snapshot().get(n, 0) for n in models
         }
-        # ground truth: the wall total of measured device-step seconds,
-        # accumulated at the exact stash the meter's split reads
-        wall = {"s": 0.0}
+        # what ``device-step`` is handed, and the most it may be: a block's
+        # dispatch stamp to the fetch's return
+        waits: list = []
+        mfu_over: list = []  # the seconds the ``mfu`` gauge divides by
         for model in models.values():
-            orig = model.step_k_fetch
+            fetch, record = model.step_k_fetch, model._record_step
 
-            def wrapped(handle, _orig=orig, _m=model):
-                out = _orig(handle)
-                wall["s"] += _m.last_block_s
+            def fetched(handle, _fetch=fetch):
+                out = _fetch(handle)
+                waits[-1].append(time.perf_counter() - handle[3])
                 return out
 
-            model.step_k_fetch = wrapped
+            def recorded(step_s, _record=record):
+                waits.append([step_s])
+                return _record(step_s)
 
-        outs = round_trip()
+            model.step_k_fetch, model._record_step = fetched, recorded
+            model.record_mfu = lambda tokens, busy_s: mfu_over.append(busy_s)
+        stage_before = RECORDER.breakdown().get("device-step", {"count": 0})["count"]
+
+        scheds = round_trip()
         assert all(o.size == max_new for o in outs)
+        # ground truth: the seconds the decode blocks occupied the device,
+        # by the ledger of the scheduler that sent them
+        wall = {"s": sum(
+            s.device_snapshot()["busy_s"]["decode"] for s in scheds.values()
+        )}
+        assert len(waits) == len(mfu_over) == blocks_seen["delivered"]
+        assert sum(mfu_over) == pytest.approx(wall["s"], abs=1e-4)
+        assert all(0 < step_s <= bound for step_s, bound in waits)
+        assert (
+            RECORDER.breakdown()["device-step"]["count"] - stage_before
+            == len(waits)
+        )
         # zero mid-traffic compiles with metering on
         assert sum(
             m.program_compiles for m in models.values()

@@ -14,6 +14,7 @@ import time
 
 import aiohttp
 import numpy as np
+import pytest
 from aiohttp import web
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -840,6 +841,226 @@ class TestStallWatchdog:
         monkeypatch.setattr(stall, "_where", lambda frame, depth=1: "x" * 400)
         wd = self._watchdog([("sched:admit", 0.0, True)])
         assert len(wd._line("sched:admit", 1.5, 0.0)) < stall.LINE_MAX
+
+
+class TestDeviceLedger:
+    """obs/device.py on stamps handed in: what a program occupied, where
+    the device stood idle and under which part of the run loop, second by
+    second.  No clock is read and no size of a wall-clock time is held."""
+
+    DISPATCH, CHAIN = ("sched:dispatch", 0.0), ("sched:chain", 0.0)
+
+    @staticmethod
+    def _ledger():
+        from seldon_core_tpu.obs.device import DeviceLedger
+
+        return DeviceLedger()
+
+    @staticmethod
+    def _snap(led, now):
+        return led.snapshot(now, ("sched:loop", now))
+
+    def test_a_chained_block_starts_at_its_predecessors_done_and_leaves_no_idle(self):
+        led = self._ledger()
+        led.sent(10.0, "decode", "decode_k:k16:w512", 16, part=("sched:dispatch", 9.99))
+        # N+1 goes out from the device carry while N runs
+        led.sent(10.02, "decode", "decode_k:k16:w512", 16, part=("sched:chain", 10.01))
+        assert led.done(10.09) == pytest.approx(0.09)   # N: from its dispatch
+        led.sent(10.10, "decode", "decode_k:k16:w512", 16, part=("sched:chain", 10.095))
+        assert led.done(10.18) == pytest.approx(0.09)   # N+1: from N's done stamp
+        assert led.done(10.27) == pytest.approx(0.09)
+        snap = self._snap(led, 10.27)
+        assert snap["idle_s"] == {} and snap["decode_steps"] == 48
+        assert snap["busy_s"] == {"decode": pytest.approx(0.27), "prefill": 0.0, "other": 0.0}
+        assert snap["programs"] == {
+            "decode_k:k16:w512": {"n": 3, "steps": 48, "busy_s": pytest.approx(0.27)}
+        }
+
+    def test_a_sync_points_gap_lands_on_the_parts_between_by_overlap(self):
+        led = self._ledger()
+        led.sent(1.0, "decode", "d", 16, part=("sched:dispatch", 0.999))
+        led.part("sched:dispatch", 0.999, 1.001)
+        led.done(1.100)                            # on the worker's thread
+        led.part("sched:fetch", 1.001, 1.101)      # the loop resumed 1 ms on
+        led.part("sched:deliver", 1.102, 1.105)    # 1 ms of loop before it
+        # the next dispatch returned at 1.108, 2 ms into its part
+        led.sent(1.108, "decode", "d", 16, part=("sched:dispatch", 1.106))
+        led.done(1.2)
+        idle = self._snap(led, 1.2)["idle_s"]
+        assert idle == {
+            "sched:fetch": pytest.approx(0.001), "sched:deliver": pytest.approx(0.003),
+            "sched:dispatch": pytest.approx(0.002), "sched:loop": pytest.approx(0.002),
+        }
+        assert sum(idle.values()) == pytest.approx(1.108 - 1.100)
+
+    def test_a_gap_found_late_is_still_the_parts_that_ran_in_it(self):
+        """N+1 was chained while N ran by the loop's word, and N's done
+        stamp says it had ended before the dispatch call returned."""
+        led = self._ledger()
+        led.sent(1.0, "decode", "d", 16, part=("sched:dispatch", 0.999))
+        led.part("sched:dispatch", 0.999, 1.001)
+        led.part("sched:hold", 1.001, 1.094)
+        led.sent(1.099, "decode", "d", 16, part=("sched:chain", 1.094))
+        led.part("sched:chain", 1.094, 1.0995)
+        assert led.done(1.097) == pytest.approx(0.097)
+        assert led.done(1.2) == pytest.approx(1.2 - 1.099)
+        assert self._snap(led, 1.2)["idle_s"] == {"sched:chain": pytest.approx(0.002)}
+
+    def test_idle_for_want_of_demand_is_kept_apart(self):
+        led = self._ledger()
+        led.sent(5.0, "decode", "d", 16, part=("sched:dispatch", 4.999))
+        led.done(5.1)
+        led.part("sched:fetch", 5.0, 5.1005)
+        led.part("sched:deliver", 5.1005, 5.102)
+        led.part("idle-park", 5.103, 65.0)        # a minute with nobody there
+        led.part("sched:admit", 65.001, 65.03)    # ... whose round is told below
+        led.sent(65.04, "decode", "d", 16, part=("sched:dispatch", 65.035))
+        snap = self._snap(led, 65.04)
+        assert snap["idle_s"]["idle-park"] == pytest.approx(65.0 - 5.103)
+        host = sum(v for k, v in snap["idle_s"].items() if k != "idle-park")
+        assert host == pytest.approx(65.04 - 5.1 - (65.0 - 5.103))
+        # a long park is many parts (a preempted scheduler wakes every
+        # 50 ms): they are booked as they come, not kept
+        led.done(65.1)
+        for i in range(1000):
+            led.part("idle-park", 100.0 + i, 100.9 + i)
+        assert len(led._parts) < 16
+        assert self._snap(led, 1100.0)["idle_s"]["idle-park"] == pytest.approx(
+            65.0 - 5.103 + 900.0
+        )
+
+    def test_a_round_of_prompts_is_one_prefill_interval_with_its_rungs_counted(self):
+        from seldon_core_tpu.executor.generation import GenerationScheduler
+
+        class Model:  # what the scheduler reads of a model here
+            name, n_slots, decode_block = "m", 2, 4
+            prefill_rows = {"by_rung": {"256": 5}}
+
+        sched = GenerationScheduler(Model())
+        before = sched._rungs()
+        Model.prefill_rows = {"by_rung": {"256": 8}}
+        sched._part_now = ("sched:admit", 2.0)
+        sched._sent_prompts(2.01, before, 3)
+        assert sched.device.done(2.5) == pytest.approx(0.49)
+        before = sched._rungs()
+        Model.prefill_rows = {"by_rung": {"256": 9, "1024": 2}}
+        sched._sent_prompts(2.6, before, 4)        # one KV import among them
+        sched.device.done(3.0)
+        before = sched._rungs()
+        sched._sent_prompts(3.0, before, 1)        # an import alone
+        sched.device.done(3.1)
+        snap = sched.device.snapshot(3.1, ("sched:loop", 3.1))
+        assert snap["programs"] == {
+            "prefill:b256": {"n": 3, "steps": 0, "busy_s": pytest.approx(0.49)},
+            "prefill:mixed": {"n": 4, "steps": 0, "busy_s": pytest.approx(0.4)},
+            "import": {"n": 1, "steps": 0, "busy_s": pytest.approx(0.1)},
+        }
+        assert snap["busy_s"] == {
+            "decode": 0.0, "prefill": pytest.approx(0.89), "other": pytest.approx(0.1)
+        }
+        assert snap["idle_s"] == {"sched:admit": pytest.approx(0.1)}
+
+    def test_a_chunk_nobody_waits_for_is_booked_with_the_next_done(self):
+        led = self._ledger()
+        led.sent(1.0, "prefill", "prefill:b256", part=("sched:advance-prefill", 0.99), waits=False)
+        led.sent(1.01, "decode", "d", 16, part=("sched:dispatch", 1.005))
+        assert led.done(1.3) == pytest.approx(0.3)
+        snap = self._snap(led, 1.3)
+        # neither kind's seconds are known apart: the step stays a decode
+        # block's own time
+        assert snap["busy_s"]["other"] == pytest.approx(0.3)
+        assert snap["decode_steps"] == 0 and snap["programs"]["mixed"]["steps"] == 16
+
+    def test_seconds_share_an_interval_by_overlap_and_sum_to_the_totals(self):
+        led = self._ledger()
+        led.sent(100.25, "decode", "d", 16, part=("sched:dispatch", 100.2))
+        led.done(100.75)
+        led.part("sched:fetch", 100.3, 100.76)
+        led.sent(100.8, "prefill", "prefill:b512", n=2, part=("sched:admit", 100.77))
+        led.done(102.3)                            # over two edges
+        led.sent(102.3, "decode", "d", 16, part=("sched:dispatch", 102.29))
+        led.done(103.1)                            # 0.7 s and 0.1 s: steps 14 and 2
+        snap = self._snap(led, 103.1)
+        rows = {r[0]: dict(zip(snap["columns"], r)) for r in snap["seconds"]}
+        assert sorted(rows) == [100, 101, 102, 103]
+        assert rows[100]["busy_prefill_s"] == pytest.approx(0.2)
+        assert rows[101]["busy_prefill_s"] == pytest.approx(1.0)
+        assert rows[102]["busy_prefill_s"] == pytest.approx(0.3)
+        assert rows[102]["decode_steps"] == pytest.approx(14.0)
+        assert rows[103]["decode_steps"] == pytest.approx(2.0)
+        assert rows[100]["idle_s"] == {
+            "sched:fetch": pytest.approx(0.01), "sched:loop": pytest.approx(0.01),
+            "sched:admit": pytest.approx(0.03),
+        }
+        for i, kind in enumerate(("decode", "prefill", "other"), 1):
+            assert sum(r[i] for r in snap["seconds"]) == pytest.approx(snap["busy_s"][kind])
+        assert sum(r[4] for r in snap["seconds"]) == pytest.approx(snap["decode_steps"]) == 32
+        idle = sum(v for r in snap["seconds"] for v in r[5].values())
+        assert idle == pytest.approx(sum(snap["idle_s"].values()))
+        # busy and idle are the wall time between the first dispatch's
+        # return (the books open there) and the last done stamp
+        assert sum(snap["busy_s"].values()) + idle == pytest.approx(103.1 - 100.25)
+
+    def test_the_ring_wraps_at_its_600_seconds_and_the_totals_do_not(self):
+        from seldon_core_tpu.obs.device import SECONDS
+
+        led = self._ledger()
+        for i in range(1000):                      # a block a second
+            led.sent(1000.0 + i, "decode", "d", 16, part=("sched:dispatch", 999.9 + i))
+            led.done(1000.5 + i)
+        snap = self._snap(led, 1999.5)
+        assert SECONDS == 600 and len(snap["seconds"]) == 600
+        assert [r[0] for r in snap["seconds"]] == list(range(1400, 2000))
+        assert snap["decode_steps"] == 1000 * 16
+        assert snap["busy_s"]["decode"] == pytest.approx(500.0)
+        # an interval longer than the ring leaves only the seconds it holds
+        led.part("idle-park", 1999.5, 3000.0)
+        snap = self._snap(led, 3000.5)
+        assert [r[0] for r in snap["seconds"]] == list(range(2401, 3001))
+        assert snap["idle_s"]["idle-park"] == pytest.approx(1000.5)
+
+    def test_profiler_marks_and_the_traced_stretch(self):
+        led = self._ledger()
+        t = 50.0
+        led.sent(t, "decode", "d", 10, part=("sched:dispatch", 49.9))
+        for i in range(80):                        # blocks of 0.1 s, chained
+            led.sent(t + 0.05, "decode", "d", 10, part=("sched:chain", t + 0.04))
+            led.done(t + 0.1)
+            t += 0.1
+            if i == 19:
+                led.profiler("start", 52.0)        # /profile/start entered
+            if i == 22:
+                led.profiler("run", 52.35)         # ... and returned
+            if i == 49:
+                led.profiler("stop", 55.05)        # /profile/stop entered
+            if i == 69:
+                led.profiler("off", 57.0)          # ... and returned
+        snap = self._snap(led, 58.0)
+        marks = {r[0]: r[6] for r in snap["seconds"]}
+        assert [marks[s] for s in range(50, 58)] == [0, 0, 1, 1, 1, 2, 2, 0]
+        traced = snap["traced"]
+        assert traced["running"] is False
+        assert traced["wall_s"] == pytest.approx(55.05 - 52.35)
+        assert traced["busy_s"]["decode"] == pytest.approx(2.7)   # blocks cut in proportion
+        assert traced["decode_steps"] == pytest.approx(270.0)
+        assert traced["idle_s"] == {}
+        # a second trace starts the stretch anew
+        led.profiler("start", 58.0)
+        led.profiler("run", 58.1)
+        assert self._snap(led, 58.2)["traced"] == {
+            "wall_s": pytest.approx(0.1), "running": True, "decode_steps": 0.0,
+            "busy_s": {"decode": 0.0, "prefill": 0.0, "other": 0.0}, "idle_s": {},
+        }
+
+    def test_what_failed_in_flight_is_not_booked(self):
+        led = self._ledger()
+        led.sent(1.0, "decode", "d", 16, part=("sched:dispatch", 0.9))
+        led.lost()
+        assert led.done(1.5) == 0.0
+        led.sent(2.0, "decode", "d", 16, part=("sched:dispatch", 1.9))
+        led.done(2.1)
+        snap = self._snap(led, 2.1)
+        assert snap["busy_s"]["decode"] == pytest.approx(0.1) and snap["idle_s"] == {}
 
 
 class TestAlwaysOnProbes:
