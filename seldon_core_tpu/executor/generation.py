@@ -77,7 +77,7 @@ from seldon_core_tpu.obs.timeline import (
     EVENT_RESUME,
     EVENT_SUSPEND,
 )
-from seldon_core_tpu.ops.flash_attention import TILE_PLANS
+from seldon_core_tpu.ops.flash_attention import TILE_PLANS, admitted_tiles
 from seldon_core_tpu.ops.paged_attention import blocks_per_step
 from seldon_core_tpu.utils.tracectx import current_trace_id
 from seldon_core_tpu.parallel.sharding import (
@@ -1334,6 +1334,9 @@ class GenerativeModel:
         self.prefill_chunks = 0  # chunked-prefill chunk dispatches
         # tokens prefilled, the rungs they were padded to, dispatches a rung
         self.prefill_rows: dict = {"real": 0, "padded": 0, "by_rung": {}}
+        # of the whole prompts admitted: the tiled kernel's grid steps a head
+        # in their rungs, and those a prompt's real length left to multiply
+        self.prefill_tiles: dict = {"stepped": 0, "live": 0}
         self.imports = 0  # disagg KV handoffs imported into this pool
         # KV/HBM pool ledger (docs/OBSERVABILITY.md "generation forensics"):
         # high-water mark of blocks in use, and the byte classes the HBM
@@ -1811,6 +1814,12 @@ class GenerativeModel:
                     self._cache,
                 )
             self._count_prefill(payload)
+            if not self._in_warmup:
+                stepped, live = admitted_tiles(
+                    int(payload["padded"].shape[1]), int(payload["length"])
+                )
+                self.prefill_tiles["stepped"] += stepped
+                self.prefill_tiles["live"] += live
         return tok
 
     def reserve_blocks(self, slot: int, total_tokens: int) -> np.ndarray:
@@ -3071,8 +3080,13 @@ class GenerativeModel:
             "variant_seconds": dict(self.warmup_program_seconds),
             "recent_compiles": list(self._program_events),
             # the tiled prompt kernel's grid by traced shape (this process's
-            # calls): steps taken, tiles multiplied, tiles masked
-            "tile_plans": {k: dict(v) for k, v in TILE_PLANS.items()},
+            # calls): steps taken, tiles multiplied, tiles masked; and over
+            # the prompts admitted (warm-up's left out) the steps of their
+            # rungs and the tiles their real lengths left to multiply
+            "tile_plans": {
+                **{k: dict(v) for k, v in TILE_PLANS.items()},
+                "admitted": dict(self.prefill_tiles),
+            },
         }
 
     def spec_snapshot(self) -> dict:

@@ -533,7 +533,7 @@ def prefill_slot_paged(
             ck = paged.write_prompt(ck, li, phys, k, bs)
             cv = paged.write_prompt(cv, li, phys, v, bs)
             if seq_impl == "flash":
-                o = flash_prompt(q, k, v, window=window)
+                o = flash_prompt(q, k, v, window=window, length=length)
             else:
                 o = _attend(q, k, v, pos, pos, window)
             attn = _project_out(o, lp["wo"])

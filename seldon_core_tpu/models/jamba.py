@@ -687,7 +687,7 @@ def prefill_slot_paged(
         q, kk, v = _qkv(_rmsnorm(x, lp["ln1"], cfg.norm_eps), lp)
         # attend what the pool will hold: the rows as stored
         kk, v = kk.astype(no_rows.dtype), v.astype(no_rows.dtype)
-        o = _attend_prompt(q, kk, v, seq_impl)
+        o = _attend_prompt(q, kk, v, seq_impl, length=length)
         return (
             _after_mixer(x, _attn_out(o, lp), lp, cfg),
             kk.reshape(lp_, row), v.reshape(lp_, row),

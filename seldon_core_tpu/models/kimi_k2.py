@@ -404,10 +404,11 @@ def _prompt_score_dtype(cfg: Config):
     return jnp.bfloat16 if cfg.prompt_score_dtype == "bfloat16" else None
 
 
-def _attend_prompt(qn, qr, c, kr, lp, cfg: Config, seq_impl: str):
+def _attend_prompt(qn, qr, c, kr, lp, cfg: Config, seq_impl: str, length=None):
     """A whole prompt's own attention at positions ``0 .. L - 1`` in the
-    EXPANDED form: by the tiled Pallas kernel (``"flash"``) or in chunked
-    XLA.  -> (L, H, dv)."""
+    EXPANDED form: by the tiled Pallas kernel (``"flash"``; with the prompt's
+    real ``length`` it leaves out the rung's padded query tiles) or in
+    chunked XLA.  -> (L, H, dv)."""
     k, v = _expand(c, kr, lp, cfg)
     q = jnp.concatenate([qn, qr], axis=-1)
     L = q.shape[0]
@@ -415,7 +416,8 @@ def _attend_prompt(qn, qr, c, kr, lp, cfg: Config, seq_impl: str):
     with jax.named_scope("attn.prompt"):
         if seq_impl == "flash":
             return flash_prompt(
-                q, k, v, scale=cfg.softmax_scale, score_dtype=rounded
+                q, k, v, scale=cfg.softmax_scale, score_dtype=rounded,
+                length=length,
             )
         pos = jnp.arange(L)
         return _attend(
@@ -657,7 +659,8 @@ def prefill_slot_paged(
         ckr = _kr_write(ckr, li, phys, kr)
         # attend what the pool now holds: the latents as stored
         o = _attend_prompt(
-            qn, qr, c.astype(cc.dtype), kr.astype(ckr.dtype), lp, cfg, seq_impl
+            qn, qr, c.astype(cc.dtype), kr.astype(ckr.dtype), lp, cfg, seq_impl,
+            length=length,
         )
         x, ctr = _after_attention(
             x, o, lp, cfg, real, ctr, dense=dense, decode=False,

@@ -68,7 +68,9 @@ def flash_prompt(q, k, v, **kw):
     """A prompt's own causal attention through the tiled Pallas kernel
     (``ops/flash_attention.py``): q (L, H, D); k (L, KV, D), v (L, KV, Dv)
     at positions 0..L-1, keys read grouped -> (L, H, Dv).  ``kw``: the
-    kernel's ``window``, ``scale`` or ``score_dtype``."""
+    kernel's ``window``, ``scale``, ``score_dtype`` or ``length`` (the
+    prompt's real length in its rung: the query tiles wholly past it are not
+    computed and give zeros)."""
     from seldon_core_tpu.ops.flash_attention import flash_attention
 
     blk = min(512, q.shape[0])
@@ -79,16 +81,17 @@ def flash_prompt(q, k, v, **kw):
     return out[0].transpose(1, 0, 2)
 
 
-def attend_prompt(q, k, v, seq_impl: str):
+def attend_prompt(q, k, v, seq_impl: str, length=None):
     """A whole prompt's causal grouped-query attention under ``attn.prompt``:
     ``q (T, H, D)`` over ``k``, ``v (T, KV, D)`` at positions ``0..T-1``,
-    through the tiled kernel (``seq_impl="flash"``) or in plain XLA, scores
-    and softmax float32.  -> (T, H, D).  For a family whose keys need no
-    window and no selection (``jamba``, ``zaya``)."""
+    through the tiled kernel (``seq_impl="flash"``; with the prompt's real
+    ``length`` it leaves out the rung's padded query tiles) or in plain XLA,
+    scores and softmax float32.  -> (T, H, D).  For a family whose keys need
+    no window and no selection (``jamba``, ``zaya``)."""
     T, H, D = q.shape
     with jax.named_scope("attn.prompt"):
         if seq_impl == "flash":
-            return flash_prompt(q, k, v)
+            return flash_prompt(q, k, v, length=length)
         kv = k.shape[1]
         qg = q.reshape(T, kv, H // kv, D)
         s = jnp.einsum(

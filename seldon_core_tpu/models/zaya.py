@@ -708,7 +708,7 @@ def prefill_slot_paged(
         k, v = p["k"].astype(ck.dtype), p["v"].astype(cv.dtype)
         ck = paged.write_prompt(ck, li, phys, k, bs)
         cv = paged.write_prompt(cv, li, phys, v, bs)
-        o = _attend_prompt(p["q"], k, v.reshape(k.shape), seq_impl)
+        o = _attend_prompt(p["q"], k, v.reshape(k.shape), seq_impl, length=length)
         x = _merge(x, _cca_out(o, lp), lp["res_a"])
         tails = {
             # a row a tap (models/jamba.py::prefill_slot_paged says why)

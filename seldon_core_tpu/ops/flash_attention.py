@@ -24,6 +24,18 @@ select); a tile wholly inside runs the products and the softmax alone.
 :func:`tile_plan` counts the three kinds: a prompt of 12,288 at 512 x 512
 steps 300 tiles of the square's 576 and masks 24 of them.
 
+**A prompt's tiles end at its real length** (PR 58).  A prompt runs in a rung
+of the ladder, its tail padding; a caller that knows the ``length`` hands it
+over (a traced scalar), and :func:`_steps_at` makes the three prefetched
+lists from the static ones and it: a step whose query tile starts at or past
+``length`` is no longer live, and its key tile is the one the step before it
+left in VMEM, so it multiplies nothing and copies nothing.  Such a tile still
+starts and still writes its zeros, as a tile that sees no key does (a later
+layer writes those rows' K/V into the slot's last block, and the decode read
+multiplies ``0 x V`` over that block's unseen rows: they have to be finite).
+The grid, the body and so the compiled kernel are what they are without a
+``length``; 8,704 tokens in the 12,288 rung multiply 153 of its 300 tiles.
+
 The running max and denominator are ``(block_q, 128)`` float32 with every lane
 of a row the same, and stay two-dimensional from the scores' reduction to the
 rescale: whole vregs in and out.  Cutting a one-lane column out of them and
@@ -69,6 +81,9 @@ _FIRST, _LAST, _LIVE, _MASKED = 1, 2, 4, 8
 # the tile plans of the calls traced in this process, by shape: what
 # ``breakdown.generation.<unit>.programs.tile_plans`` shows
 TILE_PLANS: dict[str, dict[str, int]] = {}
+# of those calls, the ones handed a prompt's real length: the arguments
+# :func:`tile_plan` counts their live tiles from at any length
+FOLLOWS_LENGTH: set[tuple] = set()
 
 
 @functools.lru_cache(maxsize=None)
@@ -104,16 +119,55 @@ def _tile_steps(S, Sk, block_q, block_k, causal, window, q_offset=0):
     return tuple(np.asarray(a, np.int32) for a in (q_of, k_of, kind))
 
 
-def tile_plan(S, Sk, block_q, block_k, causal=True, window=None, q_offset=0):
+def _past(q_of, block_q, length):
+    """Which steps' query tiles start at or past ``length``."""
+    return q_of * block_q >= length
+
+
+def tile_plan(S, Sk, block_q, block_k, causal=True, window=None, q_offset=0,
+              length=None):
     """``(stepped, live, masked)``: the grid steps a head takes, those that
     run the products, and those of them that build a mask — static in the
-    shapes, and what the kernel's grid and bodies are made from."""
-    kind = _tile_steps(S, Sk, block_q, block_k, causal, window, q_offset)[2]
+    shapes, and what the kernel's grid and bodies are made from.  With a
+    prompt's real ``length`` the last two are what :func:`_steps_at` leaves
+    of them: the steps of the query tiles that start before ``length``."""
+    q_of, _, kind = _tile_steps(S, Sk, block_q, block_k, causal, window, q_offset)
+    if length is not None:
+        kind = np.where(_past(q_of, block_q, length), kind & (_FIRST | _LAST), kind)
     return (
         len(kind),
         int(np.count_nonzero(kind & _LIVE)),
         int(np.count_nonzero(kind & _MASKED)),
     )
+
+
+def _steps_at(steps, block_q, length):
+    """The prefetched lists for a prompt ``length`` tokens long in its rung
+    (a traced int32 scalar; None: the static lists).  A step whose query tile
+    starts at or past ``length`` keeps its ``_FIRST`` / ``_LAST`` bits alone,
+    so it runs no product and its tile still writes zeros; such steps are the
+    list's tail, and their key tile is the last live step's, so that the
+    pipeline finds the block it holds and copies nothing."""
+    q_of, k_of, kind = (jnp.asarray(a) for a in steps)
+    if length is None:
+        return q_of, k_of, kind
+    dead = _past(q_of, block_q, length)
+    held = k_of[jnp.maximum(jnp.sum(~dead) - 1, 0)]
+    return (
+        q_of,
+        jnp.where(dead, held, k_of),
+        jnp.where(dead, kind & (_FIRST | _LAST), kind),
+    )
+
+
+def admitted_tiles(S, length):
+    """``(stepped, live)`` a head, summed over the calls traced in this
+    process with ``S`` query rows and a ``length`` to follow, for a prompt of
+    ``length`` tokens: what the engine adds up as it admits one."""
+    plans = [
+        tile_plan(*args, length=length) for args in FOLLOWS_LENGTH if args[0] == S
+    ]
+    return sum(p[0] for p in plans), sum(p[1] for p in plans)
 
 
 def _lanes(x, n):
@@ -200,6 +254,7 @@ def flash_attention(
     window: int | None = None,
     scale: float | None = None,
     score_dtype=None,
+    length: jax.Array | None = None,
 ) -> jax.Array:
     """``(B, H, S, D)`` attention; blocks clamp to S and must divide it.
     ``window`` (static, causal only) keeps keys ``j > i - window``;
@@ -209,7 +264,12 @@ def flash_attention(
     output is ``(B, H, S, Dv)``; ``scale`` (static) is the softmax scale,
     ``D ** -0.5`` unless given.  ``score_dtype`` (static; a negative control,
     never served) rounds a tile's scores to that type as they leave the MXU;
-    unset, they stay float32.  A row that sees no key gives zeros."""
+    unset, they stay float32.  A row that sees no key gives zeros.
+    ``length`` (a traced int32 scalar) says that only the first ``length``
+    query rows are a prompt's and the rest its rung's padding: a query tile
+    that starts at or past it runs no product, fetches no key and gives
+    zeros; every row before it, and the rest of the tile that straddles it,
+    is what it is without."""
     B, H, S, D = q.shape
     Hk, Sk = k.shape[1], k.shape[2]
     Dv = v.shape[3]
@@ -241,6 +301,8 @@ def flash_attention(
     TILE_PLANS[f"S{S}:Sk{Sk}:{block_q}x{block_k}:w{window}"] = {
         "stepped": stepped, "live": live, "masked": masked,
     }
+    if length is not None:
+        FOLLOWS_LENGTH.add((S, Sk, block_q, block_k, causal, window))
     log.info(
         "flash_attention S=%d Sk=%d H=%d D=%d Dv=%d tile %dx%d window=%s: "
         "tile plan stepped=%d live=%d masked=%d of %d",
@@ -279,7 +341,7 @@ def flash_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
         interpret=interpret,
-    )(*(jnp.asarray(a) for a in steps), q, k, v)
+    )(*_steps_at(steps, block_q, length), q, k, v)
 
 
 def _fit_block(s: int, preferred: int = 128) -> int:

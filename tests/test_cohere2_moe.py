@@ -671,6 +671,60 @@ class TestServedPath:
         assert self._component(lora_rank=4).model.lora_rank == 0
 
 
+class TestAPromptsRealLength:
+    """PR 58: the tiled kernel is handed the prompt's real length (with the
+    layer's window), and the query tiles of the rung's padding are not
+    computed."""
+
+    @pytest.mark.parametrize("length", [
+        600,     # the second tile of 512 straddles it; the third is dead
+        1024,    # on a tile's edge: a decode step's block is the dead tile's first
+    ])
+    def test_the_real_rows_are_what_they_were(self, length, monkeypatch):
+        from seldon_core_tpu.models import layers
+
+        cfg = m.Config.tiny(max_seq=2048, experts_held="4:8")
+        bs, rung = 64, 1536
+        params = _params(cfg)
+        tokens = np.zeros((1, rung), np.int32)
+        tokens[0, :length] = np.random.default_rng(length).integers(1, 256, length)
+        row = np.zeros(cfg.max_seq // bs, np.int32)
+        row[: rung // bs + 1] = np.arange(1, rung // bs + 2)[::-1]
+
+        def prefill():
+            cache = m.init_paged_cache(cfg, 2, 40, bs, jnp.float32)
+            return jax.jit(functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"))(
+                params, jnp.asarray(tokens), jnp.int32(length), jnp.int32(1),
+                jnp.asarray(row), cache,
+            )
+
+        logits, cache = prefill()
+        monkeypatch.setattr(  # the parent's program: the kernel never hears of the length
+            m, "flash_prompt",
+            lambda q, k, v, **kw: layers.flash_prompt(q, k, v, **{**kw, "length": None}),
+        )
+        want_logits, want = prefill()
+        assert np.array_equal(np.asarray(logits), np.asarray(want_logits))
+        edge = -(-length // 512) * 512
+        for name in ("k", "v"):
+            got, was = (  # (layers, rung rows, values)
+                np.asarray(c[name])[:, row[: rung // bs]].reshape(cfg.n_layers, rung, -1)
+                for c in (cache, want)
+            )
+            assert np.array_equal(got[:, :edge], was[:, :edge])
+            assert np.isfinite(got).all()
+            # the dead tile's rows did change after the first layer: it engaged
+            assert np.array_equal(got[0], was[0]) and not np.array_equal(got[1:], was[1:])
+        # a decode step over the slot's last, partly padded block is finite
+        lg, _ = jax.jit(functools.partial(
+            m.decode_slots_paged, cfg=cfg, window=cfg.max_seq, kernel=True
+        ))(
+            params, jnp.asarray([0, int(np.argmax(logits))], jnp.int32), cache,
+            jnp.asarray([False, True]),
+        )
+        assert np.isfinite(np.asarray(lg[1])).all()
+
+
 class TestEngineRoutes:
     """``examples/cohere2-moe-generative/graph.json`` through the engine's
     own app: ``/predictions`` and ``/predictions/stream`` give the same

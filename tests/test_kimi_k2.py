@@ -520,7 +520,12 @@ class TestServedPath:
                 assert plans[f"S{b}:Sk{b}:{b}x{b}:wNone"] == {
                     "stepped": 1, "live": 1, "masked": 1,
                 }
+            assert plans["admitted"] == {"stepped": 0, "live": 0}  # not warm-up's
         tok = model.admit(0, prompt.astype(np.int32), 0.0, 0, reserve_tokens=12)
+        if seq_impl == "flash":  # the prompt's one tile, live
+            assert model.program_snapshot()["tile_plans"]["admitted"] == {
+                "stepped": 1, "live": 1,
+            }
         cur, active = np.zeros(2, np.int32), np.zeros(2, bool)
         cur[0], active[0] = int(tok), True
         toks, emitted = model.step_k(
@@ -604,6 +609,78 @@ class TestServedPath:
         per_token = cfg.n_layers * 2 * cfg.n_kv_heads * (cfg.hidden // cfg.n_heads) * 4
         assert model.kv_bytes_per_block() == 4 * per_token
         assert model.pool_snapshot()["bytes"]["kv_pool"] == model.kv_blocks * 4 * per_token
+
+
+class TestAPromptsRealLength:
+    """PR 58: the tiled kernel is handed the prompt's real length, and the
+    query tiles of the rung's padding are not computed."""
+
+    @pytest.mark.parametrize("length", [
+        600,     # the second tile of 512 straddles it; the third is dead
+        1024,    # on a tile's edge: a decode step's block is the dead tile's first
+    ])
+    def test_the_real_rows_are_what_they_were(self, length, monkeypatch):
+        from seldon_core_tpu.models import layers
+
+        cfg, bs, rung = m.Config.tiny(max_seq=2048), 64, 1536
+        params = _params(cfg)
+        tokens = np.zeros((1, rung), np.int32)
+        tokens[0, :length] = np.random.default_rng(length).integers(1, 256, length)
+        row = np.zeros(cfg.max_seq // bs, np.int32)
+        row[: rung // bs + 1] = np.arange(1, rung // bs + 2)[::-1]
+
+        def prefill():
+            cache = m.init_paged_cache(cfg, 2, 40, bs, jnp.float32)
+            return jax.jit(functools.partial(m.prefill_slot_paged, cfg=cfg, seq_impl="flash"))(
+                params, jnp.asarray(tokens), jnp.int32(length), jnp.int32(1),
+                jnp.asarray(row), cache,
+            )
+
+        logits, cache = prefill()
+        monkeypatch.setattr(  # the parent's program: the kernel never hears of the length
+            m, "flash_prompt",
+            lambda q, k, v, **kw: layers.flash_prompt(q, k, v, **{**kw, "length": None}),
+        )
+        want_logits, want = prefill()
+        assert np.array_equal(np.asarray(logits), np.asarray(want_logits))
+        edge = -(-length // 512) * 512
+        for name in m.POOL_ARRAYS:
+            def by_token(c):  # (layers, rung rows, values)
+                a = np.asarray(m._kr_by_token(c[name]) if name == "kr" else c[name])
+                return a[:, row[: rung // bs]].reshape(cfg.n_layers, rung, -1)
+
+            got, was = by_token(cache), by_token(want)
+            assert np.array_equal(got[:, :edge], was[:, :edge])
+            assert np.isfinite(got).all()
+            # the dead tile's rows did change after the first layer: it engaged
+            assert np.array_equal(got[0], was[0]) and not np.array_equal(got[1:], was[1:])
+        # a decode step over the slot's last, partly padded block is finite
+        lg, _ = _jitted(cfg, "decode", window=cfg.max_seq, kernel=True)(
+            params, jnp.asarray([0, int(np.argmax(logits))], jnp.int32), cache,
+            jnp.asarray([False, True]),
+        )
+        assert np.isfinite(np.asarray(lg[1])).all()
+
+    def test_the_engine_adds_up_the_tiles_of_what_it_admits(self):
+        from seldon_core_tpu.models.registry import build_generative_component
+        from seldon_core_tpu.ops.flash_attention import tile_plan
+
+        model = build_generative_component(
+            "kimi_k2", preset="tiny", max_seq=2048, n_slots=2, decode_block=4,
+            kv_block_size=64, dtype=jnp.bfloat16, rng=5, seq_impl="flash",
+        ).model
+        assert model.program_snapshot()["tile_plans"]["admitted"] == {"stepped": 0, "live": 0}
+        rng = np.random.default_rng(2)
+        for slot, n in enumerate((1100, 300)):   # the rungs 2,048 and 512
+            model.admit(slot, rng.integers(1, 256, n).astype(np.int32), 0.0, 0, reserve_tokens=8)
+        plans = model.program_snapshot()["tile_plans"]
+        assert plans["S2048:Sk2048:512x512:wNone"] == {"stepped": 10, "live": 10, "masked": 4}
+        # kimi_k2's prompt program traces the kernel at two call sites of one
+        # shape (the dense layer and the expert layers' scan): one plan
+        a = tile_plan(2048, 2048, 512, 512, length=1100)
+        b = tile_plan(512, 512, 512, 512, length=300)
+        assert (a[:2], b[:2]) == ((10, 6), (1, 1))
+        assert plans["admitted"] == {"stepped": a[0] + b[0], "live": a[1] + b[1]}
 
 
 class TestEngineRoutes:

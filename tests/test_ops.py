@@ -311,6 +311,88 @@ class TestFlashTilePlan:
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
         assert not out[:, :, 159:].any() and out[:, :, :159].any(axis=-1).all()
 
+    @pytest.mark.parametrize("S,length,window", [
+        (2048, 2048, None),    # the rung full: no tile is dead
+        (2048, 1025, None),    # one token into the third tile: it straddles, and is whole
+        (2048, 1024, None),    # on a tile's edge
+        (2048, 1, None),       # one live tile
+        (6144, 4100, 4096),    # Command A+'s rung, windowed: dead tiles behind a window
+        (1536, 700, None),
+    ])
+    def test_a_prompts_tiles_end_at_its_real_length(self, S, length, window):
+        """PR 58: with the prompt's ``length`` (traced) a query tile that
+        starts at or past it runs no product, fetches no key and writes
+        zeros; every row before it, and the rest of the tile that straddles
+        it, is bit for bit what it is without, whatever the padding holds."""
+        from seldon_core_tpu.ops.flash_attention import (
+            _FIRST, _LAST, _LIVE, _MASKED, _steps_at, _tile_steps, tile_plan,
+        )
+
+        rng = np.random.default_rng(S + length)
+        shape = (1, 1, S, 16)
+        q, k, v = (jnp.asarray(rng.normal(size=shape), jnp.float32) for _ in range(3))
+        kw = dict(block_q=512, block_k=512, window=window)
+        want = np.asarray(flash_attention(q, k, v, **kw))
+        at = jax.jit(lambda q, k, v, n: flash_attention(q, k, v, length=n, **kw))
+        got = np.asarray(at(q, k, v, jnp.int32(length)))
+        edge = -(-length // 512) * 512   # where the first dead tile starts
+        assert np.array_equal(got[:, :, :edge], want[:, :, :edge])
+        assert not got[:, :, edge:].any()
+        # garbage past the length (large, finite) changes no row before it
+        junk = jnp.where(jnp.arange(S)[None, None, :, None] >= length, 3e4, 0.0)
+        dirty = np.asarray(at(q + junk, k - junk, v + junk, jnp.int32(length)))
+        assert np.array_equal(dirty[:, :, :length], want[:, :, :length])
+        assert np.isfinite(dirty).all() and not dirty[:, :, edge:].any()
+        # the plan counts what the kernel's lists hold
+        static = _tile_steps(S, S, 512, 512, True, window)
+        q_of, k_of, kind = (np.asarray(a) for a in _steps_at(static, 512, jnp.int32(length)))
+        dead = q_of * 512 >= length
+        assert np.array_equal(q_of, static[0])
+        assert np.array_equal(k_of[~dead], static[1][~dead])
+        assert np.array_equal(kind[~dead], static[2][~dead])
+        assert not (kind[dead] & (_LIVE | _MASKED)).any()
+        assert np.array_equal(kind[dead], static[2][dead] & (_FIRST | _LAST))
+        assert tile_plan(S, S, 512, 512, True, window, length=length) == (
+            len(kind), int(np.count_nonzero(kind & _LIVE)),
+            int(np.count_nonzero(kind & _MASKED)),
+        )
+        assert tile_plan(S, S, 512, 512, True, window, length=S) == tile_plan(
+            S, S, 512, 512, True, window
+        )
+        # a dead step names the key tile the step before it left in VMEM
+        for t in np.flatnonzero(dead):
+            assert k_of[t] == (k_of[t - 1] if t else 0)
+        assert dead.sum() == 0 or dead[np.flatnonzero(dead)[0]:].all()  # the list's tail
+
+    def test_an_admission_counts_the_rungs_plans_at_the_prompts_length(self):
+        """``admitted_tiles``: what the engine adds up a prompt — over the
+        calls traced at the rung WITH a length (Command A+'s two: the windowed
+        layers' and the full layer's), not those without; Kimi-K2.6's 8,704
+        tokens in the 12,288 rung multiply 153 of 300 tiles."""
+        from seldon_core_tpu.ops.flash_attention import admitted_tiles
+
+        def trace(S, heads, window, follows):
+            sds = jax.ShapeDtypeStruct((1, heads, S, 16), jnp.float32)
+            n = [jax.ShapeDtypeStruct((), jnp.int32)] if follows else []
+            jax.eval_shape(
+                lambda q, k, v, *n: flash_attention(
+                    q, k, v, block_q=512, block_k=512, window=window,
+                    length=n[0] if n else None,
+                ),
+                sds, sds, sds, *n,
+            )
+
+        assert admitted_tiles(12800, 9000) == (0, 0)   # no call traced at such a rung
+        trace(12288, 3, None, True)
+        trace(12288, 5, None, True)    # the same plan from another call site: once
+        trace(8192, 3, None, False)    # the rung alone: nothing to follow
+        trace(6144, 3, None, True)
+        trace(6144, 3, 4096, True)
+        assert admitted_tiles(12288, 8704) == (300, 153)
+        assert admitted_tiles(12288, 12288) == (300, 300)
+        assert admitted_tiles(8192, 7000) == (0, 0)
+        assert admitted_tiles(6144, 4576) == (78 + 72, 45 + 45)
+
 
 class TestFlashBlhdAdapter:
     """Direct unit coverage for ``flash_causal_attention_blhd`` — the

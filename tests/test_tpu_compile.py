@@ -589,10 +589,12 @@ def test_command_a_plus_prompt_program_reads_its_experts_in_place(one_chip, monk
     assert compiled.memory_analysis().temp_size_in_bytes < 1.44e9
 
 
-def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip):
+@pytest.mark.parametrize("follows", [False, True], ids=["the rung", "the prompt's length"])
+def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip, follows):
     """A prompt's expanded latent attention at the 12,288 rung: 64 heads,
     keys 192 wide (128 + the 64-wide rotary key) under values 128 wide, the
-    softmax scale stated."""
+    softmax scale stated; and with the prompt's real length, a traced scalar
+    that the three prefetched lists are made from (PR 58)."""
     import jax
     import jax.numpy as jnp
 
@@ -601,13 +603,14 @@ def test_the_tiled_kernel_compiles_at_kimi_k2s_widths(one_chip):
     def sds(width):
         return jax.ShapeDtypeStruct((1, 64, 12288, width), jnp.bfloat16, sharding=one_chip)
 
-    def f(q, k, v):
+    def f(q, k, v, *length):
         return flash_attention(
             q, k, v, causal=True, block_q=512, block_k=512, scale=0.14468,
-            interpret=False,
+            interpret=False, length=length[0] if follows else None,
         )
 
-    compiled = jax.jit(f).lower(sds(192), sds(192), sds(128)).compile()
+    length = [jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)] if follows else []
+    compiled = jax.jit(f).lower(sds(192), sds(192), sds(128), *length).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
